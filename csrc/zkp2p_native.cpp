@@ -1841,8 +1841,8 @@ static void fr_ntt_soa_stages(u64 *soa, long m, const u64 root_std[4], int nt) {
   // passes or 1.5 radix-4 passes, but ONE load/store trip over the SoA
   // planes, and every mul paired with an independent partner through
   // mont52_mul8x2 so the serial madd52 recurrences overlap.  The fused
-  // ladder at 2^19 is compute-bound on exactly those chains (NEXT.md
-  // lever 2).  Twiddle indexing per element s of the 8q block
+  // ladder at 2^19 is compute-bound on exactly those chains.  Twiddle
+  // indexing per element s of the 8q block
   // (q = len/2): stage len pairs (2t, 2t+1) ×w1[j]; stage 2len pairs
   // (4t+s, 4t+s+2) ×w2[j+s·q]; stage 4len pairs (s, s+4) ×w3[j+s·q].
   // The op sequence per element is exactly the radix-2 decomposition,
@@ -3656,7 +3656,7 @@ void fr_from_mont_batch(const u64 *in, u64 *out, long n) {
   for (long i = 0; i < n; ++i) fr_mul(out + 4 * i, in + 4 * i, ONE_STD);
 }
 // In-place x mod r for n rows of 4 u64, any x < 2^256.  The witness
-// conversion hot loop (docs/NEXT.md lever 3): Python now serializes raw
+// conversion hot loop: Python now serializes raw
 // int bytes and this replaces the per-element bigint `w % R`.  Since
 // 2^256 / r ~ 5.3 the loop runs at most 5 conditional subtracts, and
 // the common already-reduced row exits on the first compare — the pass

@@ -1,15 +1,14 @@
 """The typed prover config (SURVEY.md §5: one config, env as override).
 
-Pins the resolution order (default -> armed flags -> env), provenance
-labeling, the armable-knob whitelist, and — via a source scan — that
-every ZKP2P_* variable read anywhere in the tree is registered in the
-config's knob table (no knob may bypass the single source of truth)."""
+Pins the resolution order (default -> env), provenance labeling, and —
+via a source scan — that every ZKP2P_* variable read anywhere in the
+tree is registered in the config's knob table (no knob may bypass the
+single source of truth)."""
 
-import json
 import os
 import re
 
-from zkp2p_tpu.utils.config import ARMABLE, KNOBS, ProverConfig, load_config
+from zkp2p_tpu.utils.config import ARMABLE, KNOBS, load_config
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -103,7 +102,6 @@ def test_env_overrides_every_knob():
         "ZKP2P_TUNE_ARMS": "geometry,columns",
         "ZKP2P_TPU_SHARD": "on",
         "ZKP2P_TPU_MESH": "2x4",
-        "ZKP2P_JAX_CACHE_DIR": "/tmp/jaxcache",
         "ZKP2P_WORKER_TIER": "sharded",
         "ZKP2P_PERF_LEDGER": "0",
         "ZKP2P_PERF_TOLERANCE": "2.25",
@@ -155,7 +153,6 @@ def test_env_overrides_every_knob():
     assert cfg.profile is False and cfg.profile_path == "/tmp/prof.json"
     assert cfg.tune_budget_s == 45.0 and cfg.tune_arms == "geometry,columns"
     assert cfg.tpu_shard == "on" and cfg.tpu_mesh == "2x4"
-    assert cfg.jax_cache_dir == "/tmp/jaxcache"
     assert cfg.worker_tier == "sharded"
     assert cfg.perf_ledger is False and cfg.perf_tolerance == 2.25
     assert cfg.perf_window == 12
@@ -295,31 +292,25 @@ def test_reader_matched_parsers():
     assert load_config(environ={"ZKP2P_FLAME_COOLDOWN_S": "junk"}).flame_cooldown_s == 60.0
 
 
-def test_armed_flags_whitelist_and_precedence(tmp_path):
-    p = tmp_path / "armed_flags.json"
-    p.write_text(json.dumps({
-        "ZKP2P_MSM_AFFINE": True,
-        "ZKP2P_MSM_H": "bucket",
-        "ZKP2P_MSM_WINDOW": "16",   # NOT armable: must be ignored
-        "ZKP2P_NATIVE_IFMA": "0",   # NOT armable: must be ignored
-    }))
-    msgs = []
-    cfg = load_config(environ={}, armed_flags_path=str(p), log=msgs.append)
-    assert cfg.msm_affine == "1" and cfg.provenance["msm_affine"] == "armed"
-    assert cfg.msm_h == "bucket" and cfg.provenance["msm_h"] == "armed"
-    assert cfg.msm_window == 4 and cfg.provenance["msm_window"] == "default"
-    assert cfg.native_ifma is True
-    assert sum("non-armable" in m for m in msgs) == 2
-    # explicit env beats armed
-    cfg2 = load_config(environ={"ZKP2P_MSM_H": "windowed"}, armed_flags_path=str(p))
-    assert cfg2.msm_h == "windowed" and cfg2.provenance["msm_h"] == "env"
+def test_env_is_the_only_layer_above_defaults():
+    """default -> env, nothing between: no side file can flip a knob
+    (the hardware-session armed_flags.json layer is gone), and the
+    provenance map only ever says "default" or "env"."""
+    import inspect
+
+    assert list(inspect.signature(load_config).parameters) == ["environ"]
+    cfg = load_config(environ={"ZKP2P_MSM_H": "bucket"})
+    assert cfg.msm_h == "bucket" and cfg.provenance["msm_h"] == "env"
+    assert cfg.msm_affine == "0" and cfg.provenance["msm_affine"] == "default"
+    assert set(cfg.provenance.values()) == {"default", "env"}
 
 
-def test_corrupt_armed_flags_never_fatal(tmp_path):
-    p = tmp_path / "armed_flags.json"
-    p.write_text("{not json")
-    cfg = load_config(environ={}, armed_flags_path=str(p))
-    assert cfg == ProverConfig(provenance=cfg.provenance)
+def test_compile_cache_is_not_a_knob():
+    """The compile cache is placed by the standard
+    JAX_COMPILATION_CACHE_DIR (utils.jaxcfg), not by a ZKP2P_* knob."""
+    assert "jax_cache_dir" not in KNOBS
+    assert not any(var == "ZKP2P_JAX_CACHE_DIR" for var, _p, _d in KNOBS.values())
+    assert load_config(environ={"ZKP2P_JAX_CACHE_DIR": "/tmp/x"}) == load_config(environ={})
 
 
 def test_apply_env_roundtrip():

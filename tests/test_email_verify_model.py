@@ -29,7 +29,7 @@ def test_email_verify_twitter_end_to_end():
 
 @pytest.mark.slow
 def test_email_verify_body_hash_idx_cannot_point_elsewhere():
-    """Soundness regression (VERDICT r2, high): body_hash_idx must be tied
+    """Soundness regression: body_hash_idx must be tied
     to the bh= regex match — same attack as the venmo model's
     test_body_hash_idx_cannot_point_elsewhere.  The shift consumes the
     regex reveal mask (zero outside the match), so pointing the idx at

@@ -102,7 +102,7 @@ def test_flame_on_off_is_digest_distinguishable(monkeypatch):
 
 
 def test_preflight_arms_flame_gate():
-    rep = audit.preflight(probe=False, workload=False)
+    rep = audit.preflight(workload=False)
     assert rep["gates"].get("flame") == "off"  # default: fully off
 
 

@@ -427,7 +427,7 @@ def test_key_hash_partitions_cache(monkeypatch, toy_dpk):
 
 
 def test_witness_reduce_native_matches_python():
-    """The native fr_reduce_batch path (docs/NEXT.md lever 3) == the
+    """The native fr_reduce_batch path == the
     Python `w % R` loop, including >= r values and the big-int
     fallback for negatives."""
     from zkp2p_tpu.prover.native_prove import _lib, _witness_std_u64

@@ -48,8 +48,8 @@ def test_pod_mesh_shapes():
 
 
 def test_pod_mesh_dcn_collective():
-    """A REAL collective across the dcn axis (not just mesh shapes,
-    VERDICT r3 weakness 7): proof-batch data parallelism psums partial
+    """A REAL collective across the dcn axis (not just mesh shapes):
+    proof-batch data parallelism psums partial
     results over `dcn` while the inner `shard` axis stays live — the
     cross-slice reduction `make_pod_mesh` exists to carry."""
     import jax
@@ -62,8 +62,6 @@ def test_pod_mesh_dcn_collective():
     x = jnp.arange(2 * 4 * 3, dtype=jnp.float32).reshape(2, 4, 3)
     xs = jax.device_put(x, NamedSharding(mesh, P("dcn", "shard", None)))
 
-    from jax.experimental.shard_map import shard_map
-
     @jax.jit
     def step(v):
         # per-(dcn, shard) partial -> sum over BOTH axes via two psums:
@@ -73,7 +71,7 @@ def test_pod_mesh_dcn_collective():
             ici = jax.lax.psum(local, "shard")
             return jax.lax.psum(ici, "dcn")[None, None]
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh, in_specs=P("dcn", "shard", None), out_specs=P("dcn", "shard")
         )(v)
 

@@ -139,7 +139,7 @@ def test_batched_service_with_native_prover(batched_world, tmp_path):
 
 
 def test_service_restart_resumes_where_it_stopped(batched_world, tmp_path):
-    """Crash-recovery semantics (VERDICT r3 weakness 8): the spool IS the
+    """Crash-recovery semantics: the spool IS the
     durable state — a sweep after a 'crash' (simulated by deleting one
     result, as if the process died before emitting it) reprocesses ONLY
     the unfinished request."""
@@ -167,7 +167,7 @@ def test_crash_recovery_restart_completes(world, tmp_path):
     """A worker that dies mid-sweep (simulated KeyboardInterrupt in the
     prover) leaves bare .req.json files and stale claims; a restarted
     sweep with a healthy prover takes them over and finishes every
-    request exactly once (VERDICT r3 weak #8)."""
+    request exactly once."""
     from zkp2p_tpu.prover.native_prove import prove_native
 
     spool = str(tmp_path)

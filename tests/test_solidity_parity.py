@@ -115,7 +115,7 @@ def _verifying_key_constants(sol: str):
     reason="reference checkout not available",
 )
 def test_reference_vkey_golden_constants():
-    """Golden comparison against a REAL snarkjs export (VERDICT r3 #6):
+    """Golden comparison against a REAL snarkjs export:
     feed the reference's shipped verification key (app/src/helpers/vkey.ts)
     through our exporter and require every constant embedded in the
     generated contract — alfa1, beta2/gamma2/delta2 with snarkjs's

@@ -116,7 +116,7 @@ def test_witness_batch_16_emails_bit_exact():
 
 @pytest.mark.slow
 def test_witness_batch_16_emails_amortizes():
-    """VERDICT r3 #5 acceptance: 16 venmo-mini witnesses in ≤2x the
+    """Acceptance: 16 venmo-mini witnesses in ≤2x the
     single-witness wall time (block-level SHA/DFA/packing hooks; measured
     2.2x on the 1-core host, 5.5x per-witness amortization)."""
     cs, batch = _mini_venmo_batch(16)

@@ -104,7 +104,7 @@ def test_zkey_width_inference(tmp_path):
     identically through the narrow-classed native path, and a witness
     violating an inferred bound is rejected instead of silently proving
     wrong (the zkey has no C matrix, so x*(x-1)=y is indistinguishable
-    from a bit row at import time — VERDICT r4 weak #5)."""
+    from a bit row at import time)."""
     import numpy as np
 
     from zkp2p_tpu.gadgets.core import num2bits

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Prove the FULL-SIZE flagship (P2POnrampVerify 1024/6400: 4.94 M
-constraints, domain 2^23) ON THE REAL TPU CHIP — VERDICT r4 next #4.
+constraints, domain 2^23) ON THE REAL TPU CHIP.
 
 Loads the device key + witness that tools/prove_fullsize_native.py
 cached under .bench_cache/ (run it first on CPU; ~15 min setup), pushes

@@ -17,7 +17,7 @@ Rules (historical bugs they encode — docs/STATIC_ANALYSIS.md):
                   before the config package may import).  Writes are
                   the TRANSPORT (apply_env contract) and stay legal
                   everywhere.  A scattered read bypasses the
-                  default->armed->env resolution order and the
+                  default->env resolution order and the
                   provenance record.
 """
 
@@ -96,8 +96,8 @@ def check(tree: Tree) -> List[Finding]:
             findings.append(Finding(
                 "env-read", sf.relpath, node.lineno,
                 f"raw os.environ read of {var} outside the sanctioned fresh-read "
-                "sites — resolve through utils.config.load_config() so armed flags "
-                "and provenance apply",
+                "sites — resolve through utils.config.load_config() so the "
+                "resolution order and provenance apply",
             ))
     return findings
 

@@ -17,7 +17,7 @@ Prints per-stage rates: batched add_mixed (the MSM inner op), and a full
 G1 msm_windowed at the requested size.
 
 `--native` benches the C++ Pippenger tier (csrc zkp2p_native) instead of
-the JAX path — the arm the tunnel-down bench actually runs.  The
+the JAX path — the native prover's arm (oracle and `cpu` row).  The
 batch-affine bucket knob is A/B-able there:
 
   python tools/msm_hwbench.py --native --n 524288 --glv --batch-affine
@@ -92,7 +92,7 @@ def _native_bench(args):
     from zkp2p_tpu.prover.native_prove import _n_threads
 
     # the PROVER's thread resolution (env else core count), so the bench
-    # measures the arm the tunnel-down bench actually runs; pin
+    # measures the arm the native prover actually runs; pin
     # ZKP2P_NATIVE_THREADS=1 for single-worker microbenches
     threads = _n_threads()
     if args.window is not None and args.window <= 0:

@@ -684,7 +684,6 @@ def _runs_detail(
                 "knobs": m.get("knobs", {}),
                 "gates": m.get("gates", {}),
                 "execution_digest": m.get("execution_digest"),
-                "tpu_probe": m.get("tpu_probe"),
                 # fixed-base table accounting (family geometry + resident
                 # bytes + built-vs-cache provenance) — so a cold start's
                 # precomp_build cost in the stage table is attributable

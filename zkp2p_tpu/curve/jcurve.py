@@ -23,8 +23,6 @@ from __future__ import annotations
 from typing import List, Sequence, Tuple
 
 import jax
-
-from ..utils.jaxcfg import on_tpu as _on_tpu
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,14 +38,13 @@ AffPoint = Tuple[jnp.ndarray, jnp.ndarray]
 
 
 
-# Curve-op implementation selector: "auto" (default — pallas on a real
-# TPU backend, xla elsewhere), "xla" (force the packed-mul formulas
-# below), or "pallas" (force ops.pallas_curve where the backend allows).
+# Curve-op implementation selector: "auto"/"pallas" (ops.pallas_curve on
+# a TPU, xla elsewhere) or "xla" (force the packed-mul formulas below).
 # The pallas kernels collapse the ~8 kernel launches + HBM round-trips
-# per point add into one VMEM-resident kernel; measured on a v5e chip
-# (r4): 17.7 M G1 add_mixed/s vs 0.65 M for the XLA path (27x), MSM
-# 0.150 M pts/s vs 0.009 (16.7x) — see docs/ROOFLINE.md.
+# per point add into one VMEM-resident kernel; the builders' isolated
+# kernel timings are in docs/ROOFLINE.md.
 from ..utils.config import load_config as _load_config
+from ..utils.jaxcfg import on_tpu as _on_tpu
 
 CURVE_IMPL = _load_config().curve_kernel
 
@@ -60,9 +57,8 @@ class JCurve:
 
     def _pallas(self) -> bool:
         """Route through ops.pallas_curve?  Decided at trace time (static
-        under jit).  TPU only: on other backends the kernels would run in
-        interpret mode, which is orders of magnitude slower than the XLA
-        path (the differential tests call the kernels directly with
+        under jit).  TPU only: the kernels are compiled for the chip or
+        not used (the differential tests call them directly with
         interpret=True instead).  Reports its arm to the execution audit
         (trace-time record: the arm is baked into the executable)."""
         from ..utils.audit import record_arm
@@ -116,10 +112,9 @@ class JCurve:
         if self._pallas():
             from ..ops.pallas_curve import g1_double, g2_double
 
-            interp = not _on_tpu()
             if F.zero_limbs.ndim == 1:
-                return g1_double(F, p, interp)
-            return g2_double(F, p, interp)
+                return g1_double(F, p)
+            return g2_double(F, p)
         X1, Y1, Z1 = p
         sq = F.square(self._pack(X1, Y1))  # L1
         A, B = sq[0], sq[1]
@@ -143,10 +138,9 @@ class JCurve:
         if self._pallas():
             from ..ops.pallas_curve import g1_add, g2_add
 
-            interp = not _on_tpu()
             if F.zero_limbs.ndim == 1:
-                return g1_add(F, p, q, interp)
-            return g2_add(F, p, q, interp)
+                return g1_add(F, p, q)
+            return g2_add(F, p, q)
         X1, Y1, Z1 = p
         X2, Y2, Z2 = q
         sq = F.square(self._pack(Z1, Z2))  # L1
@@ -166,10 +160,9 @@ class JCurve:
         if self._pallas():
             from ..ops.pallas_curve import g1_add_mixed, g2_add_mixed
 
-            interp = not _on_tpu()
             if F.zero_limbs.ndim == 1:
-                return g1_add_mixed(F, p, a, interp)
-            return g2_add_mixed(F, p, a, interp)
+                return g1_add_mixed(F, p, a)
+            return g2_add_mixed(F, p, a)
         X1, Y1, Z1 = p
         X2, Y2 = a
         Z1Z1 = F.square(Z1)  # L1

@@ -107,11 +107,12 @@ from ..utils.jaxcfg import on_tpu as _on_tpu
 
 CONV_LAYOUT = _load_config().field_conv
 
-# Field-mul implementation selector: "auto" (default — the fused pallas
-# kernel on a real TPU backend, the XLA path elsewhere), "xla", or
-# "pallas" (force; runs interpret-mode off-TPU — tests only).  Measured
-# on a v5e chip (r4): 136.5 M muls/s fused vs 14.3 M XLA (7.9x) — see
-# docs/ROOFLINE.md.
+# Field-mul implementation selector: "auto"/"pallas" (the fused pallas
+# kernel on a TPU, the XLA path elsewhere) or "xla" (force the XLA
+# path).  The kernel is compiled for the chip or not used: off a TPU
+# "pallas" does not arm (preflight warns), and interpret mode is only
+# ever passed explicitly by the differential tests.  Builders' isolated
+# kernel timings are in docs/ROOFLINE.md.
 FIELD_MUL_IMPL = _load_config().field_mul
 
 
@@ -124,7 +125,7 @@ def field_mul_impl() -> str:
     that chose it)."""
     from ..utils.audit import record_arm
 
-    impl = "pallas" if (FIELD_MUL_IMPL == "pallas" or (FIELD_MUL_IMPL == "auto" and _on_tpu())) else "xla"
+    impl = "pallas" if FIELD_MUL_IMPL in ("pallas", "auto") and _on_tpu() else "xla"
     record_arm("field_mul", impl)
     return impl
 
@@ -203,11 +204,16 @@ class JPrimeField:
         self.modulus = modulus
         self.name = name
         self.mont_r, self.mont_r2, self.nprime_int = _mont_constants(modulus)
-        self.n_limbs = jnp.asarray(int_to_limbs(modulus))
-        self.nprime_limbs = jnp.asarray(int_to_limbs(self.nprime_int))
-        self.r2_limbs = jnp.asarray(int_to_limbs(self.mont_r2))
-        self.one_mont = jnp.asarray(int_to_limbs(self.mont_r))
-        self.zero_limbs = jnp.zeros(NUM_LIMBS, dtype=jnp.uint32)
+        # HOST arrays: FQ / FR are built at import, and a device array
+        # here would initialise the JAX backend — take the chip — in any
+        # process that merely imports the prover (the fleet supervisor
+        # did, through hostprof -> prover.precomp).  jnp ops take them
+        # as operands all the same.
+        self.n_limbs = int_to_limbs(modulus)
+        self.nprime_limbs = int_to_limbs(self.nprime_int)
+        self.r2_limbs = int_to_limbs(self.mont_r2)
+        self.one_mont = int_to_limbs(self.mont_r)
+        self.zero_limbs = np.zeros(NUM_LIMBS, dtype=np.uint32)
 
     # ------------------------------------------------------------ host I/O
 
@@ -268,14 +274,13 @@ class JPrimeField:
     def mul(self, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         """Montgomery product: (a*b*R^-1) mod N, R = 2^256 (SOS method).
 
-        ZKP2P_FIELD_MUL routes the implementation: "auto" (default)
-        takes the fused VMEM kernel (ops.pallas_mont, docs/ROOFLINE.md)
-        on a real TPU backend and the XLA path elsewhere; "pallas"
-        forces the kernel (interpret mode off-TPU — tests only)."""
+        ZKP2P_FIELD_MUL routes the implementation: the fused VMEM
+        kernel (ops.pallas_mont, docs/ROOFLINE.md) on a TPU unless
+        "xla" is forced, the XLA path elsewhere."""
         if field_mul_impl() == "pallas":
             from ..ops.pallas_mont import mont_mul
 
-            return mont_mul(self, a, b, not _on_tpu())
+            return mont_mul(self, a, b)
         t = _mul_wide(a, b)  # (..., 32)
         m = _mul_wide(t[..., :NUM_LIMBS], self.nprime_limbs)[..., :NUM_LIMBS]
         u = _mul_wide(m, self.n_limbs)  # (..., 32)
@@ -345,7 +350,7 @@ class JPrimeField:
         if field_mul_impl() == "pallas":
             from ..ops.pallas_mont import mont_pow
 
-            return mont_pow(self, a, self.modulus - 2, not _on_tpu())
+            return mont_pow(self, a, self.modulus - 2)
         return self.inv(a)
 
 
@@ -364,8 +369,8 @@ class JFq2Ops:
 
     def __init__(self, fq: JPrimeField = FQ):
         self.fq = fq
-        self.one_mont = jnp.stack([fq.one_mont, fq.zero_limbs])
-        self.zero_limbs = jnp.zeros((2, NUM_LIMBS), dtype=jnp.uint32)
+        self.one_mont = np.stack([fq.one_mont, fq.zero_limbs])
+        self.zero_limbs = np.zeros((2, NUM_LIMBS), dtype=np.uint32)
 
     def add(self, a, b):
         return self.fq.add(a, b)

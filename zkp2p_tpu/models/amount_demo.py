@@ -67,8 +67,8 @@ def dryrun_circuit():
     The driver validates that the FULL sharded prove step compiles and
     executes on a virtual CPU mesh of a 1-core host, on "tiny shapes" by
     its own spec; MSM runtime there scales with wire count (the
-    3.4k-constraint amount default needed ~130 s PER MSM on that host,
-    the MULTICHIP_r03 rc=124 budget kill), so the dryrun runs the
+    3.4k-constraint amount default needed ~130 s PER MSM on that host
+    and was killed at the budget), so the dryrun runs the
     identical prove dataflow at the smallest faithful shape instead.
     -> (ConstraintSystem, public values, witness seed)"""
     from ..gadgets import core

@@ -1,9 +1,12 @@
 """ctypes bridge to the native BN254 library (csrc/zkp2p_native.cpp).
 
 The C++ runtime layer of the framework (the role rapidsnark's native
-field library plays in the reference, SURVEY.md §2.2) — loaded lazily,
-built on demand with make, and everything degrades to the pure-Python
-path when a toolchain is unavailable, so imports never hard-fail.
+field library plays in the reference, SURVEY.md §2.2) — loaded lazily
+and built on demand with make.  `get_lib()` returns None when the build
+fails, so imports never hard-fail and gadget-scale callers keep their
+pure-Python paths; a caller for whom those paths would take hours (the
+array-path setup, the native prover, chip_smoke.py) treats None as an
+error.
 """
 
 from __future__ import annotations
@@ -39,12 +42,13 @@ def get_lib() -> Optional[ctypes.CDLL]:
     # Always (re)build from the committed source: a stale or prebuilt .so
     # must never be loaded in preference to the reviewed C++ (the binary is
     # gitignored; `make` is a no-op when the .so is already newer than the
-    # source, so this costs one stat on the warm path).
+    # source, so this costs one stat on the warm path).  A failed build
+    # means no library — whatever .so is on disk was built from other
+    # source or for another CPU (-march=native).
     try:
         subprocess.run(["make", "-C", _CSRC], check=True, capture_output=True)
-    except Exception:
-        if not os.path.exists(_SO):
-            return None
+    except (OSError, subprocess.CalledProcessError):
+        return None
     try:
         lib = ctypes.CDLL(_SO)
     except OSError:

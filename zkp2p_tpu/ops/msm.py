@@ -54,41 +54,35 @@ def bit_planes_from_limbs(limbs: jnp.ndarray) -> jnp.ndarray:
 
 
 def tree_reduce(curve: JCurve, pts: JacPoint, axis_len: int) -> JacPoint:
-    """Sum `axis_len` Jacobian points along axis -1-of-batch (the last batch
-    axis) by pairwise halving; all other batch axes stay vectorised."""
-    n = axis_len
+    """Sum `axis_len` Jacobian points along the last batch axis in
+    ceil(log2(n)) pairwise rounds; all other batch axes stay vectorised.
+
+    Every round has the SAME shape: adjacent pairs are added (n/2 adds)
+    and the sums are padded back to n with infinity (Z = 0), so ONE add
+    graph runs inside a `lax.scan` instead of log2(n) inlined copies at
+    log2(n) different widths.  On the chip each distinct width of a
+    curve add is its own kernel instance to lower and compile — the
+    halving tree at 4096 lanes was 12 of an MSM executable's 16 — while
+    the extra adds on infinity lanes are noise next to the accumulate."""
     ax = -1 - curve.F.zero_limbs.ndim  # the reduced batch axis
-    while n > 1:
-        if n % 2:
-            pad_cfg = [(0, 0)] * pts[0].ndim
-            pad_cfg[ax] = (0, 1)
-            pts = tuple(jnp.pad(c, pad_cfg) for c in pts)  # zero = infinity
-            n += 1
-        lo = tuple(jax.lax.slice_in_dim(c, 0, n // 2, axis=ax) for c in pts)
-        hi = tuple(jax.lax.slice_in_dim(c, n // 2, n, axis=ax) for c in pts)
-        pts = curve.add(lo, hi)
-        n //= 2
-    return tuple(jnp.squeeze(c, axis=ax) for c in pts)
+    if axis_len == 1:
+        return tuple(jnp.squeeze(c, axis=ax) for c in pts)
+    n = 1 << (axis_len - 1).bit_length()
 
+    def pad_to_n(c, have):
+        pad_cfg = [(0, 0)] * c.ndim
+        pad_cfg[ax] = (0, n - have)
+        return jnp.pad(c, pad_cfg)  # zero = infinity
 
-def fold_lanes_per_curve(curve: JCurve, per_lane: JacPoint, lanes: int) -> JacPoint:
-    """Final lane fold of a windowed MSM, shared by the Jacobian and
-    batch-affine tiers.  G1 takes the pairwise tree — log2(lanes)
-    halving adds instead of a `lanes`-step scan (cheaper dispatch on
-    1-core hosts, wider batches on TPU).  G2 joins the tree only when
-    the pallas point kernels are in use: with the XLA formulas the tree
-    inlines log2(lanes) copies of the Fq2 add graph and XLA:CPU compile
-    time blows up (r4 rehearsal: the G2 executable alone compiled
-    >400 s with the tree fold) — including bench's forced-XLA fallback
-    re-exec on a TPU backend, which must stay compilable."""
-    if curve.F.zero_limbs.ndim == 1 or curve._pallas():
-        return tree_reduce(curve, per_lane, lanes)
+    def pair_round(acc, _):
+        even = tuple(jax.lax.slice_in_dim(c, 0, n, stride=2, axis=ax) for c in acc)
+        odd = tuple(jax.lax.slice_in_dim(c, 1, n, stride=2, axis=ax) for c in acc)
+        return tuple(pad_to_n(c, n // 2) for c in curve.add(even, odd)), None
 
-    def fold(acc, p):
-        return curve.add(acc, p), None
-
-    total, _ = jax.lax.scan(fold, curve.infinity(()), per_lane)
-    return total
+    acc, _ = jax.lax.scan(
+        pair_round, tuple(pad_to_n(c, axis_len) for c in pts), None, length=n.bit_length() - 1
+    )
+    return tuple(jax.lax.index_in_dim(c, 0, axis=ax, keepdims=False) for c in acc)
 
 
 def horner_fold_planes(curve: JCurve, init: JacPoint, planes_stacked, window: int) -> JacPoint:
@@ -99,7 +93,7 @@ def horner_fold_planes(curve: JCurve, init: JacPoint, planes_stacked, window: in
     The window doublings are a nested lax.scan: ONE compiled double
     graph instead of `window` inlined copies — for G2 (Fq2 limb towers)
     the unrolled form alone pushed XLA:CPU past the driver's dryrun
-    budget (MULTICHIP_r04 rehearsal: >300 s compiling jit_local)."""
+    budget (>300 s compiling jit_local)."""
 
     def fold(acc, ps):
         def dbl(a, _):
@@ -429,7 +423,7 @@ def _msm_windowed_impl(
     per_lane = horner_fold_planes(
         curve, curve.infinity((lanes,)), tuple(c for c in partials), window
     )
-    return fold_lanes_per_curve(curve, per_lane, lanes)
+    return tree_reduce(curve, per_lane, lanes)
 
 
 def msm(curve: JCurve, bases: AffPoint, bit_planes: jnp.ndarray, lanes: int = 64) -> JacPoint:

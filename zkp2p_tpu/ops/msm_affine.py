@@ -6,8 +6,7 @@ accumulate step — one complete Jacobian+Jacobian add (16 muls) per
 with batch-affine adds: an affine+affine add is 4 muls plus a shared
 inversion, and the inversion amortises to ~5 muls/lane when every lane's
 denominator is inverted through ONE Montgomery batch inversion.  This
-module is the TPU formulation of that trick (SURVEY.md §7 step 3 /
-docs/NEXT.md lever 1):
+module is the TPU formulation of that trick (SURVEY.md §7 step 3):
 
   - The per-chunk multiples table is normalised to AFFINE once per chunk
     (Jacobian scan build -> one batched Z inversion).  Witness-
@@ -41,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..curve.jcurve import AffPoint, JacPoint, JCurve
-from .msm import fold_lanes_per_curve, horner_fold_planes
+from .msm import horner_fold_planes, tree_reduce
 
 
 def _one(F, like: jnp.ndarray) -> jnp.ndarray:
@@ -256,4 +255,4 @@ def msm_windowed_affine(
     per_lane = horner_fold_planes(
         curve, curve.infinity((lanes,)), tuple(c for c in partials), window
     )
-    return fold_lanes_per_curve(curve, per_lane, lanes)
+    return tree_reduce(curve, per_lane, lanes)

@@ -35,7 +35,7 @@ single-proof latency (the north-star p50) gains as much as throughput.
 
 The h MSM is the intended user: its coset-quotient scalars are
 full-width (width-classing cannot touch it) and it dominates the
-post-classing prover profile (docs/NEXT.md).  Differentially pinned
+post-classing prover profile.  Differentially pinned
 against the host oracle like every device tier."""
 
 from __future__ import annotations

@@ -17,7 +17,7 @@ exercised by the Groth16 host tests).
 
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -37,15 +37,31 @@ def _bit_reverse_perm(m: int) -> np.ndarray:
 
 
 def _twiddle_powers(w: int, count: int) -> jnp.ndarray:
-    """[w^0 .. w^(count-1)] in Montgomery form, built by log2(count) doublings:
-    powers[j + 2^i] = powers[j] * w^(2^i)."""
-    cur = FR.one_mont[None, :]
-    e = 1
-    while cur.shape[0] < count:
-        factor = jnp.asarray(FR.to_mont_host(pow(w, e, R)))
-        cur = jnp.concatenate([cur, FR.mul(cur, factor)], axis=0)
-        e *= 2
-    return cur[:count]
+    """[w^0 .. w^(count-1)] in Montgomery form, built on device in
+    log2(count) doubling rounds: powers[j + 2^i] = powers[j] * w^(2^i).
+
+    Every round runs at the FULL table width inside one `fori_loop`
+    (lanes outside [2^i, 2^(i+1)) keep their value), so a table is ONE
+    compiled program with one field-mul instance, shared by every table
+    of that width.  Grown round by round, each round had its own width
+    and its own compile — ~55 of them before the first prove on a 2^19
+    domain — for the sake of muls that are noise on the device."""
+    n_rounds = max(1, (count - 1).bit_length())
+    factors = np.stack([FR.to_mont_host(pow(w, 1 << i, R)) for i in range(n_rounds)])
+    return _powers_by_doubling(jnp.asarray(factors), count)
+
+
+@partial(jax.jit, static_argnums=1)
+def _powers_by_doubling(factors: jnp.ndarray, count: int) -> jnp.ndarray:
+    idx = jnp.arange(count)
+
+    def grow(i, cur):
+        half = jnp.left_shift(1, i)
+        grown = FR.mul(jnp.roll(cur, half, axis=0), factors[i])  # [j] = cur[j - 2^i] * w^(2^i)
+        return jnp.where(((idx >= half) & (idx < 2 * half))[:, None], grown, cur)
+
+    ones = jnp.broadcast_to(FR.one_mont, (count,) + FR.one_mont.shape)
+    return jax.lax.fori_loop(0, factors.shape[0], grow, ones)
 
 
 @lru_cache(maxsize=None)

@@ -57,10 +57,12 @@ def _f_cond_sub(a, n_lm):
     return jnp.where(borrow[None, :] != 0, a, d)
 
 
+@jax.jit
 def _f_add(a, b, n_lm):
     return _f_cond_sub(_carry_lm(a + b, NUM_LIMBS), n_lm)
 
 
+@jax.jit
 def _f_sub(a, b, n_lm):
     d, borrow = _sub_raw_lm(a, b)
     dn = _carry_lm(d + n_lm, NUM_LIMBS)

@@ -25,7 +25,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..curve.jcurve import AffPoint, JacPoint, JCurve
@@ -86,7 +85,7 @@ def _msm_sharded_fn(curve: JCurve, n_bases: int, mesh: Mesh, axis: str, lanes: i
         P(None, axis),
     )
     out_specs = tuple(P() for _ in range(3))
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
 def msm_sharded(
@@ -134,7 +133,7 @@ def _msm_pod_fn(curve: JCurve, n_bases: int, mesh: Mesh, dcn_axis: str, ici_axis
         P(dcn_axis, None, ici_axis),
     )
     out_specs = tuple(P() for _ in range(3))
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
 def _fold_gathered_batched(curve: JCurve, gathered: JacPoint, n: int) -> JacPoint:

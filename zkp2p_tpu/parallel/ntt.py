@@ -35,7 +35,6 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..field.bn254 import fr_domain_root, fr_inv
@@ -131,12 +130,12 @@ def _ntt_sharded_fn(log_m: int, mesh: Mesh, axis: str, inverse: bool):
         return out
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis, None), P(axis, None, None)),
             out_specs=P(axis, None),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -29,7 +29,6 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -80,12 +79,12 @@ def _dfa_scan_fn(mesh: Mesh, axis: str, S: int, block: int):
         return states  # (block,) state AFTER each byte
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis), P(None, None)),
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

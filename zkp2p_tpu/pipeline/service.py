@@ -1801,25 +1801,28 @@ class ProvingService:
         # scrape sees stage histograms, request-state counters, and a
         # scrape-time native counter refresh.
         maybe_start_metrics_server()
-        # Preflight (execution audit): arm every gate, warn LOUDLY when
-        # an expected arm failed to arm (pallas requested on a CPU
-        # backend, bucket-h without signed digits...) — the round-5
-        # silent-disarm class of failure must announce itself before the
-        # first request is claimed, not after a burned tunnel window.
-        try:
-            import sys
+        # Preflight (execution audit): arm every gate and report the
+        # arms before the first request is claimed.  A failure here is
+        # not swallowed, and a worker that proves on the device
+        # (prover_fn None -> prove_tpu_batch) REFUSES to start on a
+        # mis-armed gate (pallas requested on a CPU backend, bucket-h
+        # without signed digits...) — a mis-armed device run must stop
+        # here, not show up in the numbers after.  Preflight initialises
+        # the JAX backend; the CLI pins a `--prover native` worker's JAX
+        # to the host platform first, so it never takes the chip.
+        import sys
 
-            rep = preflight(
-                probe=False, workload=False,
-                log=lambda m: print(f"[service] {m}", file=sys.stderr, flush=True),
-            )
-            print(
-                f"[service] preflight: backend={rep['backend']} "
-                f"execution_digest={rep['execution_digest']}",
-                flush=True,
-            )
-        except Exception:  # noqa: BLE001 — observation must never stop the service
-            pass
+        rep = preflight(
+            workload=False,
+            log=lambda m: print(f"[service] {m}", file=sys.stderr, flush=True),
+        )
+        print(
+            f"[service] preflight: backend={rep['backend']} "
+            f"execution_digest={rep['execution_digest']}",
+            flush=True,
+        )
+        if self.prover_fn is None and rep["warnings"]:
+            raise RuntimeError(f"preflight: mis-armed gates: {rep['warnings']}")
         # service observability arms + time-series sampler: the SLO
         # objective and sampler interval are digest-visible gates (a
         # sampler-off A/B differs from sampler-on only on these), and

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import secrets
 from dataclasses import dataclass
+from functools import lru_cache
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -60,13 +61,13 @@ from ..ops.msm import (
 from ..ops.ntt import coset_shift, intt, ntt
 
 # All tier knobs resolve through the ONE typed config (utils.config:
-# default -> armed_flags -> env, with provenance); the module constants
+# default -> env, with provenance); the module constants
 # below are its import-time snapshot — jit identities depend on them,
 # so they are process-lifetime like the config itself.
 #
 # MSM_WINDOW: 4-bit digits -> ~78 point-adds per base instead of the 256
-#   of the bit-plane formulation (VERDICT r1 #3); w=8 halves accumulate
-#   work at the price of a 254-add per-chunk table, worth it vmapped.
+#   of the bit-plane formulation; w=8 halves accumulate work at the
+#   price of a 254-add per-chunk table, worth it vmapped.
 # MSM_SIGNED: signed digit recoding (default on) — the per-chunk
 #   multiples table halves because a negative digit is (x, -y) for free.
 # MSM_UNIFIED ("auto" = on for a real TPU backend): pad the a/b1/c/h
@@ -997,17 +998,28 @@ def prove_tpu_sharded(
     return _assemble(dpk, (a, b1, b2, c, hq), r, s)
 
 
-# Batched sharded-arm stage jits: h_evals vmapped over the witness batch
-# (the pjit data-parallel axis — inputs arrive batch-sharded, XLA
-# propagates the layout through the matvec/NTT ladder), and the UNSIGNED
-# digit-plane recode per witness ((B, n_planes, n) — the layout
-# msm_pod_batched's shard_map consumes).  The sharded MSMs use the
-# unsigned formulation like prove_tpu_sharded: group arithmetic is
-# exact, so the proof bytes match the signed vmap arm regardless.
-_jit_h_evals_batch = jax.jit(jax.vmap(h_evals, in_axes=(None, 0)))
-_jit_digit_planes_batch = jax.jit(
-    jax.vmap(lambda w_std: digit_planes_from_limbs(w_std, MSM_WINDOW))
-)
+# Batched sharded-arm h stage: h_evals vmapped over this batch group's
+# share of the witness chunk, and the UNSIGNED digit-plane recode per
+# witness ((B, n_planes, n) — the layout msm_pod_batched's shard_map
+# consumes), as ONE shard_map over the pod mesh.  It must be a
+# shard_map, not a jit over mesh-sharded inputs: on a real mesh JAX
+# refuses to partition a Mosaic kernel automatically ("wrap the call in
+# a shard_map" — found on the four-chip host, PERF.md PR 21), and every
+# field product here is one.  The sharded MSMs use the unsigned
+# formulation like prove_tpu_sharded: group arithmetic is exact, so the
+# proof bytes match the signed vmap arm regardless.
+@lru_cache(maxsize=None)
+def _h_planes_pod_fn(mesh):
+    from jax.sharding import PartitionSpec as P
+
+    def local(dpk, w_mont):  # w_mont: (B_local, n_wires, 16)
+        planes = jax.vmap(lambda x: digit_planes_from_limbs(FR.from_mont(x), MSM_WINDOW))
+        return planes(w_mont), planes(jax.vmap(h_evals, in_axes=(None, 0))(dpk, w_mont))
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P("batch")),
+        out_specs=(P("batch"), P("batch")), check_vma=False,
+    ))
 
 
 def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh):
@@ -1027,9 +1039,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh):
 
     n_ici = mesh.shape["shard"]
     w_mont = jax.device_put(w_mont, NamedSharding(mesh, P("batch")))
-    h = _jit_h_evals_batch(dpk, w_mont)
-    w_planes = _jit_digit_planes_batch(FR.from_mont(w_mont))
-    h_planes = _jit_digit_planes_batch(FR.from_mont(h))
+    w_planes, h_planes = _h_planes_pod_fn(mesh)(dpk, w_mont)
 
     def msm(curve, bases, planes):
         # lanes sized to the per-device slice (tiny CI circuits stay at
@@ -1077,9 +1087,17 @@ def _batch_chunk_size() -> int:
     return v
 
 
-def prove_tpu_batch(dpk: DeviceProvingKey, witnesses: Sequence[Sequence[int]]) -> List[Proof]:
+def prove_tpu_batch(
+    dpk: DeviceProvingKey,
+    witnesses: Sequence[Sequence[int]],
+    rs: Optional[Sequence[int]] = None,
+    ss: Optional[Sequence[int]] = None,
+) -> List[Proof]:
     """vmap the full device pipeline over a batch of witnesses (the
-    batch=64 configuration in BASELINE.json).
+    batch=64 configuration in BASELINE.json).  `rs`/`ss` pin the
+    per-proof blinding scalars (same signature as prove_native_batch):
+    with them the batch emits byte for byte what prove_native /
+    prove_host emit for the same (witness, r, s).
 
     Large batches run as shape-stable sub-chunks (see _batch_chunk_size;
     the last chunk pads by repeating its final witness) so device memory
@@ -1134,7 +1152,11 @@ def prove_tpu_batch(dpk: DeviceProvingKey, witnesses: Sequence[Sequence[int]]) -
         a, b1, c, hq = (g1_jac_to_host(accs[i]) for i in (0, 1, 3, 4))
         b2 = g2_jac_to_host(accs[2])
         proofs = [
-            _assemble(dpk, (a[i], b1[i], b2[i], c[i], hq[i]), 1 + secrets.randbelow(R - 1), 1 + secrets.randbelow(R - 1))
+            _assemble(
+                dpk, (a[i], b1[i], b2[i], c[i], hq[i]),
+                rs[i] if rs is not None else 1 + secrets.randbelow(R - 1),
+                ss[i] if ss is not None else 1 + secrets.randbelow(R - 1),
+            )
             for i in range(len(witnesses))
         ]
         sample_device_memory("tpu/prove_batch")  # exit watermark: batch HBM peak

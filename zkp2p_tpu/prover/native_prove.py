@@ -300,7 +300,7 @@ def _msm_interleave_arm() -> bool:
     through one mont52_mul8x2 register schedule plus software prefetch
     down the known bucket/point schedules; =0 is the single-chain
     byte-parity oracle arm.  This mirror records the arm into the
-    execution digest (docs/NEXT.md lever 4)."""
+    execution digest."""
     from ..utils.audit import record_arm
     from ..utils.config import load_config
 
@@ -313,8 +313,8 @@ def _ntt_radix8_arm() -> bool:
     docs/TUNING.md).  Resolved IN the C runtime (fresh getenv per
     stage-batch call): =1 fuses three butterfly stages per load/store
     pass in fr_ntt_soa_stages; unset/=0 keeps the radix-4 pairs — the
-    byte-parity oracle arm.  Mirror-recorded into the execution digest
-    (docs/NEXT.md lever 2)."""
+    byte-parity oracle arm.  Mirror-recorded into the execution
+    digest."""
     from ..utils.audit import record_arm
     from ..utils.config import load_config
 
@@ -326,9 +326,9 @@ def _use_witness_u64() -> bool:
     witness object carries a build-time standard-form `u64` array
     (snark.r1cs.Witness / WitnessRow), the witness_convert stage hands
     it off instead of re-serializing Python ints every prove; =0 (or a
-    plain witness sequence) re-serializes — the byte-parity oracle arm
-    (docs/NEXT.md lever 3).  Fresh-read per prove and record_arm-audited
-    so A/B digests distinguish the arms."""
+    plain witness sequence) re-serializes — the byte-parity oracle
+    arm.  Fresh-read per prove and record_arm-audited so A/B digests
+    distinguish the arms."""
     from ..utils.audit import record_arm
     from ..utils.config import load_config
 
@@ -364,7 +364,7 @@ def _witness_std_u64(
     lib, witness: Sequence[int], fast: bool = False, builder_u64: bool = False
 ) -> np.ndarray:
     """Witness ints -> standard-form (n, 4) u64 MSM scalars, reduced
-    mod r IN THE NATIVE LIBRARY (docs/NEXT.md lever 3): raw 256-bit
+    mod r IN THE NATIVE LIBRARY: raw 256-bit
     serialization here, `fr_reduce_batch` there — the per-element
     Python `w % R` this replaces was ~half the witness_convert stage.
     Values a 256-bit window cannot hold (negative or >= 2^256 — no
@@ -459,7 +459,7 @@ def _u64x4_to_int_arr(a: np.ndarray) -> list:
 
 def _tuned_window(tag: str, bl: int, threads: int):
     """Host-profile window resolution for the variable-base G1 curves
-    (the tune window arm, APPLIED — docs/NEXT.md §1): the measured-best
+    (the tune window arm, APPLIED): the measured-best
     c when the profile recorded one at this exact (shape, threads)
     context, else None -> the committed curve below.  A tuned value
     bypasses the multi-thread clamp: the sweep measured it AT that

@@ -34,7 +34,7 @@ class Witness(list):
     """A witness vector (Fr ints) that also carries ``u64``: the prover's
     standard-form (n, 4) little-endian u64 serialization, emitted at build
     time so the per-prove ``witness_convert`` stage collapses to an array
-    hand-off (docs/NEXT.md lever 3, gated by ``ZKP2P_WITNESS_U64``)."""
+    hand-off (gated by ``ZKP2P_WITNESS_U64``)."""
 
     u64 = None
 
@@ -588,9 +588,8 @@ class ConstraintSystem:
             stats["block_hooks"] = n_block
         toobj(np.flatnonzero(~hasobj))  # one merged materialization
         self._hooks_validated = True
-        # Standard-form u64 serialization at the builder (docs/NEXT.md
-        # lever 3), vectorized while the wires are still row-major per
-        # wire: int64-backed rows are canonical and non-negative in the
+        # Standard-form u64 serialization at the builder, vectorized
+        # while the wires are still row-major per wire: int64-backed rows are canonical and non-negative in the
         # common case and bulk-cast; object rows bulk-cast per chunk with
         # the same exact fallback as _std_u64.
         U = np.zeros((self.num_wires, K, 4), dtype=np.uint64)

@@ -2,9 +2,8 @@
 
 Every measured constant in the repo — precomp geometry c/q/L, Pippenger
 windows, `ZKP2P_NATIVE_THREADS`, batch columns — was hand-picked on one
-2-core IFMA box (docs/NEXT.md flags the first wider host as a full
-re-sweep).  `zkp2p-tpu tune` (pipeline.tune) automates that re-sweep:
-it measures this host's micro-arms and persists the winners here as an
+2-core IFMA box.  `zkp2p-tpu tune` (pipeline.tune) automates the
+re-sweep a wider host needs: it measures this host's micro-arms and persists the winners here as an
 atomic, fingerprint-keyed JSON profile beside `.bench_cache`.  This
 module is the profile's home: hardware detection (cache sizes + core
 topology via the native runtime's sysconf probe, sysfs fallback), the

@@ -386,10 +386,9 @@ def serialize_knobs(cfg) -> Dict:
 
 def run_manifest() -> Dict:
     """{run_id, pid, ts, host facts, every knob + provenance, observed
-    gate arms + execution digest, last TPU probe}."""
+    gate arms + execution digest}."""
     from .audit import execution_digest, gate_arms
     from .config import load_config
-    from .jaxcfg import last_probe
 
     cfg = load_config()
     knobs = serialize_knobs(cfg)
@@ -419,9 +418,6 @@ def run_manifest() -> Dict:
         "gates": gate_arms(),
         "execution_digest": execution_digest(),
     }
-    probe = last_probe()
-    if probe is not None:
-        man["tpu_probe"] = probe
     # where THIS process's /metrics endpoint actually listens — under
     # ZKP2P_METRICS_PORT=auto the knob value (0) says nothing, so the
     # manifest records the OS-assigned port (scrape discoverability for

@@ -15,10 +15,9 @@ bench output.  This module gives the repo that memory:
   - per-stage BUDGETS derived from it (trailing-window median ×
     ZKP2P_PERF_TOLERANCE) that `service.py` checks every terminal
     request's spans against (`zkp2p_stage_budget_overruns_total`);
-  - a committed baseline band (`PERF_BASELINE.json`) the `make
-    perf-gate` target replays the ledger head against, exiting nonzero
-    on drift — a machine-checked before/after for CI and the next
-    hardware window instead of prose.
+  - a baseline band (`PERF_BASELINE.json`, frozen with `--rebaseline`;
+    none is committed) that `zkp2p-tpu perf --gate` replays the ledger
+    head against, exiting nonzero on drift.
 
 Trust model mirrors `hostprof`: every line is stamped with this host's
 fingerprint key AND a content digest over its own body.  At read time,
@@ -75,8 +74,8 @@ def default_ledger_path() -> Optional[str]:
 
 
 def default_baseline_path() -> str:
-    """`<repo>/PERF_BASELINE.json` — the committed band `make
-    perf-gate` replays the ledger head against."""
+    """`<repo>/PERF_BASELINE.json` — the band `zkp2p-tpu perf --gate`
+    replays the ledger head against."""
     here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     return os.path.join(here, BASELINE_NAME)
 
@@ -449,7 +448,7 @@ def backfill_bench(
     """Import the committed `BENCH_r*.json` tails as ledger entries
     (source=bench_backfill, one per successful round), idempotently:
     a round already in the ledger (matched by its `backfill_of` stamp)
-    is skipped, so `make perf-gate` can run this unconditionally.
+    is skipped, so `zkp2p-tpu perf --gate` can run this unconditionally.
 
     The history predates the fingerprint stamp; entries are signed with
     THIS host's key on the documented assumption that the committed
@@ -505,7 +504,7 @@ def backfill_bench(
 
 
 # --------------------------------------------------------------------------
-# Baseline band + drift gate (`make perf-gate`).
+# Baseline band + drift gate (`zkp2p-tpu perf --gate`).
 
 
 def write_baseline(
