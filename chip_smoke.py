@@ -219,7 +219,18 @@ def serve_wave(world, svc, vk, spool: str, first: int) -> float:
     from zkp2p_tpu.snark.groth16 import verify
     from zkp2p_tpu.utils.metrics import REGISTRY
 
+    def guarantee_counters() -> dict:
+        # summed over labels; differenced around the wave, because the
+        # registry is the process's, not this wave's
+        out = dict.fromkeys(("zkp2p_service_shed_total", "zkp2p_service_degraded_total",
+                             "zkp2p_service_retries_total"), 0.0)
+        for m in REGISTRY.snapshot():
+            if m["name"] in out:
+                out[m["name"]] += m.get("value") or 0.0
+        return out
+
     os.makedirs(spool, exist_ok=True)
+    before = guarantee_counters()
     rids = [f"req{first + i:03d}" for i in range(BATCH)]
     for i, rid in enumerate(rids):
         tmp = os.path.join(spool, rid + ".tmp")
@@ -237,9 +248,7 @@ def serve_wave(world, svc, vk, spool: str, first: int) -> float:
         proof = proof_from_json(load(base + ".proof.json"))
         pub = [int(x) for x in load(base + ".public.json")]
         assert verify(vk, proof, pub), f"{rid}: proof fails the pairing check"
-    bad = {m["name"]: m["value"] for m in REGISTRY.snapshot()
-           if m["name"] in ("zkp2p_service_shed_total", "zkp2p_service_degraded_total",
-                            "zkp2p_service_retries_total") and m.get("value")}
+    bad = {name: v - before[name] for name, v in guarantee_counters().items() if v != before[name]}
     assert not bad, f"requests shed / degraded / retried: {bad}"
     say(f"wave {first // BATCH}: {BATCH} requests done, {BATCH} proofs pairing-verified, {wall:.1f}s wall")
     return wall

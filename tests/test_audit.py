@@ -235,6 +235,36 @@ def test_compile_listener_attributes_stage():
     assert REGISTRY.counter("zkp2p_compile_seconds_total", {"stage": "audit_compile_test"}).value > 0
 
 
+def test_a_forced_relowering_under_a_span_is_counted_with_the_stage_path(capsys):
+    """The listener counts tracing and lowering as it counts compiling,
+    labelled by the span open on the thread; under a service/* span — a
+    replica lowering while it serves — it also logs the path and the
+    seconds.  Every counter is there at zero from installation."""
+    import jax.numpy as jnp
+
+    from zkp2p_tpu.utils.trace import trace
+
+    assert audit.install_compile_listener()
+    names = {m["name"] for m in REGISTRY.snapshot() if not m["labels"]}
+    assert {"zkp2p_lower_events_total", "zkp2p_lower_seconds_total",
+            "zkp2p_compile_events_total", "zkp2p_compile_seconds_total"} <= names
+    stage = {"stage": "service/prove/tpu/prove_batch/dispatch"}
+    lowered = lambda: REGISTRY.counter("zkp2p_lower_events_total", stage).value  # noqa: E731
+    fn = jax.jit(lambda x: x * 104729 + 13)
+    x4, x8 = jnp.arange(4), jnp.arange(8)  # made outside the span: making them lowers programs too
+    with trace("service/prove"), trace("tpu/prove_batch"), trace("device", leaf=True), trace("dispatch"):
+        n0 = lowered()
+        fn(x4).block_until_ready()
+        assert lowered() == n0 + 1  # a warmed window reads 0; this one reads 1, with the stage's path
+        fn(x4).block_until_ready()
+        assert lowered() == n0 + 1  # a cache hit lowers nothing
+        fn(x8).block_until_ready()  # a new shape: the forced re-lowering
+        assert lowered() == n0 + 2
+    assert REGISTRY.counter("zkp2p_lower_seconds_total", stage).value > 0
+    err = capsys.readouterr().err
+    assert "[service] lowering under service/prove/tpu/prove_batch/dispatch: " in err
+
+
 # ------------------------------------------------------------ preflight
 
 

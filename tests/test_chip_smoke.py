@@ -117,6 +117,11 @@ def host_backed_device_prover(monkeypatch):
 
     monkeypatch.setattr(groth16_tpu, "prove_tpu_batch", fake)
     monkeypatch.delenv("ZKP2P_TPU_SHARD", raising=False)
+    # the smoke's process is its own; here earlier tests of this worker left
+    # spans in the ring, which the first wave would flush into the smoke's sink
+    from zkp2p_tpu.utils import trace
+
+    trace.reset()
     return seen
 
 

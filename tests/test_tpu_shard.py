@@ -170,6 +170,92 @@ def test_batch_arm_selection_and_fallback(toy_keys, monkeypatch):
     assert arm_for(3, "on", "2x4") == ("vmap", "fallback")
 
 
+# ------------------------------------------- the batch's spans (stubbed)
+
+
+@pytest.mark.parametrize("chunk,n_chunks", [("0", 1), ("2", 2)])
+def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_device(
+        toy_keys, monkeypatch, chunk, n_chunks):
+    """prove_tpu_batch on the XLA road with the six stage executables
+    stood in for (each compiles for minutes on XLA:CPU): the real
+    `_prove_device` enqueues them and the real read loop writes the
+    spans.  `prep` + `device` + `finish` partition `tpu/prove_batch`;
+    `dispatch` and six stages a chunk lie in `device`, the stages in the
+    order this road enqueues them (no narrow class: the h MSM first),
+    abutting, from `device`'s start to its end."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.utils import trace as tr
+
+    cs, _pk, _vk, dpk, x, y = toy_keys
+    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11)])  # two chunks: the second padded
+    # no narrow class (as an imported zkey without width inference): one MSM a query, no curve add to compile
+    none = jnp.zeros((0,), jnp.int32)
+    dpk = dataclasses.replace(
+        dpk, a_nsel=none, b_nsel=none, c_nsel=none, a_wsel=jnp.arange(dpk.n_wires, dtype=jnp.int32),
+        b_wsel=jnp.arange(dpk.b_sel.shape[0], dtype=jnp.int32), c_wsel=jnp.arange(dpk.c_sel.shape[0], dtype=jnp.int32))
+    monkeypatch.setenv("ZKP2P_TPU_SHARD", "off")
+    monkeypatch.setattr(G, "BATCH_CHUNK", chunk)
+    order = []
+
+    def fake_h_planes(dpk_, w_mont):
+        time.sleep(0.03)
+        b, n = w_mont.shape[0], w_mont.shape[1]
+        planes = (np.zeros((b, 4, n), np.uint32), np.zeros((b, 4, n), bool))
+        m = 1 << dpk_.log_m
+        return (planes, ()), (np.zeros((b, 4, m), np.uint32), np.zeros((b, 4, m), bool))
+
+    def fake_msm(limbs):
+        def run(bases, planes):
+            order.append(int(bases[0].shape[0]))
+            time.sleep(0.01)
+            b = planes[0].shape[0]
+            return tuple(np.zeros((b,) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
+        return run
+
+    monkeypatch.setattr(G, "_jit_h_planes_batch", fake_h_planes)
+    monkeypatch.setattr(G, "_jit_msm_g1_batch", fake_msm((16,)))
+    monkeypatch.setattr(G, "_jit_msm_h_batch", fake_msm((16,)))
+    monkeypatch.setattr(G, "_jit_msm_g2_batch", fake_msm((2, 16)))
+    monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
+    tr.reset()
+    out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3], ss=[4, 5, 6])
+    assert len(out) == 3 and all(len(acc) == 5 for acc in out)  # five accumulators a proof, per witness
+    # this road enqueues the h MSM first, and the stage spans say so
+    assert order[0::5] == [int(dpk.h_bases[0].shape[0])] * n_chunks
+    enqueued = ["h_planes", "msm_h", "msm_a", "msm_b1", "msm_b2", "msm_c"]
+
+    recs = tr.records()
+    by = {}
+    for r in recs:
+        by.setdefault(r["stage"], []).append(r)
+    (batch,), (prep,), (device,), (finish,), (dispatch,) = (
+        by["tpu/prove_batch" + s] for s in ("", "/prep", "/device", "/finish", "/dispatch"))
+    assert batch["n"] == 3 and prep["parent"] == device["parent"] == finish["parent"] == batch["id"]
+    assert prep["ms"] + device["ms"] + finish["ms"] == pytest.approx(batch["ms"], rel=0.01, abs=1.0)
+    assert dispatch["parent"] == device["id"] and dispatch["ms"] <= device["ms"]
+    stages = sorted((r for r in recs if "/stage/" in r["stage"]), key=lambda r: r["id"])
+    assert sorted(enqueued) == sorted(G.STAGES)
+    assert [r["stage"].rsplit("/", 1)[1] for r in stages] == enqueued * n_chunks
+    assert [r["chunk"] for r in stages] == [c for c in range(n_chunks) for _ in G.STAGES]
+    assert all(r["tid"] != device["tid"] for r in stages)  # read by the watching thread, as each result is ready
+    assert all(r["parent"] == device["id"] and r["stage"].startswith("tpu/prove_batch/stage/") for r in stages)
+    assert stages[0]["t0"] == pytest.approx(device["t0"], abs=1e-3)
+    for a, b in zip(stages, stages[1:]):
+        assert b["t0"] == pytest.approx(a["t0"] + a["ms"] / 1e3, abs=1e-5)  # abutting: they partition `device`
+    t_last, t_device = stages[-1]["t0"] + stages[-1]["ms"] / 1e3, device["t0"] + device["ms"] / 1e3
+    if n_chunks == 1:  # `device` ends with its last stage
+        assert t_last == pytest.approx(t_device, abs=2e-2)
+        assert sum(r["ms"] for r in stages) == pytest.approx(device["ms"], rel=0.01, abs=20.0)
+    else:  # the chunks' accumulators are concatenated on the device after the last stage (here that compiles)
+        assert t_last <= t_device + 1e-3
+    tr.reset()
+
+
 # ----------------------------------------------------------- byte parity
 
 _POD_CACHE_HINTS = ("jit_local", "jit_msm_pod", "shard_map")

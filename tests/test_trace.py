@@ -143,3 +143,104 @@ def test_concurrent_dumps_produce_only_intact_lines(tmp_path):
         t.join()
     for ln in open(p):
         json.loads(ln)  # raises on a torn line
+
+
+# ------------------------------------------------- the span model (PR 24)
+
+
+def test_a_span_records_start_id_and_parent_across_adopt_stack():
+    """t0 on the wall clock, an id unique in the process, and the id of
+    the span that caused it — kept when a worker thread adopts the stack."""
+    import time
+
+    t_before = time.time()
+    with tr.trace("outer", n=3) as outer:
+        with tr.trace("inner"):
+            pass
+        stack = tr.current_stack()
+
+        def worker():
+            tr.adopt_stack(stack)
+            with tr.trace("pooled"):
+                pass
+
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+        outer["added"] = "by the body"
+    by = {r["stage"]: r for r in tr.records()}
+    assert set(by) == {"outer", "outer/inner", "outer/pooled"}
+    out = by["outer"]
+    assert out["parent"] is None and out["n"] == 3 and out["added"] == "by the body"
+    assert t_before <= out["t0"] <= time.time() and out["ms"] >= 0
+    assert by["outer/inner"]["parent"] == out["id"] == by["outer/pooled"]["parent"]
+    assert len({r["id"] for r in by.values()}) == 3
+    assert by["outer/pooled"]["tid"] != out["tid"] == by["outer/inner"]["tid"]
+    assert out["t0"] <= by["outer/inner"]["t0"] <= by["outer/pooled"]["t0"]
+
+
+def test_record_writes_an_interval_read_from_clocks_under_the_open_span():
+    tr.set_context(request_id="req-7")
+    with tr.trace("batch") as batch:
+        tr.record("stage/one", 100.0, 100.25, chunk=0)
+    tr.record("root_interval", 5.0, 5.5, request_id="explicit")
+    tr.clear_context()
+    by = {r["stage"]: r for r in tr.records()}
+    one = by["batch/stage/one"]
+    assert (one["t0"], one["ms"], one["chunk"]) == (100.0, 250.0, 0)
+    assert one["parent"] == batch["id"] and one["request_id"] == "req-7" and one["id"] != batch["id"]
+    assert by["root_interval"]["parent"] is None and by["root_interval"]["request_id"] == "explicit"
+    # record() opens nothing: the next span is no child of it
+    with tr.trace("after"):
+        pass
+    assert tr.records()[-1]["parent"] is None
+
+
+def test_a_leaf_span_is_a_parent_but_no_path_prefix_and_t0_backdates():
+    import time
+
+    t_scan = time.time() - 0.2
+    with tr.trace("svc/sweep", leaf=True, t0=t_scan) as sweep:
+        with tr.trace("svc/prove"):
+            with tr.trace("phase", leaf=True) as phase:
+                with tr.trace("dispatch"):
+                    pass
+                tr.record("stage/x", t_scan, t_scan + 0.1)
+    by = {r["stage"]: r for r in tr.records()}
+    assert set(by) == {"svc/sweep", "svc/prove", "svc/prove/phase", "svc/prove/dispatch", "svc/prove/stage/x"}
+    assert by["svc/prove"]["parent"] == sweep["id"]
+    assert by["svc/prove/dispatch"]["parent"] == phase["id"] == by["svc/prove/stage/x"]["parent"]
+    assert sweep["t0"] == round(t_scan, 6) and sweep["ms"] >= 200.0
+
+
+def test_an_open_span_is_a_trace_annotation_only_where_jax_is_imported(monkeypatch):
+    """utils/trace.py never imports jax; where the process has, an open
+    span is a TraceAnnotation of the same path, entered and left once."""
+    import sys
+    import types
+
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setitem(sys.modules, "jax", types.SimpleNamespace(profiler=types.SimpleNamespace(TraceAnnotation=Ann)))
+    with tr.trace("a"):
+        with tr.trace("b"):
+            pass
+        tr.record("past", 1.0, 2.0)  # in the past: no annotation
+    assert seen == [("enter", "a"), ("enter", "a/b"), ("exit", "a/b"), ("exit", "a")]
+    monkeypatch.delitem(sys.modules, "jax")
+    with tr.trace("c"):
+        pass
+    assert len(seen) == 4 and not hasattr(tr, "jax_profile")
+    src = open(tr.__file__).read()
+    assert "import jax" not in src and "JAX_TRACE_DIR" not in src

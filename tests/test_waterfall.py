@@ -257,3 +257,77 @@ def test_chrome_trace_export_loads_and_is_monotonic(world, tmp_path, monkeypatch
     # the terminal instant markers carry the state
     marks = [e for e in events if e.get("ph") == "i"]
     assert len(marks) == 3 and all(m["name"] == "done" for m in marks)
+
+
+# ------------------------------------------- the service's own spans (PR 24)
+
+
+def test_a_served_sweep_writes_sweep_and_starved_and_leaves_request_spans_as_they_were(
+        world, tmp_path, monkeypatch):
+    """`ProvingService.run` over one sweep of three requests (two
+    batches): `service/sweep` around it, `service/starved` per batch
+    fetched — as stage records only.  The request records' `spans` keep
+    exactly the four names they had (every entry there labels a device
+    gap in the benchmark), and the wait in the spool stays where it was:
+    `queue_wait_s` on each record."""
+    import importlib.util
+
+    from zkp2p_tpu.utils import trace as tr
+
+    monkeypatch.delenv("ZKP2P_FAULTS", raising=False)
+    monkeypatch.delenv("ZKP2P_METRICS_SINK", raising=False)
+    faults.reset()
+    tr.reset()  # sweeps driven through process_dir alone left their spans in the ring
+    spool = str(tmp_path / "spool")
+    os.makedirs(spool)
+    _write_reqs(spool, [(3, 5), (2, 7), (4, 4)])
+    assert _mk(world).run(spool, poll_s=0.05, exit_when_spool_terminal=True) == "terminal"
+    sink = spool + ".metrics.jsonl"
+    with open(sink) as f:
+        lines = [json.loads(ln) for ln in f]
+    stages = [r for r in lines if r.get("type") == "stage"]
+    by = {}
+    for r in stages:
+        by.setdefault(r["stage"], []).append(r)
+
+    (sweep,) = by["service/sweep"]
+    assert (sweep["n_pending"], sweep["n_batches"], sweep["parent"]) == (3, 2, None)
+    assert sorted(r["n"] for r in by["service/starved"]) == [1, 2]
+    reqs = {r["request_id"]: r for r in _records(spool)}
+    assert sorted(reqs) == ["r0", "r1", "r2"] and all(r["queue_wait_s"] >= 0 for r in reqs.values())
+    # every span of the pass is the sweep's descendant, under the path it always had
+    for name in ("service/witness", "service/prove", "service/verify", "service/emit", "service/starved"):
+        assert by[name] and all(r["parent"] == sweep["id"] for r in by[name]), name
+    assert all(sweep["t0"] <= r["t0"] for r in stages if r is not sweep)
+    # the producer's spans are on another thread than the proving thread's
+    assert {r["tid"] for r in by["service/witness"]} != {r["tid"] for r in by["service/prove"]}
+    # exactly today's entries in the request records
+    assert all([s["name"] for s in r["spans"]] == ["witness", "prove", "verify", "emit"] for r in reqs.values())
+    prove = next(r for r in by["service/prove"] if r["n"] == 2)
+    shared = [s for s in reqs["r0"]["spans"] if s["name"] == "prove"][0]
+    assert (shared["t0"], shared["ms"], shared["n"]) == (prove["t0"], prove["ms"], 2) and "request_ids" not in shared
+    assert prove["request_ids"] == ["r0", "r1"]
+
+    # the Perfetto view: the stage spans on a row per thread under the worker, beside the request rows
+    spec = importlib.util.spec_from_file_location("trace_report", os.path.join(REPO, "tools", "trace_report.py"))
+    trace_report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_report)
+    st, rq, _m, _t = trace_report.load_records([sink])
+    events = trace_report.chrome_trace(rq, stages=st)["traceEvents"]
+    drawn = [e for e in events if e.get("cat") == "stage"]
+    assert {"service/sweep", "service/starved", "service/prove", "service/witness"} <= {e["name"] for e in drawn}
+    assert "queue_wait" in {e["name"] for e in events if e.get("cat") == "request"}  # the request rows keep it
+    rows = {e["tid"] for e in drawn}
+    assert all(t > trace_report.STAGE_TID_BASE for t in rows) and {e["pid"] for e in drawn} == {os.getpid()}
+    # the service's own spans: the proving thread's row and the producer's (the C++ prover's pool adds its own)
+    assert len({e["tid"] for e in drawn if e["name"].count("/") == 1}) == 2
+    named = {e["tid"] for e in events if e.get("ph") == "M" and e["name"] == "thread_name" and e["tid"] in rows}
+    assert named == rows
+    # slices on one row nest or follow each other: what a trace viewer needs of a thread
+    for row in rows:
+        open_until = []
+        for e in sorted((e for e in drawn if e["tid"] == row), key=lambda e: (e["ts"], -e["dur"])):
+            while open_until and open_until[-1] <= e["ts"] + 1e-3:
+                open_until.pop()
+            assert not open_until or e["ts"] + e["dur"] <= open_until[-1] + 1.0, e
+            open_until.append(e["ts"] + e["dur"])

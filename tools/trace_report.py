@@ -30,8 +30,11 @@ Modes:
                trace-event JSON (one pid per worker process, one tid
                per request, queue-wait vs witness/prove/emit slices,
                FLOW arrows stitching a deferred/taken-over request's
-               attempts across worker process rows) — load OUT in
-               https://ui.perfetto.dev.  Honors --run.
+               attempts across worker process rows), and under each
+               worker the stage spans on one row per thread
+               (service/sweep, service/starved, tpu/prove_batch's
+               prep/device/finish, dispatch and the six stage/* spans)
+               — load OUT in https://ui.perfetto.dev.  Honors --run.
   --fleet-dir DIR
                cross-worker mode: discover every sink a fleet run left
                behind (the shared spool sink + rotation backups, plus
@@ -266,11 +269,22 @@ def digest_callout(runs_detail: List[dict], run_a: str, run_b: str) -> List[str]
     return lines
 
 
-def chrome_trace(requests: List[dict], run: Optional[str] = None) -> dict:
+STAGE_TID_BASE = 1_000_000  # stage-span rows sort below a worker's request rows
+
+
+def chrome_trace(requests: List[dict], run: Optional[str] = None,
+                 stages: Optional[List[dict]] = None) -> dict:
     """Chrome trace-event JSON (loads in Perfetto / chrome://tracing)
     from the service's request records: **one pid per worker process,
     one tid per request**, so the UI shows each request as its own
     waterfall row under its worker.
+
+    `stages`: the stage spans (utils/trace.py records with `t0`, `id`,
+    `parent`, `tid`) drawn under the same worker on **one row per
+    thread**, below its requests: `service/sweep`, `service/starved`,
+    `tpu/prove_batch/prep|device|finish|dispatch` and the six
+    `tpu/prove_batch/stage/*` (on the row of the thread that watches
+    the stages' results) nest there as they ran.
 
     Per record: a synthesized `queue_wait` slice (req-file mtime →
     claim — the spool wait the `queue_wait_s` field sums), one complete
@@ -351,6 +365,25 @@ def chrome_trace(requests: List[dict], run: Optional[str] = None) -> dict:
                 "args": {k: r[k] for k in ("batch_index", "batch_n", "degraded_rung",
                                            "deferred_reason") if r.get(k) is not None},
             })
+
+    # ---- stage spans: a row per thread under the worker that wrote them
+    thread_rows: Dict[tuple, int] = {}  # (pid, thread ident) -> tid
+    for sp in stages or []:
+        if sp.get("t0") is None or sp.get("tid") is None or (run and sp.get("run_id") != run):
+            continue
+        pid = int(sp.get("pid") or 0)
+        key = (pid, sp["tid"])
+        if key not in thread_rows:
+            n = sum(1 for k in thread_rows if k[0] == pid) + 1
+            thread_rows[key] = STAGE_TID_BASE + n
+            events.append({"ph": "M", "name": "thread_name", "pid": pid, "tid": thread_rows[key],
+                           "args": {"name": f"stage spans, thread {n}"}})
+        events.append({
+            "ph": "X", "name": sp["stage"], "cat": "stage", "pid": pid, "tid": thread_rows[key],
+            "ts": float(sp["t0"]) * 1e6, "dur": float(sp["ms"]) * 1e3,
+            "args": {k: v for k, v in sp.items()
+                     if k not in ("stage", "t0", "ms", "tid", "pid", "type", "run_id")},
+        })
 
     # ---- flow events: stitch a request's attempts across process rows.
     # Each record is one ATTEMPT; consecutive attempts get an arrow
@@ -806,7 +839,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(request_timeline(reqs, args.request))
         return 0
     if args.chrome_trace:
-        trace = chrome_trace(requests, run=args.run)
+        trace = chrome_trace(requests, run=args.run, stages=stages)
         if flame_cap is not None:
             # the flame track rides its own pid beside the request
             # waterfalls (appended AFTER the sort: parent-before-child

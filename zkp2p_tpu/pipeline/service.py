@@ -45,10 +45,10 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..formats.proof_json import dump
-from ..utils.audit import execution_digest, preflight, sample_device_memory
+from ..utils.audit import execution_digest, install_compile_listener, preflight, sample_device_memory
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import REGISTRY, JsonlSink, maybe_start_metrics_server, publish_native_stats, run_id, run_manifest
-from ..utils.trace import drain as drain_trace, set_context, trace
+from ..utils.trace import adopt_stack, current_stack, drain as drain_trace, record, set_context, trace
 
 # The terminal-state machine (docs/ROBUSTNESS.md): every request ends in
 # EXACTLY ONE of these, recorded as a .proof.json/.error.json artifact
@@ -120,24 +120,29 @@ BATCH_FILL_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 16, 32, 64)
 
 
 @contextlib.contextmanager
-def _lifespan(reqs, name: str, **attrs):
-    """Per-request lifecycle span — the waterfall substrate: brackets
-    `name` over every request in `reqs` (one Request or a batch list)
-    with ONE shared wall-clock [t0, t0+ms] interval appended to each
-    request's `spans` list, persisted on the request's next record and
-    exported by `trace_report --chrome-trace` (one pid per worker, one
-    tid per request).  Wall-clock (`time.time`), not perf_counter: the
-    waterfall is cross-process, so spans must share the spool's arrival
-    clock (req-file mtime).  Cost: one dict build + one append per
-    request per span — microseconds against multi-second proves."""
-    t0 = time.time()
+def _span(reqs, name: str, **attrs):
+    """One span of the served path, `service/<name>`, over every request
+    in `reqs` (one Request or a batch list), with two readers: the stage
+    record (utils.trace: path, `t0`, `id`, `parent`, `request_id` from
+    the ambient context or the batch's `request_ids`) and, on close, the
+    same `{name, t0, ms, **attrs}` appended to each request's `spans`
+    list — the waterfall substrate, persisted on the request's next
+    record and exported by `trace_report --chrome-trace` (one pid per
+    worker, one tid per request).  `t0` is wall-clock (`time.time`): the
+    waterfall is cross-process, so spans share the spool's arrival clock
+    (req-file mtime).  A failed attempt's span closes on the way out of
+    the exception."""
+    many = isinstance(reqs, (list, tuple))
+    ids = {"request_ids": [r.rid for r in reqs]} if many else {}
+    sp = None
     try:
-        yield
+        with trace("service/" + name, **attrs, **ids) as sp:
+            yield
     finally:
-        rec = {"name": name, "t0": round(t0, 6), "ms": round((time.time() - t0) * 1e3, 3)}
-        rec.update(attrs)
-        for r in (reqs if isinstance(reqs, (list, tuple)) else (reqs,)):
-            r.spans.append(dict(rec))
+        if sp is not None:
+            rec = {"name": name, "t0": sp["t0"], "ms": sp["ms"], **attrs}
+            for r in (reqs if many else (reqs,)):
+                r.spans.append(dict(rec))
 
 
 def scan_spool(spool: str, now: float, window_s: float, stale_claim_s: float) -> Dict:
@@ -1037,22 +1042,20 @@ class ProvingService:
             span_attrs["attempt"] = attempt
         if rung:
             span_attrs["rung"] = rung
-        with _lifespan(batch, "prove", **span_attrs):
+        with _span(batch, "prove", **span_attrs):
             fault_point("prove")
-            with trace("service/prove", n=len(batch), request_ids=[r.rid for r in batch]):
-                prove = self.prover_fn or prove_tpu_batch
-                proofs = prove(self.dpk, [r.witness for r in batch])
+            prove = self.prover_fn or prove_tpu_batch
+            proofs = prove(self.dpk, [r.witness for r in batch])
         proofs = list(proofs) if proofs is not None else []
         if len(proofs) != len(batch):
             raise RuntimeError(
                 f"prover returned {len(proofs)} proofs for a batch of {len(batch)}"
             )
-        with _lifespan(batch, "verify"):
+        with _span(batch, "verify"):
             fault_point("verify")
-            with trace("service/verify"):
-                sample_pub = self.public_fn(batch[0].witness)
-                if not verify(self.vk, proofs[0], sample_pub):
-                    raise RuntimeError("sample proof failed verification")
+            sample_pub = self.public_fn(batch[0].witness)
+            if not verify(self.vk, proofs[0], sample_pub):
+                raise RuntimeError("sample proof failed verification")
         return proofs
 
     def _prove_with_retries(self, batch: List[Request]) -> list:
@@ -1072,7 +1075,7 @@ class ProvingService:
                 if delay > 0:
                     # backoff is part of the request's latency story:
                     # span it so the waterfall shows waiting, not a gap
-                    with _lifespan(batch, "retry_backoff", attempt=attempt):
+                    with _span(batch, "retry_backoff", attempt=attempt):
                         time.sleep(delay)
 
     def _degraded_prove(self, batch: List[Request], cause: BaseException):
@@ -1157,16 +1160,15 @@ class ProvingService:
             set_context(request_id=req.rid)
             try:
                 try:
-                    with _lifespan(req, "emit"):
+                    with _span(req, "emit"):
                         fault_point("emit")
-                        with trace("service/emit"):
-                            # public first, proof last: the sweep treats
-                            # .proof.json as the done marker, so a crash
-                            # between the two atomic writes leaves a
-                            # retryable request, never a proof without its
-                            # public signals
-                            dump(public_to_json(self.public_fn(req.witness)), req.path + ".public.json")
-                            dump(proof_to_json(proof), req.path + ".proof.json")
+                        # public first, proof last: the sweep treats
+                        # .proof.json as the done marker, so a crash
+                        # between the two atomic writes leaves a
+                        # retryable request, never a proof without its
+                        # public signals
+                        dump(public_to_json(self.public_fn(req.witness)), req.path + ".public.json")
+                        dump(proof_to_json(proof), req.path + ".proof.json")
                 except Exception as e:  # noqa: BLE001 — emit failure is per-request
                     REGISTRY.counter("zkp2p_service_emit_failures_total").inc()
                     if _is_transient(e):
@@ -1332,6 +1334,7 @@ class ProvingService:
         """One spool sweep; returns counters. Files: <name>.req.json in,
         <name>.proof.json / <name>.error.json out."""
         self._resolve_policy()
+        t_sweep = time.time()
         stats = {s: 0 for s in TERMINAL_STATES}
         # draining before the sweep even starts: claim nothing, scan
         # nothing — the spool belongs to the peers now
@@ -1508,7 +1511,7 @@ class ProvingService:
         def scalar_witness(req: Request) -> bool:
             set_context(request_id=req.rid)
             try:
-                with trace("service/witness"), _lifespan(req, "witness"):
+                with _span(req, "witness"):
                     fault_point("witness")
                     req.witness = self.witness_fn(req.payload)
                     self.cs.check_witness(req.witness)
@@ -1538,7 +1541,7 @@ class ProvingService:
             for req in cand:
                 try:
                     set_context(request_id=req.rid)
-                    with trace("service/inputs"), _lifespan(req, "inputs"):
+                    with _span(req, "inputs"):
                         fault_point("witness")
                         inputs.append(self.inputs_fn(req.payload))
                     batch.append(req)
@@ -1555,8 +1558,7 @@ class ProvingService:
             if not batch:
                 return []
             try:
-                with trace("service/witness_batch", n=len(batch)), \
-                        _lifespan(batch, "witness_batch", n=len(batch)):
+                with _span(batch, "witness_batch", n=len(batch)):
                     ws = self.cs.witness_batch(inputs)
                 # EVERY witness gets the Az∘Bz=Cz self-check, exactly like
                 # the scalar tier — only checking a sample would let an
@@ -1570,6 +1572,7 @@ class ProvingService:
                 return [r for r in batch if scalar_witness(r)]
 
         def produce():
+            adopt_stack(sweep_stack)  # the producer's spans are the sweep's children too
             try:
                 # adaptive: the controller's lane-sorted partition;
                 # static: fixed batch_size slices of the scan order —
@@ -1637,16 +1640,24 @@ class ProvingService:
                 # consumer blocks on ready_q.get() forever.
                 ready_q.put(None)
 
-        hb = threading.Thread(target=_sweep_heartbeat, daemon=True)
-        hb.start()
-        producer = threading.Thread(target=produce, daemon=True)
-        producer.start()
-        try:
-            self._consume(spool, ready_q, knobs, stats)
-        finally:
-            stop_hb.set()
-            hb.join()
-        producer.join()
+        # One span around a pass that found pending requests (leaf: the
+        # spans under it keep their paths and name it as their parent).
+        sweep = (
+            trace("service/sweep", leaf=True, t0=t_sweep, n_pending=len(pending))
+            if pending else contextlib.nullcontext({})
+        )
+        with sweep as sweep_rec:
+            sweep_stack = current_stack()
+            hb = threading.Thread(target=_sweep_heartbeat, daemon=True)
+            hb.start()
+            producer = threading.Thread(target=produce, daemon=True)
+            producer.start()
+            try:
+                sweep_rec["n_batches"] = self._consume(spool, ready_q, knobs, stats)
+            finally:
+                stop_hb.set()
+                hb.join()
+            producer.join()
         if producer_error:
             # Requests after the failure point got no witness, no proof
             # and no record this sweep — the claim-file discipline means
@@ -1669,14 +1680,21 @@ class ProvingService:
             pass
         return stats
 
-    def _consume(self, spool, ready_q, knobs, stats) -> None:
+    def _consume(self, spool, ready_q, knobs, stats) -> int:
         """Drain ready batches: deadline-gate, then prove with the full
         rescue ladder, terminal-ing every request exactly once.  Claims
-        stay fresh via the caller's sweep-level heartbeat."""
+        stay fresh via the caller's sweep-level heartbeat.  Returns how
+        many batches it fetched."""
+        n_batches = 0
         while True:
+            t_wait = time.time()
             batch = ready_q.get()
             if batch is None:
-                break
+                return n_batches
+            # the prover had nothing to prove while it waited here (the
+            # sentinel's wait is the sweep ending, not a starved prover)
+            record("service/starved", t_wait, time.time(), n=len(batch))
+            n_batches += 1
             # deadline gate #2, at batch assembly: queue wait behind a
             # slow batch may have burned the remaining budget — check
             # again immediately before committing prove compute
@@ -1801,6 +1819,9 @@ class ProvingService:
         # scrape sees stage histograms, request-state counters, and a
         # scrape-time native counter refresh.
         maybe_start_metrics_server()
+        # lower/compile events counted per stage from the first
+        # sweep on, and logged when they fall under a service/* span
+        install_compile_listener()
         # Preflight (execution audit): arm every gate and report the
         # arms before the first request is claimed.  A failure here is
         # not swallowed, and a worker that proves on the device

@@ -27,7 +27,10 @@ one email at a time); this is the TPU data-parallel axis.
 
 from __future__ import annotations
 
+import queue
 import secrets
+import threading
+import time
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -518,9 +521,10 @@ def abc_evals(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
     by the single-chip and sharded H ladders (and vmapped over the batch
     axis by the dryrun's data-parallel step)."""
     m = 1 << dpk.log_m
-    a_ev = _matvec(dpk.a_coeff, dpk.a_wire, dpk.a_row, w_mont, m)
-    b_ev = _matvec(dpk.b_coeff, dpk.b_wire, dpk.b_row, w_mont, m)
-    return a_ev, b_ev, FR.mul(a_ev, b_ev)
+    with jax.named_scope("matvec"):
+        a_ev = _matvec(dpk.a_coeff, dpk.a_wire, dpk.a_row, w_mont, m)
+        b_ev = _matvec(dpk.b_coeff, dpk.b_wire, dpk.b_row, w_mont, m)
+        return a_ev, b_ev, FR.mul(a_ev, b_ev)
 
 
 def h_evals(dpk: DeviceProvingKey, w_mont: jnp.ndarray) -> jnp.ndarray:
@@ -532,15 +536,25 @@ def h_evals(dpk: DeviceProvingKey, w_mont: jnp.ndarray) -> jnp.ndarray:
     division — Z is constant on the coset and folded into h_bases), every
     step batched on limb lanes."""
     g = coset_gen(dpk.log_m)
+
+    def to_coset(ev):
+        with jax.named_scope("intt"):
+            coeffs = intt(ev, dpk.log_m)
+        with jax.named_scope("coset_ntt"):
+            return ntt(coset_shift(coeffs, g, dpk.log_m), dpk.log_m)
+
     a_ev, b_ev, c_ev = abc_evals(dpk, w_mont)
-    a_cos = ntt(coset_shift(intt(a_ev, dpk.log_m), g, dpk.log_m), dpk.log_m)
-    b_cos = ntt(coset_shift(intt(b_ev, dpk.log_m), g, dpk.log_m), dpk.log_m)
-    c_cos = ntt(coset_shift(intt(c_ev, dpk.log_m), g, dpk.log_m), dpk.log_m)
+    a_cos, b_cos, c_cos = to_coset(a_ev), to_coset(b_ev), to_coset(c_ev)
     return FR.sub(FR.mul(a_cos, b_cos), c_cos)
 
 
 def _h_and_planes(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
     h = h_evals(dpk, w_mont)
+    with jax.named_scope("recode"):
+        return _recode(dpk, w_mont, h)
+
+
+def _recode(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h: jnp.ndarray):
     if MSM_SIGNED:
         w_std = FR.from_mont(w_mont)
         h_window = H_BUCKET_WINDOW if _h_bucket() else MSM_WINDOW
@@ -698,7 +712,71 @@ def _pad_msm(bases, planes, n_to: int):
     return bases, planes
 
 
-def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = False):
+STAGES = ("h_planes", "msm_a", "msm_b1", "msm_b2", "msm_c", "msm_h")
+
+
+class _StageWatch:
+    """Writes one `stage/<name>` span per device stage of a batch.  The
+    proving thread names a stage as it enqueues it (`enqueued`: a few
+    bytes of its result to wait on, and when the host began to enqueue
+    it — the instant it had enqueued the stage before); this thread
+    waits for those results in turn.  One device runs what was enqueued
+    in order, so a stage ends when its result is ready and starts at the
+    later of the previous stage's end and its own enqueue: the spans
+    partition `device`, whose start is `t0`.  The eager gathers and pads
+    between two stages run between them on the device too, and count to
+    the later one.  A thread of its own, because the runtime bounds the
+    programs in flight and holds the enqueuing thread inside `dispatch`
+    while the first stages retire (PERF.md, PR 24): read from there,
+    their ends would all read as dispatch's."""
+
+    def __init__(self, t0: float):
+        from ..utils.trace import adopt_context, adopt_stack, current_context, current_stack
+
+        self.chunk = 0
+        self._t = t0
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
+        stack, ctx = current_stack(), current_context()
+
+        def run():
+            from ..utils.trace import record
+
+            adopt_stack(stack)  # the spans nest under `device`, open on the proving thread
+            adopt_context(ctx)
+            t_ready = t0
+            for name, chunk, t_enqueue, value in iter(self._q.get, None):
+                try:
+                    jax.block_until_ready(value)
+                except Exception:  # noqa: BLE001 — the proving thread meets it where it reads the accumulator
+                    return
+                t_start, t_ready = max(t_ready, t_enqueue), time.time()
+                record("stage/" + name, t_start, t_ready, chunk=chunk)
+
+        self._thread = threading.Thread(target=run, name="zkp2p-stage-watch", daemon=True)
+        self._thread.start()
+
+    def enqueued(self, name: str, value) -> None:
+        self._q.put((name, self.chunk, self._t, value))
+        self._t = time.time()
+
+    def close(self) -> None:
+        """Returns when the last stage enqueued has its result ready and
+        its span written."""
+        self._q.put(None)
+        self._thread.join()
+
+
+_first_element = jax.jit(lambda x: jax.lax.slice(x, (0,) * x.ndim, (1,) * x.ndim))
+
+
+def _enqueued(watch: Optional[_StageWatch], name: str, value):
+    if watch is not None:
+        watch.enqueued(name, value)
+    return value
+
+
+def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = False,
+                  watch: Optional[_StageWatch] = None):
     """The five big MSMs; everything else about the proof is host-cheap.
     The b/c MSMs run only over their pruned non-infinity lanes (plane
     columns gathered through b_sel/c_sel), and with width metadata each
@@ -719,6 +797,11 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         else (_jit_msm_g1_narrow, _jit_msm_g2_narrow)
     )
     w_all, h_planes = jh(dpk, w_mont)
+    if watch is not None:
+        # a few bytes that are ready when the stage is, cut from a plane by a
+        # program of their own: the stage's program stays as it is, and no
+        # plane is kept alive to wait on
+        watch.enqueued("h_planes", _first_element(jax.tree_util.tree_leaves(h_planes)[0]))
     if _glv():
         # GLV layout: G1 planes carry 2*n_wires columns (k1 digits for
         # the P half, k2 for the phi(P) half); the G2 MSM keeps its own
@@ -754,16 +837,16 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         b2_planes = g2_planes if _glv() else b_planes
         # windowed mode keeps the m1 wrapper so the compiled-executable
         # identity (and its persistent-cache entry) is unchanged
-        h_acc = (
+        h_acc = _enqueued(watch, "msm_h", (
             mh(h_b, h_planes)
             if _h_bucket()
             else m1(*_pad_msm(h_b, h_planes, g1_n))
-        )
+        ))
         return (
-            m1(*_pad_msm(a_b, w_planes, g1_n)),
-            m1(*_pad_msm(b1_b, b_planes, g1_n)),
-            m2(dpk.b2_bases, b2_planes),
-            m1(*_pad_msm(c_b, c_planes, g1_n)),
+            _enqueued(watch, "msm_a", m1(*_pad_msm(a_b, w_planes, g1_n))),
+            _enqueued(watch, "msm_b1", m1(*_pad_msm(b1_b, b_planes, g1_n))),
+            _enqueued(watch, "msm_b2", m2(dpk.b2_bases, b2_planes)),
+            _enqueued(watch, "msm_c", m1(*_pad_msm(c_b, c_planes, g1_n))),
             h_acc,
         )
 
@@ -825,11 +908,11 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         return accs[0] if len(accs) == 1 else G2J.add(accs[0], accs[1])
 
     return (
-        query("a", dpk.a_bases, dpk.a_nsel, dpk.a_wsel, None),
-        query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel),
-        query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel),
-        query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel),
-        (mh if _h_bucket() else m1)(g1_bases("h", dpk.h_bases), h_planes),
+        _enqueued(watch, "msm_a", query("a", dpk.a_bases, dpk.a_nsel, dpk.a_wsel, None)),
+        _enqueued(watch, "msm_b1", query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
+        _enqueued(watch, "msm_b2", query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
+        _enqueued(watch, "msm_c", query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel)),
+        _enqueued(watch, "msm_h", (mh if _h_bucket() else m1)(g1_bases("h", dpk.h_bases), h_planes)),
     )
 
 
@@ -1014,15 +1097,18 @@ def _h_planes_pod_fn(mesh):
 
     def local(dpk, w_mont):  # w_mont: (B_local, n_wires, 16)
         planes = jax.vmap(lambda x: digit_planes_from_limbs(FR.from_mont(x), MSM_WINDOW))
-        return planes(w_mont), planes(jax.vmap(h_evals, in_axes=(None, 0))(dpk, w_mont))
+        h = jax.vmap(h_evals, in_axes=(None, 0))(dpk, w_mont)
+        with jax.named_scope("recode"):
+            # the last is `done`: one limb of h a witness, ready when the stage is (_StageWatch waits on it)
+            return planes(w_mont), planes(h), h[:, 0, 0]
 
     return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P(), P("batch")),
-        out_specs=(P("batch"), P("batch")), check_vma=False,
+        out_specs=(P("batch"), P("batch"), P("batch")), check_vma=False,
     ))
 
 
-def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh):
+def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch: Optional[_StageWatch] = None):
     """One prove_tpu_batch chunk on a ("batch", "shard") pod mesh: the
     (B, n_wires, 16) witness chunk is placed batch-sharded
     (`NamedSharding(mesh, P("batch"))` — each batch group proves its
@@ -1039,9 +1125,10 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh):
 
     n_ici = mesh.shape["shard"]
     w_mont = jax.device_put(w_mont, NamedSharding(mesh, P("batch")))
-    w_planes, h_planes = _h_planes_pod_fn(mesh)(dpk, w_mont)
+    w_planes, h_planes, done = _h_planes_pod_fn(mesh)(dpk, w_mont)
+    _enqueued(watch, "h_planes", done)
 
-    def msm(curve, bases, planes):
+    def msm(name, curve, bases, planes):
         # lanes sized to the per-device slice (tiny CI circuits stay at
         # lanes ~ n/S instead of padding 16x to a 64-lane step); the pad
         # rule matches prove_tpu_sharded — bases to a multiple of
@@ -1049,18 +1136,18 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh):
         n = bases[0].shape[0]
         lanes = max(1, min(64, -(-n // n_ici)))
         b, p = pad_to_multiple(bases, planes, n_ici * lanes)
-        return msm_pod_batched(
+        return _enqueued(watch, name, msm_pod_batched(
             curve, b, p, mesh,
             dcn_axis="batch", ici_axis="shard", lanes=lanes, window=MSM_WINDOW,
-        )
+        ))
 
     b_planes = jnp.take(w_planes, dpk.b_sel, axis=-1)
     return (
-        msm(G1J, dpk.a_bases, w_planes),
-        msm(G1J, dpk.b1_bases, b_planes),
-        msm(G2J, dpk.b2_bases, b_planes),
-        msm(G1J, dpk.c_bases, jnp.take(w_planes, dpk.c_sel, axis=-1)),
-        msm(G1J, dpk.h_bases, h_planes),
+        msm("msm_a", G1J, dpk.a_bases, w_planes),
+        msm("msm_b1", G1J, dpk.b1_bases, b_planes),
+        msm("msm_b2", G2J, dpk.b2_bases, b_planes),
+        msm("msm_c", G1J, dpk.c_bases, jnp.take(w_planes, dpk.c_sel, axis=-1)),
+        msm("msm_h", G1J, dpk.h_bases, h_planes),
     )
 
 
@@ -1115,50 +1202,69 @@ def prove_tpu_batch(
     from ..utils.metrics import REGISTRY
     from ..utils.trace import trace
 
+    # Spans (utils.trace): `prep`, `device` and `finish` partition
+    # `tpu/prove_batch`.  `device` runs from the batch's first enqueue to
+    # the instant its last stage's result is ready; `dispatch` and one
+    # span per device stage (per chunk, _StageWatch) lie inside it.
     with trace("tpu/prove_batch", n=len(witnesses)):
-        sample_device_memory("tpu/prove_batch")  # entry watermark
-        for wit in witnesses:
-            _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
-        n = len(witnesses)
-        chunk = _batch_chunk_size()
-        if chunk <= 0 or n <= chunk:
-            spans = [list(witnesses)]
-        else:
-            spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
-            spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
-        mesh = _shard_mesh()
-        if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
-            _record_arm("tpu_shard", "fallback")
-            mesh = None
-        parts = []
-        for span in spans:
-            # one batched to_mont per chunk (not one device dispatch per witness)
-            w = FR.to_mont(jnp.asarray(np.stack([_witness_std_limbs(wit) for wit in span])))
-            parts.append(
-                _prove_batch_sharded(dpk, w, mesh)
-                if mesh is not None
-                else _prove_device(dpk, w, batched=True)
-            )
-            # sub-chunk HBM watermark: the batched pipeline's peak is
-            # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16
-            # with no telemetry) — sample per chunk so the staircase is
-            # on record BEFORE the allocator walks off the top
-            sample_device_memory("tpu/prove_batch_chunk")
-        accs = (
-            parts[0]
-            if len(parts) == 1
-            else jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        )
-        a, b1, c, hq = (g1_jac_to_host(accs[i]) for i in (0, 1, 3, 4))
-        b2 = g2_jac_to_host(accs[2])
-        proofs = [
-            _assemble(
-                dpk, (a[i], b1[i], b2[i], c[i], hq[i]),
-                rs[i] if rs is not None else 1 + secrets.randbelow(R - 1),
-                ss[i] if ss is not None else 1 + secrets.randbelow(R - 1),
-            )
-            for i in range(len(witnesses))
-        ]
-        sample_device_memory("tpu/prove_batch")  # exit watermark: batch HBM peak
+        with trace("prep"):
+            sample_device_memory("tpu/prove_batch")  # entry watermark
+            for wit in witnesses:
+                _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
+            n = len(witnesses)
+            chunk = _batch_chunk_size()
+            if chunk <= 0 or n <= chunk:
+                spans = [list(witnesses)]
+            else:
+                spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
+                spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
+            mesh = _shard_mesh()
+            if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
+                _record_arm("tpu_shard", "fallback")
+                mesh = None
+            limbs = np.stack([_witness_std_limbs(wit) for wit in spans[0]])
+        with trace("device", leaf=True) as device:
+            watch = _StageWatch(device["t0"])
+            try:
+                with trace("dispatch"):
+                    parts = []
+                    for i, span in enumerate(spans):
+                        if i:
+                            limbs = np.stack([_witness_std_limbs(wit) for wit in span])
+                        watch.chunk = i
+                        # one batched to_mont per chunk (not one device dispatch per
+                        # witness); the h_planes stage includes it
+                        w = FR.to_mont(jnp.asarray(limbs))
+                        parts.append(
+                            _prove_batch_sharded(dpk, w, mesh, watch)
+                            if mesh is not None
+                            else _prove_device(dpk, w, batched=True, watch=watch)
+                        )
+                        # sub-chunk HBM watermark: the batched pipeline's peak is
+                        # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16
+                        # with no telemetry) — sample per chunk so the staircase is
+                        # on record BEFORE the allocator walks off the top
+                        sample_device_memory("tpu/prove_batch_chunk")
+                    accs = (
+                        parts[0]
+                        if len(parts) == 1
+                        else jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+                    )
+                # these go to the host while the device is on a later stage
+                a, b1, c = (g1_jac_to_host(accs[i]) for i in (0, 1, 3))
+            finally:
+                watch.close()  # the last stage's result is ready: the device has nothing left
+        with trace("finish"):
+            hq = g1_jac_to_host(accs[4])
+            b2 = g2_jac_to_host(accs[2])
+            proofs = [
+                _assemble(
+                    dpk, (a[i], b1[i], b2[i], c[i], hq[i]),
+                    rs[i] if rs is not None else 1 + secrets.randbelow(R - 1),
+                    ss[i] if ss is not None else 1 + secrets.randbelow(R - 1),
+                )
+                for i in range(len(witnesses))
+            ]
+            sample_device_memory("tpu/prove_batch")  # exit watermark: batch HBM peak
     REGISTRY.counter("zkp2p_proves_total", {"prover": "tpu"}).inc(len(witnesses))
     return proofs

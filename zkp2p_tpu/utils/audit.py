@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -187,37 +188,58 @@ def sample_device_memory(stage: str = "") -> Optional[Dict]:
 
 
 # ---------------------------------------------------------------------------
-# Flight recorder, part 2: compile events.  jax.monitoring publishes
-# '/jax/core/compile/backend_compile_duration' per XLA compile; the
-# listener attributes each to the calling thread's CURRENT trace stage
-# (compiles run synchronously inside the first dispatch), so a 20-minute
-# cold prover compile shows up as compile seconds under its stage
-# instead of silently inflating the stage's own latency histogram.
+# Flight recorder, part 2: lower and compile events.  jax.monitoring
+# publishes '/jax/core/compile/jaxpr_to_mlir_module_duration' and
+# '.../backend_compile_duration' per jit cache miss; the listener
+# attributes each to the calling thread's CURRENT trace span (both run
+# synchronously inside the first dispatch), so a 20-minute
+# cold prover compile shows up as lowering and compile seconds under its
+# stage instead of silently inflating the stage's own latency histogram,
+# and a replica that re-lowers while it serves says in which stage.
 
 _compile_installed = False
 
+# event-name suffix -> (what the log line calls it, events counter, seconds counter)
+_JIT_EVENTS = {
+    "jaxpr_to_mlir_module_duration": ("lowering", "zkp2p_lower_events_total", "zkp2p_lower_seconds_total"),
+    "backend_compile_duration": ("compile", "zkp2p_compile_events_total", "zkp2p_compile_seconds_total"),
+}
+
+
+def _zero_jit_counters() -> None:
+    """0 is a reading: a warmed window that lowered nothing shows the
+    counters at zero (get-or-create, so also after a REGISTRY.reset())."""
+    for _what, events, seconds in _JIT_EVENTS.values():
+        REGISTRY.counter(events)
+        REGISTRY.counter(seconds)
+
 
 def install_compile_listener() -> bool:
-    """Idempotently register the jit-compile event listener; False when
-    the jax.monitoring API is unavailable."""
+    """Idempotently register the jit lower/compile event listener;
+    False when the jax.monitoring API is unavailable."""
     global _compile_installed
     if _compile_installed:
+        _zero_jit_counters()
         return True
     try:
         from jax import monitoring
     except Exception:  # noqa: BLE001 — jax absent or too old
         return False
 
-    from .trace import current_stack
+    from .trace import current_path
 
     def _on_event(name: str, secs: float, **_kw) -> None:
-        if not name.endswith("backend_compile_duration"):
+        kind = _JIT_EVENTS.get(name.rsplit("/", 1)[-1])
+        if kind is None:
             return
+        what, events, seconds = kind
         try:
-            stack = current_stack()
-            stage = "/".join(stack) if stack else "(none)"
-            REGISTRY.counter("zkp2p_compile_events_total", {"stage": stage}).inc()
-            REGISTRY.counter("zkp2p_compile_seconds_total", {"stage": stage}).inc(secs)
+            stage = current_path() or "(none)"
+            REGISTRY.counter(events, {"stage": stage}).inc()
+            REGISTRY.counter(seconds, {"stage": stage}).inc(secs)
+            if stage.startswith("service/"):
+                # a replica lowering or compiling while it serves
+                print(f"[service] {what} under {stage}: {secs:.3f}s", file=sys.stderr, flush=True)
         except Exception:  # noqa: BLE001 — observation must never fail a compile
             pass
 
@@ -225,6 +247,7 @@ def install_compile_listener() -> bool:
         monitoring.register_event_duration_secs_listener(_on_event)
     except Exception:  # noqa: BLE001
         return False
+    _zero_jit_counters()
     _compile_installed = True
     return True
 

@@ -2,14 +2,29 @@
 
 The reference's observability is `start=$(date +%s)` brackets in shell
 scripts, `console.time("zk-dl"/"zk-gen")` and a UI stopwatch
-(SURVEY.md §5 tracing).  This is the structured version: nested stage
-timers with one JSON-lines sink, plus optional JAX profiler capture for
-xprof when JAX_TRACE_DIR is set.
+(SURVEY.md §5 tracing).  This is the structured version: nested spans
+with one JSON-lines sink.
 
     with trace("prove", batch=16):
         with trace("h_poly"):
             ...
-    dump_trace()  ->  [{"stage": "prove", "ms": ..., "batch": 16, ...}]
+    dump_trace()  ->  [{"stage": "prove", "ms": ..., "t0": ..., "id": 1,
+                        "parent": None, "batch": 16}, ...]
+
+A closed span records its path (`stage`), its duration (`ms`), its start
+on the wall clock (`t0`, `time.time()` — the clock of the spool's
+mtimes and of the request records), an `id` unique in the process and
+the `id` of the span that was open on its thread when it opened
+(`parent`; `adopt_stack` carries it to worker threads), the thread that
+closed it (`tid`), plus the ambient
+context (`request_id`) and its own attributes.  `record()` writes a span
+whose ends were read from clocks instead of bracketed by a `with`.
+
+While a span is open it is also a `jax.profiler.TraceAnnotation` of the
+same path, so a profiler capture shows the program's spans in the host
+plane on the trace's own clock — only where `jax` is already imported:
+this module never imports it (the native prover and the tools stay
+JAX-free).
 
 Every closed span also feeds the process metrics registry
 (utils.metrics REGISTRY, `zkp2p_stage_ms{stage=...}` histograms), so a
@@ -25,12 +40,13 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import sys
 import threading
 import time
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 
 def _ring_capacity() -> int:
@@ -86,37 +102,96 @@ def _append(rec: Dict[str, Any]) -> None:
         REGISTRY.counter("zkp2p_trace_dropped_total").inc()
 
 
-@contextlib.contextmanager
-def trace(stage: str, **attrs):
+# One frame per open span: (its path, its id, the path its children extend).
+_Frame = Tuple[str, int, str]
+_ids = itertools.count(1)  # next() is atomic under the GIL
+
+
+def _open(stage: str, leaf: bool = False):
+    """(stack, frame, parent id) for a span named `stage` under the span
+    open on this thread."""
     stack = getattr(_tls, "stack", None)
     if stack is None:
         stack = _tls.stack = []
-    stack.append(stage)
-    path = "/".join(stack)
-    t0 = time.perf_counter()
+    parent_id, prefix = (stack[-1][1], stack[-1][2]) if stack else (None, "")
+    path = f"{prefix}/{stage}" if prefix else stage
+    return stack, (path, next(_ids), prefix if leaf else path), parent_id
+
+
+def _close(rec: Dict[str, Any]) -> None:
+    for k, v in (getattr(_tls, "ctx", None) or {}).items():
+        rec.setdefault(k, v)  # explicit attributes win over the ambient context
+    _append(rec)
+    _observe_stage(rec["stage"], rec["ms"])
+
+
+def _annotation(path: str):
+    """The open span as a TraceAnnotation, where jax is already imported
+    (inactive outside a profiler capture: one C++ flag test)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
     try:
-        yield
+        ann = jax.profiler.TraceAnnotation(path)
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 — jax half-imported or without a profiler: no annotation
+        return None
+
+
+@contextlib.contextmanager
+def trace(stage: str, *, leaf: bool = False, t0: Optional[float] = None, **attrs):
+    """Open a span; yields its record, to which the body may add
+    attributes (`ms` is filled in at close).
+
+    leaf: the span is the parent of what opens under it, but their paths
+    extend its parent's path, not its own — a phase ("tpu/prove_batch/
+    device") whose children are named as the batch's, or a sweep around
+    spans whose paths predate it.
+    t0: the span began at this `time.time()` reading, taken before it was
+    known that there was a span to open."""
+    stack, frame, parent = _open(stage, leaf)
+    stack.append(frame)
+    rec = {"stage": frame[0], "ms": None, "t0": round(time.time() if t0 is None else t0, 6),
+           "id": frame[1], "parent": parent, "tid": threading.get_ident(), **attrs}
+    p0 = time.perf_counter()
+    ann = _annotation(frame[0])
+    try:
+        yield rec
     finally:
-        ms = round((time.perf_counter() - t0) * 1e3, 3)
-        ctx = getattr(_tls, "ctx", None)
-        rec = {"stage": path, "ms": ms}
-        if ctx:
-            rec.update(ctx)
-        rec.update(attrs)
-        _append(rec)
-        _observe_stage(path, ms)
+        secs = time.perf_counter() - p0 if t0 is None else time.time() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        rec["ms"] = round(secs * 1e3, 3)
         stack.pop()
+        _close(rec)
 
 
-def current_stack() -> List[str]:
-    """Snapshot of this thread's stage-nesting stack — hand it to worker
+def record(stage: str, t0: float, t1: float, **attrs) -> None:
+    """A span whose ends were read from clocks (`time.time()`), not
+    bracketed by a `with`: it nests under the span open on this thread
+    like any other, and being in the past gets no TraceAnnotation."""
+    _stack, frame, parent = _open(stage)
+    _close({"stage": frame[0], "ms": round((t1 - t0) * 1e3, 3), "t0": round(t0, 6),
+            "id": frame[1], "parent": parent, "tid": threading.get_ident(), **attrs})
+
+
+def current_path() -> str:
+    """The path of the span open on this thread ("" at a root)."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1][0] if stack else ""
+
+
+def current_stack() -> List[_Frame]:
+    """Snapshot of this thread's stack of open spans — hand it to worker
     threads (with adopt_stack) so their records keep the submitting
-    stage's path prefix instead of starting a fresh root."""
+    span's path prefix and name it as their parent instead of starting a
+    fresh root."""
     return list(getattr(_tls, "stack", None) or [])
 
 
-def adopt_stack(stack: List[str]) -> None:
-    """Seed THIS thread's nesting stack (see current_stack)."""
+def adopt_stack(stack: List[_Frame]) -> None:
+    """Seed THIS thread's stack of open spans (see current_stack)."""
     _tls.stack = list(stack)
 
 
@@ -211,19 +286,3 @@ def dump_trace(path: Optional[str] = None) -> None:
             os.close(fd)
     else:
         print("\n".join(json.dumps(r) for r in recs), file=sys.stderr)
-
-
-@contextlib.contextmanager
-def jax_profile(name: str = "zkp2p"):
-    """xprof capture when JAX_TRACE_DIR is set; no-op otherwise."""
-    trace_dir = os.environ.get("JAX_TRACE_DIR")
-    if not trace_dir:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(os.path.join(trace_dir, name))
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
