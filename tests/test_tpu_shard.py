@@ -173,16 +173,19 @@ def test_batch_arm_selection_and_fallback(toy_keys, monkeypatch):
 # ------------------------------------------- the batch's spans (stubbed)
 
 
-@pytest.mark.parametrize("chunk,n_chunks", [("0", 1), ("2", 2)])
+@pytest.mark.parametrize("chunk,n_chunks,h_road", [("0", 1, "resident"), ("2", 2, "resident"), ("0", 1, "scan")])
 def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_device(
-        toy_keys, monkeypatch, chunk, n_chunks):
+        toy_keys, monkeypatch, chunk, n_chunks, h_road):
     """prove_tpu_batch on the XLA road with the six stage executables
     stood in for (each compiles for minutes on XLA:CPU): the real
     `_prove_device` enqueues them and the real read loop writes the
     spans.  `prep` + `device` + `finish` partition `tpu/prove_batch`;
     `dispatch` and six stages a chunk lie in `device`, the stages in the
     order this road enqueues them (no narrow class: the h MSM first),
-    abutting, from `device`'s start to its end."""
+    abutting, from `device`'s start to its end.  The h MSM reads the
+    key's resident table where the window rule gives one (`h_road`),
+    built once a key under `tpu/prove_batch/h_table`, and its stage span
+    says which road it took."""
     import dataclasses
 
     import jax.numpy as jnp
@@ -220,13 +223,31 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
     monkeypatch.setattr(G, "_jit_h_planes_batch", fake_h_planes)
     monkeypatch.setattr(G, "_jit_msm_g1_batch", fake_msm((16,)))
     monkeypatch.setattr(G, "_jit_msm_h_batch", fake_msm((16,)))
+    m = 1 << dpk.log_m
+    built = []
+
+    def fake_h_table(bases, window):
+        built.append(window)
+        return np.zeros((1, 1 << (window - 1), int(bases[0].shape[0]), 16), np.uint32)
+
+    def fake_msm_resident(table, planes):
+        return fake_msm((16,))((np.zeros((table.shape[0] * table.shape[2], 16)),), planes)
+
+    monkeypatch.setattr(G, "_jit_h_table", fake_h_table)
+    monkeypatch.setattr(G, "_jit_msm_h_resident_batch", fake_msm_resident)
+    if h_road == "scan":
+        monkeypatch.setattr(G, "_h_table_window", lambda log_m: None)
     monkeypatch.setattr(G, "_jit_msm_g2_batch", fake_msm((2, 16)))
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
     tr.reset()
     out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3], ss=[4, 5, 6])
     assert len(out) == 3 and all(len(acc) == 5 for acc in out)  # five accumulators a proof, per witness
     # this road enqueues the h MSM first, and the stage spans say so
-    assert order[0::5] == [int(dpk.h_bases[0].shape[0])] * n_chunks
+    assert order[0::5] == [m] * n_chunks
+    from zkp2p_tpu.utils.metrics import REGISTRY
+
+    assert built == ([8] if h_road == "resident" else [])  # once a key, whatever the chunks
+    assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == (m * 128 * 64 if h_road == "resident" else 0)
     enqueued = ["h_planes", "msm_h", "msm_a", "msm_b1", "msm_b2", "msm_c"]
 
     recs = tr.records()
@@ -239,6 +260,12 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
     assert prep["ms"] + device["ms"] + finish["ms"] == pytest.approx(batch["ms"], rel=0.01, abs=1.0)
     assert dispatch["parent"] == device["id"] and dispatch["ms"] <= device["ms"]
     stages = sorted((r for r in recs if "/stage/" in r["stage"]), key=lambda r: r["id"])
+    assert [(r["window"], r["table"]) for r in stages if r["stage"].endswith("/msm_h")] == [
+        (8, "resident") if h_road == "resident" else (4, "scan")] * n_chunks
+    assert all("table" not in r for r in stages if not r["stage"].endswith("/msm_h"))
+    tables = [r for r in recs if r["stage"].endswith("/h_table")]
+    assert [(r["stage"], r["parent"]) for r in tables] == (
+        [("tpu/prove_batch/h_table", device["id"])] if h_road == "resident" else [])
     assert sorted(enqueued) == sorted(G.STAGES)
     assert [r["stage"].rsplit("/", 1)[1] for r in stages] == enqueued * n_chunks
     assert [r["chunk"] for r in stages] == [c for c in range(n_chunks) for _ in G.STAGES]
@@ -253,6 +280,59 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
         assert sum(r["ms"] for r in stages) == pytest.approx(device["ms"], rel=0.01, abs=20.0)
     else:  # the chunks' accumulators are concatenated on the device after the last stage (here that compiles)
         assert t_last <= t_device + 1e-3
+    tr.reset()
+
+
+def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
+    """`_prove_batch_sharded` recodes unsigned at MSM_WINDOW and runs
+    `msm_pod_batched`: it bypasses the key's resident h table by
+    construction.  The real road on the 1x4 virtual mesh, its two
+    compiled programs stood in for: no table is built, none is memoised
+    on the key, the gauge stays 0 and no `h_table` span is written."""
+    import dataclasses
+
+    import numpy as np
+
+    from zkp2p_tpu.curve.jcurve import G2J
+    from zkp2p_tpu.parallel import mesh as pmesh
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.utils import trace as tr
+    from zkp2p_tpu.utils.audit import gate_arms
+    from zkp2p_tpu.utils.metrics import REGISTRY
+
+    cs, _pk, _vk, dpk, x, y = toy_keys
+    dpk = dataclasses.replace(dpk)  # a key instance of its own: nothing memoised on it yet
+    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11), (1, 1)])
+    monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
+    monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")
+    monkeypatch.setattr(G, "BATCH_CHUNK", "0")
+    n_planes = 256 // G.MSM_WINDOW
+
+    def fake_h_planes_pod(mesh):
+        def run(dpk_, w_mont):
+            b = w_mont.shape[0]
+            return (np.zeros((b, n_planes, dpk_.n_wires), np.uint32),
+                    np.zeros((b, n_planes, 1 << dpk_.log_m), np.uint32), np.zeros((b,), np.uint32))
+        return run
+
+    def fake_msm_pod(curve, bases, planes, mesh, **kw):
+        limbs = (2, 16) if curve is G2J else (16,)
+        return tuple(np.zeros((planes.shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
+
+    monkeypatch.setattr(G, "_h_planes_pod_fn", fake_h_planes_pod)
+    monkeypatch.setattr(pmesh, "msm_pod_batched", fake_msm_pod)
+    monkeypatch.setattr(G, "_jit_h_table", lambda *a, **k: pytest.fail("the mesh road built a table"))
+    monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
+    REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0)
+    tr.reset()
+    out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3, 4], ss=[5, 6, 7, 8])
+    assert len(out) == 4 and gate_arms()["tpu_shard"] == "1x4"
+    recs = tr.records()
+    assert not [r for r in recs if r["stage"].endswith("/h_table")]
+    (h_stage,) = [r for r in recs if r["stage"].endswith("/stage/msm_h")]
+    assert "table" not in h_stage and "window" not in h_stage
+    assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == 0
+    assert not hasattr(dpk, "_h_table_cache")
     tr.reset()
 
 

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..curve.jcurve import AffPoint, JacPoint, JCurve
-from ..field.jfield import LIMB_BITS
+from ..field.jfield import LIMB_BITS, NUM_LIMBS
 
 SCALAR_BITS = 256
 
@@ -423,6 +423,118 @@ def _msm_windowed_impl(
     per_lane = horner_fold_planes(
         curve, curve.infinity((lanes,)), tuple(c for c in partials), window
     )
+    return tree_reduce(curve, per_lane, lanes)
+
+
+# ---------------------------------------------------------------------------
+# Fixed-base window multiples, resident with the key.  `_msm_windowed_impl`
+# rebuilds [1P..2^(w-1)P] for every chunk of every batch, in Jacobian, so
+# its accumulate is the full add.  Bases that belong to the key (the h
+# query) get the table ONCE, normalised to affine: the accumulate becomes
+# select -> negate y -> `add_mixed`, and a wide window costs memory, not a
+# table build a batch.
+#
+# Layout (chosen on the chip, PERF.md PR 25): (steps, 2^(w-1), lanes, 16)
+# u32, word j = x limb j | y limb j << 16 — the limbs are 16-bit values in
+# 32-bit words, so one word carries both coordinates: 64 bytes a multiple,
+# one gather for the two of them, and the scan slices it by step as it
+# slices the bases.  Entry k-1 holds k*P; digit 0 is masked to (0, 0).
+
+RESIDENT_ENTRY_BYTES = 4 * NUM_LIMBS  # 16 words: x and y, 16 limbs of 16 bits each
+# The build runs in this many chunks of bases, ONE kernel width.  Its
+# temporaries are ~74 KB a base in flight at w=8 (2.49 GB at 2^15 bases,
+# compiled for the v5e): at a sixteenth of the bases they stay under what a
+# batch claims later (~7 KiB a base), so the build sets no memory peak.
+RESIDENT_BUILD_CHUNKS = 16
+
+
+def _affine_multiples(curve: JCurve, pt: AffPoint, n_table: int) -> AffPoint:
+    """Affine bases (C, 16) -> affine k*P for k = 1..n_table, (n_table, C,
+    16) a coordinate; a (0, 0) hole stays a hole in every multiple.
+
+    One `add_mixed` scan over k, then ONE inversion a base: prefix
+    products of the n_table Z's along k, `inv_fused` of the total, and
+    the suffix sweep back (7 products an entry).  Every kernel runs at
+    the one width C — `msm_affine.batch_inverse` over the whole array
+    would lower an instance a halving level."""
+    F = curve.F
+
+    def table_step(prev, _):
+        return curve.add_mixed(prev, pt), prev
+
+    _, (X, Y, Z) = jax.lax.scan(table_step, curve.from_affine(pt), None, length=n_table)
+    inf = F.is_zero(Z)
+    Zs = F.select(inf, jnp.broadcast_to(F.one_mont, Z.shape), Z)
+
+    def prefix(run, z):
+        return F.mul(run, z), run
+
+    total, pre = jax.lax.scan(prefix, jnp.broadcast_to(F.one_mont, Z.shape[1:]), Zs)
+
+    def suffix(run, xs):  # run = 1 / (Z_1 * ... * Z_k)
+        X_k, Y_k, z, p = xs
+        zinv = F.mul(run, p)
+        zi2 = F.square(zinv)
+        return F.mul(run, z), (F.mul(X_k, zi2), F.mul(Y_k, F.mul(zi2, zinv)))
+
+    _, (x, y) = jax.lax.scan(suffix, F.inv_fused(total), (X, Y, Zs, pre), reverse=True)
+    zero = jnp.zeros_like(x)
+    return F.select(inf, zero, x), F.select(inf, zero, y)
+
+
+def resident_table(curve: JCurve, bases: AffPoint, window: int, lanes: int) -> jnp.ndarray:
+    """The signed window multiples of `bases` in the layout above, for
+    `msm_resident`, which reads the window off its shape.  Bases are padded with holes to
+    whole steps of `lanes`; built chunk by chunk inside one program."""
+    n = bases[0].shape[0]
+    lanes = min(lanes, n)
+    pad = (-n) % lanes
+    if pad:
+        bases = tuple(jnp.pad(c, [(0, pad)] + [(0, 0)] * (c.ndim - 1)) for c in bases)
+    steps = (n + pad) // lanes
+    n_table = 1 << (window - 1)
+    g = max(d for d in range(1, max(1, steps // RESIDENT_BUILD_CHUNKS) + 1) if steps % d == 0)  # steps a chunk
+
+    def chunk(pt):
+        x, y = _affine_multiples(curve, pt, n_table)  # (n_table, g * lanes, 16)
+        words = x | (y << LIMB_BITS)
+        return words.reshape((n_table, g, lanes) + words.shape[2:]).swapaxes(0, 1)
+
+    chunks = jax.lax.map(chunk, tuple(c.reshape((steps // g, g * lanes) + c.shape[1:]) for c in bases))
+    return chunks.reshape((steps,) + chunks.shape[2:])
+
+
+def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp.ndarray) -> JacPoint:
+    """`msm_windowed_signed` over the bases a `resident_table` was built
+    from, at the table's window (2^(w-1) entries a base): the same signed
+    digit planes, the same Horner fold and tree reduce, the same point.
+    The scan carries the step's slice of the
+    table in place of the bases.  Digit 0 selects (0, 0), which
+    `add_mixed` passes through; equal and opposite points stay the
+    kernel's case selects, so the sum is exact for every input."""
+    steps, n_table, lanes = table.shape[:3]
+    window = n_table.bit_length()
+    n_digits, n = mags.shape
+    pad = steps * lanes - n
+    if pad:
+        mags = jnp.pad(mags, [(0, 0), (0, pad)])
+        negs = jnp.pad(negs, [(0, 0), (0, pad)])
+    digits = mags.reshape(n_digits, steps, lanes).transpose(1, 0, 2)
+    neg_t = negs.reshape(n_digits, steps, lanes).transpose(1, 0, 2)
+    lane_ix = jnp.arange(lanes)[None, :]
+    F = curve.F
+    low = jnp.uint32((1 << LIMB_BITS) - 1)
+
+    def accumulate(acc, xs):
+        t, d, neg = xs
+        words = t[jnp.maximum(d, 1).astype(jnp.int32) - 1, lane_ix]  # (n_digits, lanes, 16)
+        words = jnp.where((d == 0)[..., None], jnp.uint32(0), words)
+        y = words >> LIMB_BITS
+        y = jnp.where(neg[..., None], F.neg(y), y)  # F.neg keeps -0 = 0
+        return curve.add_mixed(acc, (words & low, y)), None
+
+    partials, _ = jax.lax.scan(accumulate, curve.infinity((n_digits, lanes)), (table, digits, neg_t))
+    per_lane = horner_fold_planes(curve, curve.infinity((lanes,)), tuple(c for c in partials), window)
     return tree_reduce(curve, per_lane, lanes)
 
 

@@ -52,13 +52,16 @@ from ..curve.jcurve import (
 from ..field.bn254 import R
 from ..field.jfield import FR, lazy_segment_sum_mod
 from ..ops.msm import (
+    RESIDENT_ENTRY_BYTES,
     default_lanes,
     digit_planes_from_limbs,
     glv_extend_bases,
     glv_sel,
     glv_signed_planes_from_limbs,
+    msm_resident,
     msm_windowed,
     msm_windowed_signed,
+    resident_table,
     signed_digit_planes_from_limbs,
 )
 from ..ops.ntt import coset_shift, intt, ntt
@@ -115,6 +118,52 @@ def _glv() -> bool:
     Rides the signed-digit machinery, so MSM_SIGNED off disables it —
     the unsigned path stays the byte-stable fallback."""
     return _record_arm("msm_glv", MSM_GLV and MSM_SIGNED)
+
+
+# The h MSM's window multiples live in a table resident with the key
+# (ops.msm.resident_table) where the device can hold it: h_bases depend
+# on the key alone, so the table is built once and the window is a
+# function of what the key's size lets the chip hold — w=8 (32 planes,
+# 128 multiples a base), else w=4 (64 planes, 8 multiples), else the
+# in-scan table of `_msm_g1`.  A table fits when it and the prover's
+# working set stay under H_TABLE_HBM_FRACTION of the device's memory.
+# H_WORK_BYTES_A_POINT is that working set per domain point, the key
+# included, at a batch chunk of four: peak_hbm_bytes 459,698,176 at 2^16
+# is 7,014 B a point and 3,088,086,016 at 2^19 is 5,890 (PERF_LEDGER.jsonl,
+# PR 24); 7 KiB is the larger.  The fifth left free is the allocator's
+# fragmentation and the build's own temporaries (2.5 GB at 2^19, w=8,
+# which the batch's working set has not claimed yet when the table is
+# built).  Where the backend reports no memory_stats (XLA:CPU) the same
+# rule runs on NOMINAL_HBM_BYTES, one v5e chip's.
+H_TABLE_HBM_FRACTION = 0.8
+H_WORK_BYTES_A_POINT = 7 << 10
+NOMINAL_HBM_BYTES = 16 << 30
+
+
+def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int) -> Optional[int]:
+    """The widest signed window whose multiples table (2^(w-1) entries
+    of `entry_bytes` a base, 2^log_m bases) fits a device of
+    `bytes_limit` beside the prover's working set; None: neither does."""
+    for window in (8, 4):
+        if ((entry_bytes << (window - 1)) + H_WORK_BYTES_A_POINT) << log_m <= H_TABLE_HBM_FRACTION * bytes_limit:
+            return window
+    return None
+
+
+@lru_cache(maxsize=None)
+def _hbm_bytes_limit() -> int:
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or NOMINAL_HBM_BYTES)
+
+
+def _h_table_window(log_m: int) -> Optional[int]:
+    """The window at which this process keeps a resident h table for a
+    key of 2^log_m domain points; None: the h MSM takes today's road
+    (the table does not fit, or an arm gives the h planes another
+    layout).  Static under jit: `_recode` and `_prove_device` agree."""
+    if not MSM_SIGNED or _h_bucket() or _glv():
+        return None
+    return h_table_window(log_m, RESIDENT_ENTRY_BYTES, _hbm_bytes_limit())
 
 
 def _parse_mesh_spec(spec: str, n_devices: int) -> Optional[Tuple[int, int]]:
@@ -557,7 +606,7 @@ def _h_and_planes(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
 def _recode(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h: jnp.ndarray):
     if MSM_SIGNED:
         w_std = FR.from_mont(w_mont)
-        h_window = H_BUCKET_WINDOW if _h_bucket() else MSM_WINDOW
+        h_window = H_BUCKET_WINDOW if _h_bucket() else (_h_table_window(dpk.log_m) or MSM_WINDOW)
         if _glv():
             # G1 planes in the GLV-doubled column layout (k1 digits for
             # P_i, k2 digits for phi(P_i)): HALF the digit planes over
@@ -651,6 +700,15 @@ def _msm_h(bases, planes):
     return _msm_g1(bases, planes)
 
 
+def _h_table_fn(bases, window: int):
+    return resident_table(G1J, bases, window, default_lanes(bases[0].shape[0]))
+
+
+def _msm_h_resident(table, planes):
+    """The h MSM against the key's resident multiples table."""
+    return msm_resident(G1J, table, *planes)
+
+
 # Stage-wise jits, NOT one fused program: XLA compile time scales with
 # traced-graph size, so the pipeline is a handful of small executables
 # with intermediates staying on device between stages.  Since b/c
@@ -664,10 +722,13 @@ _jit_msm_g2 = jax.jit(_msm_g2)
 _jit_msm_h = jax.jit(_msm_h)
 _jit_msm_g1_narrow = jax.jit(_msm_g1_narrow)
 _jit_msm_g2_narrow = jax.jit(_msm_g2_narrow)
+_jit_h_table = jax.jit(_h_table_fn, static_argnames="window")
+_jit_msm_h_resident = jax.jit(_msm_h_resident)
 _jit_h_planes_batch = jax.jit(jax.vmap(_h_and_planes, in_axes=(None, 0)))
 _jit_msm_g1_batch = jax.jit(jax.vmap(_msm_g1, in_axes=(None, 0)))
 _jit_msm_g2_batch = jax.jit(jax.vmap(_msm_g2, in_axes=(None, 0)))
 _jit_msm_h_batch = jax.jit(jax.vmap(_msm_h, in_axes=(None, 0)))
+_jit_msm_h_resident_batch = jax.jit(jax.vmap(_msm_h_resident, in_axes=(None, 0)))
 _jit_msm_g1_narrow_batch = jax.jit(jax.vmap(_msm_g1_narrow, in_axes=(None, 0)))
 _jit_msm_g2_narrow_batch = jax.jit(jax.vmap(_msm_g2_narrow, in_axes=(None, 0)))
 
@@ -692,6 +753,27 @@ def _glv_key_bases(dpk: DeviceProvingKey, name: str, bases: AffPoint) -> AffPoin
         got = glv_extend_bases(bases)
         cache[name] = got
     return got
+
+
+def _h_table(dpk: DeviceProvingKey) -> Optional[jnp.ndarray]:
+    """The key's resident h table, built by the first prove that needs
+    it and memoised on the instance like `_split_cache` (not a pytree
+    field: its bytes never ride into a jitted stage as part of the key);
+    None where `_h_table_window` says the h MSM builds its multiples in
+    the scan.  `zkp2p_msm_h_table_bytes` says which."""
+    from ..utils.metrics import REGISTRY
+    from ..utils.trace import trace
+
+    window, table = _h_table_window(dpk.log_m), None
+    if window is not None:
+        table = getattr(dpk, "_h_table_cache", None)
+        if table is None:
+            with trace("h_table", window=window) as span:
+                table = jax.block_until_ready(_jit_h_table(dpk.h_bases, window=window))
+                span["bytes"] = int(table.nbytes)
+            setattr(dpk, "_h_table_cache", table)
+    REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0 if table is None else table.nbytes)
+    return table
 
 
 def _take_bases(bases, pos):
@@ -744,19 +826,19 @@ class _StageWatch:
             adopt_stack(stack)  # the spans nest under `device`, open on the proving thread
             adopt_context(ctx)
             t_ready = t0
-            for name, chunk, t_enqueue, value in iter(self._q.get, None):
+            for name, chunk, t_enqueue, value, attrs in iter(self._q.get, None):
                 try:
                     jax.block_until_ready(value)
                 except Exception:  # noqa: BLE001 — the proving thread meets it where it reads the accumulator
                     return
                 t_start, t_ready = max(t_ready, t_enqueue), time.time()
-                record("stage/" + name, t_start, t_ready, chunk=chunk)
+                record("stage/" + name, t_start, t_ready, chunk=chunk, **attrs)
 
         self._thread = threading.Thread(target=run, name="zkp2p-stage-watch", daemon=True)
         self._thread.start()
 
-    def enqueued(self, name: str, value) -> None:
-        self._q.put((name, self.chunk, self._t, value))
+    def enqueued(self, name: str, value, **attrs) -> None:
+        self._q.put((name, self.chunk, self._t, value, attrs))
         self._t = time.time()
 
     def close(self) -> None:
@@ -769,9 +851,9 @@ class _StageWatch:
 _first_element = jax.jit(lambda x: jax.lax.slice(x, (0,) * x.ndim, (1,) * x.ndim))
 
 
-def _enqueued(watch: Optional[_StageWatch], name: str, value):
+def _enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
     if watch is not None:
-        watch.enqueued(name, value)
+        watch.enqueued(name, value, **attrs)
     return value
 
 
@@ -796,6 +878,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         if batched
         else (_jit_msm_g1_narrow, _jit_msm_g2_narrow)
     )
+    h_table = _h_table(dpk)
     w_all, h_planes = jh(dpk, w_mont)
     if watch is not None:
         # a few bytes that are ready when the stage is, cut from a plane by a
@@ -819,17 +902,29 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         g1_bases = lambda name, b: b  # noqa: E731
         g1_cols = lambda sel: sel  # noqa: E731
 
+    def msm_h(scan):
+        """The h stage, enqueued: against the resident table where the
+        key has one, else `scan()` — today's road."""
+        if h_table is not None:
+            mhr = _jit_msm_h_resident_batch if batched else _jit_msm_h_resident
+            return _enqueued(watch, "msm_h", mhr(h_table, h_planes),
+                             window=int(h_table.shape[1]).bit_length(), table="resident")
+        return _enqueued(watch, "msm_h", scan(),
+                         window=H_BUCKET_WINDOW if _h_bucket() else MSM_WINDOW, table="scan")
+
     if not classed:
         a_b = g1_bases("a", dpk.a_bases)
         b1_b = g1_bases("b1", dpk.b1_bases)
         c_b = g1_bases("c", dpk.c_bases)
         h_b = g1_bases("h", dpk.h_bases)
-        # bucket-h mode: h no longer shares the unified executable, so
-        # padding a/b1/c up to the (domain-sized) h base count would be
-        # pure waste — unify the three query MSMs among themselves only.
+        # bucket-h mode, or a resident h table: h no longer shares the
+        # unified executable, so padding a/b1/c up to the (domain-sized)
+        # h base count would be pure waste — unify the three query MSMs
+        # among themselves only.
+        h_apart = _h_bucket() or h_table is not None
         g1_n = 0 if not _unified() else max(
             a_b[0].shape[0], b1_b[0].shape[0], c_b[0].shape[0],
-            *(() if _h_bucket() else (h_b[0].shape[0],)),
+            *(() if h_apart else (h_b[0].shape[0],)),
         )
         b_planes = _take_planes(w_planes, g1_cols(dpk.b_sel))
         c_planes = _take_planes(w_planes, g1_cols(dpk.c_sel))
@@ -837,7 +932,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         b2_planes = g2_planes if _glv() else b_planes
         # windowed mode keeps the m1 wrapper so the compiled-executable
         # identity (and its persistent-cache entry) is unchanged
-        h_acc = _enqueued(watch, "msm_h", (
+        h_acc = msm_h(lambda: (
             mh(h_b, h_planes)
             if _h_bucket()
             else m1(*_pad_msm(h_b, h_planes, g1_n))
@@ -912,7 +1007,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         _enqueued(watch, "msm_b1", query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
         _enqueued(watch, "msm_b2", query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
         _enqueued(watch, "msm_c", query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel)),
-        _enqueued(watch, "msm_h", (mh if _h_bucket() else m1)(g1_bases("h", dpk.h_bases), h_planes)),
+        msm_h(lambda: (mh if _h_bucket() else m1)(g1_bases("h", dpk.h_bases), h_planes)),
     )
 
 
@@ -1224,6 +1319,8 @@ def prove_tpu_batch(
                 mesh = None
             limbs = np.stack([_witness_std_limbs(wit) for wit in spans[0]])
         with trace("device", leaf=True) as device:
+            if mesh is None:
+                _h_table(dpk)  # the first batch of a key builds it: one `tpu/prove_batch/h_table` span
             watch = _StageWatch(device["t0"])
             try:
                 with trace("dispatch"):
