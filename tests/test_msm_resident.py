@@ -147,6 +147,39 @@ def test_window_rule_is_a_function_of_size_bytes_a_base_and_limit(log_m, limit, 
         assert (h_table_window(log_m, 64 * jmsm.RESIDENT_ENTRY_BYTES, limit) or 0) <= want
 
 
+# what a 16 GiB chip is given at each domain: today's three cells (2^16 and 2^19: a chunk
+# of four, w=8) and the published EmailVerify (2^22: one proof at a time, the w=4 table)
+@pytest.mark.parametrize("log_m,chunk,window", [
+    (16, 4, 8), (17, 4, 8), (18, 4, 8), (19, 4, 8), (20, 4, 4), (21, 2, 4), (22, 1, 4), (23, 1, None),
+])
+@pytest.mark.parametrize("limit", [16 * GIB, int(15.75 * GIB)])
+def test_chunk_and_window_are_functions_of_the_keys_size_and_the_devices_memory(log_m, chunk, window, limit):
+    from zkp2p_tpu.prover import groth16_tpu as G
+
+    assert G.batch_chunk_for(log_m, limit) == chunk
+    assert G.h_table_window(log_m, jmsm.RESIDENT_ENTRY_BYTES, limit, chunk) == window
+    # what the rule plans fits what it was given, and the next chunk up would not (or is the cap)
+    assert chunk == 1 or G.work_bytes_a_point(chunk) << log_m <= G.HBM_PLAN_FRACTION * limit
+    assert chunk == G.BATCH_CHUNK_MAX or G.work_bytes_a_point(2 * chunk) << log_m > G.HBM_PLAN_FRACTION * limit
+    # a chunk of four plans what PR 25's window rule planned: 7 KiB a point
+    assert G.work_bytes_a_point(4) == 7 << 10
+    assert G.h_table_window(log_m, jmsm.RESIDENT_ENTRY_BYTES, limit) == (8 if log_m <= 19 else 4 if log_m == 20 else None)
+
+
+@pytest.mark.parametrize("log_m,knob,on_tpu,want", [
+    (22, "auto", True, 1), (19, "auto", True, 4), (None, "auto", True, 4), (22, "auto", False, 0),
+    (22, "4", True, 4), (19, "2", False, 2), (22, "four", True, 1),
+])
+def test_the_knob_overrides_the_rule_and_the_arm_records_the_size_chosen(monkeypatch, log_m, knob, on_tpu, want):
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.utils import audit
+
+    monkeypatch.setattr(G, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(G, "BATCH_CHUNK", knob)
+    assert G._batch_chunk_size(log_m) == want
+    assert audit.gate_arms()["batch_chunk"] == str(want)
+
+
 @pytest.mark.parametrize("knob,value", [("MSM_H", "bucket"), ("MSM_GLV", True), ("MSM_SIGNED", False)])
 def test_an_arm_that_lays_the_h_planes_out_otherwise_keeps_the_scan(monkeypatch, knob, value):
     from zkp2p_tpu.prover import groth16_tpu as G
@@ -189,21 +222,19 @@ def _jac_g1(points):
     return x, y, jnp.asarray(z)
 
 
-def test_prove_tpu_batch_through_the_resident_table_is_byte_equal_to_prove_host(monkeypatch):
-    """The toy circuit through the real `prove_tpu_batch`: the h stage,
-    the table's build and the resident h MSM are the real programs; the
-    four witness MSMs — untouched by the table, minutes of XLA:CPU compile
+def _toy_world(monkeypatch):
+    """The toy circuit for the real `prove_tpu_batch`: the h stage, the
+    table's build and the resident h MSM are the real programs; the four
+    witness MSMs — untouched by the table, minutes of XLA:CPU compile
     each — are answered by the host curve from the very bases and planes
-    the prover hands them.  Two batches: the table is built once."""
+    the prover hands them.  Returns (cs, pk, dpk, four witnesses)."""
     from zkp2p_tpu.curve.host import g2_msm
     from zkp2p_tpu.curve.jcurve import g2_to_affine_arrays
     from zkp2p_tpu.field.tower import Fq2 as G2_FQ2
     from zkp2p_tpu.prover import device_pk
     from zkp2p_tpu.prover import groth16_tpu as G
-    from zkp2p_tpu.snark.groth16 import prove_host, setup
+    from zkp2p_tpu.snark.groth16 import setup
     from zkp2p_tpu.snark.r1cs import LC, ConstraintSystem
-    from zkp2p_tpu.utils import trace as tr
-    from zkp2p_tpu.utils.metrics import REGISTRY
 
     cs = ConstraintSystem("toy")
     out, x, y, z = cs.new_public("out"), cs.new_wire("x"), cs.new_wire("y"), cs.new_wire("z")
@@ -241,7 +272,17 @@ def test_prove_tpu_batch_through_the_resident_table_is_byte_equal_to_prove_host(
     monkeypatch.setattr(G, "_jit_msm_g2_batch", host_g2(G.MSM_WINDOW))
     monkeypatch.setattr(G, "_jit_msm_g2_narrow_batch", host_g2(4))
     monkeypatch.setattr(G, "_jit_msm_h_batch", lambda *a: pytest.fail("the h MSM took the scan"))
+    return cs, pk, dpk, wits
 
+
+def test_prove_tpu_batch_through_the_resident_table_is_byte_equal_to_prove_host(monkeypatch):
+    """Two batches of four as one chunk: the table is built once."""
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.snark.groth16 import prove_host
+    from zkp2p_tpu.utils import trace as tr
+    from zkp2p_tpu.utils.metrics import REGISTRY
+
+    cs, pk, dpk, wits = _toy_world(monkeypatch)
     tr.reset()
     rs, ss = [11, 12, 13, 14], [21, 22, 23, 24]
     first = G.prove_tpu_batch(dpk, wits, rs=rs, ss=ss)
@@ -259,4 +300,29 @@ def test_prove_tpu_batch_through_the_resident_table_is_byte_equal_to_prove_host(
     assert built["stage"] == "tpu/prove_batch/h_table" and built["window"] == 8 and built["bytes"] == table.nbytes
     h_stages = [r for r in recs if r["stage"].endswith("/stage/msm_h")]
     assert len(h_stages) == 2 and all(r["window"] == 8 and r["table"] == "resident" for r in h_stages)
+    tr.reset()
+
+
+@pytest.mark.parametrize("n,n_chunks", [(3, 2), (1, 1)])
+def test_a_batch_above_and_below_the_chunk_gives_the_proofs_of_one_at_a_time(monkeypatch, n, n_chunks):
+    """A chunk of two: three witnesses run as two chunks through one
+    executable (the tail padded with its last witness), one witness as a
+    batch of its own shape; either way each proof is the oracle's for its
+    own (witness, r, s), and the batch span says what was chosen."""
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.snark.groth16 import prove_host
+    from zkp2p_tpu.utils import trace as tr
+    from zkp2p_tpu.utils.metrics import REGISTRY
+
+    cs, pk, dpk, wits = _toy_world(monkeypatch)
+    monkeypatch.setattr(G, "BATCH_CHUNK", "2")
+    tr.reset()
+    rs, ss = [31, 32, 33][:n], [41, 42, 43][:n]
+    proofs = G.prove_tpu_batch(dpk, wits[:n], rs=rs, ss=ss)
+    assert proofs == [prove_host(pk, cs, wits[i], r=rs[i], s=ss[i]) for i in range(n)]
+    (batch,) = [r for r in tr.records() if r["stage"] == "tpu/prove_batch"]
+    assert (batch["n"], batch["chunk"], batch["n_chunks"], batch["log_m"]) == (n, 2, n_chunks, dpk.log_m)
+    assert REGISTRY.gauge("zkp2p_prove_chunk").value == 2
+    h_stages = [r for r in tr.records() if r["stage"].endswith("/stage/msm_h")]
+    assert [r["chunk"] for r in h_stages] == list(range(n_chunks))
     tr.reset()

@@ -42,3 +42,30 @@ def test_witness_u64_fast_path_rejects_unreduced():
             witness_to_device(rows)
     # boundary value R - 1 stays accepted
     witness_to_device(_to_u64_rows([R - 1]))
+
+
+def test_the_matvec_reduced_in_row_blocks_is_the_matvec_reduced_in_one(monkeypatch):
+    """Above SEGMENT_REDUCE_ROWS segments the rows' limb sums are reduced
+    a block of rows at a time: the same field elements as the one-block
+    program and as plain integers; a row count no block divides keeps the
+    one-block program."""
+    import jax.numpy as jnp
+
+    from zkp2p_tpu.field import jfield
+    from zkp2p_tpu.field.jfield import FR
+    from zkp2p_tpu.prover import groth16_tpu as G
+
+    rng = np.random.default_rng(11)
+    n_wires, m, nnz = 12, 8, 43
+    w = [int.from_bytes(rng.bytes(31), "little") % R for _ in range(n_wires)]
+    coeff = [int.from_bytes(rng.bytes(31), "little") % R for _ in range(nnz)]
+    wire = rng.integers(0, n_wires, nnz).astype(np.int32)
+    row = np.sort(rng.integers(0, m, nnz)).astype(np.int32)
+    args = (jnp.asarray(np.stack([FR.to_mont_host(c) for c in coeff])), jnp.asarray(wire), jnp.asarray(row),
+            jnp.asarray(np.stack([FR.to_mont_host(v) for v in w])), m)
+    one = np.asarray(G._matvec(*args))
+    want = [sum(coeff[j] * w[wire[j]] for j in range(nnz) if row[j] == i) % R for i in range(m)]
+    assert [FR.from_mont_host(r) for r in one] == want
+    for rows in (2, 4, 3):
+        monkeypatch.setattr(jfield, "SEGMENT_REDUCE_ROWS", rows)
+        assert (np.asarray(G._matvec(*args)) == one).all(), rows

@@ -433,6 +433,9 @@ def reduce_wide(field: JPrimeField, wide: jnp.ndarray) -> jnp.ndarray:
     return field.mul(t, field.r2_limbs)
 
 
+SEGMENT_REDUCE_ROWS = 1 << 19
+
+
 def lazy_segment_sum_mod(
     field: JPrimeField, values: jnp.ndarray, segment_ids: jnp.ndarray, num_segments: int
 ) -> jnp.ndarray:
@@ -443,5 +446,17 @@ def lazy_segment_sum_mod(
     systems.  This is the sparse-matvec primitive behind Az/Bz/Cz.
     """
     acc = jax.ops.segment_sum(values, segment_ids, num_segments=num_segments)
-    wide = _carry_canon(acc, NUM_LIMBS + 2)
-    return reduce_wide(field, wide)
+
+    def reduce(sums):
+        return reduce_wide(field, _carry_canon(sums, NUM_LIMBS + 2))
+
+    # `reduce_wide` multiplies wide: a (rows, 16, 16) tensor of partial
+    # products, 1,440 B a segment by the v5e compiler's count — 6.0 GB of
+    # temporaries at the 2^22 segments of EmailVerify(1024, 1536), twice
+    # that under vmap, more than the chip has (PERF.md, PR 26).  Above
+    # SEGMENT_REDUCE_ROWS the sums are reduced that many rows at a time;
+    # at or under it (2^19: venmo 256/192) the program is the one it was.
+    if num_segments <= SEGMENT_REDUCE_ROWS or num_segments % SEGMENT_REDUCE_ROWS:
+        return reduce(acc)
+    blocks = acc.reshape(num_segments // SEGMENT_REDUCE_ROWS, SEGMENT_REDUCE_ROWS, acc.shape[-1])
+    return jax.lax.map(reduce, blocks).reshape(acc.shape)

@@ -57,6 +57,13 @@ def _build_email_mini():
     return cs
 
 
+def _build_email_full():
+    from .email_verify import EmailVerifyParams, build_email_verify
+
+    cs, _ = build_email_verify(EmailVerifyParams())  # 1024/1536: the published size, 2.14M constraints
+    return cs
+
+
 def _build_amount_demo():
     from .amount_demo import amount_circuit
 
@@ -113,6 +120,11 @@ SPECS: Dict[str, CircuitSpec] = {
         CircuitSpec(
             "email_verify", _build_email_mini, 20,
             "generic DKIM EmailVerify at the CI shape (256/128)",
+        ),
+        CircuitSpec(
+            "email_verify-full", _build_email_full, 20,
+            "EmailVerify(1024, 1536, 121, 17) as email.circom:222 instantiates it (2^22 domain)",
+            flagship=True,
         ),
         CircuitSpec(
             "amount_demo", _build_amount_demo, 3,

@@ -343,6 +343,38 @@ def _u64_to_limbs16(a: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(a).view("<u2").astype(np.uint32).reshape(*a.shape[:-1], 16)
 
 
+# rows a call of a fixed-base batch kernel: each call builds its own window
+# table (8,160 additions) and inverts once, so a slice this long repays both
+_FIXED_BASE_MIN_ROWS = 1 << 14
+
+
+def _fixed_base_rows(kernel, base_arr: np.ndarray, sc: np.ndarray, out: np.ndarray) -> None:
+    """`kernel(base, scalars, n, out)` over the rows of `sc`, in slices on
+    a thread each: the kernels are single-threaded, keep their table on
+    the heap a call and run without the GIL, and every row of `out` is
+    the affine point of its own scalar whatever slice it falls in.  A key
+    of 2^22 domain points is 15 million rows (prover.setup_device)."""
+    import concurrent.futures
+
+    from ..utils.config import load_config
+
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    n = len(sc)
+    threads = load_config().native_threads or os.cpu_count() or 1
+    step = max(_FIXED_BASE_MIN_ROWS, -(-n // threads))
+
+    def run(lo: int) -> None:
+        hi = min(n, lo + step)
+        kernel(base_arr.ctypes.data_as(u64p), sc[lo:hi].ctypes.data_as(u64p), hi - lo, out[lo:hi].ctypes.data_as(u64p))
+
+    starts = range(0, n, step)
+    if len(starts) <= 1:
+        run(0)
+        return
+    with concurrent.futures.ThreadPoolExecutor(max_workers=len(starts)) as pool:
+        list(pool.map(run, starts))
+
+
 def g1_fixed_base_batch_mont_limbs(base: Tuple[int, int], scalars: Sequence[int]):
     """Batch k_i * base over G1, emitted directly as Montgomery (n, 16)
     u32 limb arrays (the DeviceProvingKey base layout) — skips every
@@ -351,13 +383,10 @@ def g1_fixed_base_batch_mont_limbs(base: Tuple[int, int], scalars: Sequence[int]
     if lib is None:
         return None
     n = len(scalars)
-    u64p = ctypes.POINTER(ctypes.c_uint64)
     base_arr = np.concatenate([_int_to_u64x4(base[0]), _int_to_u64x4(base[1])])
     sc = np.ascontiguousarray(_scalars_to_u64(scalars))
     out = np.zeros((n, 8), dtype=np.uint64)
-    lib.g1_fixed_base_batch_mont(
-        base_arr.ctypes.data_as(u64p), sc.ctypes.data_as(u64p), n, out.ctypes.data_as(u64p)
-    )
+    _fixed_base_rows(lib.g1_fixed_base_batch_mont, base_arr, sc, out)
     limbs = _u64_to_limbs16(out.reshape(n, 2, 4))  # (n, 2, 16)
     return limbs[:, 0], limbs[:, 1]
 
@@ -370,15 +399,12 @@ def g2_fixed_base_batch_mont_limbs(base, scalars: Sequence[int]):
     if lib is None:
         return None
     n = len(scalars)
-    u64p = ctypes.POINTER(ctypes.c_uint64)
     x, y = base
     base_arr = np.concatenate(
         [_int_to_u64x4(x.c0), _int_to_u64x4(x.c1), _int_to_u64x4(y.c0), _int_to_u64x4(y.c1)]
     )
     sc = np.ascontiguousarray(_scalars_to_u64(scalars))
     out = np.zeros((n, 16), dtype=np.uint64)
-    lib.g2_fixed_base_batch_mont(
-        base_arr.ctypes.data_as(u64p), sc.ctypes.data_as(u64p), n, out.ctypes.data_as(u64p)
-    )
+    _fixed_base_rows(lib.g2_fixed_base_batch_mont, base_arr, sc, out)
     limbs = _u64_to_limbs16(out.reshape(n, 4, 4))  # (n, 4, 16): x0 x1 y0 y1
     return limbs[:, 0:2], limbs[:, 2:4]

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..field.bn254 import R, fr_domain_root, fr_inv
-from ..field.jfield import FR
+from ..field.jfield import FR, field_mul_impl
 
 
 def _bit_reverse_perm(m: int) -> np.ndarray:
@@ -119,16 +119,84 @@ def _ntt_core(x: jnp.ndarray, tw: jnp.ndarray, perm: np.ndarray) -> jnp.ndarray:
     return jax.lax.fori_loop(0, log_m, stage, x)
 
 
+# The ladder's gathers keep their pace only while the vector they read is
+# small: on a v5e one transform of 2^22 points took 9.3 s through them (six
+# were the 55 s of EmailVerify(1024, 1536)'s h stage) and 1.3 s without,
+# before the butterfly became one kernel (PERF.md, PR 26).  Above
+# 2^NTT_GATHER_LOG points the transform takes the ladder without gathers
+# (`_ntt_constant_geometry`); at or under it the program is the one it was:
+# what the gather-free ladder does at 2^19 and 2^16 has not been measured.
+NTT_GATHER_LOG = 19
+
+
+def _bit_reverse_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """x[rev(i)] on (m, 16) without a gather of m rows: as a 2^p x 2^q
+    matrix of rows, reverse the row index (2^p whole slices), transpose,
+    reverse the new row index."""
+    m, limbs = x.shape
+    k = m.bit_length() - 1
+    p = k // 2
+    rows = x.reshape(1 << p, 1 << (k - p), limbs)[_bit_reverse_perm(1 << p)]
+    return jnp.swapaxes(rows, 0, 1)[_bit_reverse_perm(1 << (k - p))].reshape(m, limbs)
+
+
+def _butterfly(a: jnp.ndarray, b: jnp.ndarray, t: jnp.ndarray):
+    """(a + t*b, a - t*b): one fused kernel where the field's product is
+    one (ops.pallas_ntt), else the field's own three operations."""
+    if field_mul_impl() == "pallas":
+        from .pallas_ntt import butterfly
+
+        return butterfly(FR, a, b, t)
+    p = FR.mul(b, t)
+    return FR.add(a, p), FR.sub(a, p)
+
+
+@jax.jit
+def _ntt_constant_geometry(x: jnp.ndarray, tw: jnp.ndarray) -> jnp.ndarray:
+    """The same transform as `_ntt_core` on (m, 16) limbs with no gather
+    (Pease's constant geometry, decimation in time): every stage
+    multiplies the upper half by its twiddles, adds and subtracts the
+    two halves and interleaves sum and difference, so all log m stages
+    share one loop body over slices; the result comes out bit-reversed
+    and `_bit_reverse_rows` puts it in order.
+
+    The twiddle of position i at stage q is tw[rev(i mod 2^q)], periodic
+    in i; it is carried and grown a stage at a time (positions whose bit
+    q is set take the factor tw[m / 2^(q+2)]), one more product a stage
+    instead of a table a stage.  A jit of its own with the twiddles as
+    an argument, so a program that transforms six vectors lowers the
+    ladder once."""
+    m = x.shape[-2]
+    k, half = m.bit_length() - 1, m // 2
+    pos = jnp.arange(half, dtype=jnp.int32)
+
+    def stage(q, carry):
+        z, t = carry
+        z = jnp.stack(_butterfly(z[:half], z[half:], t), axis=-2).reshape(x.shape)
+        factor = jax.lax.dynamic_index_in_dim(tw, jnp.left_shift(1, jnp.maximum(k - 2 - q, 0)), keepdims=False)
+        return z, jnp.where((((pos >> q) & 1) == 1)[:, None], FR.mul(t, factor), t)
+
+    ones = jnp.broadcast_to(FR.one_mont, (half, x.shape[-1]))
+    return _bit_reverse_rows(jax.lax.fori_loop(0, k, stage, (x, ones))[0])
+
+
+def _transform(x: jnp.ndarray, tw: jnp.ndarray, log_m: int) -> jnp.ndarray:
+    if log_m <= NTT_GATHER_LOG:
+        return _ntt_core(x, tw, domain(log_m)["perm"])
+    fn = _ntt_constant_geometry
+    for _ in x.shape[:-2]:
+        fn = jax.vmap(fn, in_axes=(0, None))
+    return fn(x, tw)
+
+
 def ntt(x: jnp.ndarray, log_m: int) -> jnp.ndarray:
     """Evaluations of the coefficient vector on the 2^log_m roots domain."""
-    d = domain(log_m)
-    return _ntt_core(x, d["tw"], d["perm"])
+    return _transform(x, domain(log_m)["tw"], log_m)
 
 
 def intt(x: jnp.ndarray, log_m: int) -> jnp.ndarray:
     d = domain(log_m)
-    y = _ntt_core(x, d["tw_inv"], d["perm"])
-    return FR.mul(y, d["m_inv_mont"])
+    return FR.mul(_transform(x, d["tw_inv"], log_m), d["m_inv_mont"])
 
 
 @lru_cache(maxsize=None)

@@ -397,21 +397,8 @@ def cmd_service(args):
         stale_claim_s=args.stale_claim_s, deadline_s=args.deadline_s,
         spool_cap=args.spool_cap,
     )
-    if args.circuit == "venmo":
-        svc = ProvingService.for_venmo(cs, lay, params, dpk, vk, **svc_kw)
-    else:
-
-        def witness_fn(payload):
-            from ..inputs.email import email_verify_from_eml, generate_email_verify_inputs
-
-            with open(payload["eml_path"], "rb") as f:
-                email, modulus = email_verify_from_eml(f.read())
-            inputs = generate_email_verify_inputs(email, modulus, params, lay)
-            return cs.witness(inputs.public_signals, inputs.seed)
-
-        svc = ProvingService(
-            cs, dpk, vk, witness_fn, lambda w: list(w[1 : cs.num_public + 1]), **svc_kw
-        )
+    make = ProvingService.for_venmo if args.circuit == "venmo" else ProvingService.for_email_verify
+    svc = make(cs, lay, params, dpk, vk, **svc_kw)
     os.makedirs(args.spool, exist_ok=True)
     # graceful drain (docs/ROBUSTNESS.md §fleet): SIGTERM/SIGINT stop
     # claiming, finish in-flight batches, flush sinks, exit 0 — so a

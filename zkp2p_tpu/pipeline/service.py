@@ -1766,6 +1766,22 @@ class ProvingService:
     # ------------------------------------------------------------- daemon
 
     @classmethod
+    def _from_inputs_fn(cls, cs, dpk, vk, inputs_fn, **kw) -> "ProvingService":
+        """A service whose witness comes from `inputs_fn` (payload ->
+        (public signals, seed)): set as the service's `inputs_fn`, so
+        whole batches take the `witness_batch` tier."""
+
+        def witness_fn(payload: Dict) -> list:
+            pubs, seed = inputs_fn(payload)
+            return cs.witness(pubs, seed)
+
+        def public_fn(witness: list) -> list:
+            return list(witness[1 : cs.num_public + 1])
+
+        kw.setdefault("inputs_fn", inputs_fn)
+        return cls(cs, dpk, vk, witness_fn, public_fn, **kw)
+
+    @classmethod
     def for_venmo(cls, cs, lay, params, dpk, vk, keys=None, **kw) -> "ProvingService":
         """Service wired for the flagship circuit: request payloads are
         either {"eml_path": ...} (real DKIM email, keys resolved from the
@@ -1790,15 +1806,39 @@ class ProvingService:
             inputs = generate_inputs(email, modulus, order_id, claim_id, params, lay)
             return inputs.public_signals, inputs.seed
 
-        def witness_fn(payload: Dict) -> list:
-            pubs, seed = inputs_fn(payload)
-            return cs.witness(pubs, seed)
+        return cls._from_inputs_fn(cs, dpk, vk, inputs_fn, **kw)
 
-        def public_fn(witness: list) -> list:
-            return list(witness[1 : cs.num_public + 1])
+    @classmethod
+    def for_email_verify(cls, cs, lay, params, dpk, vk, keys=None, **kw) -> "ProvingService":
+        """Service wired for the generic EmailVerify circuit (models.
+        email_verify, TwitterResetRegex body): request payloads are either
+        {"eml_path": ...} (real DKIM email, keys resolved from the
+        known-keys registry) or the synthetic shape {"handle", "filler"}
+        (a password-reset email for @handle with `filler` body bytes
+        before it, signed under the demo key; hermetic tests and the
+        benchmark)."""
+        from ..inputs.email import (
+            email_verify_from_eml,
+            generate_email_verify_inputs,
+            make_test_key,
+            make_twitter_email,
+        )
 
-        kw.setdefault("inputs_fn", inputs_fn)
-        return cls(cs, dpk, vk, witness_fn, public_fn, **kw)
+        demo_key = make_test_key(1)
+
+        def inputs_fn(payload: Dict) -> tuple:
+            if "eml_path" in payload:
+                with open(payload["eml_path"], "rb") as f:
+                    email, modulus = email_verify_from_eml(f.read(), keys)  # unknown keys raise
+            else:
+                email = make_twitter_email(
+                    demo_key, handle=str(payload["handle"]), filler=int(payload.get("filler", 0))
+                )
+                modulus = demo_key.n
+            inputs = generate_email_verify_inputs(email, modulus, params, lay)
+            return inputs.public_signals, inputs.seed
+
+        return cls._from_inputs_fn(cs, dpk, vk, inputs_fn, **kw)
 
     def run(
         self,

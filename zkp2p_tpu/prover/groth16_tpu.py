@@ -125,27 +125,54 @@ def _glv() -> bool:
 # on the key alone, so the table is built once and the window is a
 # function of what the key's size lets the chip hold — w=8 (32 planes,
 # 128 multiples a base), else w=4 (64 planes, 8 multiples), else the
-# in-scan table of `_msm_g1`.  A table fits when it and the prover's
-# working set stay under H_TABLE_HBM_FRACTION of the device's memory.
-# H_WORK_BYTES_A_POINT is that working set per domain point, the key
-# included, at a batch chunk of four: peak_hbm_bytes 459,698,176 at 2^16
-# is 7,014 B a point and 3,088,086,016 at 2^19 is 5,890 (PERF_LEDGER.jsonl,
-# PR 24); 7 KiB is the larger.  The fifth left free is the allocator's
-# fragmentation and the build's own temporaries (2.5 GB at 2^19, w=8,
-# which the batch's working set has not claimed yet when the table is
-# built).  Where the backend reports no memory_stats (XLA:CPU) the same
-# rule runs on NOMINAL_HBM_BYTES, one v5e chip's.
-H_TABLE_HBM_FRACTION = 0.8
-H_WORK_BYTES_A_POINT = 7 << 10
+# in-scan table of `_msm_g1`.  The batch chunk is a function of the same
+# two things: as many proofs at a time, up to four, as the device holds
+# beside the key.  Both rules plan `work_bytes_a_point(chunk)` a domain
+# point — the key and `chunk` proofs' working set — under
+# HBM_PLAN_FRACTION of the device's memory.
+# KEY_BYTES_A_POINT and PROOF_BYTES_A_POINT were calibrated on the
+# ledger's peak_hbm_bytes (PERF_LEDGER.jsonl, PR 24, a chunk of four,
+# before any table): 3,088,086,016 at 2^19 with a key of 459,873,288 is
+# 877 B a point of key and 1,253 a proof; 459,698,176 at 2^16 is 7,014 B
+# a point for key and four proofs.  1 KiB and 1.5 KiB are the larger
+# readings rounded up, and give the 7 KiB a point that the window rule
+# planned at a chunk of four since PR 25.  Checked against the 2^22 run
+# (PERF.md, PR 26).  The fifth left free is the allocator's fragmentation
+# and the table build's own temporaries (2.5 GB at 2^19, w=8, which the
+# batch's working set has not claimed yet when the table is built).
+# Where the backend reports no memory_stats (XLA:CPU) the same rules run
+# on NOMINAL_HBM_BYTES, one v5e chip's.
+HBM_PLAN_FRACTION = 0.8
+KEY_BYTES_A_POINT = 1 << 10
+PROOF_BYTES_A_POINT = 3 << 9
+BATCH_CHUNK_MAX = 4
 NOMINAL_HBM_BYTES = 16 << 30
 
 
-def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int) -> Optional[int]:
+def work_bytes_a_point(chunk: int) -> int:
+    """Device bytes a domain point that the key and `chunk` proofs in
+    flight are planned to take."""
+    return KEY_BYTES_A_POINT + chunk * PROOF_BYTES_A_POINT
+
+
+def batch_chunk_for(log_m: int, bytes_limit: int) -> int:
+    """Proofs a chunk of `prove_tpu_batch` for a key of 2^log_m domain
+    points on a device of `bytes_limit`: BATCH_CHUNK_MAX where that many
+    working sets fit beside the key, half as many while they do not, and
+    never fewer than one."""
+    chunk = BATCH_CHUNK_MAX
+    while chunk > 1 and work_bytes_a_point(chunk) << log_m > HBM_PLAN_FRACTION * bytes_limit:
+        chunk //= 2
+    return chunk
+
+
+def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int, chunk: int = BATCH_CHUNK_MAX) -> Optional[int]:
     """The widest signed window whose multiples table (2^(w-1) entries
     of `entry_bytes` a base, 2^log_m bases) fits a device of
-    `bytes_limit` beside the prover's working set; None: neither does."""
+    `bytes_limit` beside the key and a chunk of `chunk` proofs; None:
+    neither does."""
     for window in (8, 4):
-        if ((entry_bytes << (window - 1)) + H_WORK_BYTES_A_POINT) << log_m <= H_TABLE_HBM_FRACTION * bytes_limit:
+        if ((entry_bytes << (window - 1)) + work_bytes_a_point(chunk)) << log_m <= HBM_PLAN_FRACTION * bytes_limit:
             return window
     return None
 
@@ -163,7 +190,10 @@ def _h_table_window(log_m: int) -> Optional[int]:
     layout).  Static under jit: `_recode` and `_prove_device` agree."""
     if not MSM_SIGNED or _h_bucket() or _glv():
         return None
-    return h_table_window(log_m, RESIDENT_ENTRY_BYTES, _hbm_bytes_limit())
+    limit = _hbm_bytes_limit()
+    # off a TPU `auto` does not chunk (0): plan the chunk a TPU would take
+    chunk = _batch_chunk_size(log_m) or batch_chunk_for(log_m, limit)
+    return h_table_window(log_m, RESIDENT_ENTRY_BYTES, limit, chunk)
 
 
 def _parse_mesh_spec(spec: str, n_devices: int) -> Optional[Tuple[int, int]]:
@@ -1246,15 +1276,23 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
     )
 
 
-def _batch_chunk_size() -> int:
+def _batch_chunk_size(log_m: Optional[int] = None) -> int:
     """Sub-batch size for prove_tpu_batch; 0 = whole batch in one vmap.
 
-    "auto" chunks only on a real TPU: the batched pipeline's peak HBM is
-    linear in the vmapped batch (~1.3 GB per witness at the 499k venmo
-    shape on the XLA field path), so a 16-witness batch plans 20+ GB
-    against the v5e's 15.75 G — chunks of 4 keep every chunk's peak
-    under ~7 GB while reusing ONE compiled executable across chunks."""
-    auto = 4 if _on_tpu() else 0
+    "auto" chunks only on a real TPU, and there the chunk is a function
+    of the key's size and the device's memory (`batch_chunk_for`): the
+    batched pipeline's peak HBM is linear in the vmapped batch and in
+    the domain — 1,253 B a domain point a proof and 877 B a point of key
+    at the 499k venmo shape (PERF_LEDGER.jsonl, PR 24), so a chunk of
+    four peaks at 3.1 GB at 2^19 and would plan 29 GB at 2^22 against
+    the v5e's 15.75 G.  Four up to 2^20, two at 2^21, one from 2^22 on a
+    16 GB chip; every chunk reuses ONE compiled executable.  Without a
+    key (`log_m` None: preflight arms the gate before any key is loaded)
+    the answer is the largest chunk.  ZKP2P_BATCH_CHUNK, when a number,
+    overrides the rule."""
+    auto = 0
+    if _on_tpu():
+        auto = BATCH_CHUNK_MAX if log_m is None else batch_chunk_for(log_m, _hbm_bytes_limit())
     if BATCH_CHUNK == "auto":
         v = auto
     else:
@@ -1301,18 +1339,21 @@ def prove_tpu_batch(
     # `tpu/prove_batch`.  `device` runs from the batch's first enqueue to
     # the instant its last stage's result is ready; `dispatch` and one
     # span per device stage (per chunk, _StageWatch) lie inside it.
-    with trace("tpu/prove_batch", n=len(witnesses)):
+    with trace("tpu/prove_batch", n=len(witnesses), log_m=dpk.log_m) as batch_span:
         with trace("prep"):
             sample_device_memory("tpu/prove_batch")  # entry watermark
             for wit in witnesses:
                 _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
             n = len(witnesses)
-            chunk = _batch_chunk_size()
+            chunk = _batch_chunk_size(dpk.log_m)
             if chunk <= 0 or n <= chunk:
                 spans = [list(witnesses)]
             else:
                 spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
                 spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
+            # the size chosen (0: the whole batch as one) and how many ran
+            batch_span.update(chunk=chunk, n_chunks=len(spans))
+            REGISTRY.gauge("zkp2p_prove_chunk").set(chunk)
             mesh = _shard_mesh()
             if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
                 _record_arm("tpu_shard", "fallback")
@@ -1328,6 +1369,11 @@ def prove_tpu_batch(
                     for i, span in enumerate(spans):
                         if i:
                             limbs = np.stack([_witness_std_limbs(wit) for wit in span])
+                        if i and chunk < BATCH_CHUNK_MAX:
+                            # fewer than four at a time: the device's memory is the
+                            # ceiling, and a chunk enqueued behind another has its
+                            # buffers planned beside it — wait the last one out
+                            jax.block_until_ready(parts[-1])
                         watch.chunk = i
                         # one batched to_mont per chunk (not one device dispatch per
                         # witness); the h_planes stage includes it

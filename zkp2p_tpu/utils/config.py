@@ -264,10 +264,13 @@ KNOBS: Dict[str, Tuple[str, object, object]] = {
     # re-serializes — the byte-parity oracle arm.  Fresh-read per prove
     # at the _witness_std_u64 call site.
     "witness_u64": ("ZKP2P_WITNESS_U64", _not_zero, True),
-    # proof-batch sub-chunking: "auto" (4 per chunk on a real TPU — the
-    # 16 GB HBM budget; whole batch elsewhere), "0" (never chunk), or an
-    # explicit chunk size.  r5 bench1 on-chip: the batched h-evals stage
-    # materialises a (batch, nnz, 16, 16) partial-product tensor on the
+    # proof-batch sub-chunking: "auto" (on a real TPU a function of the
+    # key's size and the device's memory — groth16_tpu.batch_chunk_for:
+    # as many proofs a chunk, up to 4, as fit beside the key; 4 up to a
+    # 2^20 domain, 2 at 2^21, 1 from 2^22 on a 16 GB chip; whole batch
+    # elsewhere), "0" (never chunk), or an explicit chunk size, which
+    # overrides the rule.  r5 bench1 on-chip: the batched h-evals stage
+    # materialises a (batch, rows, 16, 16) partial-product tensor on the
     # XLA field path — 18 GB at batch=16 against 15.75 G HBM.
     "batch_chunk": ("ZKP2P_BATCH_CHUNK", str, "auto"),
     # device field/curve kernel selection — see field.jfield, curve.jcurve
