@@ -2,17 +2,34 @@
 
 The reference's H-polynomial FFTs run inside snarkjs/rapidsnark over the
 2^23-point domain (6.6M constraints -> next pow2; SURVEY.md §2.7, §7 step 3).
-Here each stage is a reshape + one batched Montgomery mul + add/sub —
-pure elementwise dataflow on (..., m, 16) limb tensors, `vmap`-able over
-proof batches and shardable over the coefficient axis (all-to-all at the
-stage boundary where the butterfly stride crosses the shard width).
+Here a transform is log m stages over (..., m, 16) Montgomery limb
+tensors, `vmap`-able over proof batches, and the file holds two ladders
+that compute the same field elements:
+
+- `_ntt_constant_geometry`, which `ntt` and `intt` run: Pease's constant
+  geometry.  Every stage slices the vector into its two halves, runs one
+  butterfly over them ((a + t*b, a - t*b): one Pallas kernel where the
+  field's product is one, `ops.pallas_ntt`, else the field's three
+  operations) and interleaves sum and difference, so all stages share
+  one loop body and none reads through an index vector; the twiddles
+  are grown a stage at a time and the bit-reversed result is put in
+  order by two row permutations and a transpose.  Under the prover's
+  batch axis the chunk goes through ONE kernel call a stage and shares
+  the twiddles.
+- `_ntt_core`, the decimation-in-time ladder whose stages are whole-
+  vector gathers.  On a v5e its gathers lose to the slices at every size
+  and chunk the served cells run (the table above `_transform`); it
+  stays as the local transform of `parallel/ntt.py` (`prove_tpu_sharded`:
+  many short row transforms between all-to-alls, which no served cell
+  runs and nobody has measured) and as this ladder's oracle in the tests.
 
 Twiddle tables are generated ON DEVICE in log m doubling steps
 (`_twiddle_powers`), so domain setup for 2^23 costs m Montgomery muls on
 TPU instead of m Python bigint muls on host.
 
 Differentially tested against the host oracle `snark.fft_host` (itself
-exercised by the Groth16 host tests).
+exercised by the Groth16 host tests), and the two ladders against each
+other (tests/test_ntt_constant_geometry.py).
 """
 
 from __future__ import annotations
@@ -119,16 +136,6 @@ def _ntt_core(x: jnp.ndarray, tw: jnp.ndarray, perm: np.ndarray) -> jnp.ndarray:
     return jax.lax.fori_loop(0, log_m, stage, x)
 
 
-# The ladder's gathers keep their pace only while the vector they read is
-# small: on a v5e one transform of 2^22 points took 9.3 s through them (six
-# were the 55 s of EmailVerify(1024, 1536)'s h stage) and 1.3 s without,
-# before the butterfly became one kernel (PERF.md, PR 26).  Above
-# 2^NTT_GATHER_LOG points the transform takes the ladder without gathers
-# (`_ntt_constant_geometry`); at or under it the program is the one it was:
-# what the gather-free ladder does at 2^19 and 2^16 has not been measured.
-NTT_GATHER_LOG = 19
-
-
 def _bit_reverse_rows(x: jnp.ndarray) -> jnp.ndarray:
     """x[rev(i)] on (m, 16) without a gather of m rows: as a 2^p x 2^q
     matrix of rows, reverse the row index (2^p whole slices), transpose,
@@ -167,6 +174,8 @@ def _ntt_constant_geometry(x: jnp.ndarray, tw: jnp.ndarray) -> jnp.ndarray:
     an argument, so a program that transforms six vectors lowers the
     ladder once."""
     m = x.shape[-2]
+    if m == 1:
+        return x
     k, half = m.bit_length() - 1, m // 2
     pos = jnp.arange(half, dtype=jnp.int32)
 
@@ -180,9 +189,30 @@ def _ntt_constant_geometry(x: jnp.ndarray, tw: jnp.ndarray) -> jnp.ndarray:
     return _bit_reverse_rows(jax.lax.fori_loop(0, k, stage, (x, ones))[0])
 
 
-def _transform(x: jnp.ndarray, tw: jnp.ndarray, log_m: int) -> jnp.ndarray:
-    if log_m <= NTT_GATHER_LOG:
-        return _ntt_core(x, tw, domain(log_m)["perm"])
+# Which ladder, measured on a v5e at the shapes the served cells run (PERF.md,
+# PR 27; ms, three or four runs in a row, the results bit-equal): one
+# transform of a chunk through the gather ladder and through constant
+# geometry, and the h stage's whole program (six transforms, two matvecs, the
+# recode) on the cell's own key beside its resident h table.
+#
+#   points x chunk   one transform             the h program
+#                    gathers      without      gathers            without
+#   2^16 x 1         12.1-12.4    4.6-4.7      55.4-58.9          33.5-34.4
+#   2^16 x 4         49.2-49.4    15.2-15.5    324.8-325.2        139.6-140.6
+#   2^17 x 4         106.3-106.4  31.6-31.7
+#   2^18 x 4         488.6-501.1  67.9-68.0
+#   2^19 x 1         184.8-185.1  44.1-44.2    1,372.0-1,372.6    502.4-503.4
+#   2^19 x 4         2,108-2,139  168.8-168.9  13,923.1-13,925.0  1,728.5-1,729.0
+#   2^22 x 1         9,261        under 600    (PR 26)
+#
+# The gathers fall off a cliff as the vector they read grows, and lose under
+# it too: there is no crossover to put a threshold at, so `ntt` and `intt`
+# run one ladder at every size.  LADDER is its name, as the prover's
+# `stage/h_planes` span carries it (`ntt`).
+LADDER = "constant_geometry"
+
+
+def _transform(x: jnp.ndarray, tw: jnp.ndarray) -> jnp.ndarray:
     fn = _ntt_constant_geometry
     for _ in x.shape[:-2]:
         fn = jax.vmap(fn, in_axes=(0, None))
@@ -191,12 +221,12 @@ def _transform(x: jnp.ndarray, tw: jnp.ndarray, log_m: int) -> jnp.ndarray:
 
 def ntt(x: jnp.ndarray, log_m: int) -> jnp.ndarray:
     """Evaluations of the coefficient vector on the 2^log_m roots domain."""
-    return _transform(x, domain(log_m)["tw"], log_m)
+    return _transform(x, domain(log_m)["tw"])
 
 
 def intt(x: jnp.ndarray, log_m: int) -> jnp.ndarray:
     d = domain(log_m)
-    return FR.mul(_transform(x, d["tw_inv"], log_m), d["m_inv_mont"])
+    return FR.mul(_transform(x, d["tw_inv"]), d["m_inv_mont"])
 
 
 @lru_cache(maxsize=None)

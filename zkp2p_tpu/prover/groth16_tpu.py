@@ -64,7 +64,7 @@ from ..ops.msm import (
     resident_table,
     signed_digit_planes_from_limbs,
 )
-from ..ops.ntt import coset_shift, intt, ntt
+from ..ops.ntt import LADDER as NTT_LADDER, coset_shift, intt, ntt
 
 # All tier knobs resolve through the ONE typed config (utils.config:
 # default -> env, with provenance); the module constants
@@ -914,7 +914,8 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         # a few bytes that are ready when the stage is, cut from a plane by a
         # program of their own: the stage's program stays as it is, and no
         # plane is kept alive to wait on
-        watch.enqueued("h_planes", _first_element(jax.tree_util.tree_leaves(h_planes)[0]))
+        watch.enqueued("h_planes", _first_element(jax.tree_util.tree_leaves(h_planes)[0]),
+                       ntt=NTT_LADDER)
     if _glv():
         # GLV layout: G1 planes carry 2*n_wires columns (k1 digits for
         # the P half, k2 for the phi(P) half); the G2 MSM keeps its own
@@ -1251,7 +1252,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
     n_ici = mesh.shape["shard"]
     w_mont = jax.device_put(w_mont, NamedSharding(mesh, P("batch")))
     w_planes, h_planes, done = _h_planes_pod_fn(mesh)(dpk, w_mont)
-    _enqueued(watch, "h_planes", done)
+    _enqueued(watch, "h_planes", done, ntt=NTT_LADDER)
 
     def msm(name, curve, bases, planes):
         # lanes sized to the per-device slice (tiny CI circuits stay at
