@@ -33,10 +33,9 @@ if os.environ.get("ZKP2P_NO_CACHE") != "1":
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
-# Slow-marked tests (model witnesses, sharded-prover compiles) are opt-in:
+# Slow-marked tests (model witnesses, device-prover compiles) are opt-in:
 # a default `pytest tests/` must finish on the 1-core CI host in minutes,
-# not hours.  Set ZKP2P_RUN_SLOW=1 to run them;
-# they are exercised out-of-band (and by the driver's dryrun/bench paths).
+# not hours.  Set ZKP2P_RUN_SLOW=1 to run them.
 import pytest  # noqa: E402
 
 
@@ -44,15 +43,15 @@ def pytest_collection_modifyitems(config, items):
     # Three tiers. default: fast semantics (<2 min). ZKP2P_RUN_SLOW=1
     # adds the model/witness/crypto differential tests (~minutes; the
     # committed per-round green-log tier). ZKP2P_RUN_XSLOW=1 adds the
-    # XLA-compile-heavy device-path differentials (prove_tpu / sharded
-    # prove): on this 1-core host XLA:CPU recompiles cost 2-15 min PER
-    # EXECUTABLE and cross-process cache reuse is unreliable (machine-
-    # feature-gated AOT entries), so these are exercised out-of-band —
-    # the driver's own bench.py and dryrun_multichip artifacts run the
-    # same code end-to-end (proof byte-equality + pairing verification)
-    # every round.
+    # XLA-compile-heavy device-path differentials (whole proves through
+    # the real MSM programs): on this 1-core host XLA:CPU recompiles
+    # cost 2-15 min PER EXECUTABLE and cross-process cache reuse is
+    # unreliable (machine-feature-gated AOT entries), so nothing runs
+    # them by default.  The same code runs end to end on the chip in
+    # every cell of the benchmark (proof byte-equality with the C++
+    # prover + pairing verification).
     if not os.environ.get("ZKP2P_RUN_XSLOW"):
-        skipx = pytest.mark.skip(reason="xslow; set ZKP2P_RUN_XSLOW=1 (covered by driver bench/dryrun artifacts)")
+        skipx = pytest.mark.skip(reason="xslow; set ZKP2P_RUN_XSLOW=1 (compile-heavy device differentials)")
         for item in items:
             if "xslow" in item.keywords:
                 item.add_marker(skipx)

@@ -4,7 +4,7 @@ execution digest, and the flight recorder.
 The gate-matrix test pins the one rule every backend gate funnels
 through: `on_tpu()` is the first device's `platform == "tpu"` and
 nothing else.  The device platform is mocked as "tpu" / "cpu" and every
-`auto` gate must resolve to its documented arm.
+choice the device prover makes by itself must follow it.
 """
 
 import re
@@ -34,49 +34,114 @@ def test_on_tpu_matrix(monkeypatch, plat, expect):
     assert audit.gate_arms()["on_tpu"] == ("tpu" if expect else "host")
 
 
+def _shape_key(n_wires=7, log_m=3):
+    """A DeviceProvingKey of shapes alone, for a `_prove_device` whose
+    stage programs are stood in for: 7 wires, a over all of them (4
+    narrow, 3 wide), b over 5 (3 + 2), c over 4 (3 + 1)."""
+    import numpy as np
+
+    from zkp2p_tpu.prover import groth16_tpu as g
+
+    u32, i32 = np.uint32, np.int32
+    g1 = lambda n: (np.zeros((n, 16), u32), np.zeros((n, 16), u32))  # noqa: E731
+    g2 = lambda n: (np.zeros((n, 2, 16), u32), np.zeros((n, 2, 16), u32))  # noqa: E731
+    sel = lambda n: np.arange(n, dtype=i32)  # noqa: E731
+    return g.DeviceProvingKey(
+        n_public=1, n_wires=n_wires, log_m=log_m,
+        a_coeff=np.zeros((1, 16), u32), a_wire=sel(1), a_row=sel(1),
+        b_coeff=np.zeros((1, 16), u32), b_wire=sel(1), b_row=sel(1),
+        a_bases=g1(n_wires), b1_bases=g1(5), b2_bases=g2(5), c_bases=g1(4), h_bases=g1(1 << log_m),
+        b_sel=sel(5), c_sel=sel(4),
+        a_nsel=sel(4), a_wsel=sel(3) + 4, b_nsel=sel(3), b_wsel=sel(2) + 3, c_nsel=sel(3), c_wsel=sel(1) + 3,
+        alpha_1=None, beta_1=None, beta_2=None, delta_1=None, delta_2=None)
+
+
+def _stand_in_stages(monkeypatch, g, dpk):
+    """The six stage programs stood in for; returns the base counts the
+    G1 MSMs were handed, by program."""
+    import numpy as np
+
+    n, m = dpk.n_wires, 1 << dpk.log_m
+    counts = {"g1": [], "g1_narrow": []}
+    planes = lambda b, k, cols: (np.zeros((b, k, cols), np.uint32), np.zeros((b, k, cols), bool))  # noqa: E731
+
+    def h_planes(dpk_, w_mont):
+        b = w_mont.shape[0]
+        return (planes(b, 64, n), planes(b, g.NARROW_PLANES, n)), planes(b, 64, m)
+
+    def msm(name, limbs):
+        def run(bases, planes_):
+            if name in counts:
+                counts[name].append(int(bases[0].shape[0]))
+            return tuple(np.zeros((planes_[0].shape[0],) + limbs, np.uint32) for _ in range(3))
+        return run
+
+    adds = type("Adds", (), {"add": staticmethod(lambda a, b: a)})
+    monkeypatch.setattr(g, "_jit_h_planes", h_planes)
+    monkeypatch.setattr(g, "_jit_msm_g1", msm("g1", (16,)))
+    monkeypatch.setattr(g, "_jit_msm_g1_narrow", msm("g1_narrow", (16,)))
+    monkeypatch.setattr(g, "_jit_msm_g2", msm("g2", (2, 16)))
+    monkeypatch.setattr(g, "_jit_msm_g2_narrow", msm("g2_narrow", (2, 16)))
+    monkeypatch.setattr(g, "G1J", adds)
+    monkeypatch.setattr(g, "G2J", adds)
+    monkeypatch.setattr(g, "_h_table_window", lambda log_m: None)  # the scan road: h through the G1 program too
+    return counts
+
+
 @pytest.mark.parametrize("plat,armed", [("tpu", True), ("cpu", False)])
 def test_auto_gates_resolve_documented_arms(monkeypatch, plat, armed):
-    """Every 'auto' impl gate arms exactly when the DEVICE platform is
-    a TPU."""
+    """What the device prover chooses by itself it chooses from the
+    DEVICE platform: on a TPU the batch is chunked, and the MSMs of a
+    class are padded to one base count so they share one executable (h
+    apart, at its own size); elsewhere neither."""
+    import numpy as np
+
     from zkp2p_tpu.prover import groth16_tpu as g
 
     _patch_backend(monkeypatch, plat)
-    monkeypatch.setattr(g, "MSM_UNIFIED", "auto")
-    monkeypatch.setattr(g, "MSM_AFFINE", "auto")
-    monkeypatch.setattr(g, "MSM_H", "auto")
-    monkeypatch.setattr(g, "MSM_SIGNED", True)
-    monkeypatch.setattr(g, "MSM_GLV", True)
     monkeypatch.setattr(g, "BATCH_CHUNK", "auto")
-    assert g._unified() is armed
-    assert g._affine() is armed
-    assert g._h_bucket() is armed
-    assert g._glv() is True  # GLV is backend-independent (signed-gated)
     assert g._batch_chunk_size() == (4 if armed else 0)
-    arms = audit.gate_arms()
-    assert arms["msm_unified"] == ("on" if armed else "off")
-    assert arms["msm_affine"] == ("on" if armed else "off")
-    assert arms["msm_h"] == ("bucket" if armed else "windowed")
-    assert arms["msm_glv"] == "on"
-    assert arms["batch_chunk"] == ("4" if armed else "0")
+    assert audit.gate_arms()["batch_chunk"] == ("4" if armed else "0")
+
+    dpk = _shape_key()
+    counts = _stand_in_stages(monkeypatch, g, dpk)
+    acc = g._prove_device(dpk, np.zeros((2, dpk.n_wires, 16), np.uint32))
+    assert len(acc) == 5
+    assert counts["g1_narrow"] == ([4, 4, 4] if armed else [4, 3, 3])  # a, b1, c
+    assert counts["g1"] == ([3, 3, 3, 8] if armed else [3, 2, 1, 8])  # a, b1, c wide; then h over the domain
 
 
-def test_forced_arms_beat_the_backend(monkeypatch):
-    """'1'/'bucket' force the arm even on a host backend (the tests-only
-    configuration), and signed-off disarms bucket-h and GLV."""
+def test_gate_arms_after_a_prove(monkeypatch):
+    """What a prove leaves in the gate map: the platform, the field and
+    curve implementations, the chunk and the mesh — and no arm of an
+    MSM formulation, because there is one.  The key's own stage programs
+    are traced (a gate baked into a program records at its trace), not
+    compiled: each answers with zeros of its output's shape."""
+    import jax
+    import numpy as np
+    from test_msm_resident import _no_narrow_class, _toy_world
+
     from zkp2p_tpu.prover import groth16_tpu as g
 
-    _patch_backend(monkeypatch, "cpu")
-    monkeypatch.setattr(g, "MSM_UNIFIED", "1")
-    monkeypatch.setattr(g, "MSM_AFFINE", "1")
-    monkeypatch.setattr(g, "MSM_H", "bucket")
-    monkeypatch.setattr(g, "MSM_SIGNED", True)
-    assert g._unified() is True and g._affine() is True and g._h_bucket() is True
-    # signed off: bucket-h and GLV ride the signed machinery
-    monkeypatch.setattr(g, "MSM_SIGNED", False)
-    monkeypatch.setattr(g, "MSM_GLV", True)
-    assert g._h_bucket() is False and g._glv() is False
-    assert audit.gate_arms()["msm_h"] == "windowed"
-    assert audit.gate_arms()["msm_glv"] == "off"
+    def traced(jitted):
+        def run(*args, **kw):
+            out = jax.eval_shape(jitted, *args, **kw)
+            return jax.tree_util.tree_map(lambda s: np.zeros(s.shape, s.dtype), out)
+        return run
+
+    _cs, _pk, dpk, wits = _toy_world(monkeypatch)
+    dpk = _no_narrow_class(dpk)  # one MSM a query: no curve add between two classes to run op by op
+    for name in ("_jit_h_planes", "_jit_h_table", "_jit_msm_h_resident"):
+        monkeypatch.setattr(g, name, traced(getattr(g, name)))
+    monkeypatch.setattr(g, "_assemble", lambda dpk_, acc, r, s: acc)
+    monkeypatch.setattr(audit, "_arms", {})
+    g.prove_tpu_batch(dpk, wits[:1], rs=[5], ss=[7])
+    arms = audit.gate_arms()
+    device = {k: v for k, v in arms.items() if not k.startswith("native_")}
+    assert set(device) - {"field_conv"} == {"on_tpu", "field_mul", "curve_kernel", "batch_chunk", "tpu_shard"}, arms
+    assert (device["on_tpu"], device["field_mul"], device["curve_kernel"]) == ("host", "xla", "xla")
+    assert (device["batch_chunk"], device["tpu_shard"]) == ("0", "off")
+    assert not {"msm_unified", "msm_affine", "msm_h", "msm_glv"} & set(arms)
 
 
 def test_field_and_curve_gates(monkeypatch):
@@ -120,7 +185,7 @@ def test_native_gates(monkeypatch):
     monkeypatch.setenv("ZKP2P_MSM_BATCH_AFFINE", "0")
     monkeypatch.setenv("ZKP2P_MSM_MULTI", "0")
     monkeypatch.setenv("ZKP2P_MSM_PRECOMP", "0")
-    assert npv._use_glv() is True
+    assert npv._glv_arm() is True
     assert npv._use_batch_affine() is False
     assert npv._use_msm_multi() is False
     assert npv._use_msm_precomp() is False
@@ -271,11 +336,12 @@ def test_a_forced_relowering_under_a_span_is_counted_with_the_stage_path(capsys)
 def test_preflight_reports_every_gate_and_is_stable():
     rep = audit.preflight(workload=False)
     for gate in (
-        "on_tpu", "field_mul", "curve_kernel", "msm_unified", "msm_affine",
-        "msm_h", "msm_glv", "batch_chunk", "native_msm_glv",
+        "on_tpu", "field_mul", "curve_kernel", "batch_chunk", "tpu_shard", "native_msm_glv",
         "native_batch_affine", "native_msm_multi", "native_tier",
     ):
         assert rep["gates"].get(gate), f"gate {gate} reported no arm"
+    # the device prover has one MSM formulation, so no arm of one
+    assert not {"msm_unified", "msm_affine", "msm_h", "msm_glv"} & set(rep["gates"])
     assert re.fullmatch(r"[0-9a-f]{16}", rep["execution_digest"])
     assert rep["backend"] == "cpu"
     assert "tpu_probe" not in rep  # no probe: the backend is what JAX initialised

@@ -15,9 +15,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_defaults():
     cfg = load_config(environ={})
-    assert cfg.msm_window == 4
-    assert cfg.msm_signed is True
-    assert cfg.msm_h == "windowed"
+    assert cfg.batch_chunk == "auto"
+    assert cfg.tpu_shard == "off"
+    assert cfg.msm_glv is False
     assert cfg.native_ifma is True
     # the native batch-affine bucket tier is the committed-on arm; its
     # parser follows the C runtime's leading-'0' rule like native_ifma
@@ -29,11 +29,6 @@ def test_defaults():
 
 def test_env_overrides_every_knob():
     env = {
-        "ZKP2P_MSM_WINDOW": "8",
-        "ZKP2P_MSM_SIGNED": "0",
-        "ZKP2P_MSM_UNIFIED": "1",
-        "ZKP2P_MSM_AFFINE": "1",
-        "ZKP2P_MSM_H": "bucket",
         "ZKP2P_MSM_GLV": "1",
         "ZKP2P_MSM_OVERLAP": "0",
         "ZKP2P_MSM_BATCH_AFFINE": "0",
@@ -112,8 +107,6 @@ def test_env_overrides_every_knob():
         "ZKP2P_FLAME_COOLDOWN_S": "15",
     }
     cfg = load_config(environ=env)
-    assert cfg.msm_window == 8 and cfg.msm_signed is False
-    assert cfg.msm_unified == "1" and cfg.msm_affine == "1" and cfg.msm_h == "bucket"
     assert cfg.msm_glv is True
     assert cfg.msm_overlap is False
     assert cfg.msm_batch_affine is False
@@ -299,9 +292,9 @@ def test_env_is_the_only_layer_above_defaults():
     import inspect
 
     assert list(inspect.signature(load_config).parameters) == ["environ"]
-    cfg = load_config(environ={"ZKP2P_MSM_H": "bucket"})
-    assert cfg.msm_h == "bucket" and cfg.provenance["msm_h"] == "env"
-    assert cfg.msm_affine == "0" and cfg.provenance["msm_affine"] == "default"
+    cfg = load_config(environ={"ZKP2P_BATCH_CHUNK": "2"})
+    assert cfg.batch_chunk == "2" and cfg.provenance["batch_chunk"] == "env"
+    assert cfg.tpu_shard == "off" and cfg.provenance["tpu_shard"] == "default"
     assert set(cfg.provenance.values()) == {"default", "env"}
 
 
@@ -314,11 +307,11 @@ def test_compile_cache_is_not_a_knob():
 
 
 def test_apply_env_roundtrip():
-    cfg = load_config(environ={"ZKP2P_MSM_H": "bucket", "ZKP2P_NATIVE_THREADS": "3"})
+    cfg = load_config(environ={"ZKP2P_BATCH_CHUNK": "2", "ZKP2P_NATIVE_THREADS": "3"})
     env: dict = {}
     cfg.apply_env(env)
-    assert env["ZKP2P_MSM_H"] == "bucket"
-    assert env["ZKP2P_MSM_SIGNED"] == "1"
+    assert env["ZKP2P_BATCH_CHUNK"] == "2"
+    assert env["ZKP2P_MSM_OVERLAP"] == "1"
     assert env["ZKP2P_NATIVE_THREADS"] == "3"
     # a second load from the exported env reproduces the config
     cfg2 = load_config(environ=env)

@@ -39,8 +39,7 @@ def test_doctor_report_parses_and_every_gate_reports_an_arm(doctor_report):
     assert rep["backend"] == "cpu"
     assert "tpu_probe" not in rep
     for gate in (
-        "on_tpu", "field_mul", "curve_kernel", "msm_unified", "msm_affine",
-        "msm_h", "msm_glv", "batch_chunk", "native_msm_glv",
+        "on_tpu", "field_mul", "curve_kernel", "batch_chunk", "tpu_shard", "native_msm_glv",
         "native_batch_affine", "native_msm_multi", "native_msm_precomp",
         "native_tier",
     ):

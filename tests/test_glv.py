@@ -1,12 +1,12 @@
-"""GLV endomorphism decomposition: host oracle, JAX limb kernel, and
-native C runtime diffed integer-for-integer, plus the group-law property
-k*P == k1*P + k2*phi(P) that the whole tentpole rests on.
+"""GLV endomorphism decomposition: host oracle and native C runtime
+diffed integer-for-integer, plus the group-law property
+k*P == k1*P + k2*phi(P) that the native arm rests on.
 
-The three implementations share derived constants (field.bn254 computes
+The two implementations share derived constants (field.bn254 computes
 the cube roots, the lattice basis, and the Barrett mus at import), so
 these tests pin both the math and the plumbing: a drifted constant or a
-limb-arithmetic bug in any one kernel breaks a parity assert here
-before it can reach a prover MSM."""
+limb-arithmetic bug in the kernel breaks a parity assert here before it
+can reach a prover MSM."""
 
 import random
 
@@ -82,74 +82,6 @@ def test_glv_endomorphism_group_law():
             t2 = g1_mul(phi, abs(k2))
             t2 = g1_neg(t2) if k2 < 0 else t2
             assert g1_add(t1, t2) == g1_mul(pt, k), k
-
-
-def _scalar_limbs(scalars):
-    import jax.numpy as jnp
-
-    from zkp2p_tpu.field.jfield import FR
-
-    return jnp.asarray(np.stack([FR.to_std_host(s) for s in scalars]))
-
-
-def _limbs_to_int(row):
-    return sum(int(v) << (16 * i) for i, v in enumerate(row))
-
-
-def test_jax_decomposer_matches_host():
-    from zkp2p_tpu.ops import msm as jmsm
-
-    ks = EDGE_SCALARS + _random_scalars(40)
-    m1, m2, n1, n2 = (np.asarray(a) for a in jmsm.glv_decompose_limbs(_scalar_limbs(ks)))
-    for i, k in enumerate(ks):
-        want = glv_decompose(k)
-        got = (
-            -_limbs_to_int(m1[i]) if n1[i] else _limbs_to_int(m1[i]),
-            -_limbs_to_int(m2[i]) if n2[i] else _limbs_to_int(m2[i]),
-        )
-        assert got == want, k
-
-
-def test_jax_glv_planes_reconstruct():
-    """Signed GLV digit planes decode back to k (mod r) through the
-    k1 + lambda*k2 identity, for every windowed/bucket window size."""
-    from zkp2p_tpu.ops import msm as jmsm
-
-    ks = EDGE_SCALARS + _random_scalars(8)
-    n = len(ks)
-    limbs = _scalar_limbs(ks)
-    for w in (4, 8, 16):
-        mags, negs = (np.asarray(a) for a in jmsm.glv_signed_planes_from_limbs(limbs, w))
-        nk = glv_num_planes(w)
-        assert mags.shape == (nk, 2 * n)
-        assert mags.max() <= (1 << (w - 1))
-        for i, k in enumerate(ks):
-            k1 = sum(
-                (-1) ** int(negs[j, i]) * int(mags[j, i]) * (1 << (w * (nk - 1 - j)))
-                for j in range(nk)
-            )
-            k2 = sum(
-                (-1) ** int(negs[j, n + i]) * int(mags[j, n + i]) * (1 << (w * (nk - 1 - j)))
-                for j in range(nk)
-            )
-            assert (k1 + k2 * GLV_LAMBDA - k) % R == 0, (w, k)
-
-
-def test_jax_glv_extend_bases_phi():
-    """glv_extend_bases emits [P, phi(P)] with (0,0) holes preserved."""
-    from zkp2p_tpu.curve.jcurve import g1_to_affine_arrays
-    from zkp2p_tpu.field.jfield import FQ
-    from zkp2p_tpu.ops.msm import glv_extend_bases
-
-    pts = [G1_GENERATOR, g1_mul(G1_GENERATOR, 7), None]
-    x2, y2 = (np.asarray(c) for c in glv_extend_bases(g1_to_affine_arrays(pts)))
-    assert x2.shape[0] == 6
-    for i, pt in enumerate(pts):
-        if pt is None:
-            assert not x2[3 + i].any() and not y2[3 + i].any()
-            continue
-        assert FQ.from_mont_host(x2[3 + i]) == GLV_BETA * pt[0] % P
-        assert FQ.from_mont_host(y2[3 + i]) == pt[1]
 
 
 # ---------------------------------------------------------------- native

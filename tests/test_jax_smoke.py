@@ -1,7 +1,7 @@
 """Fast JAX-path smoke checks for the default suite.
 
 The heavy differential files (test_jfield/test_jcurve/test_ops/
-test_parallel/test_prover_tpu) are ZKP2P_RUN_SLOW-gated because each
+test_prover_tpu) are ZKP2P_RUN_SLOW-gated because each
 costs minutes of XLA compile on a 1-core host.  This file keeps one tiny
 representative of each layer in the default run: a field mul, a curve
 add, and an NTT round trip — enough to catch gross breakage (wrong

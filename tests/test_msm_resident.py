@@ -180,13 +180,19 @@ def test_the_knob_overrides_the_rule_and_the_arm_records_the_size_chosen(monkeyp
     assert audit.gate_arms()["batch_chunk"] == str(want)
 
 
-@pytest.mark.parametrize("knob,value", [("MSM_H", "bucket"), ("MSM_GLV", True), ("MSM_SIGNED", False)])
-def test_an_arm_that_lays_the_h_planes_out_otherwise_keeps_the_scan(monkeypatch, knob, value):
+@pytest.mark.parametrize("log_m,want", [(16, 8), (20, 4), (23, None)])
+def test_the_process_takes_the_rule_and_nothing_else(monkeypatch, log_m, want):
+    """`_h_table_window` is `h_table_window` at the device's memory and
+    the chunk planned for the key: only the key's size and the device's
+    memory can refuse a table (XLA:CPU reports no memory_stats, so the
+    rule runs on the nominal chip, at the chunk a TPU would take)."""
     from zkp2p_tpu.prover import groth16_tpu as G
 
-    assert G._h_table_window(16) == 8  # XLA:CPU reports no memory_stats: the rule runs on the nominal chip
-    monkeypatch.setattr(G, knob, value)
-    assert G._h_table_window(16) is None
+    monkeypatch.setattr(G, "BATCH_CHUNK", "auto")
+    assert G._hbm_bytes_limit() == G.NOMINAL_HBM_BYTES
+    assert G._h_table_window(log_m) == want
+    chunk = G.batch_chunk_for(log_m, G.NOMINAL_HBM_BYTES)
+    assert want == G.h_table_window(log_m, jmsm.RESIDENT_ENTRY_BYTES, G.NOMINAL_HBM_BYTES, chunk)
 
 
 # ------------------------------------------- a toy batch through prove_tpu_batch
@@ -267,12 +273,52 @@ def _toy_world(monkeypatch):
             return gx, gy, jnp.asarray(z)
         return run
 
-    monkeypatch.setattr(G, "_jit_msm_g1_batch", host_g1(G.MSM_WINDOW))
-    monkeypatch.setattr(G, "_jit_msm_g1_narrow_batch", host_g1(4))
-    monkeypatch.setattr(G, "_jit_msm_g2_batch", host_g2(G.MSM_WINDOW))
-    monkeypatch.setattr(G, "_jit_msm_g2_narrow_batch", host_g2(4))
-    monkeypatch.setattr(G, "_jit_msm_h_batch", lambda *a: pytest.fail("the h MSM took the scan"))
+    monkeypatch.setattr(G, "_jit_msm_g1", host_g1(G.MSM_WINDOW))
+    monkeypatch.setattr(G, "_jit_msm_g1_narrow", host_g1(4))
+    monkeypatch.setattr(G, "_jit_msm_g2", host_g2(G.MSM_WINDOW))
+    monkeypatch.setattr(G, "_jit_msm_g2_narrow", host_g2(4))
     return cs, pk, dpk, wits
+
+
+def _no_narrow_class(dpk):
+    """The key as an imported zkey without width inference has it: every
+    position of a query in its wide class."""
+    import dataclasses
+
+    none = jnp.zeros((0,), jnp.int32)
+    return dataclasses.replace(
+        dpk, a_nsel=none, b_nsel=none, c_nsel=none, a_wsel=jnp.arange(dpk.n_wires, dtype=jnp.int32),
+        b_wsel=jnp.arange(dpk.b_sel.shape[0], dtype=jnp.int32), c_wsel=jnp.arange(dpk.c_sel.shape[0], dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("classed", [True, False])
+def test_recode_has_one_shape(monkeypatch, classed):
+    """`_recode` returns `((mags, negs), narrow), (h_mags, h_negs)` for a
+    key with a narrow class and for one without (`narrow` is `()`): the
+    signed planes rebuild the witness and the h scalars mod r, h at the
+    resident table's window, and the narrow planes are the low
+    NARROW_PLANES of a w=4 recode."""
+    from zkp2p_tpu.prover import groth16_tpu as G
+
+    _cs, _pk, dpk, wits = _toy_world(monkeypatch)
+    assert int(dpk.a_nsel.shape[0]) > 0  # the toy key classes its constant-one wire narrow
+    if not classed:
+        dpk = _no_narrow_class(dpk)
+    m, rng = 1 << dpk.log_m, random.Random(28)
+    h_scalars = [rng.randrange(R) for _ in range(m - 2)] + [0, R - 1]
+    h = jnp.asarray(np.stack([FR.to_mont_host(v) for v in h_scalars]))
+    (w_planes, narrow), h_planes = jax.jit(G._recode)(dpk, G.witness_to_device(wits[0]), h)
+    assert _planes_to_scalars(tuple(p[None] for p in w_planes), G.MSM_WINDOW) == [[v % R for v in wits[0]]]
+    h_window = G._h_table_window(dpk.log_m)
+    assert h_window == 8 and h_planes[0].shape == h_planes[1].shape == (256 // h_window, m)
+    assert _planes_to_scalars(tuple(p[None] for p in h_planes), h_window) == [h_scalars]
+    if not classed:
+        assert narrow == ()
+        return
+    mags4, negs4 = jmsm.signed_digit_planes_from_limbs(FR.from_mont(G.witness_to_device(wits[0])), 4)
+    assert len(narrow) == 2 and narrow[0].shape == narrow[1].shape == (G.NARROW_PLANES, dpk.n_wires)
+    np.testing.assert_array_equal(narrow[0], mags4[-G.NARROW_PLANES:])
+    np.testing.assert_array_equal(narrow[1], negs4[-G.NARROW_PLANES:])
 
 
 def test_prove_tpu_batch_through_the_resident_table_is_byte_equal_to_prove_host(monkeypatch):

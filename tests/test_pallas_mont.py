@@ -42,8 +42,8 @@ def test_pallas_mont_padding_and_batch_dims():
 
 def test_pallas_mont_pow_inverse():
     """The fused square-and-multiply ladder (one kernel launch) vs the
-    host Fermat inverse — the batched-inversion primitive of the affine
-    MSM tier (ops.msm_affine)."""
+    host Fermat inverse — the inversion primitive of the resident h
+    table's build (ops.msm `_affine_multiples`, through `inv_fused`)."""
     from zkp2p_tpu.ops.pallas_mont import mont_pow
 
     xs = [rng.randrange(1, P) for _ in range(5)] + [1, P - 1]
@@ -64,9 +64,8 @@ def test_pallas_mont_pow_small_exponent():
 
 
 def test_pallas_mont_pow_under_vmap():
-    """The affine MSM tier calls inv_fused inside a scan UNDER VMAP in
-    the batched prover — exercise the pallas batching rule for the pow
-    kernel in interpret mode so the combination is not TPU-only."""
+    """Exercise the pallas batching rule for the pow kernel in interpret
+    mode, so a caller that vmaps `inv_fused` is not TPU-only."""
     import jax
 
     from zkp2p_tpu.ops.pallas_mont import mont_pow

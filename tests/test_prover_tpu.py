@@ -60,90 +60,6 @@ def test_tpu_matches_host_prover():
     assert verify(vk, got, [225])
 
 
-def test_tpu_glv_matches_host_prover():
-    """ZKP2P_MSM_GLV=1 device prover == host oracle, on BOTH the
-    unclassed toy circuit and a width-classed one (narrow wires ride
-    the non-GLV 3-plane path while the wide class and h decompose, and
-    the G2 planes carry b_sel-position columns).  Subprocess: the flag
-    is an import-time module constant (jit identities hang off it), so
-    an in-process monkeypatch could reuse a stale traced executable
-    whose shapes happen to match."""
-    import os
-    import subprocess
-    import sys
-    import textwrap
-
-    env = dict(os.environ, ZKP2P_MSM_GLV="1", JAX_PLATFORMS="cpu")
-    code = textwrap.dedent(
-        """
-        import random
-        from zkp2p_tpu.field.bn254 import R
-        from zkp2p_tpu.gadgets.core import bits2num, num2bits
-        from zkp2p_tpu.prover import device_pk, prove_tpu
-        from zkp2p_tpu.snark.groth16 import prove_host, setup, verify
-        from zkp2p_tpu.snark.r1cs import LC, ConstraintSystem
-
-        rng = random.Random(42)
-
-        def diff(cs, pub, assigns):
-            w = cs.witness(pub, assigns)
-            pk, vk = setup(cs)
-            dpk = device_pk(pk, cs)
-            r, s = rng.randrange(1, R), rng.randrange(1, R)
-            got = prove_tpu(dpk, w, r=r, s=s)
-            assert got == prove_host(pk, cs, w, r=r, s=s), cs.name
-            assert verify(vk, got, pub), cs.name
-            return dpk
-
-        cs = ConstraintSystem("toy")
-        out = cs.new_public("out")
-        x, y, z = cs.new_wire("x"), cs.new_wire("y"), cs.new_wire("z")
-        cs.enforce(LC.of(x), LC.of(y), LC.of(z), "mul")
-        cs.enforce(LC.of(z), LC.of(z), LC.of(out), "sq")
-        cs.compute(z, lambda a, b: a * b % R, [x, y])
-        # width tags exist even here (the constant-one wire), so this is
-        # the CLASSED path; the unclassed branch (zkey import shape,
-        # widths=None) is diffed explicitly below.
-        w = cs.witness([225], {x: 3, y: 5})
-        pk, vk = setup(cs)
-        dpk = diff(cs, [225], {x: 3, y: 5})
-        from zkp2p_tpu.prover.groth16_tpu import device_pk_from_rows
-        from zkp2p_tpu.snark.groth16 import domain_size_for, qap_rows
-
-        rows = qap_rows(cs)
-        dpk_u = device_pk_from_rows(
-            pk, [t[0] for t in rows], [t[1] for t in rows],
-            domain_size_for(cs), cs.num_wires, widths=None,
-        )
-        assert int(dpk_u.a_nsel.shape[0]) == 0  # really unclassed
-        r, s = rng.randrange(1, R), rng.randrange(1, R)
-        got = prove_tpu(dpk_u, w, r=r, s=s)
-        assert got == prove_host(pk, cs, w, r=r, s=s), "unclassed"
-        assert verify(vk, got, [225]), "unclassed"
-
-        cs = ConstraintSystem("classed")
-        out = cs.new_public("out")
-        x = cs.new_wire("x")
-        bits = num2bits(cs, x, 16, "xb")
-        y = bits2num(cs, bits[:8], "ylow")
-        z = cs.new_wire("z")
-        cs.enforce(LC.of(y), LC.of(x), LC.of(z), "mul")
-        cs.enforce(LC.of(z) + LC.const(3), LC.of(z), LC.of(out), "fin")
-        cs.compute(z, lambda a, b: a * b % R, [y, x])
-        cs.compute(out, lambda a: (a + 3) * a % R, [z])
-        xv = 0xBEEF
-        zv = (xv & 0xFF) * xv
-        dpk = diff(cs, [(zv + 3) * zv % R], {x: xv})
-        assert int(dpk.a_nsel.shape[0]) > 16 and int(dpk.a_wsel.shape[0]) >= 2
-        print("GLV-OK")
-        """
-    )
-    res = subprocess.run(
-        [sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=5400
-    )
-    assert res.returncode == 0 and "GLV-OK" in res.stdout, res.stderr[-2000:]
-
-
 def test_tpu_prover_wide_circuit():
     cs = build_wide()
     pub = [7, 11]

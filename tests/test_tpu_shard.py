@@ -220,9 +220,8 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
             return tuple(np.zeros((b,) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
         return run
 
-    monkeypatch.setattr(G, "_jit_h_planes_batch", fake_h_planes)
-    monkeypatch.setattr(G, "_jit_msm_g1_batch", fake_msm((16,)))
-    monkeypatch.setattr(G, "_jit_msm_h_batch", fake_msm((16,)))
+    monkeypatch.setattr(G, "_jit_h_planes", fake_h_planes)
+    monkeypatch.setattr(G, "_jit_msm_g1", fake_msm((16,)))
     m = 1 << dpk.log_m
     built = []
 
@@ -234,10 +233,10 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
         return fake_msm((16,))((np.zeros((table.shape[0] * table.shape[2], 16)),), planes)
 
     monkeypatch.setattr(G, "_jit_h_table", fake_h_table)
-    monkeypatch.setattr(G, "_jit_msm_h_resident_batch", fake_msm_resident)
+    monkeypatch.setattr(G, "_jit_msm_h_resident", fake_msm_resident)
     if h_road == "scan":
         monkeypatch.setattr(G, "_h_table_window", lambda log_m: None)
-    monkeypatch.setattr(G, "_jit_msm_g2_batch", fake_msm((2, 16)))
+    monkeypatch.setattr(G, "_jit_msm_g2", fake_msm((2, 16)))
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
     tr.reset()
     out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3], ss=[4, 5, 6])
@@ -333,6 +332,42 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
     assert "table" not in h_stage and "window" not in h_stage
     assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == 0
     assert not hasattr(dpk, "_h_table_cache")
+    tr.reset()
+
+
+@pytest.mark.parametrize("classed", [True, False])
+def test_prove_tpu_is_a_batch_of_one(monkeypatch, classed):
+    """`prove_tpu(dpk, w, r, s)` is `prove_tpu_batch(dpk, [w], [r],
+    [s])[0]`: the bytes of the batch and of `prove_host`, and what it
+    writes is the batch's — one `tpu/prove_batch` span with n=1 and six
+    `stage/*` spans under its `device`.  The h stage, the table's build
+    and the resident h MSM are the real programs at B=1; the witness
+    MSMs are test_msm_resident's host stand-ins.  Over a key with a
+    narrow class and one without."""
+    from test_msm_resident import _no_narrow_class, _toy_world
+
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.snark.groth16 import prove_host
+    from zkp2p_tpu.utils import trace as tr
+
+    cs, pk, dpk, wits = _toy_world(monkeypatch)
+    if not classed:
+        dpk = _no_narrow_class(dpk)
+    r, s = 1234567, 7654321
+    want = G.prove_tpu_batch(dpk, [wits[1]], [r], [s])[0]
+    tr.reset()
+    got = G.prove_tpu(dpk, wits[1], r, s)
+    assert got == want == prove_host(pk, cs, wits[1], r=r, s=s)
+    recs = tr.records()
+    (batch,) = [rec for rec in recs if rec["stage"] == "tpu/prove_batch"]
+    (device,) = [rec for rec in recs if rec["stage"] == "tpu/prove_batch/device"]
+    assert batch["n"] == 1 and device["parent"] == batch["id"]
+    stages = [rec for rec in recs if "/stage/" in rec["stage"]]
+    assert sorted(rec["stage"] for rec in stages) == sorted("tpu/prove_batch/stage/" + name for name in G.STAGES)
+    assert all(rec["parent"] == device["id"] for rec in stages)
+    assert not [rec for rec in recs if rec["stage"].startswith("tpu/prove/") or rec["stage"] == "tpu/prove"]
+    # unpinned blinding still proves: a fresh (r, s) a call
+    assert G.prove_tpu(dpk, wits[1]) != G.prove_tpu(dpk, wits[1])
     tr.reset()
 
 
@@ -466,6 +501,57 @@ def test_per_device_bucket_partials_match_unsharded():
     got = g1_jac_to_host(acc)
     for i, row in enumerate(batch_scalars):
         assert got[i] == g1_msm(pts, row), f"batch element {i}"
+
+
+@pytest.mark.slow
+@pytest.mark.xslow
+def test_make_mesh_shapes():
+    import jax
+
+    from zkp2p_tpu.parallel.mesh import make_mesh
+
+    assert make_mesh(8).shape["shard"] == 8
+    assert make_mesh(2).shape["shard"] == 2
+    assert make_mesh().size == len(jax.devices())
+
+
+@pytest.mark.slow
+@pytest.mark.xslow
+def test_msm_pod_batched_dcn_axis():
+    """A REAL collective over the dcn axis: proof batch data-parallel
+    over dcn, base axis sharded over ici, one proof point per batch element crossing
+    DCN — each batched result must equal the host oracle."""
+    import jax
+    import numpy as np
+
+    from zkp2p_tpu.curve.host import G1_GENERATOR, g1_msm, g1_mul
+    from zkp2p_tpu.curve.jcurve import G1J, g1_jac_to_host, g1_to_affine_arrays
+    from zkp2p_tpu.field.jfield import int_to_limbs
+    from zkp2p_tpu.ops import msm as jmsm
+    from zkp2p_tpu.parallel.mesh import make_pod_mesh, msm_pod_batched, pad_to_multiple
+
+    n = 11  # deliberately not a multiple of any mesh size (exercises padding)
+    mesh = make_pod_mesh(2, 4)  # 2 slices x 4-wide ICI on the 8 vdevs
+    rng = np.random.default_rng(42)
+    pts = [g1_mul(G1_GENERATOR, int(k)) for k in rng.integers(1, 2**62, n)]
+    rng = np.random.default_rng(7)
+    batch_scalars = [[int(s) for s in rng.integers(1, 2**62, n)] for _ in range(4)]
+    planes = jax.numpy.stack(
+        [
+            jmsm.digit_planes_from_limbs(
+                jax.numpy.asarray(np.stack([int_to_limbs(s) for s in sc])), 4
+            )
+            for sc in batch_scalars
+        ]
+    )
+    bases, planes = pad_to_multiple(g1_to_affine_arrays(pts), planes[0], 8)[0], planes
+    # pad the plane N axis to the padded base count
+    pad = bases[0].shape[0] - n
+    planes = jax.numpy.pad(planes, [(0, 0), (0, 0), (0, pad)])
+    acc = msm_pod_batched(G1J, bases, planes, mesh, lanes=8, window=4)
+    got = g1_jac_to_host(acc)
+    for i, sc in enumerate(batch_scalars):
+        assert got[i] == g1_msm(pts, sc), f"batch element {i}"
 
 
 # ------------------------------------------- real-mesh lowering (no chip)

@@ -629,8 +629,8 @@ def main():
     glv_grp = ap.add_mutually_exclusive_group()
     glv_grp.add_argument(
         "--glv", action="store_true",
-        help="GLV endomorphism arm: half the signed digit planes over the "
-        "endomorphism-doubled [P, phi(P)] base axis (implies --signed)",
+        help="native arms: the GLV endomorphism MSM (half the Pippenger windows "
+        "over the endomorphism-doubled [P, phi(P)] base axis)",
     )
     glv_grp.add_argument(
         "--no-glv", action="store_true",
@@ -785,17 +785,9 @@ def _dispatch(args):
     # ---- full windowed MSM ----
     limbs_np = rng.integers(0, 1 << 16, size=(n, 16), dtype=np.uint32)
     limbs_np[:, 15] &= 0x3FFF  # < 2^254, like Fr scalars (signed recoding bound)
-    lanes = args.lanes or default_lanes(2 * n if args.glv else n)
+    lanes = args.lanes or default_lanes(n)
     tag = f"n={n} lanes={lanes} w={args.window}"
-    if args.glv:
-        from zkp2p_tpu.ops.msm import glv_extend_bases, glv_signed_planes_from_limbs
-
-        gb = glv_extend_bases(bases)
-        mags, negs = glv_signed_planes_from_limbs(jnp.asarray(limbs_np), args.window)
-        f = jax.jit(lambda b, m, s: msm_windowed_signed(curve, b, m, s, lanes=lanes, window=args.window))
-        fargs = (gb, mags, negs)
-        tag += f" glv({mags.shape[0]} planes x 2n bases)"
-    elif args.signed:
+    if args.signed:
         mags, negs = signed_digit_planes_from_limbs(jnp.asarray(limbs_np), args.window)
         f = jax.jit(lambda b, m, s: msm_windowed_signed(curve, b, m, s, lanes=lanes, window=args.window))
         fargs = (bases, mags, negs)

@@ -295,9 +295,8 @@ GLV_MAX_BITS = max(GLV_MAX_K1.bit_length(), GLV_MAX_K2.bit_length())
 def glv_decompose(k: int):
     """k (mod r) -> (k1, k2) signed ints with k = k1 + k2*lambda (mod r)
     and |k_i| < 2^GLV_MAX_BITS.  This is the HOST ORACLE: it implements
-    the exact floor-Barrett limb algorithm of the JAX and C kernels
-    (ops.msm.glv_decompose_limbs, csrc glv_split) so the three can be
-    diffed integer-for-integer."""
+    the exact floor-Barrett limb algorithm of the C kernel (csrc
+    glv_split) so the two can be diffed integer-for-integer."""
     k %= R
     c1 = (k * GLV_MU1) >> GLV_SHIFT
     c2 = (k * GLV_MU2) >> GLV_SHIFT

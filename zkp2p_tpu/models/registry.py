@@ -79,10 +79,10 @@ def _build_dryrun_vid():
 
 
 def build_sha2b() -> Tuple[object, List[int]]:
-    """Two-block fixed SHA-256 over 128 padded private bytes — the
-    tools/sharded_scale.py shape (the flagship's dominant gadget family
-    at a 2^16 domain).  Returns (cs, digest bit wires); no publics (the
-    scale harness compares the witness digest against hashlib)."""
+    """Two-block fixed SHA-256 over 128 padded private bytes (the
+    flagship's dominant gadget family at a 2^16 domain): the circuit of
+    the benchmark's sha2b cells.  Returns (cs, digest bit wires); no
+    publics (a caller compares the witness digest against hashlib)."""
     from ..gadgets import core, sha256
     from ..snark.r1cs import ConstraintSystem
 
@@ -136,7 +136,7 @@ SPECS: Dict[str, CircuitSpec] = {
         ),
         CircuitSpec(
             "sha2b", lambda: build_sha2b()[0], 0,
-            "two-block SHA-256, the tools/sharded_scale.py scale shape",
+            "two-block SHA-256, the benchmark's sha2b shape",
         ),
         CircuitSpec(
             "regex_actor", _build_regex_actor, 2,

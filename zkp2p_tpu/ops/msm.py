@@ -88,7 +88,7 @@ def tree_reduce(curve: JCurve, pts: JacPoint, axis_len: int) -> JacPoint:
 def horner_fold_planes(curve: JCurve, init: JacPoint, planes_stacked, window: int) -> JacPoint:
     """MSB-first Horner fold over stacked digit-plane partials (leading
     axis = planes): acc = 2^window * acc + plane.  Shared by the
-    windowed, batch-affine, and bucket MSMs.
+    windowed and resident-table MSMs.
 
     The window doublings are a nested lax.scan: ONE compiled double
     graph instead of `window` inlined copies — for G2 (Fq2 limb towers)
@@ -165,149 +165,6 @@ def signed_digit_planes_from_limbs(limbs: jnp.ndarray, window: int = 4):
     return mags, negs
 
 
-# ---------------------------------------------------------------------------
-# GLV endomorphism decomposition (field.bn254 derives the constants):
-# every Fr scalar splits into two ~128-bit half-scalars k = k1 + k2*lam,
-# and k*P = k1*P + k2*phi(P) with phi(x, y) = (beta*x, y).  A length-n
-# G1 MSM becomes a length-2n MSM over HALF the signed digit planes —
-# the per-scalar sequential work (Horner fold, Pippenger windows/suffix)
-# halves, which is what the latency-bound small/medium MSMs and the
-# bucket triangle pay for.
-#
-# The decomposer is fully vectorised 16-bit-limb arithmetic (it must run
-# INSIDE _h_and_planes / vmap: the h scalars are born on device from the
-# NTT ladder, and a host round-trip per witness would serialize the
-# batch).  All multiprecision values are mod-2^256 wraparound; the final
-# half-scalars are tiny (< 2^GLV_MAX_BITS), so the top bit is the sign.
-
-from ..field.bn254 import (  # noqa: E402 — grouped with their consumers
-    GLV_BETA,
-    GLV_K1_TERMS,
-    GLV_K2_TERMS,
-    GLV_MU1,
-    GLV_MU2,
-    GLV_SHIFT,
-    glv_num_planes,
-)
-
-_GLV_SHIFT_LIMBS = GLV_SHIFT // LIMB_BITS
-_GLV_C_LIMBS = 9  # Barrett quotients are < 2^129 (8 limbs) + 1 margin
-NUM_LIMBS_GLV = 16  # half-scalars stay in the 16-limb layout (top half zero)
-
-
-def _mp_carry_stack(cols):
-    """Carry-propagate a list of per-limb column sums (each < 2^31) into
-    canonical 16-bit limbs, dropping the final carry (mod 2^(16*len))."""
-    out = []
-    carry = None
-    for c in cols:
-        cur = c if carry is None else c + carry
-        out.append(cur & jnp.uint32(0xFFFF))
-        carry = cur >> 16
-    return jnp.stack(out, axis=-1)
-
-
-def _mp_mul_const(limbs: jnp.ndarray, const: int, out_limbs: int) -> jnp.ndarray:
-    """(..., L) 16-bit limbs * python-int constant -> (..., out_limbs)
-    limbs of the product mod 2^(16*out_limbs).  Exact carries from limb
-    0 up, so high slices (Barrett shifts) are exact floors."""
-    zero = jnp.zeros(limbs.shape[:-1], jnp.uint32)
-    cols = [zero] * out_limbs
-    L1 = limbs.shape[-1]
-    j = 0
-    while (const >> (16 * j)) or j == 0:
-        cj = (const >> (16 * j)) & 0xFFFF
-        if cj:
-            prod = limbs * jnp.uint32(cj)  # 16x16-bit -> fits u32
-            lo, hi = prod & jnp.uint32(0xFFFF), prod >> 16
-            for i in range(L1):
-                if j + i < out_limbs:
-                    cols[j + i] = cols[j + i] + lo[..., i]
-                if j + i + 1 < out_limbs:
-                    cols[j + i + 1] = cols[j + i + 1] + hi[..., i]
-        j += 1
-    return _mp_carry_stack(cols)
-
-
-def _mp_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    n = a.shape[-1]
-    return _mp_carry_stack([a[..., i] + b[..., i] for i in range(n)])
-
-
-def _mp_sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """a - b mod 2^(16n) via two's complement (a + ~b + 1)."""
-    n = a.shape[-1]
-    cols = [a[..., i] + (b[..., i] ^ jnp.uint32(0xFFFF)) for i in range(n)]
-    cols[0] = cols[0] + jnp.uint32(1)
-    return _mp_carry_stack(cols)
-
-
-def _mp_neg(a: jnp.ndarray) -> jnp.ndarray:
-    return _mp_sub(jnp.zeros_like(a), a)
-
-
-def glv_decompose_limbs(limbs: jnp.ndarray):
-    """Standard-form Fr scalar limbs (..., 16) u32 -> (mag1, mag2, neg1,
-    neg2): half-scalar magnitude limbs (..., 16) and sign masks (...,)
-    with k = (-1)^neg1 * mag1 + (-1)^neg2 * mag2 * lambda (mod r).
-
-    Integer-for-integer identical to the host oracle
-    ``field.bn254.glv_decompose`` (floor-Barrett quotients, mod-2^256
-    accumulation) — the differential tests diff all three kernels."""
-    c1 = _mp_mul_const(limbs, GLV_MU1, _GLV_SHIFT_LIMBS + _GLV_C_LIMBS)[..., _GLV_SHIFT_LIMBS:]
-    c2 = _mp_mul_const(limbs, GLV_MU2, _GLV_SHIFT_LIMBS + _GLV_C_LIMBS)[..., _GLV_SHIFT_LIMBS:]
-    k1 = limbs
-    for c, (mag, sub) in zip((c1, c2), GLV_K1_TERMS):
-        t = _mp_mul_const(c, mag, NUM_LIMBS_GLV)
-        k1 = _mp_sub(k1, t) if sub else _mp_add(k1, t)
-    k2 = jnp.zeros_like(limbs)
-    for c, (mag, sub) in zip((c1, c2), GLV_K2_TERMS):
-        t = _mp_mul_const(c, mag, NUM_LIMBS_GLV)
-        k2 = _mp_sub(k2, t) if sub else _mp_add(k2, t)
-    neg1 = (k1[..., -1] >> 15).astype(bool)
-    neg2 = (k2[..., -1] >> 15).astype(bool)
-    mag1 = jnp.where(neg1[..., None], _mp_neg(k1), k1)
-    mag2 = jnp.where(neg2[..., None], _mp_neg(k2), k2)
-    return mag1, mag2, neg1, neg2
-
-
-def glv_signed_planes_from_limbs(limbs: jnp.ndarray, window: int = 4):
-    """Standard-form Fr limbs (..., n, 16) -> GLV signed digit planes
-    (mags, negs) of shape (glv_num_planes(window), ..., 2n): the first n
-    columns are k1's digits (for bases P_i), the last n are k2's (for
-    the endomorphism-mapped bases phi(P_i) — see `glv_extend_bases`).
-    A negative half-scalar flips every digit's sign mask (-(sum d_j 2^jw)
-    = sum (-d_j) 2^jw)."""
-    mag1, mag2, neg1, neg2 = glv_decompose_limbs(limbs)
-    nk = glv_num_planes(window)
-    m1, s1 = signed_digit_planes_from_limbs(mag1, window)
-    m2, s2 = signed_digit_planes_from_limbs(mag2, window)
-    m1, s1 = m1[-nk:], s1[-nk:]
-    m2, s2 = m2[-nk:], s2[-nk:]
-    mags = jnp.concatenate([m1, m2], axis=-1)
-    negs = jnp.concatenate([s1 ^ neg1, s2 ^ neg2], axis=-1)
-    return mags, negs
-
-
-def glv_extend_bases(bases: AffPoint) -> AffPoint:
-    """G1 affine base limbs (x, y) with leading axis n -> the GLV-doubled
-    (2n) base set [P_0..P_{n-1}, phi(P_0)..phi(P_{n-1})] with phi(x, y) =
-    (beta*x, y).  One batched Fq mul; (0, 0) infinity holes map to
-    (0, 0).  Key-dependent only, so callers cache it per proving key."""
-    from ..field.jfield import FQ
-
-    x, y = bases
-    beta = jnp.asarray(FQ.to_mont_host(GLV_BETA))
-    phix = FQ.mul(x, jnp.broadcast_to(beta, x.shape))
-    return jnp.concatenate([x, phix]), jnp.concatenate([y, y])
-
-
-def glv_sel(sel: jnp.ndarray, n: int) -> jnp.ndarray:
-    """Lift a base/plane column selector over n points to the GLV-doubled
-    layout: position j also selects its endomorphism twin j + n."""
-    return jnp.concatenate([jnp.asarray(sel), jnp.asarray(sel) + n])
-
-
 def msm_windowed_signed(
     curve: JCurve,
     bases: AffPoint,
@@ -322,8 +179,7 @@ def msm_windowed_signed(
     subtract — negligible next to a curve add).  The table cost is the
     batch-amortised term of the windowed MSM (it is witness-independent
     under vmap), so halving it is what makes w=8 win at small batches
-    too: ~63.8 adds/pt at batch=4 vs 95.5 unsigned (see the bench
-    arming note in prover.groth16_tpu)."""
+    too: ~63.8 adds/pt at batch=4 vs 95.5 unsigned."""
     return _msm_windowed_impl(curve, bases, mags, negs, lanes, window)
 
 
@@ -356,9 +212,9 @@ def _msm_windowed_impl(
     window: int,
 ) -> JacPoint:
     """Shared body of `msm_windowed` (negs=None: unsigned 2^w - 1 table +
-    masked accumulate — kept op-for-op identical so the sharded/dryrun
-    executables and their compile cache are untouched) and
-    `msm_windowed_signed` (half table + Y negation)."""
+    masked accumulate — the mesh road's, parallel.mesh) and
+    `msm_windowed_signed` (half table + Y negation — the one-chip
+    road's)."""
     signed = negs is not None
     n_digits = planes_in.shape[0]
     n = bases[0].shape[0]
@@ -379,9 +235,7 @@ def _msm_windowed_impl(
     F = curve.F
 
     def accumulate(acc, xs):
-        # the neg planes ride the scan only on the signed path — the
-        # unsigned jaxpr must stay IDENTICAL to keep the sharded/dryrun
-        # compile-cache entries valid
+        # the neg planes ride the scan only on the signed path
         if signed:
             pt, digits, neg = xs
         else:
@@ -455,8 +309,8 @@ def _affine_multiples(curve: JCurve, pt: AffPoint, n_table: int) -> AffPoint:
     One `add_mixed` scan over k, then ONE inversion a base: prefix
     products of the n_table Z's along k, `inv_fused` of the total, and
     the suffix sweep back (7 products an entry).  Every kernel runs at
-    the one width C — `msm_affine.batch_inverse` over the whole array
-    would lower an instance a halving level."""
+    the one width C — a tree inversion over the whole array would lower
+    an instance a halving level."""
     F = curve.F
 
     def table_step(prev, _):

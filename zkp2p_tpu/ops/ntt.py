@@ -18,10 +18,8 @@ that compute the same field elements:
   the twiddles.
 - `_ntt_core`, the decimation-in-time ladder whose stages are whole-
   vector gathers.  On a v5e its gathers lose to the slices at every size
-  and chunk the served cells run (the table above `_transform`); it
-  stays as the local transform of `parallel/ntt.py` (`prove_tpu_sharded`:
-  many short row transforms between all-to-alls, which no served cell
-  runs and nobody has measured) and as this ladder's oracle in the tests.
+  and chunk the served cells run (the table above `_transform`); nothing
+  the prover runs calls it: it stays as this ladder's oracle in the tests.
 
 Twiddle tables are generated ON DEVICE in log m doubling steps
 (`_twiddle_powers`), so domain setup for 2^23 costs m Montgomery muls on
@@ -100,7 +98,9 @@ def domain(log_m: int):
 
 
 def _ntt_core(x: jnp.ndarray, tw: jnp.ndarray, perm: np.ndarray) -> jnp.ndarray:
-    """Iterative DIT butterfly ladder on (..., m, 16) Montgomery limbs.
+    """Iterative DIT butterfly ladder on (..., m, 16) Montgomery limbs:
+    the oracle of tests/test_ntt_constant_geometry.py, not a road of the
+    prover's.
 
     ONE `fori_loop` stage body with gather-based butterflies instead of an
     unrolled per-stage reshape ladder: XLA compile time scales with traced

@@ -130,8 +130,8 @@ def _pow_kernel(nbits: int):
     254-step ladder runs inside one kernel (fori_loop, all state in
     VMEM).  The XLA-level `JPrimeField.pow_const` scan issues 2 mul
     dispatches per exponent bit — ~508 kernel launches per inversion —
-    which makes the per-chunk batch-inversion totals of the affine MSM
-    (ops.msm_affine) latency-bound; this kernel is one launch.
+    which makes the resident h table's build (ops.msm `_affine_multiples`:
+    one inversion a base) latency-bound; this kernel is one launch.
 
     The exponent bits ride as a (nbits, 1) u32 operand (LSB first) —
     kernels cannot capture traced constants (Mosaic note above) and a

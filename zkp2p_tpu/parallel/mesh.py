@@ -1,4 +1,4 @@
-"""Device-mesh parallelism: sharded MSM + batched proving over ICI.
+"""Device-mesh parallelism: batched proving with sharded MSMs over ICI.
 
 The reference's only parallelism is artifact chunking + rapidsnark's
 shared-memory threads (SURVEY.md §2.7); the TPU build gets real
@@ -41,72 +41,15 @@ def make_pod_mesh(n_dcn: int, n_ici: Optional[int] = None, names=("dcn", "shard"
     BASELINE.json): the outer `dcn` axis spans slices (data-center
     network — carry only the proof-batch data parallelism there, one
     all-gather of proof points per batch), the inner axis rides ICI and
-    carries the MSM/NTT sharding (msm_sharded / ntt_sharded take
-    axis=names[1] unchanged).  On a single host this builds the same
-    layout over virtual devices, which is how the driver's dryrun and the
-    tests exercise it."""
+    carries the MSM base-axis sharding (`msm_pod_batched`).  On a single
+    host this builds the same layout over virtual devices, which is how
+    the driver's dryrun and the tests exercise it."""
     devs = jax.devices()
     if n_ici is None:
         n_ici = len(devs) // n_dcn
     if n_ici < 1 or n_dcn * n_ici > len(devs):
         raise ValueError(f"need {n_dcn}x{n_ici or '?'} devices, have {len(devs)}")
     return Mesh(np.array(devs[: n_dcn * n_ici]).reshape(n_dcn, n_ici), names)
-
-
-def _fold_gathered(curve: JCurve, gathered: JacPoint, n: int) -> JacPoint:
-    """Fold the per-device partial points (leading axis n) with a scan —
-    the 'reduce' half of the group-op all-reduce."""
-
-    def body(acc, p):
-        return curve.add(acc, p), None
-
-    acc, _ = jax.lax.scan(body, curve.infinity(()), gathered)
-    return acc
-
-
-@lru_cache(maxsize=None)
-def _msm_sharded_fn(curve: JCurve, n_bases: int, mesh: Mesh, axis: str, lanes: int, window: int):
-    """Cached jitted shard_map executable per (curve, mesh, msm config).
-
-    Same reuse story as parallel.ntt._ntt_sharded_fn: one executable per
-    curve/config, shared by the a/b1/c MSMs of every prove (jit re-keys on
-    operand shapes, so differing base counts still share the callable)."""
-
-    def local(bs, pl):
-        if window:
-            part = msm_windowed(curve, bs, pl, lanes=lanes, window=window)
-        else:
-            part = msm(curve, bs, pl, lanes=lanes)
-        gathered = jax.lax.all_gather(part, axis)  # (n_dev,) points on ICI
-        return _fold_gathered(curve, gathered, mesh.shape[axis])
-
-    in_specs = (
-        tuple(P(axis) for _ in range(n_bases)),
-        P(None, axis),
-    )
-    out_specs = tuple(P() for _ in range(3))
-    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
-
-
-def msm_sharded(
-    curve: JCurve,
-    bases: AffPoint,
-    planes: jnp.ndarray,
-    mesh: Mesh,
-    axis: str = "shard",
-    lanes: int = 64,
-    window: int = 0,
-) -> JacPoint:
-    """MSM with the base-point axis sharded over `mesh`'s `axis`.
-
-    bases components must have N divisible by the mesh size (pad with the
-    (0,0) infinity sentinel + zero planes first).  `planes` is bit planes
-    (window=0, 256 rows) or 2^window digit planes (the prover's fast path,
-    rows = 256/window).  Returns the full sum, replicated on every device."""
-    n_dev = mesh.shape[axis]
-    n = bases[0].shape[0]
-    assert n % n_dev == 0, "pad the base axis to the mesh size first"
-    return _msm_sharded_fn(curve, len(bases), mesh, axis, lanes, window)(bases, planes)
 
 
 @lru_cache(maxsize=None)
@@ -173,23 +116,15 @@ def msm_pod_batched(
     return _msm_pod_fn(curve, len(bases), mesh, dcn_axis, ici_axis, lanes, window)(bases, planes_batch)
 
 
-def pad_to_multiple(bases: AffPoint, bit_planes, multiple: int) -> Tuple[AffPoint, jnp.ndarray]:
+def pad_to_multiple(bases: AffPoint, planes: jnp.ndarray, multiple: int) -> Tuple[AffPoint, jnp.ndarray]:
     """Pad the MSM base axis (and the matching LAST plane axis) up to a
     multiple of the mesh width: (0, 0) infinity bases and zero digit
     columns contribute nothing.  Planes may be (n_planes, N) single-proof
-    or (B, n_planes, N) batched (msm_pod_batched), and signed planes
-    arrive as a (mags, negs) tuple — the pad is rank-generic on the last
-    axis either way."""
+    or (B, n_planes, N) batched (msm_pod_batched): the pad is
+    rank-generic on the last axis either way."""
     n = bases[0].shape[0]
     pad = (-n) % multiple
     if pad:
         bases = tuple(jnp.pad(c, [(0, pad)] + [(0, 0)] * (c.ndim - 1)) for c in bases)
-
-        def pad_last(p):
-            return jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, pad)])
-
-        if isinstance(bit_planes, tuple):
-            bit_planes = tuple(pad_last(p) for p in bit_planes)
-        else:
-            bit_planes = pad_last(bit_planes)
-    return bases, bit_planes
+        planes = jnp.pad(planes, [(0, 0)] * (planes.ndim - 1) + [(0, pad)])
+    return bases, planes

@@ -664,9 +664,7 @@ def cmd_doctor(args):
         _log(f"backend: {rep['backend']}")
         prov = rep["provenance"]
         gate_knob = {  # gate -> the knob that steers it, for the listing
-            "field_mul": "field_mul", "curve_kernel": "curve_kernel",
-            "msm_unified": "msm_unified", "msm_affine": "msm_affine",
-            "msm_h": "msm_h", "msm_glv": "msm_glv", "batch_chunk": "batch_chunk",
+            "field_mul": "field_mul", "curve_kernel": "curve_kernel", "batch_chunk": "batch_chunk",
             "native_msm_glv": "msm_glv", "native_batch_affine": "msm_batch_affine",
             "native_tier": "native_ifma",
         }

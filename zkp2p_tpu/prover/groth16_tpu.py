@@ -6,14 +6,18 @@ rapidsnark (`dizkus-scripts/5_gen_proof.sh`, `6_gen_proof_rapidsnark.sh`):
 same zkey material + witness in, same proof out, verified by the same
 pairing equation (`contracts/Verifier.sol:340-380`).
 
-Dataflow (one jitted program, SURVEY.md §7 step 6):
+Dataflow (six stage programs a chunk of witnesses, SURVEY.md §7 step 6):
 
   witness w (mont limbs, n_wires x 16)
     ├─ Az/Bz/Cz: gather coeffs -> Montgomery mul -> modular segment-sum
     │  over rows (the sparse matvec; zero scatter)
     ├─ H: iNTT -> coset shift -> NTT -> (a·b - c)·Z⁻¹ -> iNTT -> unshift
-    └─ 4 G1 MSMs + 1 G2 MSM over bit planes (ops.msm)
+    └─ 4 G1 MSMs + 1 G2 MSM over signed digit planes (ops.msm)
   host: the ~10 scalar ops that blind with (r, s) and assemble (A, B, C)
+
+Two roads, both behind `prove_tpu_batch` (`prove_tpu` is a batch of
+one): `_prove_device` on one chip, and `_prove_batch_sharded` on a
+("batch", "shard") pod mesh where ZKP2P_TPU_SHARD=on.
 
 Determinism contract: given the same (witness, r, s) this emits the exact
 proof `snark.groth16.prove_host` does — the two provers are diffed
@@ -55,70 +59,27 @@ from ..ops.msm import (
     RESIDENT_ENTRY_BYTES,
     default_lanes,
     digit_planes_from_limbs,
-    glv_extend_bases,
-    glv_sel,
-    glv_signed_planes_from_limbs,
     msm_resident,
-    msm_windowed,
     msm_windowed_signed,
     resident_table,
     signed_digit_planes_from_limbs,
 )
 from ..ops.ntt import LADDER as NTT_LADDER, coset_shift, intt, ntt
 
-# All tier knobs resolve through the ONE typed config (utils.config:
-# default -> env, with provenance); the module constants
-# below are its import-time snapshot — jit identities depend on them,
-# so they are process-lifetime like the config itself.
-#
-# MSM_WINDOW: 4-bit digits -> ~78 point-adds per base instead of the 256
-#   of the bit-plane formulation; w=8 halves accumulate work at the
-#   price of a 254-add per-chunk table, worth it vmapped.
-# MSM_SIGNED: signed digit recoding (default on) — the per-chunk
-#   multiples table halves because a negative digit is (x, -y) for free.
-# MSM_UNIFIED ("auto" = on for a real TPU backend): pad the a/b1/c/h
-#   MSM inputs to one common base count so all four share ONE compiled
-#   executable (each cold TPU MSM compile measured ~2 min).
-# MSM_AFFINE: batch-affine accumulate tier (ops.msm_affine) — hardware-
-#   gated until the on-chip A/B proves it.
-# MSM_H: "windowed" or "bucket" (ops.msm_bucket sorted-prefix
-#   Pippenger) — hardware-gated like MSM_AFFINE.
-from ..utils.jaxcfg import on_tpu as _on_tpu
-from ..utils.audit import record_arm as _record_arm
-from ..utils.config import load_config as _load_config
-
-_CFG = _load_config()
-MSM_WINDOW = _CFG.msm_window
-MSM_SIGNED = _CFG.msm_signed
-MSM_UNIFIED = _CFG.msm_unified
-MSM_AFFINE = _CFG.msm_affine
-MSM_H = _CFG.msm_h
-MSM_GLV = _CFG.msm_glv
-BATCH_CHUNK = _CFG.batch_chunk
-H_BUCKET_WINDOW = 16
-
 from ..snark.groth16 import Proof, ProvingKey, coset_gen, domain_size_for, qap_rows
 from ..snark.r1cs import ConstraintSystem
-def _unified() -> bool:
-    return _record_arm("msm_unified", MSM_UNIFIED == "1" or (MSM_UNIFIED == "auto" and _on_tpu()))
+from ..utils.audit import record_arm as _record_arm
+from ..utils.config import load_config as _load_config
+from ..utils.jaxcfg import on_tpu as _on_tpu
 
-
-def _affine() -> bool:
-    return _record_arm("msm_affine", MSM_AFFINE == "1" or (MSM_AFFINE == "auto" and _on_tpu()))
-
-
-def _h_bucket() -> bool:
-    v = MSM_SIGNED and (MSM_H == "bucket" or (MSM_H == "auto" and _on_tpu()))
-    _record_arm("msm_h", "bucket" if v else "windowed")
-    return v
-
-
-def _glv() -> bool:
-    """GLV endomorphism decomposition for the G1 MSMs (ZKP2P_MSM_GLV).
-    Rides the signed-digit machinery, so MSM_SIGNED off disables it —
-    the unsigned path stays the byte-stable fallback."""
-    return _record_arm("msm_glv", MSM_GLV and MSM_SIGNED)
-
+# The one knob of this module that is a module constant: the config's
+# import-time snapshot (utils.config: default -> env, with provenance).
+BATCH_CHUNK = _load_config().batch_chunk
+# The witness MSMs' digits: signed 4-bit windows, ~72 point-adds a base
+# against the 256 of the bit-plane formulation, an 8-entry multiples
+# table a scan step (a negative digit is (x, -y) for free).  The mesh
+# road recodes unsigned at the same window.
+MSM_WINDOW = 4
 
 # The h MSM's window multiples live in a table resident with the key
 # (ops.msm.resident_table) where the device can hold it: h_bases depend
@@ -185,11 +146,9 @@ def _hbm_bytes_limit() -> int:
 
 def _h_table_window(log_m: int) -> Optional[int]:
     """The window at which this process keeps a resident h table for a
-    key of 2^log_m domain points; None: the h MSM takes today's road
-    (the table does not fit, or an arm gives the h planes another
-    layout).  Static under jit: `_recode` and `_prove_device` agree."""
-    if not MSM_SIGNED or _h_bucket() or _glv():
-        return None
+    key of 2^log_m domain points; None: the table does not fit, and the
+    h MSM builds its multiples in the scan (`_msm_g1`).  Static under
+    jit: `_recode` and `_prove_device` agree."""
     limit = _hbm_bytes_limit()
     # off a TPU `auto` does not chunk (0): plan the chunk a TPU would take
     chunk = _batch_chunk_size(log_m) or batch_chunk_for(log_m, limit)
@@ -596,9 +555,8 @@ def _matvec(coeff, wire, row, w_mont, m):
 
 
 def abc_evals(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
-    """Az/Bz/Cz evaluations on the domain: the sparse-matvec stage shared
-    by the single-chip and sharded H ladders (and vmapped over the batch
-    axis by the dryrun's data-parallel step)."""
+    """Az/Bz/Cz evaluations on the domain: the sparse-matvec stage of
+    the H ladder."""
     m = 1 << dpk.log_m
     with jax.named_scope("matvec"):
         a_ev = _matvec(dpk.a_coeff, dpk.a_wire, dpk.a_row, w_mont, m)
@@ -634,46 +592,24 @@ def _h_and_planes(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
 
 
 def _recode(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h: jnp.ndarray):
-    if MSM_SIGNED:
-        w_std = FR.from_mont(w_mont)
-        h_window = H_BUCKET_WINDOW if _h_bucket() else (_h_table_window(dpk.log_m) or MSM_WINDOW)
-        if _glv():
-            # G1 planes in the GLV-doubled column layout (k1 digits for
-            # P_i, k2 digits for phi(P_i)): HALF the digit planes over
-            # twice the columns.  The G2 MSM has no cheap endomorphism
-            # here, so it keeps full-width signed planes — but ONLY for
-            # the b_sel wires it can consume (recoding all n_wires just
-            # for b2 would materialize ~65 planes x n_wires per proof);
-            # its columns are therefore b_sel POSITIONS, not wire ids.
-            w_mags, w_negs = glv_signed_planes_from_limbs(w_std, MSM_WINDOW)
-            g2_planes = signed_digit_planes_from_limbs(
-                jnp.take(w_std, dpk.b_sel, axis=-2), MSM_WINDOW
-            )
-            h_mags, h_negs = glv_signed_planes_from_limbs(FR.from_mont(h), h_window)
-            if int(dpk.a_nsel.shape[0]) > 0:
-                n4_mags, n4_negs = signed_digit_planes_from_limbs(w_std, 4)
-                narrow = (n4_mags[-NARROW_PLANES:], n4_negs[-NARROW_PLANES:])
-            else:
-                narrow = ()
-            return ((w_mags, w_negs), narrow, g2_planes), (h_mags, h_negs)
-        w_mags, w_negs = signed_digit_planes_from_limbs(w_std, MSM_WINDOW)
-        h_mags, h_negs = signed_digit_planes_from_limbs(FR.from_mont(h), h_window)
-        # Narrow-class planes: witness wires with width bounds <= 2^11
-        # only populate the last NARROW_PLANES signed w=4 digits — the
-        # upper 61 planes are provably zero and never reach an MSM.
-        # Keys with no narrow class (zkey import) skip the w=4 recode
-        # entirely — shapes are static under jit, so this prunes at
-        # trace time.
-        if int(dpk.a_nsel.shape[0]) > 0:
-            n4_mags, n4_negs = signed_digit_planes_from_limbs(w_std, 4)
-            narrow = (n4_mags[-NARROW_PLANES:], n4_negs[-NARROW_PLANES:])
-        else:
-            narrow = ()
-        return ((w_mags, w_negs), narrow), (h_mags, h_negs)
-    return (
-        digit_planes_from_limbs(FR.from_mont(w_mont), MSM_WINDOW),
-        digit_planes_from_limbs(FR.from_mont(h), MSM_WINDOW),
-    )
+    """Witness and h scalars -> signed digit planes, most significant
+    first: `((w_mags, w_negs), narrow), (h_mags, h_negs)`.  The witness
+    at MSM_WINDOW, h at the resident table's window where the key has
+    one.  `narrow` is the low NARROW_PLANES of the witness's w=4 recode:
+    wires with width bounds <= 2^11 only populate those — the upper 61
+    planes are provably zero and never reach an MSM; `()` for a key
+    with no narrow class (zkey import): shapes are static under jit,
+    so that prunes at trace time."""
+    w_std = FR.from_mont(w_mont)
+    w_mags, w_negs = signed_digit_planes_from_limbs(w_std, MSM_WINDOW)
+    h_mags, h_negs = signed_digit_planes_from_limbs(FR.from_mont(h), _h_table_window(dpk.log_m) or MSM_WINDOW)
+    narrow = ()
+    if int(dpk.a_nsel.shape[0]) > 0:
+        # a recode of its own: the narrow MSMs run at w=4 whatever the
+        # wide window is (equal today, and XLA folds the two into one)
+        n4_mags, n4_negs = signed_digit_planes_from_limbs(w_std, 4)
+        narrow = (n4_mags[-NARROW_PLANES:], n4_negs[-NARROW_PLANES:])
+    return ((w_mags, w_negs), narrow), (h_mags, h_negs)
 
 
 def _msm_g1(bases, planes):
@@ -681,53 +617,25 @@ def _msm_g1(bases, planes):
     # large (TPU ops are latency-bound at small batches — see
     # ops.msm.default_lanes).
     lanes = default_lanes(bases[0].shape[0])
-    if MSM_SIGNED:
-        return _signed_windowed(G1J, bases, planes, lanes, MSM_WINDOW)
-    return msm_windowed(G1J, bases, planes, lanes=lanes, window=MSM_WINDOW)
-
-
-def _signed_windowed(curve, bases, planes, lanes, window):
-    """Signed windowed MSM with the accumulate-tier selector: batch
-    affine (ops.msm_affine) when armed, Jacobian otherwise."""
-    mags, negs = planes
-    if _affine():
-        from ..ops.msm_affine import msm_windowed_affine
-
-        return msm_windowed_affine(curve, bases, mags, negs, lanes=lanes, window=window)
-    return msm_windowed_signed(curve, bases, mags, negs, lanes=lanes, window=window)
+    return msm_windowed_signed(G1J, bases, *planes, lanes=lanes, window=MSM_WINDOW)
 
 
 def _msm_g1_narrow(bases, planes):
     # 3-plane signed w=4 MSM for width-bounded wires: ~3.5 adds/pt at
     # batch=16 vs ~40 on the wide path.  Wider lanes keep the per-step
     # batch (NARROW_PLANES x lanes) off the latency floor.
-    return _signed_windowed(
-        G1J, bases, planes, default_lanes(bases[0].shape[0], cap=16384), 4
-    )
+    lanes = default_lanes(bases[0].shape[0], cap=16384)
+    return msm_windowed_signed(G1J, bases, *planes, lanes=lanes, window=4)
 
 
 def _msm_g2_narrow(bases, planes):
-    return _signed_windowed(
-        G2J, bases, planes, default_lanes(bases[0].shape[0], cap=4096), 4
-    )
+    lanes = default_lanes(bases[0].shape[0], cap=4096)
+    return msm_windowed_signed(G2J, bases, *planes, lanes=lanes, window=4)
 
 
 def _msm_g2(bases, planes):
     lanes = default_lanes(bases[0].shape[0], cap=2048)
-    if MSM_SIGNED:
-        return _signed_windowed(G2J, bases, planes, lanes, MSM_WINDOW)
-    return msm_windowed(G2J, bases, planes, lanes=lanes, window=MSM_WINDOW)
-
-
-def _msm_h(bases, planes):
-    """The h MSM: full-width coset-quotient scalars, the dominant prover
-    cost — routed to the sorted-prefix bucket formulation when armed."""
-    if _h_bucket():
-        from ..ops.msm_bucket import msm_bucket_affine
-
-        mags, negs = planes
-        return msm_bucket_affine(G1J, bases, mags, negs, window=H_BUCKET_WINDOW)
-    return _msm_g1(bases, planes)
+    return msm_windowed_signed(G2J, bases, *planes, lanes=lanes, window=MSM_WINDOW)
 
 
 def _h_table_fn(bases, window: int):
@@ -741,48 +649,24 @@ def _msm_h_resident(table, planes):
 
 # Stage-wise jits, NOT one fused program: XLA compile time scales with
 # traced-graph size, so the pipeline is a handful of small executables
-# with intermediates staying on device between stages.  Since b/c
-# pruning the G1 MSMs run at three different lane counts (a: all wires,
-# b1: |b_sel|, c: |c_sel|), so jit re-specializes _msm_g1 per shape —
-# the ~50% runtime cut on b1/b2/c outweighs the extra first-proof
-# compiles (and the persistent cache amortises them across processes).
-_jit_h_planes = jax.jit(_h_and_planes)
-_jit_msm_g1 = jax.jit(_msm_g1)
-_jit_msm_g2 = jax.jit(_msm_g2)
-_jit_msm_h = jax.jit(_msm_h)
-_jit_msm_g1_narrow = jax.jit(_msm_g1_narrow)
-_jit_msm_g2_narrow = jax.jit(_msm_g2_narrow)
+# with intermediates staying on device between stages.  Each is vmapped
+# over the witnesses of a chunk, the key unbatched.  Since b/c pruning
+# the G1 MSMs run at three different lane counts (a: all wires, b1:
+# |b_sel|, c: |c_sel|), so jit re-specializes _msm_g1 per shape — the
+# ~50% runtime cut on b1/b2/c outweighs the extra first-proof compiles
+# (and the persistent cache amortises them across processes).
+_jit_h_planes = jax.jit(jax.vmap(_h_and_planes, in_axes=(None, 0)))
+_jit_msm_g1 = jax.jit(jax.vmap(_msm_g1, in_axes=(None, 0)))
+_jit_msm_g2 = jax.jit(jax.vmap(_msm_g2, in_axes=(None, 0)))
+_jit_msm_g1_narrow = jax.jit(jax.vmap(_msm_g1_narrow, in_axes=(None, 0)))
+_jit_msm_g2_narrow = jax.jit(jax.vmap(_msm_g2_narrow, in_axes=(None, 0)))
+_jit_msm_h_resident = jax.jit(jax.vmap(_msm_h_resident, in_axes=(None, 0)))
 _jit_h_table = jax.jit(_h_table_fn, static_argnames="window")
-_jit_msm_h_resident = jax.jit(_msm_h_resident)
-_jit_h_planes_batch = jax.jit(jax.vmap(_h_and_planes, in_axes=(None, 0)))
-_jit_msm_g1_batch = jax.jit(jax.vmap(_msm_g1, in_axes=(None, 0)))
-_jit_msm_g2_batch = jax.jit(jax.vmap(_msm_g2, in_axes=(None, 0)))
-_jit_msm_h_batch = jax.jit(jax.vmap(_msm_h, in_axes=(None, 0)))
-_jit_msm_h_resident_batch = jax.jit(jax.vmap(_msm_h_resident, in_axes=(None, 0)))
-_jit_msm_g1_narrow_batch = jax.jit(jax.vmap(_msm_g1_narrow, in_axes=(None, 0)))
-_jit_msm_g2_narrow_batch = jax.jit(jax.vmap(_msm_g2_narrow, in_axes=(None, 0)))
 
 
 def _take_planes(planes, sel):
-    # signed planes are a (mags, negs) pair; both gather on wires
-    if isinstance(planes, tuple):
-        return tuple(jnp.take(p, sel, axis=-1) for p in planes)
-    return jnp.take(planes, sel, axis=-1)
-
-
-def _glv_key_bases(dpk: DeviceProvingKey, name: str, bases: AffPoint) -> AffPoint:
-    """GLV-doubled base set [P, phi(P)] for one query, memoised on the
-    key instance (one batched Fq mul per query per key — witness-
-    independent, like _split_cache)."""
-    cache = getattr(dpk, "_glv_cache", None)
-    if cache is None:
-        cache = {}
-        setattr(dpk, "_glv_cache", cache)
-    got = cache.get(name)
-    if got is None:
-        got = glv_extend_bases(bases)
-        cache[name] = got
-    return got
+    """Signed planes are a (mags, negs) pair; both gather on wires."""
+    return tuple(jnp.take(p, sel, axis=-1) for p in planes)
 
 
 def _h_table(dpk: DeviceProvingKey) -> Optional[jnp.ndarray]:
@@ -817,10 +701,7 @@ def _pad_msm(bases, planes, n_to: int):
     n = bases[0].shape[0]
     if n_to and n < n_to:
         bases = tuple(jnp.pad(c, [(0, n_to - n)] + [(0, 0)] * (c.ndim - 1)) for c in bases)
-        if isinstance(planes, tuple):
-            planes = tuple(jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, n_to - n)]) for p in planes)
-        else:
-            planes = jnp.pad(planes, [(0, 0)] * (planes.ndim - 1) + [(0, n_to - n)])
+        planes = tuple(jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, n_to - n)]) for p in planes)
     return bases, planes
 
 
@@ -887,92 +768,53 @@ def _enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
     return value
 
 
-def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = False,
-                  watch: Optional[_StageWatch] = None):
-    """The five big MSMs; everything else about the proof is host-cheap.
-    The b/c MSMs run only over their pruned non-infinity lanes (plane
-    columns gathered through b_sel/c_sel), and with width metadata each
-    witness MSM splits into a narrow class (3 signed w=4 planes — the
-    ~90% of wires that are constraint-bounded bits/bytes) and a wide
-    class (full planes); the two partial sums combine with one Jacobian
-    add per query."""
-    classed = MSM_SIGNED and int(dpk.a_nsel.shape[0]) > 0
-    jh, m1, m2 = (
-        (_jit_h_planes_batch, _jit_msm_g1_batch, _jit_msm_g2_batch)
-        if batched
-        else (_jit_h_planes, _jit_msm_g1, _jit_msm_g2)
-    )
-    mh = _jit_msm_h_batch if batched else _jit_msm_h
-    m1n, m2n = (
-        (_jit_msm_g1_narrow_batch, _jit_msm_g2_narrow_batch)
-        if batched
-        else (_jit_msm_g1_narrow, _jit_msm_g2_narrow)
-    )
+def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_StageWatch] = None):
+    """The five big MSMs of a chunk of witnesses, `w_mont` (B, n_wires,
+    16); everything else about the proof is host-cheap.  The b/c MSMs
+    run only over their pruned non-infinity lanes (plane columns
+    gathered through b_sel/c_sel), and with width metadata each witness
+    MSM splits into a narrow class (3 signed w=4 planes — the ~90% of
+    wires that are constraint-bounded bits/bytes) and a wide class (full
+    planes); the two partial sums combine with one Jacobian add per
+    query.  On a TPU the MSMs of a class are padded to one base count,
+    so they share ONE compiled executable (each cold TPU MSM compile
+    measured ~2 min)."""
+    unify = _on_tpu()
     h_table = _h_table(dpk)
-    w_all, h_planes = jh(dpk, w_mont)
+    (w_planes, w_narrow), h_planes = _jit_h_planes(dpk, w_mont)
     if watch is not None:
         # a few bytes that are ready when the stage is, cut from a plane by a
         # program of their own: the stage's program stays as it is, and no
         # plane is kept alive to wait on
-        watch.enqueued("h_planes", _first_element(jax.tree_util.tree_leaves(h_planes)[0]),
-                       ntt=NTT_LADDER)
-    if _glv():
-        # GLV layout: G1 planes carry 2*n_wires columns (k1 digits for
-        # the P half, k2 for the phi(P) half); the G2 MSM keeps its own
-        # full-width planes.  G1 bases and column selectors lift to the
-        # doubled layout; everything downstream is shape-generic.
-        w_planes, w_narrow, g2_planes = w_all
-        g1_bases = lambda name, b: _glv_key_bases(dpk, name, b)  # noqa: E731
-        g1_cols = lambda sel: glv_sel(sel, dpk.n_wires)  # noqa: E731
-    else:
-        if MSM_SIGNED:
-            w_planes, w_narrow = w_all
-        else:
-            w_planes, w_narrow = w_all, None
-        g2_planes = w_planes
-        g1_bases = lambda name, b: b  # noqa: E731
-        g1_cols = lambda sel: sel  # noqa: E731
+        watch.enqueued("h_planes", _first_element(h_planes[0]), ntt=NTT_LADDER)
 
-    def msm_h(scan):
+    def msm_h(n_to: int = 0):
         """The h stage, enqueued: against the resident table where the
-        key has one, else `scan()` — today's road."""
+        key has one, else `_msm_g1` builds the multiples in its scan,
+        over `n_to` bases where it shares the query MSMs' executable."""
         if h_table is not None:
-            mhr = _jit_msm_h_resident_batch if batched else _jit_msm_h_resident
-            return _enqueued(watch, "msm_h", mhr(h_table, h_planes),
+            return _enqueued(watch, "msm_h", _jit_msm_h_resident(h_table, h_planes),
                              window=int(h_table.shape[1]).bit_length(), table="resident")
-        return _enqueued(watch, "msm_h", scan(),
-                         window=H_BUCKET_WINDOW if _h_bucket() else MSM_WINDOW, table="scan")
+        return _enqueued(watch, "msm_h", _jit_msm_g1(*_pad_msm(dpk.h_bases, h_planes, n_to)),
+                         window=MSM_WINDOW, table="scan")
 
-    if not classed:
-        a_b = g1_bases("a", dpk.a_bases)
-        b1_b = g1_bases("b1", dpk.b1_bases)
-        c_b = g1_bases("c", dpk.c_bases)
-        h_b = g1_bases("h", dpk.h_bases)
-        # bucket-h mode, or a resident h table: h no longer shares the
-        # unified executable, so padding a/b1/c up to the (domain-sized)
-        # h base count would be pure waste — unify the three query MSMs
-        # among themselves only.
-        h_apart = _h_bucket() or h_table is not None
-        g1_n = 0 if not _unified() else max(
-            a_b[0].shape[0], b1_b[0].shape[0], c_b[0].shape[0],
-            *(() if h_apart else (h_b[0].shape[0],)),
+    if not int(dpk.a_nsel.shape[0]):  # no narrow class: one MSM a query
+        # with a resident h table h no longer shares the unified
+        # executable, so padding a/b1/c up to the (domain-sized) h base
+        # count would be pure waste — unify the three query MSMs among
+        # themselves only
+        g1_n = 0 if not unify else max(
+            dpk.a_bases[0].shape[0], dpk.b1_bases[0].shape[0], dpk.c_bases[0].shape[0],
+            *(() if h_table is not None else (dpk.h_bases[0].shape[0],)),
         )
-        b_planes = _take_planes(w_planes, g1_cols(dpk.b_sel))
-        c_planes = _take_planes(w_planes, g1_cols(dpk.c_sel))
-        # GLV g2_planes are already gathered to the b_sel columns
-        b2_planes = g2_planes if _glv() else b_planes
-        # windowed mode keeps the m1 wrapper so the compiled-executable
-        # identity (and its persistent-cache entry) is unchanged
-        h_acc = msm_h(lambda: (
-            mh(h_b, h_planes)
-            if _h_bucket()
-            else m1(*_pad_msm(h_b, h_planes, g1_n))
-        ))
+        b_planes = _take_planes(w_planes, dpk.b_sel)
+        c_planes = _take_planes(w_planes, dpk.c_sel)
+        h_acc = msm_h(g1_n)
         return (
-            _enqueued(watch, "msm_a", m1(*_pad_msm(a_b, w_planes, g1_n))),
-            _enqueued(watch, "msm_b1", m1(*_pad_msm(b1_b, b_planes, g1_n))),
-            _enqueued(watch, "msm_b2", m2(dpk.b2_bases, b2_planes)),
-            _enqueued(watch, "msm_c", m1(*_pad_msm(c_b, c_planes, g1_n))),
+            _enqueued(watch, "msm_a", _jit_msm_g1(*_pad_msm(dpk.a_bases, w_planes, g1_n))),
+            _enqueued(watch, "msm_b1", _jit_msm_g1(*_pad_msm(dpk.b1_bases, b_planes, g1_n))),
+            _enqueued(watch, "msm_b2", _jit_msm_g2(dpk.b2_bases, b_planes)),
+            _enqueued(watch, "msm_c", _jit_msm_g1(*_pad_msm(dpk.c_bases, c_planes, g1_n))),
             h_acc,
         )
 
@@ -982,11 +824,9 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
     # would burn ~16x the work the classing just removed.  Three G1
     # executables total (narrow, query-wide, h).
     g1_wide_n = g1_narrow_n = 0
-    if _unified():
+    if unify:
         g1_wide_n = max(dpk.a_wsel.shape[0], dpk.b_wsel.shape[0], dpk.c_wsel.shape[0])
         g1_narrow_n = max(dpk.a_nsel.shape[0], dpk.b_nsel.shape[0], dpk.c_nsel.shape[0])
-        if _glv():
-            g1_wide_n *= 2  # wide-class MSMs run over the doubled base axis
 
     # The split bases/wire arrays depend only on the KEY — memoise them
     # on the dpk instance so the gathers (O(key size) HBM copies) run
@@ -1006,31 +846,24 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
 
     def query(name, bases, nsel, wsel, wires_of):
         """One witness MSM (a/b1/c): narrow + wide class partial sums.
-        wires_of maps base positions to wire ids (None = identity).
-        Under GLV only the WIDE class decomposes — narrow wires are
-        width-bounded below 2^11, where a 2-term split has nothing to
-        halve — so the narrow executable is byte-identical either way."""
+        wires_of maps base positions to wire ids (None = identity)."""
         accs = []
         if int(nsel.shape[0]):
             nb, nw = key_split(name + ".n", bases, nsel, wires_of)
-            accs.append(m1n(*_pad_msm(nb, _take_planes(w_narrow, nw), g1_narrow_n)))
+            accs.append(_jit_msm_g1_narrow(*_pad_msm(nb, _take_planes(w_narrow, nw), g1_narrow_n)))
         if int(wsel.shape[0]):
             wb, ww = key_split(name + ".w", bases, wsel, wires_of)
-            wb = g1_bases(name + ".w", wb)
-            accs.append(m1(*_pad_msm(wb, _take_planes(w_planes, g1_cols(ww)), g1_wide_n)))
+            accs.append(_jit_msm_g1(*_pad_msm(wb, _take_planes(w_planes, ww), g1_wide_n)))
         return accs[0] if len(accs) == 1 else G1J.add(accs[0], accs[1])
 
     def query_g2(name, bases, nsel, wsel, wires_of):
         accs = []
         if int(nsel.shape[0]):
             nb, nw = key_split(name + ".n", bases, nsel, wires_of)
-            accs.append(m2n(nb, _take_planes(w_narrow, nw)))
+            accs.append(_jit_msm_g2_narrow(nb, _take_planes(w_narrow, nw)))
         if int(wsel.shape[0]):
             wb, ww = key_split(name + ".w", bases, wsel, wires_of)
-            # GLV g2_planes carry b_sel POSITIONS (wsel indexes those);
-            # the plain path's full-wire planes gather by wire id
-            cols = wsel if _glv() else ww
-            accs.append(m2(wb, _take_planes(g2_planes, cols)))
+            accs.append(_jit_msm_g2(wb, _take_planes(w_planes, ww)))
         return accs[0] if len(accs) == 1 else G2J.add(accs[0], accs[1])
 
     return (
@@ -1038,7 +871,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, batched: bool = Fa
         _enqueued(watch, "msm_b1", query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
         _enqueued(watch, "msm_b2", query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
         _enqueued(watch, "msm_c", query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel)),
-        msm_h(lambda: (mh if _h_bucket() else m1)(g1_bases("h", dpk.h_bases), h_planes)),
+        msm_h(),
     )
 
 
@@ -1060,151 +893,9 @@ def prove_tpu(
     r: Optional[int] = None,
     s: Optional[int] = None,
 ) -> Proof:
-    from ..utils.audit import sample_device_memory
-    from ..utils.metrics import REGISTRY
-    from ..utils.trace import trace
-
-    if r is None:
-        r = 1 + secrets.randbelow(R - 1)
-    if s is None:
-        s = 1 + secrets.randbelow(R - 1)
-    with trace("tpu/prove"):
-        sample_device_memory("tpu/prove")  # entry watermark (flight recorder)
-        _check_inferred_widths(dpk, witness, w_std=witness if _is_u64_witness(witness) else None)
-        acc = _prove_device(dpk, witness_to_device(witness))
-        a, b1, c, hq = (g1_jac_to_host(p)[0] for p in (acc[0], acc[1], acc[3], acc[4]))
-        b2 = g2_jac_to_host(acc[2])[0]
-        proof = _assemble(dpk, (a, b1, b2, c, hq), r, s)
-        sample_device_memory("tpu/prove")  # exit watermark: per-prove HBM peak
-    REGISTRY.counter("zkp2p_proves_total", {"prover": "tpu"}).inc()
-    return proof
-
-
-def h_evals_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, axis: str = "shard") -> jnp.ndarray:
-    """`h_evals` with the six domain transforms sharded over `mesh`:
-    the production multi-chip path (SURVEY.md §2.7 NTT parallelism).
-
-    The sparse matvec stays replicated (it is ~1% of prove FLOPs and its
-    segment-sum does not shard cleanly); each (m, 16) vector is then laid
-    out shard-major and run through the four-step `ntt_sharded` with its
-    three ICI all-to-alls.  Requires both Bailey factors of the domain to
-    be divisible by the mesh width: m >= (mesh size)^2."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..parallel.ntt import ntt_sharded
-
-    g = coset_gen(dpk.log_m)
-    a_ev, b_ev, c_ev = abc_evals(dpk, w_mont)
-    shard = NamedSharding(mesh, P(axis, None))
-
-    def ladder(v):
-        v = jax.device_put(v, shard)
-        v = ntt_sharded(v, dpk.log_m, mesh, axis=axis, inverse=True)
-        v = coset_shift(v, g, dpk.log_m)
-        return ntt_sharded(v, dpk.log_m, mesh, axis=axis)
-
-    a_cos, b_cos, c_cos = ladder(a_ev), ladder(b_ev), ladder(c_ev)
-    return FR.sub(FR.mul(a_cos, b_cos), c_cos)
-
-
-def prove_tpu_sharded(
-    dpk: DeviceProvingKey,
-    witness: Sequence[int],
-    mesh,
-    r: Optional[int] = None,
-    s: Optional[int] = None,
-    axis: str = "shard",
-    lanes: int = 64,
-    unified: bool = False,
-    progress=None,
-) -> Proof:
-    """`prove_tpu` with the MSM base axis AND the NTT domain sharded over
-    `mesh` — the same dataflow a v5e slice runs, exercised by the driver's
-    `dryrun_multichip` on virtual CPU devices.  Emits the exact proof
-    `prove_host`/`prove_tpu` produce for the same (witness, r, s).
-
-    unified=True pads every G1 MSM (a/b1/c/h) to one common base count so
-    all four share a single compiled executable — the dryrun/cold-start
-    configuration, where XLA compile time on the driver host dwarfs the
-    masked-lane runtime waste.  Production keeps per-shape sizing.
-    progress, when given, is called with a short string after each
-    device stage (the dryrun's per-stage timestamps)."""
-    from ..parallel.mesh import msm_sharded, pad_to_multiple
-    from ..utils.trace import trace
-
-    if r is None:
-        r = 1 + secrets.randbelow(R - 1)
-    if s is None:
-        s = 1 + secrets.randbelow(R - 1)
-
-    def note(arr, msg: str) -> None:
-        # Sync + report only when a progress callback asked for stage
-        # boundaries (the dryrun); production dispatch stays fully async.
-        if progress is not None:
-            arr.block_until_ready()
-            progress(msg)
-
-    # Stage spans feed the same trace/metrics rails as the single-chip
-    # provers, so a MULTICHIP dryrun dumped to a sink is diffable with
-    # trace_report like any bench run.  With a progress callback each
-    # span brackets block_until_ready (true stage time); without one
-    # dispatch is async and spans measure enqueue latency only.
-    n_dev = mesh.shape[axis]
-    with trace("sharded/witness"):
-        w_mont = witness_to_device(witness)
-    with trace("sharded/h_evals"):
-        h = h_evals_sharded(dpk, w_mont, mesh, axis)
-        note(h, "h_evals_sharded")
-    with trace("sharded/planes"):
-        w_planes = digit_planes_from_limbs(FR.from_mont(w_mont), MSM_WINDOW)
-        h_planes = digit_planes_from_limbs(FR.from_mont(h), MSM_WINDOW)
-    if unified:
-        # One executable for ALL FOUR G1 MSMs needs identical input
-        # LAYOUTS, not just shapes: h_planes inherits the NTT's shard-axis
-        # sharding while w_planes is replicated, and jit keys compiled
-        # programs on input shardings — without this the h MSM recompiles
-        # the whole G1 program (~250 s of the dryrun's cold budget).
-        # Replicating h_planes is dryrun-sized traffic only; production
-        # (unified=False) keeps the sharded layout.
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        rep = NamedSharding(mesh, P())
-        w_planes = jax.device_put(w_planes, rep)
-        h_planes = jax.device_put(h_planes, rep)
-
-    base_chunk = n_dev * lanes
-    g1_chunk = base_chunk
-    if unified:
-        n_max = max(
-            dpk.a_bases[0].shape[0], dpk.b1_bases[0].shape[0],
-            dpk.c_bases[0].shape[0], dpk.h_bases[0].shape[0],
-        )
-        g1_chunk = ((n_max + base_chunk - 1) // base_chunk) * base_chunk
-
-    def msm(curve, bases, planes, tag):
-        # Per-MSM padding: the b/c queries are pruned to their
-        # non-infinity lanes, so each MSM runs at its own (smaller) size
-        # rather than a unified shape (runtime beats executable reuse on
-        # the production path); unified=True pads the four G1 MSMs to one
-        # shared shape.  G2 compiles its own executable either way (other
-        # curve type), so it always keeps its minimal padded size — its
-        # per-point cost is ~3x G1's.
-        chunk = g1_chunk if curve is G1J else base_chunk
-        with trace(f"sharded/msm_{tag}"):
-            b, p = pad_to_multiple(bases, planes, chunk)
-            acc = msm_sharded(curve, b, p, mesh, axis=axis, lanes=lanes, window=MSM_WINDOW)
-            note(acc[0], f"msm {tag} ({b[0].shape[0]} bases)")
-        return acc
-
-    b_planes = jnp.take(w_planes, dpk.b_sel, axis=-1)
-    a_acc = msm(G1J, dpk.a_bases, w_planes, "a")
-    b1_acc = msm(G1J, dpk.b1_bases, b_planes, "b1")
-    b2_acc = msm(G2J, dpk.b2_bases, b_planes, "b2")
-    c_acc = msm(G1J, dpk.c_bases, jnp.take(w_planes, dpk.c_sel, axis=-1), "c")
-    h_acc = msm(G1J, dpk.h_bases, h_planes, "h")
-    a, b1, c, hq = (g1_jac_to_host(p)[0] for p in (a_acc, b1_acc, c_acc, h_acc))
-    b2 = g2_jac_to_host(b2_acc)[0]
-    return _assemble(dpk, (a, b1, b2, c, hq), r, s)
+    """One proof: a batch of one (the same programs at B=1, the same
+    bytes — group arithmetic is exact)."""
+    return prove_tpu_batch(dpk, [witness], None if r is None else [r], None if s is None else [s])[0]
 
 
 # Batched sharded-arm h stage: h_evals vmapped over this batch group's
@@ -1215,8 +906,8 @@ def prove_tpu_sharded(
 # refuses to partition a Mosaic kernel automatically ("wrap the call in
 # a shard_map" — found on the four-chip host, PERF.md PR 21), and every
 # field product here is one.  The sharded MSMs use the unsigned
-# formulation like prove_tpu_sharded: group arithmetic is exact, so the
-# proof bytes match the signed vmap arm regardless.
+# formulation: group arithmetic is exact, so the proof bytes match the
+# signed vmap arm regardless.
 @lru_cache(maxsize=None)
 def _h_planes_pod_fn(mesh):
     from jax.sharding import PartitionSpec as P
@@ -1243,8 +934,8 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
     ONE group-op allreduce (all_gather + Jacobian fold — ICI on real
     hardware, host rings on the virtual CPU mesh; parallel.mesh.
     msm_pod_batched).  Returns the same five (B,)-batched accumulators
-    `_prove_device(batched=True)` emits, so chunks from either arm
-    concatenate identically downstream."""
+    `_prove_device` emits, so chunks from either arm concatenate
+    identically downstream."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..parallel.mesh import msm_pod_batched, pad_to_multiple
@@ -1256,9 +947,8 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
 
     def msm(name, curve, bases, planes):
         # lanes sized to the per-device slice (tiny CI circuits stay at
-        # lanes ~ n/S instead of padding 16x to a 64-lane step); the pad
-        # rule matches prove_tpu_sharded — bases to a multiple of
-        # S * lanes so every device sees whole steps.
+        # lanes ~ n/S instead of padding 16x to a 64-lane step); bases
+        # pad to a multiple of S * lanes so every device sees whole steps.
         n = bases[0].shape[0]
         lanes = max(1, min(64, -(-n // n_ici)))
         b, p = pad_to_multiple(bases, planes, n_ici * lanes)
@@ -1382,7 +1072,7 @@ def prove_tpu_batch(
                         parts.append(
                             _prove_batch_sharded(dpk, w, mesh, watch)
                             if mesh is not None
-                            else _prove_device(dpk, w, batched=True, watch=watch)
+                            else _prove_device(dpk, w, watch=watch)
                         )
                         # sub-chunk HBM watermark: the batched pipeline's peak is
                         # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16

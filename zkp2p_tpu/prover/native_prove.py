@@ -214,7 +214,7 @@ def _g1_bases_glv_u64(bases) -> np.ndarray:
     return _bases_memo(bases, convert, tag="glv")
 
 
-def _use_glv() -> bool:
+def _glv_arm() -> bool:
     from ..utils.audit import record_arm
     from ..utils.config import load_config
 
@@ -683,7 +683,7 @@ def prove_native(
     b_sel = np.asarray(dpk.b_sel)
     c_sel = np.asarray(dpk.c_sel)
 
-    glv = _use_glv()
+    glv = _glv_arm()
     # Fixed-base precomputed tables for the frozen G1 families: resolved
     # ONCE per key (built or cache-loaded on first prove), then each
     # family's MSM is pure digit scatter + gather/add — the GLV split
@@ -838,7 +838,7 @@ def prove_native_batch(
 
     m = 1 << dpk.log_m
     threads = _n_threads()
-    glv = _use_glv()
+    glv = _glv_arm()
     b_sel = np.asarray(dpk.b_sel)
     c_sel = np.asarray(dpk.c_sel)
 

@@ -16,9 +16,8 @@ The emitted DeviceProvingKey is bit-identical to
 tests/test_setup_device.py — and the matching VerifyingKey is a host
 object usable by `snark.groth16.verify` and the Solidity export.
 
-The same key feeds every device arm unchanged: the single-device loop,
-`prove_tpu_sharded`, and the pjit batch-axis arm (ZKP2P_TPU_SHARD=on,
-docs/TPU.md).  The pruned b/c query lanes emitted here are NOT padded
+The same key feeds both device roads unchanged: the one-chip batch and
+the pod-mesh batch (ZKP2P_TPU_SHARD=on, docs/TPU.md).  The pruned b/c query lanes emitted here are NOT padded
 to any mesh width — the sharded MSMs pad bases and digit planes with
 infinity lanes per-mesh at trace time (parallel.mesh.pad_to_multiple),
 so one key serves every mesh shape.

@@ -7,8 +7,8 @@ telemetry, is invisible in the numbers.  This module closes that blind
 spot:
 
   1. **Arm recording** (`record_arm`): every backend/impl gate —
-     `jaxcfg.on_tpu`, the prover's `_unified`/`_affine`/`_h_bucket`/
-     `_glv`, the pallas-vs-XLA field mul and curve kernel, the native
+     `jaxcfg.on_tpu`, the prover's batch chunk and mesh, the
+     pallas-vs-XLA field mul and curve kernel, the native
      GLV / batch-affine / IFMA-vs-scalar tiers — reports `(gate, arm)`
      at its call site into `zkp2p_path_taken_total{gate,arm}` counters
      and a process-wide gate→arm map.
@@ -70,7 +70,7 @@ def record_arm(gate: str, arm):
     """Report that `gate` resolved to `arm` at its call site.
 
     Returns `arm` unchanged so gate resolvers can
-    `return record_arm("msm_glv", value)`.  Cost: two dict ops + a
+    `return record_arm("native_msm_glv", value)`.  Cost: two dict ops + a
     float add — cheap enough for resolvers consulted per-MSM or at
     jit-trace time (thousands of calls per trace)."""
     global _counters_gen
@@ -287,24 +287,6 @@ def _mis_arm_warnings(cfg, backend: str, arms: Dict[str, str], native_ok: bool) 
             f"curve_kernel=pallas requested but the gate did not arm (backend={backend} "
             "is not a TPU): running the XLA curve path"
         )
-    # NOTE the device-prover gates read IMPORT-TIME knob snapshots (jit
-    # identities depend on them) while cfg re-reads the env — so these
-    # two warnings also catch a knob exported AFTER prover import, which
-    # silently has no effect on the device prover (the native prover
-    # re-reads the config and may still arm).
-    if cfg.msm_h == "bucket" and arms.get("msm_h") != "bucket":
-        w.append(
-            "msm_h=bucket requested but the device-prover gate did not arm "
-            "(msm_signed off, or ZKP2P_MSM_H was set after prover import — module "
-            "constants snapshot at import): running the windowed h MSM"
-        )
-    if cfg.msm_glv and arms.get("msm_glv") == "off":
-        w.append(
-            "msm_glv requested but the device-prover gate did not arm "
-            "(msm_signed off, or ZKP2P_MSM_GLV was set after prover import — module "
-            "constants snapshot at import): unsigned digit planes on the device "
-            "prover; the native prover re-reads the env and may still arm"
-        )
     if not native_ok:
         w.append(
             "native library unavailable (csrc toolchain/build failed?): native prover "
@@ -360,14 +342,10 @@ def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
     on_tpu()
     from ..curve.jcurve import G1J
     from ..field.jfield import field_mul_impl
-    from ..prover.groth16_tpu import _affine, _batch_chunk_size, _glv, _h_bucket, _shard_mesh, _unified
+    from ..prover.groth16_tpu import _batch_chunk_size, _shard_mesh
 
     field_mul_impl()
     G1J._pallas()
-    _unified()
-    _affine()
-    _h_bucket()
-    _glv()
     _batch_chunk_size()
     # sharded-batch gate: "off" | "BxS" mesh shape | "fallback" — a
     # pjit-sharded batch prove must never share a digest with the
@@ -381,7 +359,7 @@ def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
         _ntt_pool_arm,
         _ntt_radix8_arm,
         _use_batch_affine,
-        _use_glv,
+        _glv_arm,
         _use_matvec_seg,
         _use_msm_multi,
         _use_msm_overlap,
@@ -389,7 +367,7 @@ def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
         _use_witness_u64,
     )
 
-    _use_glv()
+    _glv_arm()
     _use_batch_affine()
     _use_msm_multi()
     _use_msm_overlap()

@@ -10,7 +10,7 @@ Resolution order per knob:
   2. explicit environment variable — always wins (operator intent).
 
 `provenance` records which layer produced each value, so a bench record
-or bug report can say "msm_h=bucket (env)" instead of guessing.
+or bug report can say "batch_chunk=2 (env)" instead of guessing.
 
 The environment remains the TRANSPORT (child processes, the C runtime's
 getenv, jit-time module constants) — `apply_env()` writes the resolved
@@ -150,17 +150,12 @@ def _fraction(default: float):
 
 
 KNOBS: Dict[str, Tuple[str, object, object]] = {
-    # device (XLA/Pallas) prover MSM tiers — see prover.groth16_tpu
-    "msm_window": ("ZKP2P_MSM_WINDOW", int, 4),
-    "msm_signed": ("ZKP2P_MSM_SIGNED", _BOOL, True),
-    "msm_unified": ("ZKP2P_MSM_UNIFIED", str, "auto"),
-    "msm_affine": ("ZKP2P_MSM_AFFINE", str, "0"),
-    "msm_h": ("ZKP2P_MSM_H", str, "windowed"),
-    # GLV endomorphism scalar decomposition for the G1 MSMs (JAX and
-    # native provers): every Fr scalar splits into two ~128-bit halves,
-    # halving digit planes / Pippenger windows at the cost of doubling
-    # the base axis.  Off by default (the existing path is the pinned
-    # fallback); armable so a hardware A/B session can switch it on.
+    # GLV endomorphism scalar decomposition for the G1 MSMs of the
+    # NATIVE prover only (native_prove `_glv_arm`, arm `native_msm_glv`;
+    # the device prover has no such arm): every Fr scalar splits into
+    # two ~128-bit halves, halving the Pippenger windows at the cost of
+    # doubling the base axis.  Off by default (the existing path is the
+    # pinned fallback).
     "msm_glv": ("ZKP2P_MSM_GLV", _BOOL, False),
     # Stage task-graph in prove_native: the a/b1/b2/c MSMs run on worker
     # threads overlapping the H ladder + msm_h ("1"), or strictly
@@ -451,7 +446,7 @@ KNOBS: Dict[str, Tuple[str, object, object]] = {
 # The A/B arm switches: a function that branches on one of these must
 # record which arm it took (tools/lint gate-arm rule, audit.record_arm).
 ARMABLE = (
-    "msm_affine", "msm_h", "msm_glv", "msm_batch_affine", "msm_overlap",
+    "msm_glv", "msm_batch_affine", "msm_overlap",
     "msm_multi", "msm_precomp", "matvec_seg", "ntt_pool", "sched",
     "profile", "tpu_shard", "worker_tier", "perf_ledger", "flame",
     "msm_interleave", "ntt_radix8", "witness_u64",
@@ -460,11 +455,6 @@ ARMABLE = (
 
 @dataclass(frozen=True)
 class ProverConfig:
-    msm_window: int = 4
-    msm_signed: bool = True
-    msm_unified: str = "auto"
-    msm_affine: str = "0"
-    msm_h: str = "windowed"
     msm_glv: bool = False
     msm_overlap: bool = True
     msm_batch_affine: bool = True
