@@ -1,4 +1,4 @@
-"""Vectorised G1/G2 Jacobian ops vs the host curve oracle.
+"""Vectorised G1/G2 projective ops vs the host curve oracle.
 
 Differential testing mirrors the reference's trust chain: snarkjs point ops
 are checked against the EVM precompiles on-chain; here the TPU lanes are
@@ -64,7 +64,7 @@ CASES = [
 def test_add_double_cases(curve, to_arrays, to_host, h_add, h_double, h_mul, h_neg, mk):
     pts = mk(4)
     # Lane layout exercises every branch of the complete adder:
-    # random+random, P+P (double path), P+(-P) (infinity), inf+Q, P+inf, inf+inf.
+    # random+random, P+P, P+(-P) (infinity), inf+Q, P+inf, inf+inf.
     a_pts = [pts[0], pts[1], pts[2], None, pts[3], None]
     b_pts = [pts[1], pts[1], h_neg(pts[2]), pts[0], None, None]
     a = curve.from_affine(to_arrays(a_pts))

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from zkp2p_tpu.curve.host import G1_GENERATOR, g1_add, g1_msm, g1_mul
+from zkp2p_tpu.curve.host import G1_GENERATOR, g1_add, g1_msm, g1_mul, g1_neg
 from zkp2p_tpu.curve.jcurve import G1J, g1_jac_to_host, g1_to_affine_arrays
 from zkp2p_tpu.field.bn254 import R
 from zkp2p_tpu.field.jfield import FQ, FR
@@ -72,6 +72,25 @@ def test_table_holds_every_multiple_affine_with_holes_kept(window):
         assert all(_entry(table, i, k) is None for k in (1, n_table))
 
 
+@lru_cache(maxsize=None)
+def _multiples_of_four_bases():
+    """(host bases with a hole at 2, `_affine_multiples` of them for k = 1..8)."""
+    pts = _points(random.Random(29), 4)
+    pts[2] = None
+    fn = jax.jit(lambda b: jmsm._affine_multiples(G1J, b, 8))
+    return pts, tuple(np.asarray(c) for c in fn(g1_to_affine_arrays(pts)))
+
+
+@pytest.mark.parametrize("k", range(1, 9))
+def test_affine_multiples_are_k_times_each_base_and_a_hole_stays_a_hole(k):
+    """k = 2 is the scan's first step, P + P; the inversion is one a base
+    over all eight Z's, so a hole's Z = 0 must not reach it."""
+    pts, (x, y) = _multiples_of_four_bases()
+    assert x.shape == y.shape == (8, 4, 16)
+    got = [(FQ.from_mont_host(x[k - 1, i]), FQ.from_mont_host(y[k - 1, i])) for i in range(4)]
+    assert got == [(0, 0) if pt is None else g1_mul(pt, k) for pt in pts]
+
+
 def _case(name, rng):
     """(points, scalars) for one named case, all of one shape."""
     pts, scalars = _points(rng), [rng.randrange(R) for _ in range(N)]
@@ -90,12 +109,19 @@ def _case(name, rng):
     elif name == "opposite_base":
         # the same lane meets the negated entry: P + (-P), then a live point again
         pts[LANES + 2], scalars[LANES + 2] = pts[2], R - scalars[2]
+    elif name == "equal_and_opposite_lanes":
+        # lanes 0 and 1 hold the same bases and scalars at every step, lanes 2 and 3 opposite ones:
+        # the fold's full add meets X + X and Y + (-Y), and carries the (0 : y : 0) it gets on
+        for i in (0, LANES, 2 * LANES):
+            pts[i + 1], scalars[i + 1] = pts[i], scalars[i]
+        for i in (2, LANES + 2):
+            pts[i + 1], scalars[i + 1] = g1_neg(pts[i]), scalars[i]
     else:
         assert name == "random"
     return pts, scalars
 
 
-CASES = ["random", "zero_digits", "negative_digits", "holes", "duplicate_base", "opposite_base"]
+CASES = ["random", "zero_digits", "negative_digits", "holes", "duplicate_base", "opposite_base", "equal_and_opposite_lanes"]
 
 
 @pytest.mark.parametrize("name", CASES)
@@ -222,7 +248,7 @@ def _host_g1(bases):
     return pts
 
 
-def _jac_g1(points):
+def _proj_g1(points):
     x, y = g1_to_affine_arrays(points)
     z = np.stack([np.zeros(16, np.uint32) if p is None else np.asarray(FQ.one_mont) for p in points])
     return x, y, jnp.asarray(z)
@@ -256,7 +282,7 @@ def _toy_world(monkeypatch):
     def host_g1(window):
         def run(bases, planes):
             pts = _host_g1(bases)
-            return _jac_g1([g1_msm(pts, s) for s in _planes_to_scalars(planes, window)])
+            return _proj_g1([g1_msm(pts, s) for s in _planes_to_scalars(planes, window)])
         return run
 
     def host_g2(window):

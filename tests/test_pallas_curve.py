@@ -1,9 +1,10 @@
 """Differential test: the fused Pallas G1 point-op kernels vs
 curve.jcurve (interpret mode — no TPU needed).
 
-Every special-case lane the jcurve selects handle is pinned:
-P+Q generic, P+P (dbl fallthrough), P+(-P) (infinity), inf+Q, P+inf,
-and the (0, 0) affine sentinel for add_mixed.
+Every lane that was once a special case is pinned: P+Q generic, P+P
+(equal operands: a lane like any other to the complete formulas),
+P+(-P) (comes out as (0 : y : 0)), inf+Q, P+inf, and the (0, 0) affine
+sentinel for add_mixed.
 """
 
 import jax.numpy as jnp
@@ -29,8 +30,8 @@ def _points(n):
 @pytest.fixture(scope="module")
 def cases():
     # Lanes (P finite on 1..7 so the special cases bind to FINITE points):
-    # [0]=inf+Q, [1]=P+P (the same_x & same_y -> double fallthrough),
-    # [2]=P+(-P) (-> infinity), [3]=P+inf, [4]=inf+inf, [5:]=generic.
+    # [0]=inf+Q, [1]=P+P (equal operands, no case of their own),
+    # [2]=P+(-P) (-> (0 : y : 0)), [3]=P+inf, [4]=inf+inf, [5:]=generic.
     aff_p = g1_to_affine_arrays([None] + _points(7))
     aff_q = g1_to_affine_arrays(_points(8))
     P_ = G1J.from_affine(aff_p)
@@ -81,8 +82,9 @@ def test_g2_point_math_matches_jcurve():
 
     from zkp2p_tpu.curve.host import G2_GENERATOR, g2_mul, g2_neg
     from zkp2p_tpu.curve.jcurve import G2J, g2_to_affine_arrays
+    from zkp2p_tpu.field.jfield import FQ2
     from zkp2p_tpu.ops.pallas_curve import (
-        _consts,
+        _consts_g2,
         _add_math,
         _add_mixed_math,
         _double_math,
@@ -90,7 +92,8 @@ def test_g2_point_math_matches_jcurve():
         _FqOps,
     )
 
-    f = _Fq2Ops(_FqOps(*_consts(FQ)))
+    consts = _consts_g2(FQ2)
+    f = _Fq2Ops(_FqOps(*consts[:3]), consts[3:])
 
     def to_lm(c):
         B = int(onp.prod(c.shape[:-2]))
@@ -102,7 +105,7 @@ def test_g2_point_math_matches_jcurve():
         c1 = jnp.moveaxis(pair[1], 0, -1)
         return jnp.stack([c0, c1], axis=-2).reshape(bshape + (2, 16))
 
-    # lane 1: equal (double fallthrough), lane 2: negated, lane 3: inf+Q
+    # lane 1: equal operands, lane 2: negated, lane 3: inf+Q
     pts_p = [g2_mul(G2_GENERATOR, k) for k in (5, 11, 3)] + [None]
     pts_q = [g2_mul(G2_GENERATOR, k) for k in (9, 11, 3, 7)]
     pts_q[2] = g2_neg(pts_q[2])

@@ -262,6 +262,8 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
     assert [(r["window"], r["table"]) for r in stages if r["stage"].endswith("/msm_h")] == [
         (8, "resident") if h_road == "resident" else (4, "scan")] * n_chunks
     assert all("table" not in r for r in stages if not r["stage"].endswith("/msm_h"))
+    # the five MSM stages carry the curve's addition law, and only they
+    assert [r.get("add") for r in stages] == [None if r["stage"].endswith("/h_planes") else "complete_projective" for r in stages]
     tables = [r for r in recs if r["stage"].endswith("/h_table")]
     assert [(r["stage"], r["parent"]) for r in tables] == (
         [("tpu/prove_batch/h_table", device["id"])] if h_road == "resident" else [])
@@ -329,7 +331,7 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
     recs = tr.records()
     assert not [r for r in recs if r["stage"].endswith("/h_table")]
     (h_stage,) = [r for r in recs if r["stage"].endswith("/stage/msm_h")]
-    assert "table" not in h_stage and "window" not in h_stage
+    assert "table" not in h_stage and "window" not in h_stage and h_stage["add"] == "complete_projective"
     assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == 0
     assert not hasattr(dpk, "_h_table_cache")
     tr.reset()

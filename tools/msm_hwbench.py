@@ -13,8 +13,9 @@ time, so each arm runs in its own process).  The defaults are "auto"
   ZKP2P_CURVE_KERNEL=xla ZKP2P_FIELD_MUL=xla python tools/msm_hwbench.py \
       [--n 131072] [--window 4] [--lanes ...]
 
-Prints per-stage rates: batched add_mixed (the MSM inner op), and a full
-G1 msm_windowed at the requested size.
+Prints per-stage rates: the six batched point ops (G1 and G2 add_mixed,
+add and double: the MSM inner ops) at `--adds` lanes, and a full G1
+msm_windowed at the requested size.
 
 `--native` benches the C++ Pippenger tier (csrc zkp2p_native) instead of
 the JAX path — the native prover's arm (oracle and `cpu` row).  The
@@ -757,27 +758,33 @@ def _dispatch(args):
     by = jnp.asarray(np.tile(ay_np, (reps, 1))[:n])
     bases = (bx, by)
 
-    # ---- raw batched add_mixed rate (the MSM inner op) ----
+    # ---- raw batched point-op rates (the MSM inner ops), the six kernels ----
     if not args.skip_adds:
-        B = args.adds
-        reps_b = (B + 63) // 64
-        px = jnp.asarray(np.tile(ax_np, (reps_b, 1))[:B])
-        py = jnp.asarray(np.tile(ay_np, (reps_b, 1))[:B])
-        P = curve.from_affine((px, py))
-        qx = jnp.roll(px, 1, axis=0)
-        qy = jnp.roll(py, 1, axis=0)
+        from zkp2p_tpu.curve.host import G2_GENERATOR, g2_mul
+        from zkp2p_tpu.curve.jcurve import G2J, g2_to_affine_arrays
 
-        addm = jax.jit(lambda p, a: curve.add_mixed(p, a))
-        out = addm(P, (qx, qy))
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        iters = 4
-        for _ in range(iters):
-            out = addm(P, (qx, qy))
-        jax.block_until_ready(out)
-        dt = (time.perf_counter() - t0) / iters
-        print(f"add_mixed: B={B} {dt*1e3:.1f} ms -> {B/dt/1e6:.2f} M adds/s", flush=True)
-        _rec(arm="jax_add_mixed", n=B, min_s=dt, reps=iters)
+        B, iters = args.adds, 8
+        reps_b = (B + 63) // 64
+        g2_pts = [g2_mul(G2_GENERATOR, int(k)) for k in rng.integers(1, 1 << 30, 64)]
+        for tag, crv, aff in (("g1", curve, (ax_np, ay_np)), ("g2", G2J, g2_to_affine_arrays(g2_pts))):
+            a = tuple(jnp.asarray(np.tile(np.asarray(c), (reps_b,) + (1,) * (c.ndim - 1))[:B]) for c in aff)
+            q = tuple(jnp.roll(c, 1, axis=0) for c in a)
+            # Z != 1 on the left, as the accumulators have it
+            P = jax.jit(crv.double)(crv.from_affine(a))
+            for op, fn, rhs in (
+                ("add_mixed", crv.add_mixed, (q,)),
+                ("add", crv.add, (crv.from_affine(q),)),
+                ("double", crv.double, ()),
+            ):
+                f = jax.jit(fn)
+                jax.block_until_ready(f(P, *rhs))
+                t0 = time.perf_counter()
+                for _ in range(iters):
+                    out = f(P, *rhs)
+                jax.block_until_ready(out)
+                dt = (time.perf_counter() - t0) / iters
+                print(f"{tag}_{op}: B={B} {dt*1e3:.1f} ms -> {B/dt/1e6:.2f} M ops/s", flush=True)
+                _rec(arm=f"jax_{tag}_{op}", n=B, min_s=dt, reps=iters)
 
     if args.skip_msm:
         return

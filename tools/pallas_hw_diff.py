@@ -17,7 +17,7 @@ from __future__ import annotations
 
 def kernel_differential(interpret: bool, log=print) -> None:
     """Raise AssertionError on the first kernel that disagrees with the
-    host oracle.  Jacobian operands with Z != 1 are produced by the
+    host oracle.  Projective operands with Z != 1 are produced by the
     kernels themselves (the doubled points feed the adds)."""
     import jax.numpy as jnp
     import numpy as np
@@ -63,9 +63,9 @@ def kernel_differential(interpret: bool, log=print) -> None:
         check(f"{tag}_double", to_host(dp), [dbl(a) for a in p])
         check(f"{tag}_add", to_host(k_add(field, jp, jq, interpret)), [add(a, b) for a, b in zip(p, q)])
         check(f"{tag}_add_mixed", to_host(k_mixed(field, jp, aff_q, interpret)), [add(a, b) for a, b in zip(p, q)])
-        # Z != 1 on the left (2P from the kernel): the equal / negated
-        # cases must be found across representations — [4] 2P + 2P,
-        # [5] 2P + (-2P) — Jacobian and mixed
+        # Z != 1 on the left (2P from the kernel): equal and opposite
+        # operands in different representations — [4] 2P + 2P,
+        # [5] 2P + (-2P) — projective and mixed
         q[4], q[5] = dbl(p[4]), neg(dbl(p[5]))
         aff_q = to_arrays(q)
         want = [add(dbl(a), b) for a, b in zip(p, q)]

@@ -33,7 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..curve.jcurve import AffPoint, JacPoint, JCurve
+from ..curve.jcurve import AffPoint, ProjPoint, JCurve
 from ..field.jfield import LIMB_BITS, NUM_LIMBS
 
 SCALAR_BITS = 256
@@ -53,8 +53,8 @@ def bit_planes_from_limbs(limbs: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(flat, -1, 0)
 
 
-def tree_reduce(curve: JCurve, pts: JacPoint, axis_len: int) -> JacPoint:
-    """Sum `axis_len` Jacobian points along the last batch axis in
+def tree_reduce(curve: JCurve, pts: ProjPoint, axis_len: int) -> ProjPoint:
+    """Sum `axis_len` projective points along the last batch axis in
     ceil(log2(n)) pairwise rounds; all other batch axes stay vectorised.
 
     Every round has the SAME shape: adjacent pairs are added (n/2 adds)
@@ -85,7 +85,7 @@ def tree_reduce(curve: JCurve, pts: JacPoint, axis_len: int) -> JacPoint:
     return tuple(jax.lax.index_in_dim(c, 0, axis=ax, keepdims=False) for c in acc)
 
 
-def horner_fold_planes(curve: JCurve, init: JacPoint, planes_stacked, window: int) -> JacPoint:
+def horner_fold_planes(curve: JCurve, init: ProjPoint, planes_stacked, window: int) -> ProjPoint:
     """MSB-first Horner fold over stacked digit-plane partials (leading
     axis = planes): acc = 2^window * acc + plane.  Shared by the
     windowed and resident-table MSMs.
@@ -172,7 +172,7 @@ def msm_windowed_signed(
     negs: jnp.ndarray,
     lanes: int = 64,
     window: int = 4,
-) -> JacPoint:
+) -> ProjPoint:
     """`msm_windowed` on signed digits: the per-chunk multiples table is
     2^(w-1) entries instead of 2^w - 1 (built with half the adds), and a
     negated digit flips the selected point's Y (one conditional field
@@ -191,7 +191,7 @@ def default_lanes(n: int, cap: int = 4096) -> int:
     return max(64, min(cap, n // 16))
 
 
-def msm_windowed(curve: JCurve, bases: AffPoint, digit_planes: jnp.ndarray, lanes: int = 64, window: int = 4) -> JacPoint:
+def msm_windowed(curve: JCurve, bases: AffPoint, digit_planes: jnp.ndarray, lanes: int = 64, window: int = 4) -> ProjPoint:
     """Windowed MSM: ~(2^window - 2 + 256/window) adds per point instead of
     256 (window=4 -> ~78, a 3.3x work cut vs `msm`).
 
@@ -210,7 +210,7 @@ def _msm_windowed_impl(
     negs: Optional[jnp.ndarray],
     lanes: int,
     window: int,
-) -> JacPoint:
+) -> ProjPoint:
     """Shared body of `msm_windowed` (negs=None: unsigned 2^w - 1 table +
     masked accumulate — the mesh road's, parallel.mesh) and
     `msm_windowed_signed` (half table + Y negation — the one-chip
@@ -259,7 +259,7 @@ def _msm_windowed_impl(
             # so infinity lanes (digit 0) stay (0, 0, 0).  The mask
             # broadcasts over the element dims (one for G1 limbs, two
             # for G2 Fq2 pairs).  Digit 0 selects the Z = 0 infinity
-            # entry, which curve.add's case selects pass through — no
+            # entry, which curve.add's infinity selects pass through — no
             # explicit mask needed.
             mask = neg.reshape(neg.shape + (1,) * (sel[1].ndim - neg.ndim))
             sel[1] = jnp.where(mask, F.neg(sel[1]), sel[1])
@@ -282,7 +282,7 @@ def _msm_windowed_impl(
 
 # ---------------------------------------------------------------------------
 # Fixed-base window multiples, resident with the key.  `_msm_windowed_impl`
-# rebuilds [1P..2^(w-1)P] for every chunk of every batch, in Jacobian, so
+# rebuilds [1P..2^(w-1)P] for every chunk of every batch, in projective, so
 # its accumulate is the full add.  Bases that belong to the key (the h
 # query) get the table ONCE, normalised to affine: the accumulate becomes
 # select -> negate y -> `add_mixed`, and a wide window costs memory, not a
@@ -306,9 +306,11 @@ def _affine_multiples(curve: JCurve, pt: AffPoint, n_table: int) -> AffPoint:
     """Affine bases (C, 16) -> affine k*P for k = 1..n_table, (n_table, C,
     16) a coordinate; a (0, 0) hole stays a hole in every multiple.
 
-    One `add_mixed` scan over k, then ONE inversion a base: prefix
-    products of the n_table Z's along k, `inv_fused` of the total, and
-    the suffix sweep back (7 products an entry).  Every kernel runs at
+    One `add_mixed` scan over k (its first step is P + P: a lane like
+    any other to the complete formulas), then ONE inversion a base:
+    prefix products of the n_table Z's along k, `inv_fused` of the
+    total, and the suffix sweep back to x = X/Z, y = Y/Z (5 products an
+    entry).  Every kernel runs at
     the one width C — a tree inversion over the whole array would lower
     an instance a halving level."""
     F = curve.F
@@ -328,8 +330,7 @@ def _affine_multiples(curve: JCurve, pt: AffPoint, n_table: int) -> AffPoint:
     def suffix(run, xs):  # run = 1 / (Z_1 * ... * Z_k)
         X_k, Y_k, z, p = xs
         zinv = F.mul(run, p)
-        zi2 = F.square(zinv)
-        return F.mul(run, z), (F.mul(X_k, zi2), F.mul(Y_k, F.mul(zi2, zinv)))
+        return F.mul(run, z), (F.mul(X_k, zinv), F.mul(Y_k, zinv))
 
     _, (x, y) = jax.lax.scan(suffix, F.inv_fused(total), (X, Y, Zs, pre), reverse=True)
     zero = jnp.zeros_like(x)
@@ -358,14 +359,15 @@ def resident_table(curve: JCurve, bases: AffPoint, window: int, lanes: int) -> j
     return chunks.reshape((steps,) + chunks.shape[2:])
 
 
-def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp.ndarray) -> JacPoint:
+def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp.ndarray) -> ProjPoint:
     """`msm_windowed_signed` over the bases a `resident_table` was built
     from, at the table's window (2^(w-1) entries a base): the same signed
     digit planes, the same Horner fold and tree reduce, the same point.
     The scan carries the step's slice of the
     table in place of the bases.  Digit 0 selects (0, 0), which
-    `add_mixed` passes through; equal and opposite points stay the
-    kernel's case selects, so the sum is exact for every input."""
+    `add_mixed` passes through; equal and opposite points are lanes like
+    any other to the complete formulas, so the sum is exact for every
+    input."""
     steps, n_table, lanes = table.shape[:3]
     window = n_table.bit_length()
     n_digits, n = mags.shape
@@ -392,8 +394,8 @@ def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp
     return tree_reduce(curve, per_lane, lanes)
 
 
-def msm(curve: JCurve, bases: AffPoint, bit_planes: jnp.ndarray, lanes: int = 64) -> JacPoint:
-    """MSM: sum_i s_i * P_i -> one Jacobian point.
+def msm(curve: JCurve, bases: AffPoint, bit_planes: jnp.ndarray, lanes: int = 64) -> ProjPoint:
+    """MSM: sum_i s_i * P_i -> one projective point.
 
     bases: affine limb arrays, leading axis N ((0,0) lanes = infinity, e.g.
     zkey padding or public-wire holes in the c_query).

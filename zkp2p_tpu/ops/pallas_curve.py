@@ -2,22 +2,30 @@
 
 docs/ROOFLINE.md round-4 addendum: with the Pallas Montgomery mul
 (`ops.pallas_mont`) the field layer reaches ~136 M muls/s on a v5e chip
-(7.9x the XLA path), but a Jacobian point add is ~16 muls issued as ~8
+(7.9x the XLA path), but a point add issued product by product is ~8
 separate kernels/fusions — every intermediate round-trips HBM and every
 launch re-pays the (B, 16) <-> (16, B) boundary transposes.  These
-kernels run the COMPLETE curve op (all muls, adds, carries, and the
-branchless infinity/equal/negated case selects of `curve.jcurve`) in
-ONE pallas_call with all intermediates VMEM-resident: per point-add the
-HBM traffic drops from ~19 mul-kernel round-trips to one read of the
-operands and one write of the result.
+kernels run the WHOLE curve op (all muls, adds, carries, and the two
+branchless infinity selects of `curve.jcurve`) in ONE pallas_call with
+all intermediates VMEM-resident: per point-add the HBM traffic drops
+from ~19 mul-kernel round-trips to one read of the operands and one
+write of the result.
 
-Semantics mirror `curve.jcurve.JCurve` exactly (same dbl-2009-l and
-add-2007-bl formulas, same (0, 0) affine / Z == 0 Jacobian infinity
-encodings, same select ordering), and the differential tests pin every
-case lane-for-lane against it (tests/test_pallas_curve.py).  The point
-math is written once over a tiny field-ops object; the G1 instance works
-on single (16, T) limb tiles, the G2 instance on (c0, c1) pairs with
-Karatsuba Fq2 products (u^2 = -1, mirroring field.jfield.JFq2Ops.mul).
+Formulas: the complete addition laws of Renes, Costello and Batina
+(Eurocrypt 2016, "Complete addition formulas for prime order elliptic
+curves"), algorithms 7 (add, 12 products), 8 (mixed add, 11) and 9
+(doubling, 8) for a = 0, on homogeneous projective points (X : Y : Z),
+x = X/Z, y = Y/Z.  They have no exceptional case on a curve without a
+point of order two (G1 has prime order; the twist's group order
+r(2q - r) is odd), so P + P and P + (-P) are lanes like any other and
+no doubling is computed beside an add.  Semantics mirror
+`curve.jcurve.JCurve` exactly (same formulas, same (0, 0) affine /
+Z == 0 projective infinity encodings, same select ordering), and the
+differential tests pin every case lane-for-lane against it
+(tests/test_pallas_curve.py).  The point math is written once over a
+tiny field-ops object; the G1 instance works on single (16, T) limb
+tiles, the G2 instance on (c0, c1) pairs with Karatsuba Fq2 products
+(u^2 = -1, mirroring field.jfield.JFq2Ops.mul).
 
 Layout: limb-major (16, T) tiles like `pallas_mont` — limbs on the
 sublane axis, batch on the 128-wide lane axis.  Field helpers are the
@@ -28,11 +36,12 @@ an unsupported scatter — limb-0 adds are built by slice-and-concat
 (NOT broadcasted_iota one-hots: an iota materialised while an outer
 jit trace is live becomes a captured kernel constant, which
 pallas_call rejects); kernels cannot capture traced constants — the
-modulus / N' / R limbs are passed as (16, 1) operands and zeros are
-derived from tracers (`a ^ a`), never `jnp.zeros`.
+modulus / N' / R limbs (and the twist's 3b) are passed as (16, 1)
+operands and zeros are derived from tracers (`a ^ a`), never
+`jnp.zeros`.
 
-Reference analog: rapidsnark's Jacobian point kernels (its G1/G2 hot
-loops); this is the TPU-native equivalent.
+Reference analog: rapidsnark's point kernels (its G1/G2 hot loops);
+this is the TPU-native equivalent.
 """
 
 from __future__ import annotations
@@ -43,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..curve.jcurve import G2_B3_MONT
 from ..field.jfield import NUM_LIMBS, int_to_limbs
 from .pallas_mont import TILE, _carry_lm, _mont_mul_math, _sub_raw_lm
 
@@ -93,6 +103,13 @@ class _FqOps:
     def sub(self, a, b):
         return _f_sub(a, b, self.n_lm)
 
+    def mul_b3(self, a):
+        """3b = 9 on G1: 8a + a, four additions."""
+        t = self.add(a, a)
+        t = self.add(t, t)
+        t = self.add(t, t)
+        return self.add(t, a)
+
     def is_zero(self, a):
         return _f_is_zero(a)
 
@@ -113,8 +130,11 @@ class _Fq2Ops:
     """Fq2 = Fq[u]/(u^2 + 1) on (c0, c1) tile pairs; Karatsuba product —
     the exact dataflow of field.jfield.JFq2Ops.mul."""
 
-    def __init__(self, fq: _FqOps):
-        self.fq = fq
+    def __init__(self, fq: _FqOps, b3):
+        self.fq, self.b3 = fq, b3  # b3: the twist's 3b = 9/(9 + u), a (16, 1) pair
+
+    def mul_b3(self, a):
+        return self.mul(a, tuple(jnp.broadcast_to(c, a[0].shape) for c in self.b3))
 
     def mul(self, a, b):
         f = self.fq
@@ -151,75 +171,67 @@ def _psel(f, cond, p, q):
     return tuple(f.sel(cond, x, y) for x, y in zip(p, q))
 
 
-def _double_math(f, X1, Y1, Z1):
-    """dbl-2009-l, mirror of JCurve.double (infinity -> infinity free)."""
-    A = f.mul(X1, X1)
-    B = f.mul(Y1, Y1)
-    C = f.mul(B, B)
-    XB = f.add(X1, B)
-    XB2 = f.mul(XB, XB)
-    YZ = f.mul(Y1, Z1)
-    t = f.sub(f.sub(XB2, A), C)
-    D = f.add(t, t)
-    E = f.add(f.add(A, A), A)
-    Fv = f.mul(E, E)
-    X3 = f.sub(Fv, f.add(D, D))
-    C8 = f.add(C, C)
-    C8 = f.add(C8, C8)
-    C8 = f.add(C8, C8)
-    Y3 = f.sub(f.mul(E, f.sub(D, X3)), C8)
-    Z3 = f.add(YZ, YZ)
-    return X3, Y3, Z3
+def _double_math(f, X, Y, Z):
+    """RCB algorithm 9 (a = 0), mirror of JCurve.double: 8 products and
+    one by 3b; 2P for every P, the Z == 0 encodings of infinity
+    included (Z3 = 8 Y^3 Z)."""
+    YY = f.mul(Y, Y)
+    YZ = f.mul(Y, Z)
+    XY = f.mul(X, Y)
+    t2 = f.mul_b3(f.mul(Z, Z))
+    Y8 = f.add(YY, YY)
+    Y8 = f.add(Y8, Y8)
+    Y8 = f.add(Y8, Y8)
+    t0 = f.sub(YY, f.add(f.add(t2, t2), t2))
+    X3 = f.mul(t0, XY)
+    Y3 = f.add(f.mul(t2, Y8), f.mul(t0, f.add(YY, t2)))
+    return f.add(X3, X3), Y3, f.mul(YZ, Y8)
 
 
-def _add_core_math(f, p, q, U1, U2, S1, S2, Z1Z2):
-    """Mirror of JCurve._add_core: the shared tail of add / add_mixed,
-    including the same-x / same-y / infinity case selects in the same
-    order."""
-    H = f.sub(U2, U1)
-    Rr = f.sub(S2, S1)
-    HH = f.mul(H, H)
-    R2 = f.mul(Rr, Rr)
-    HHH = f.mul(H, HH)
-    V = f.mul(U1, HH)
-    X3 = f.sub(f.sub(R2, HHH), f.add(V, V))
-    Y3 = f.sub(f.mul(Rr, f.sub(V, X3)), f.mul(S1, HHH))
-    Z3 = f.mul(Z1Z2, H)
-    res = (X3, Y3, Z3)
-
-    same_x = f.is_zero(H)
-    same_y = f.is_zero(Rr)
-    res = _psel(f, same_x & same_y, _double_math(f, *p), res)
-    zero = f.zero_like(res[0])
-    res = _psel(f, same_x & ~same_y, (zero, zero, zero), res)
-    res = _psel(f, f.is_zero(p[2]), q, res)
-    res = _psel(f, f.is_zero(q[2]), p, res)
-    return res
+def _add_tail_math(f, p, q, t0, t1, z, t3, t4, xz):
+    """Mirror of JCurve._add_tail: the shared second half of algorithms
+    7 and 8 from t0 = X1 X2, t1 = Y1 Y2, z = Z1 Z2, t3 = X1 Y2 + X2 Y1,
+    t4 = Y1 Z2 + Y2 Z1, xz = X1 Z2 + X2 Z1, then the two infinity
+    selects in the same order.  P + P needs no case; P + (-P) comes out
+    as (0 : y : 0), which the Z == 0 encoding reads as infinity."""
+    t0 = f.add(f.add(t0, t0), t0)
+    bz = f.mul_b3(z)
+    y3 = f.mul_b3(xz)
+    z3 = f.add(t1, bz)
+    t1 = f.sub(t1, bz)
+    X3 = f.sub(f.mul(t3, t1), f.mul(t4, y3))
+    Y3 = f.add(f.mul(t1, z3), f.mul(y3, t0))
+    Z3 = f.add(f.mul(z3, t4), f.mul(t0, t3))
+    res = _psel(f, f.is_zero(p[2]), q, (X3, Y3, Z3))
+    return _psel(f, f.is_zero(q[2]), p, res)
 
 
 def _add_math(f, p, q):
+    """RCB algorithm 7 (a = 0): 12 products and two by 3b."""
     X1, Y1, Z1 = p
     X2, Y2, Z2 = q
-    Z1Z1 = f.mul(Z1, Z1)
-    Z2Z2 = f.mul(Z2, Z2)
-    U1 = f.mul(X1, Z2Z2)
-    U2 = f.mul(X2, Z1Z1)
-    S1 = f.mul(f.mul(Y1, Z2), Z2Z2)
-    S2 = f.mul(f.mul(Y2, Z1), Z1Z1)
-    Z1Z2 = f.mul(Z1, Z2)
-    return _add_core_math(f, p, q, U1, U2, S1, S2, Z1Z2)
+    t0 = f.mul(X1, X2)
+    t1 = f.mul(Y1, Y2)
+    t2 = f.mul(Z1, Z2)
+    t3 = f.sub(f.mul(f.add(X1, Y1), f.add(X2, Y2)), f.add(t0, t1))
+    t4 = f.sub(f.mul(f.add(Y1, Z1), f.add(Y2, Z2)), f.add(t1, t2))
+    xz = f.sub(f.mul(f.add(X1, Z1), f.add(X2, Z2)), f.add(t0, t2))
+    return _add_tail_math(f, p, q, t0, t1, t2, t3, t4, xz)
 
 
 def _add_mixed_math(f, p, a):
+    """RCB algorithm 8 (a = 0, Z2 = 1): 11 products and two by 3b."""
     X1, Y1, Z1 = p
     X2, Y2 = a
-    Z1Z1 = f.mul(Z1, Z1)
-    U2 = f.mul(X2, Z1Z1)
-    S2 = f.mul(Y2, f.mul(Z1, Z1Z1))
+    t0 = f.mul(X1, X2)
+    t1 = f.mul(Y1, Y2)
+    t3 = f.sub(f.mul(f.add(X1, Y1), f.add(X2, Y2)), f.add(t0, t1))
+    t4 = f.add(f.mul(Y2, Z1), Y1)
+    xz = f.add(f.mul(X2, Z1), X1)
     # q = from_affine(a): (0, 0) sentinel -> Z = 0, else Z = R (Mont 1)
     a_inf = f.is_zero(X2) & f.is_zero(Y2)
     zq = f.sel(a_inf, f.zero_like(X2), f.one_bcast(X2))
-    return _add_core_math(f, p, (X2, Y2, zq), X1, U2, Y1, S2, Z1)
+    return _add_tail_math(f, p, (X2, Y2, zq), t0, t1, Z1, t3, t4, xz)
 
 
 # ------------------------------------------------------- kernel factories
@@ -252,9 +264,9 @@ def _g2_kernel(op):
 
     def kernel(*refs):
         ins, outs = refs[:-6], refs[-6:]
-        n_lm, np_lm, one_lm = (r[:] for r in ins[-3:])
-        f = _Fq2Ops(_FqOps(n_lm, np_lm, one_lm))
-        raw = [r[:] for r in ins[:-3]]
+        n_lm, np_lm, one_lm, b3_c0, b3_c1 = (r[:] for r in ins[-5:])
+        f = _Fq2Ops(_FqOps(n_lm, np_lm, one_lm), (b3_c0, b3_c1))
+        raw = [r[:] for r in ins[:-5]]
         pairs = [(raw[i], raw[i + 1]) for i in range(0, len(raw), 2)]
         if op == "add":
             r = math_fn(f, tuple(pairs[:3]), tuple(pairs[3:6]))
@@ -282,6 +294,11 @@ def _consts(field):
         jnp.asarray(np.asarray(int_to_limbs(field.nprime_int))[:, None]),
         jnp.asarray(np.asarray(int_to_limbs(field.mont_r))[:, None]),
     )
+
+
+def _consts_g2(fq2):
+    """The base field's constants and the twist's 3b as a (c0, c1) pair."""
+    return _consts(fq2.fq) + tuple(jnp.asarray(c[:, None]) for c in G2_B3_MONT)
 
 
 def _run_g1(op, field, coords, interpret: bool, tile: int = TILE):
@@ -336,11 +353,11 @@ def _run_g2(op, fq2, coords, interpret: bool, tile: int = G2_TILE):
     outs = pl.pallas_call(
         _G2_KERNELS[op],
         grid=((B + pad) // tile,),
-        in_specs=[spec] * len(lm) + [cspec] * 3,
+        in_specs=[spec] * len(lm) + [cspec] * 5,
         out_specs=[spec] * 6,
         out_shape=[jax.ShapeDtypeStruct((NUM_LIMBS, B + pad), jnp.uint32)] * 6,
         interpret=interpret,
-    )(*lm, *_consts(fq2.fq))
+    )(*lm, *_consts_g2(fq2))
     pts = []
     for i in range(3):
         c0 = jnp.moveaxis(outs[2 * i][:, :B], 0, -1)
@@ -351,14 +368,14 @@ def _run_g2(op, fq2, coords, interpret: bool, tile: int = G2_TILE):
 
 @partial(jax.jit, static_argnums=(0, 3))
 def g1_add(field, p, q, interpret: bool = False):
-    """Complete Jacobian + Jacobian, one fused kernel.  p, q: (X, Y, Z)
+    """Complete projective + projective, one fused kernel.  p, q: (X, Y, Z)
     triples of (..., 16) uint32 Montgomery limbs."""
     return _run_g1("add", field, (*p, *q), interpret)
 
 
 @partial(jax.jit, static_argnums=(0, 3))
 def g1_add_mixed(field, p, a, interpret: bool = False):
-    """Complete Jacobian + affine ((0,0) = infinity), one fused kernel."""
+    """Complete projective + affine ((0,0) = infinity), one fused kernel."""
     return _run_g1("add_mixed", field, (*p, *a), interpret)
 
 
@@ -369,7 +386,7 @@ def g1_double(field, p, interpret: bool = False):
 
 @partial(jax.jit, static_argnums=(0, 3))
 def g2_add(fq2, p, q, interpret: bool = False):
-    """G2 Jacobian + Jacobian over Fq2; coords (..., 2, 16)."""
+    """G2 projective + projective over Fq2; coords (..., 2, 16)."""
     return _run_g2("add", fq2, (*p, *q), interpret)
 
 

@@ -8,7 +8,7 @@ distributed axes:
     the batched-onramp configuration of BASELINE.json.
   - "shard": model parallelism over the MSM base-point axis — each device
     accumulates bucket/plane partial sums for its slice of the zkey, and
-    ONE group-operation all-reduce (all_gather + local Jacobian fold)
+    ONE group-operation all-reduce (all_gather + local projective fold)
     combines them over ICI.  This is the Pippenger partial-sum allreduce
     of SURVEY.md §2.7 expressed with XLA collectives instead of NCCL.
 
@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..curve.jcurve import AffPoint, JacPoint, JCurve
+from ..curve.jcurve import AffPoint, ProjPoint, JCurve
 from ..ops.msm import msm, msm_windowed
 
 
@@ -79,7 +79,7 @@ def _msm_pod_fn(curve: JCurve, n_bases: int, mesh: Mesh, dcn_axis: str, ici_axis
     return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
-def _fold_gathered_batched(curve: JCurve, gathered: JacPoint, n: int) -> JacPoint:
+def _fold_gathered_batched(curve: JCurve, gathered: ProjPoint, n: int) -> ProjPoint:
     """Fold per-device partials with a batch axis: gathered components
     are (B_local, n_dev, ...); scan over the device axis."""
 
@@ -100,7 +100,7 @@ def msm_pod_batched(
     ici_axis: str = "shard",
     lanes: int = 64,
     window: int = 4,
-) -> JacPoint:
+) -> ProjPoint:
     """Batched MSM over a pod mesh (`make_pod_mesh`): the proof batch is
     data-parallel over the `dcn` axis (each slice proves its share of
     the batch) while each slice shards the base-point axis over its ICI
@@ -108,7 +108,7 @@ def msm_pod_batched(
     only DCN traffic being one proof point per batch element.
 
     planes_batch: (B, n_planes, N) digit planes, B divisible by the dcn
-    width, N by the ici width.  Returns (B,)-batched Jacobian points,
+    width, N by the ici width.  Returns (B,)-batched projective points,
     replicated everywhere."""
     B = planes_batch.shape[0]
     assert B % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"

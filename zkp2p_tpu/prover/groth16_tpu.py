@@ -45,6 +45,7 @@ import numpy as np
 
 from ..curve.host import G1Point, G2Point, g1_add, g1_mul, g1_neg, g2_add, g2_mul
 from ..curve.jcurve import (
+    ADD_LAW,
     AffPoint,
     G1J,
     G2J,
@@ -768,6 +769,12 @@ def _enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
     return value
 
 
+def _msm_enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
+    """`_enqueued` for the five MSM stages: the span carries the curve's
+    addition law (`add`), as `h_planes` carries its ladder."""
+    return _enqueued(watch, name, value, add=ADD_LAW, **attrs)
+
+
 def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_StageWatch] = None):
     """The five big MSMs of a chunk of witnesses, `w_mont` (B, n_wires,
     16); everything else about the proof is host-cheap.  The b/c MSMs
@@ -775,7 +782,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
     gathered through b_sel/c_sel), and with width metadata each witness
     MSM splits into a narrow class (3 signed w=4 planes — the ~90% of
     wires that are constraint-bounded bits/bytes) and a wide class (full
-    planes); the two partial sums combine with one Jacobian add per
+    planes); the two partial sums combine with one projective add per
     query.  On a TPU the MSMs of a class are padded to one base count,
     so they share ONE compiled executable (each cold TPU MSM compile
     measured ~2 min)."""
@@ -793,9 +800,9 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
         key has one, else `_msm_g1` builds the multiples in its scan,
         over `n_to` bases where it shares the query MSMs' executable."""
         if h_table is not None:
-            return _enqueued(watch, "msm_h", _jit_msm_h_resident(h_table, h_planes),
+            return _msm_enqueued(watch, "msm_h", _jit_msm_h_resident(h_table, h_planes),
                              window=int(h_table.shape[1]).bit_length(), table="resident")
-        return _enqueued(watch, "msm_h", _jit_msm_g1(*_pad_msm(dpk.h_bases, h_planes, n_to)),
+        return _msm_enqueued(watch, "msm_h", _jit_msm_g1(*_pad_msm(dpk.h_bases, h_planes, n_to)),
                          window=MSM_WINDOW, table="scan")
 
     if not int(dpk.a_nsel.shape[0]):  # no narrow class: one MSM a query
@@ -811,10 +818,10 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
         c_planes = _take_planes(w_planes, dpk.c_sel)
         h_acc = msm_h(g1_n)
         return (
-            _enqueued(watch, "msm_a", _jit_msm_g1(*_pad_msm(dpk.a_bases, w_planes, g1_n))),
-            _enqueued(watch, "msm_b1", _jit_msm_g1(*_pad_msm(dpk.b1_bases, b_planes, g1_n))),
-            _enqueued(watch, "msm_b2", _jit_msm_g2(dpk.b2_bases, b_planes)),
-            _enqueued(watch, "msm_c", _jit_msm_g1(*_pad_msm(dpk.c_bases, c_planes, g1_n))),
+            _msm_enqueued(watch, "msm_a", _jit_msm_g1(*_pad_msm(dpk.a_bases, w_planes, g1_n))),
+            _msm_enqueued(watch, "msm_b1", _jit_msm_g1(*_pad_msm(dpk.b1_bases, b_planes, g1_n))),
+            _msm_enqueued(watch, "msm_b2", _jit_msm_g2(dpk.b2_bases, b_planes)),
+            _msm_enqueued(watch, "msm_c", _jit_msm_g1(*_pad_msm(dpk.c_bases, c_planes, g1_n))),
             h_acc,
         )
 
@@ -867,10 +874,10 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
         return accs[0] if len(accs) == 1 else G2J.add(accs[0], accs[1])
 
     return (
-        _enqueued(watch, "msm_a", query("a", dpk.a_bases, dpk.a_nsel, dpk.a_wsel, None)),
-        _enqueued(watch, "msm_b1", query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
-        _enqueued(watch, "msm_b2", query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
-        _enqueued(watch, "msm_c", query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel)),
+        _msm_enqueued(watch, "msm_a", query("a", dpk.a_bases, dpk.a_nsel, dpk.a_wsel, None)),
+        _msm_enqueued(watch, "msm_b1", query("b1", dpk.b1_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
+        _msm_enqueued(watch, "msm_b2", query_g2("b2", dpk.b2_bases, dpk.b_nsel, dpk.b_wsel, dpk.b_sel)),
+        _msm_enqueued(watch, "msm_c", query("c", dpk.c_bases, dpk.c_nsel, dpk.c_wsel, dpk.c_sel)),
         msm_h(),
     )
 
@@ -931,7 +938,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
     (`NamedSharding(mesh, P("batch"))` — each batch group proves its
     share of the chunk), and every MSM runs base-axis-sharded over the
     inner "shard" axis with per-device bucket partial sums combined by
-    ONE group-op allreduce (all_gather + Jacobian fold — ICI on real
+    ONE group-op allreduce (all_gather + projective fold — ICI on real
     hardware, host rings on the virtual CPU mesh; parallel.mesh.
     msm_pod_batched).  Returns the same five (B,)-batched accumulators
     `_prove_device` emits, so chunks from either arm concatenate
@@ -952,7 +959,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
         n = bases[0].shape[0]
         lanes = max(1, min(64, -(-n // n_ici)))
         b, p = pad_to_multiple(bases, planes, n_ici * lanes)
-        return _enqueued(watch, name, msm_pod_batched(
+        return _msm_enqueued(watch, name, msm_pod_batched(
             curve, b, p, mesh,
             dcn_axis="batch", ici_axis="shard", lanes=lanes, window=MSM_WINDOW,
         ))
