@@ -65,7 +65,7 @@ def _stand_in_stages(monkeypatch, g, dpk):
     counts = {"g1": [], "g1_narrow": []}
     planes = lambda b, k, cols: (np.zeros((b, k, cols), np.uint32), np.zeros((b, k, cols), bool))  # noqa: E731
 
-    def h_planes(dpk_, w_mont):
+    def h_planes(dpk_, w_mont, h_window):
         b = w_mont.shape[0]
         return (planes(b, 64, n), planes(b, g.NARROW_PLANES, n)), planes(b, 64, m)
 
@@ -84,7 +84,7 @@ def _stand_in_stages(monkeypatch, g, dpk):
     monkeypatch.setattr(g, "_jit_msm_g2_narrow", msm("g2_narrow", (2, 16)))
     monkeypatch.setattr(g, "G1J", adds)
     monkeypatch.setattr(g, "G2J", adds)
-    monkeypatch.setattr(g, "_h_table_window", lambda log_m: None)  # the scan road: h through the G1 program too
+    monkeypatch.setattr(g, "_h_table_window", lambda log_m, device=None: None)  # the scan road: h through the G1 program too
     return counts
 
 

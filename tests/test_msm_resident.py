@@ -333,9 +333,9 @@ def test_recode_has_one_shape(monkeypatch, classed):
     m, rng = 1 << dpk.log_m, random.Random(28)
     h_scalars = [rng.randrange(R) for _ in range(m - 2)] + [0, R - 1]
     h = jnp.asarray(np.stack([FR.to_mont_host(v) for v in h_scalars]))
-    (w_planes, narrow), h_planes = jax.jit(G._recode)(dpk, G.witness_to_device(wits[0]), h)
+    h_window = G._h_table_window(dpk.log_m, G.key_device(dpk))
+    (w_planes, narrow), h_planes = jax.jit(G._recode, static_argnums=3)(dpk, G.witness_to_device(wits[0]), h, h_window)
     assert _planes_to_scalars(tuple(p[None] for p in w_planes), G.MSM_WINDOW) == [[v % R for v in wits[0]]]
-    h_window = G._h_table_window(dpk.log_m)
     assert h_window == 8 and h_planes[0].shape == h_planes[1].shape == (256 // h_window, m)
     assert _planes_to_scalars(tuple(p[None] for p in h_planes), h_window) == [h_scalars]
     if not classed:

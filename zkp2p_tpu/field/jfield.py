@@ -82,17 +82,17 @@ def _carry_canon(x: jnp.ndarray, out_limbs: int) -> jnp.ndarray:
 
 
 @lru_cache(maxsize=None)
-def _conv_onehot(n: int, m: int) -> jnp.ndarray:
+def _conv_onehot(n: int, m: int) -> np.ndarray:
     """(2*n*m, n+m+1) 0/1 f32 matrix folding lo/hi partial-product planes
-    onto their limb offsets: flat index (p, i, j) -> column i + j + p."""
+    onto their limb offsets: flat index (p, i, j) -> column i + j + p.
+    A host array: a constant of whichever device's program uses it."""
     L = n + m + 1
     w = np.zeros((2, n, m, L), dtype=np.float32)
     for i in range(n):
         for j in range(m):
             w[0, i, j, i + j] = 1.0
             w[1, i, j, i + j + 1] = 1.0
-    with jax.ensure_compile_time_eval():
-        return jnp.asarray(w.reshape(2 * n * m, L))
+    return w.reshape(2 * n * m, L)
 
 
 # Convolution layout selector.  "matmul": the f32 one-hot matmul below

@@ -51,9 +51,12 @@ def _bit_reverse_perm(m: int) -> np.ndarray:
     return rev
 
 
-def _twiddle_powers(w: int, count: int) -> jnp.ndarray:
+def _twiddle_powers(w: int, count: int) -> np.ndarray:
     """[w^0 .. w^(count-1)] in Montgomery form, built on device in
-    log2(count) doubling rounds: powers[j + 2^i] = powers[j] * w^(2^i).
+    log2(count) doubling rounds: powers[j + 2^i] = powers[j] * w^(2^i),
+    and brought back to the host: a table is a constant of the programs
+    that close over it, whichever device they run on, not a buffer of
+    the device that happened to build it.
 
     Every round runs at the FULL table width inside one `fori_loop`
     (lanes outside [2^i, 2^(i+1)) keep their value), so a table is ONE
@@ -63,7 +66,7 @@ def _twiddle_powers(w: int, count: int) -> jnp.ndarray:
     domain — for the sake of muls that are noise on the device."""
     n_rounds = max(1, (count - 1).bit_length())
     factors = np.stack([FR.to_mont_host(pow(w, 1 << i, R)) for i in range(n_rounds)])
-    return _powers_by_doubling(jnp.asarray(factors), count)
+    return np.asarray(_powers_by_doubling(jnp.asarray(factors), count))
 
 
 @partial(jax.jit, static_argnums=1)
@@ -81,10 +84,12 @@ def _powers_by_doubling(factors: jnp.ndarray, count: int) -> jnp.ndarray:
 
 @lru_cache(maxsize=None)
 def domain(log_m: int):
-    """Precomputed tables for the 2^log_m domain (cached per process).
+    """Precomputed tables for the 2^log_m domain (cached per process),
+    as host arrays: the programs of every device close over the same
+    constants.
 
     Built under `ensure_compile_time_eval` so a first call from inside a
-    traced function still produces concrete device arrays (safe to cache)."""
+    traced function still computes concrete values (safe to cache)."""
     m = 1 << log_m
     w = fr_domain_root(log_m)
     with jax.ensure_compile_time_eval():
@@ -93,7 +98,7 @@ def domain(log_m: int):
             "perm": _bit_reverse_perm(m),
             "tw": _twiddle_powers(w, m // 2),
             "tw_inv": _twiddle_powers(fr_inv(w), m // 2),
-            "m_inv_mont": jnp.asarray(FR.to_mont_host(fr_inv(m))),
+            "m_inv_mont": np.asarray(FR.to_mont_host(fr_inv(m))),
         }
 
 
@@ -230,7 +235,7 @@ def intt(x: jnp.ndarray, log_m: int) -> jnp.ndarray:
 
 
 @lru_cache(maxsize=None)
-def _coset_powers(g: int, log_m: int) -> jnp.ndarray:
+def _coset_powers(g: int, log_m: int) -> np.ndarray:
     with jax.ensure_compile_time_eval():
         return _twiddle_powers(g, 1 << log_m)
 

@@ -398,7 +398,16 @@ def cmd_service(args):
         spool_cap=args.spool_cap,
     )
     make = ProvingService.for_venmo if args.circuit == "venmo" else ProvingService.for_email_verify
-    svc = make(cs, lay, params, dpk, vk, **svc_kw)
+    replicas = getattr(args, "replicas", "1")
+    if replicas != "1":
+        # one replica a local device, in this process, on the one spool
+        # (pipeline.replicas): the four-chip host that is not a mesh
+        from ..pipeline.replicas import ReplicaSet
+
+        svc = ReplicaSet(lambda key: make(cs, lay, params, key, vk, **svc_kw), dpk,
+                         n=None if replicas == "auto" else int(replicas))
+    else:
+        svc = make(cs, lay, params, dpk, vk, **svc_kw)
     os.makedirs(args.spool, exist_ok=True)
     # graceful drain (docs/ROBUSTNESS.md §fleet): SIGTERM/SIGINT stop
     # claiming, finish in-flight batches, flush sinks, exit 0 — so a
@@ -1048,6 +1057,10 @@ def main(argv=None):
     s.add_argument("--prover", choices=["tpu", "native"], default="tpu",
                    help="tpu: vmapped XLA batch; native: C++ runtime, sequential")
     s.add_argument("--prefetch", type=int, default=1, help="ready-batch queue depth")
+    s.add_argument("--replicas", default="1", metavar="N|auto",
+                   help="one-chip replicas of this process on the spool, one a local device, each "
+                        "with the key pinned to its device (auto = every local device; 1 = a solo "
+                        "service; a mesh over the same chips is ZKP2P_TPU_SHARD=on instead)")
     s.add_argument("--stale-claim-s", type=float, default=300.0,
                    help="claim age after which a dead worker's request is taken over")
     s.add_argument("--deadline-s", type=float, default=None,

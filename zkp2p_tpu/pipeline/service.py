@@ -32,6 +32,7 @@ global invariant).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import errno
 import json
@@ -48,7 +49,16 @@ from ..formats.proof_json import dump
 from ..utils.audit import execution_digest, install_compile_listener, preflight, sample_device_memory
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import REGISTRY, JsonlSink, maybe_start_metrics_server, publish_native_stats, run_id, run_manifest
-from ..utils.trace import adopt_stack, current_stack, drain as drain_trace, record, set_context, trace
+from ..utils.trace import (
+    adopt_context,
+    adopt_stack,
+    current_context,
+    current_stack,
+    drain as drain_trace,
+    record,
+    set_context,
+    trace,
+)
 
 # The terminal-state machine (docs/ROBUSTNESS.md): every request ends in
 # EXACTLY ONE of these, recorded as a .proof.json/.error.json artifact
@@ -505,10 +515,39 @@ class ProvingService:
         # `circuit` labels this service's ledger entries and selects its
         # budget rows; "" = the generic "service" bucket.
         self.circuit = circuit or "service"
+        # a member of a replica set (pipeline.replicas; `join_set`): the
+        # index of the local device this service's key lives on, carried
+        # by every span its threads close and every request record; how
+        # many loops of the set are up (its scheduler's peers); what to
+        # call when this loop is up.  None: a solo service.
+        self.replica: Optional[int] = None
+        self._set_live: Optional[Callable[[], int]] = None
+        self._on_loop_up: Optional[Callable[[int, Dict], None]] = None
+        # what a set reads when it has drained (`replicas/idle`): this
+        # service's first claim and last terminal, the seconds it had a
+        # batch in its prover, the batches and the proofs it served
+        self.t_first_claim: Optional[float] = None
+        self.t_last_terminal: Optional[float] = None
+        self.busy_s = 0.0
+        self.n_batches = 0
+        self.n_done = 0
         self._perf_book = None
         self._perf_lock = threading.Lock()
         self._perf_hb: Optional[Dict] = None
         self._perf_agg: Dict[str, List[float]] = {}
+
+    def join_set(self, replica: int, live: Callable[[], int], sinks: Dict, sinks_lock,
+                 on_loop_up: Callable[[int, Dict], None]) -> None:
+        """Made replica `replica` of a set (pipeline.replicas.ReplicaSet),
+        before `run`: the set's members write one sink a path between
+        them (four JsonlSink instances on one file would rotate against
+        each other), count each other as peers, and the set stamps
+        `last_preflight` itself, once every loop has called
+        `on_loop_up`."""
+        self.replica = replica
+        self._set_live = live
+        self._sinks, self._sinks_lock = sinks, sinks_lock
+        self._on_loop_up = on_loop_up
 
     def request_drain(self) -> None:
         """Flip the drain flag: stop claiming, finish in-flight work,
@@ -548,15 +587,18 @@ class ProvingService:
     _PEER_HB_FRESH_S = 15.0
 
     def _live_peers(self) -> int:
-        """Live workers sharing this spool (self included), from fresh
-        heartbeat files in the fleet dir — the scheduler's parallelism:
-        N workers pull ONE queue, so a worker predicting completion
-        times as if it served the whole backlog alone would shed
-        requests its peers could still serve.  Solo service (no fleet
-        dir) = 1; an unreadable dir degrades to 1 (predictions turn
-        conservative, never wrong-side)."""
+        """Live workers sharing this spool (self included): fresh
+        heartbeat files in the fleet dir, one a process, and the other
+        replicas of this process's set whose loops are up — the
+        scheduler's parallelism: N workers pull ONE queue, so a worker
+        predicting completion times as if it served the whole backlog
+        alone would shed requests its peers could still serve.  Solo
+        service (no fleet dir, no set) = 1; an unreadable dir degrades
+        to this process's own count (predictions turn conservative,
+        never wrong-side)."""
+        mine = max(1, self._set_live()) if self._set_live is not None else 1
         if not getattr(self, "_fleet_dir", ""):
-            return 1
+            return mine
         n = 0
         now = time.time()
         try:
@@ -569,8 +611,8 @@ class ProvingService:
                 except OSError:
                     pass
         except OSError:
-            return 1
-        return max(1, n)
+            return mine
+        return max(1, n) - 1 + mine
 
     def _live_peer_tiers(self) -> List[str]:
         """Advertised tiers of live fleet peers (self EXCLUDED), from
@@ -672,6 +714,8 @@ class ProvingService:
             # fleet attribution: which worker of which fleet produced
             # this record — pids recycle across restarts, worker ids
             # don't, so trace_report groups waterfall rows by worker
+            if self.replica is not None:
+                rec["replica"] = self.replica
             if self._worker_id:
                 rec["worker"] = self._worker_id
             if self._fleet_id:
@@ -722,6 +766,8 @@ class ProvingService:
             pass
         if state in TERMINAL_STATES:
             REGISTRY.counter("zkp2p_service_requests_total", {"state": state}).inc()
+            self.t_last_terminal = time.time()
+            self.n_done += state == "done"
             # SLO accounting: full-life latency (spool arrival ->
             # terminal) into the rolling-window tracker; only `done`
             # counts as good (docs/OBSERVABILITY.md §SLO).  The anchor
@@ -880,6 +926,10 @@ class ProvingService:
             except OSError:
                 return False  # vanished: owner just completed it
             if age < self.stale_claim_s:
+                # a peer's fresh claim: an attempt lost (replicas and
+                # fleet workers that scan one backlog lose most of
+                # what they try)
+                REGISTRY.counter("zkp2p_service_claim_lost_total").inc()
                 return False
             # Stale claim: STEAL it by renaming it aside — rename is
             # atomic and the kernel picks exactly ONE winner (every
@@ -1042,15 +1092,29 @@ class ProvingService:
             span_attrs["attempt"] = attempt
         if rung:
             span_attrs["rung"] = rung
-        with _span(batch, "prove", **span_attrs):
-            fault_point("prove")
-            prove = self.prover_fn or prove_tpu_batch
-            proofs = prove(self.dpk, [r.witness for r in batch])
+        witnesses = [r.witness for r in batch]
+        if self.prover_fn is None:
+            # The device prover lowers and compiles a program a batch shape
+            # (ROADMAP R2: minutes each), so a batch short of the size it
+            # was claimed for (a sweep's remainder, a peer's lost claims,
+            # a bisected half) proves at that size, its last witness
+            # repeated: device time a full batch's, nothing compiled.
+            short = max((r.batch_target or 0 for r in batch), default=0) - len(batch)
+            witnesses += witnesses[-1:] * max(0, short)
+        t_busy = time.perf_counter()
+        try:
+            with _span(batch, "prove", **span_attrs):
+                fault_point("prove")
+                prove = self.prover_fn or prove_tpu_batch
+                proofs = prove(self.dpk, witnesses)
+        finally:
+            self.busy_s += time.perf_counter() - t_busy
         proofs = list(proofs) if proofs is not None else []
-        if len(proofs) != len(batch):
+        if len(proofs) != len(witnesses):
             raise RuntimeError(
-                f"prover returned {len(proofs)} proofs for a batch of {len(batch)}"
+                f"prover returned {len(proofs)} proofs for a batch of {len(witnesses)}"
             )
+        del proofs[len(batch):]  # what the padding proved
         with _span(batch, "verify"):
             fault_point("verify")
             sample_pub = self.public_fn(batch[0].witness)
@@ -1334,6 +1398,8 @@ class ProvingService:
         """One spool sweep; returns counters. Files: <name>.req.json in,
         <name>.proof.json / <name>.error.json out."""
         self._resolve_policy()
+        if self.replica is not None:
+            set_context(replica=self.replica)  # on every span this sweep's threads close
         t_sweep = time.time()
         stats = {s: 0 for s in TERMINAL_STATES}
         # draining before the sweep even starts: claim nothing, scan
@@ -1480,6 +1546,14 @@ class ProvingService:
         # two-stage shell pipeline (2_gen_wtns.sh -> 5_gen_proof.sh),
         # overlapped instead of sequential.
         ready_q: "queue.Queue[Optional[List[Request]]]" = queue.Queue(maxsize=self.prefetch)
+        # A batch is claimed when the queue has a place for it, not
+        # before: claimed, witnessed and waiting at a full queue, it is
+        # one more batch that no peer on the spool can take while this
+        # worker's prover is two batches away from it (four replicas on
+        # 32 requests held 3 / 3 / 2 / 0).  The consumer frees a place
+        # when it takes a batch; in flight are the batch in the prover
+        # and `prefetch` behind it.
+        slots = threading.Semaphore(self.prefetch)
         producer_error: List[BaseException] = []
 
         # Sweep-level claim heartbeat: refreshes EVERY claim this sweep
@@ -1571,20 +1645,62 @@ class ProvingService:
             except Exception:  # noqa: BLE001 — batch tier is an optimization
                 return [r for r in batch if scalar_witness(r)]
 
+        def claim_batch(source: "collections.deque", target: int) -> List[Request]:
+            """Up to `target` requests claimed off the front of `source`,
+            skipping what a peer holds: a batch is filled from what is
+            still free, not cut from a slice of the scan a peer has
+            been through (claim at DEQUEUE, not at scan: a long sweep
+            must not hold scan-time claims that go stale while earlier
+            batches prove)."""
+            cand: List[Request] = []
+            while source and len(cand) < target:
+                r = source.popleft()
+                if not self._try_claim(r.path):
+                    continue
+                r.t_claim = time.time()
+                if self.t_first_claim is None:
+                    self.t_first_claim = r.t_claim
+                r.batch_target = target
+                with hb_lock:
+                    hb_reqs.append(r)  # heartbeat from claim to terminal
+                # deadline gate #1, at claim: a request that
+                # arrived already-expired (or aged out in the
+                # spool) terminals before any witness work
+                dl = self._deadline_of(r)
+                if dl is not None and r.t_claim > dl:
+                    if self._terminal_error(
+                        spool, r, "error-deadline-exceeded",
+                        RuntimeError(
+                            f"deadline exceeded at claim "
+                            f"({r.t_claim - r.t_submit:.3f}s since submit)"
+                        ),
+                        knobs, stats,
+                    ):
+                        REGISTRY.counter("zkp2p_service_deadline_total").inc()
+                    continue
+                cand.append(r)
+            return cand
+
+        def batches():
+            """(what to claim from, the INTENDED size) a batch — records
+            carry the size as batch_size_target.  Adaptive: the
+            controller's lane-sorted partition, a planned chunk a batch;
+            static: batch_size at a time off the scan order, until it
+            is used up."""
+            if batch_plan is not None:
+                for chunk in batch_plan:
+                    yield collections.deque(chunk), len(chunk)
+            else:
+                todo = collections.deque(pending)
+                while todo:
+                    yield todo, self.batch_size
+
         def produce():
             adopt_stack(sweep_stack)  # the producer's spans are the sweep's children too
+            adopt_context(sweep_ctx)
             try:
-                # adaptive: the controller's lane-sorted partition;
-                # static: fixed batch_size slices of the scan order —
-                # the exact pre-scheduler behavior
-                if batch_plan is not None:
-                    slices = batch_plan
-                else:
-                    slices = [
-                        pending[i : i + self.batch_size]
-                        for i in range(0, len(pending), self.batch_size)
-                    ]
-                for chunk in slices:
+                for source, target in batches():
+                    slots.acquire()  # a place in ready_q for what is claimed next
                     # Drain gate: once the flag is up, claim NOTHING
                     # more.  Checked per batch, before any claim — the
                     # batches already claimed (proving now, or queued in
@@ -1594,44 +1710,15 @@ class ProvingService:
                     # zero proofs (docs/ROBUSTNESS.md §fleet).
                     if self._drain.is_set():
                         break
-                    # the INTENDED size for this batch: the static cap,
-                    # or the controller's planned chunk (records carry
-                    # it as batch_size_target)
-                    target = len(chunk) if batch_plan is not None else self.batch_size
-                    # Claim at DEQUEUE, not at scan: a long sweep must
-                    # not hold scan-time claims that go stale while
-                    # earlier batches prove (peer takeover would then
-                    # duplicate in-progress work).
-                    cand = []
-                    for r in chunk:
-                        if not self._try_claim(r.path):
-                            continue
-                        r.t_claim = time.time()
-                        r.batch_target = target
-                        with hb_lock:
-                            hb_reqs.append(r)  # heartbeat from claim to terminal
-                        # deadline gate #1, at claim: a request that
-                        # arrived already-expired (or aged out in the
-                        # spool) terminals before any witness work
-                        dl = self._deadline_of(r)
-                        if dl is not None and r.t_claim > dl:
-                            if self._terminal_error(
-                                spool, r, "error-deadline-exceeded",
-                                RuntimeError(
-                                    f"deadline exceeded at claim "
-                                    f"({r.t_claim - r.t_submit:.3f}s since submit)"
-                                ),
-                                knobs, stats,
-                            ):
-                                REGISTRY.counter("zkp2p_service_deadline_total").inc()
-                            continue
-                        cand.append(r)
+                    cand = claim_batch(source, target)
                     if self.inputs_fn is not None:
                         batch = batched_witness(cand)
                     else:
                         batch = [r for r in cand if scalar_witness(r)]
                     if batch:
                         ready_q.put(batch)
+                    else:
+                        slots.release()
             except BaseException as e:  # noqa: BLE001 — re-raised by the consumer
                 producer_error.append(e)
             finally:
@@ -1647,13 +1734,13 @@ class ProvingService:
             if pending else contextlib.nullcontext({})
         )
         with sweep as sweep_rec:
-            sweep_stack = current_stack()
+            sweep_stack, sweep_ctx = current_stack(), current_context()
             hb = threading.Thread(target=_sweep_heartbeat, daemon=True)
             hb.start()
             producer = threading.Thread(target=produce, daemon=True)
             producer.start()
             try:
-                sweep_rec["n_batches"] = self._consume(spool, ready_q, knobs, stats)
+                sweep_rec["n_batches"] = self._consume(spool, ready_q, knobs, stats, slots)
             finally:
                 stop_hb.set()
                 hb.join()
@@ -1680,21 +1767,26 @@ class ProvingService:
             pass
         return stats
 
-    def _consume(self, spool, ready_q, knobs, stats) -> int:
+    def _consume(self, spool, ready_q, knobs, stats, slots) -> int:
         """Drain ready batches: deadline-gate, then prove with the full
         rescue ladder, terminal-ing every request exactly once.  Claims
-        stay fresh via the caller's sweep-level heartbeat.  Returns how
-        many batches it fetched."""
+        stay fresh via the caller's sweep-level heartbeat.  Each batch
+        taken frees a place in the queue (`slots`): the producer may
+        claim the next.  Returns how many batches it fetched."""
         n_batches = 0
         while True:
             t_wait = time.time()
             batch = ready_q.get()
             if batch is None:
                 return n_batches
+            slots.release()
             # the prover had nothing to prove while it waited here (the
             # sentinel's wait is the sweep ending, not a starved prover)
             record("service/starved", t_wait, time.time(), n=len(batch))
             n_batches += 1
+            self.n_batches += 1
+            if self.replica is not None:
+                REGISTRY.counter("zkp2p_replica_batches_total", {"replica": str(self.replica)}).inc()
             # deadline gate #2, at batch assembly: queue wait behind a
             # slow batch may have burned the remaining budget — check
             # again immediately before committing prove compute
@@ -1876,6 +1968,7 @@ class ProvingService:
         rep = preflight(
             workload=False,
             log=lambda m: print(f"[service] {m}", file=sys.stderr, flush=True),
+            stamp=self._on_loop_up is None,  # a set stamps once, when every loop is up
         )
         print(
             f"[service] preflight: backend={rep['backend']} "
@@ -1913,6 +2006,10 @@ class ProvingService:
 
         self._resolve_policy()
         fleet_member_arm()
+        if self.replica is None:
+            from .replicas import replicas_arm
+
+            replicas_arm(None)  # a solo service: "off" (a set records its own count)
         fleet_dir = load_config().fleet_dir or None
         self._sampler = TimeseriesSampler(load_config().ts_sample_s, self.stale_claim_s)
 
@@ -1943,6 +2040,8 @@ class ProvingService:
                 hb_stop = start_heartbeat_thread(self, fleet_dir)
             except Exception:  # noqa: BLE001
                 pass
+        if self._on_loop_up is not None:
+            self._on_loop_up(self.replica, rep["stamp"])
         deadline = (time.time() + max_seconds) if max_seconds else None
         sweeps = 0
         why = "sweeps"

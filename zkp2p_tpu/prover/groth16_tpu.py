@@ -140,19 +140,21 @@ def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int, chunk: int = 
 
 
 @lru_cache(maxsize=None)
-def _hbm_bytes_limit() -> int:
-    stats = jax.devices()[0].memory_stats() or {}
+def _hbm_bytes_limit(device=None) -> int:
+    """The memory of the device a key lives on (`key_device`); without
+    one, the process's first device."""
+    stats = (device or jax.devices()[0]).memory_stats() or {}
     return int(stats.get("bytes_limit") or NOMINAL_HBM_BYTES)
 
 
-def _h_table_window(log_m: int) -> Optional[int]:
-    """The window at which this process keeps a resident h table for a
-    key of 2^log_m domain points; None: the table does not fit, and the
-    h MSM builds its multiples in the scan (`_msm_g1`).  Static under
-    jit: `_recode` and `_prove_device` agree."""
-    limit = _hbm_bytes_limit()
+def _h_table_window(log_m: int, device=None) -> Optional[int]:
+    """The window at which a key of 2^log_m domain points on `device`
+    keeps a resident h table; None: the table does not fit, and the h
+    MSM builds its multiples in the scan (`_msm_g1`).  `_prove_device`
+    hands the window of the table it holds to the h stage's program."""
+    limit = _hbm_bytes_limit(device)
     # off a TPU `auto` does not chunk (0): plan the chunk a TPU would take
-    chunk = _batch_chunk_size(log_m) or batch_chunk_for(log_m, limit)
+    chunk = _batch_chunk_size(log_m, device) or batch_chunk_for(log_m, limit)
     return h_table_window(log_m, RESIDENT_ENTRY_BYTES, limit, chunk)
 
 
@@ -287,6 +289,40 @@ def _dpk_unflatten(meta, children) -> "DeviceProvingKey":
 
 
 jax.tree_util.register_pytree_node(DeviceProvingKey, _dpk_flatten, _dpk_unflatten)
+
+
+def key_device(dpk: "DeviceProvingKey"):
+    """The device a key is pinned to (`place_key`), where the prover
+    then runs: a batch's inputs are put beside the key, every eager take
+    and pad follows its operands, and the chunk and the h window are
+    planned for that device's memory.  None for a key as `load_dpk`,
+    `setup_device` and `device_pk` make it, pinned nowhere: it lives and
+    proves on the process's default device, through the programs a
+    one-chip service has always run (an argument pinned to a device is
+    lowered with a sharding attribute, so pinned and unpinned keys do
+    not share programs: PERF.md, PR 30)."""
+    a = dpk.a_coeff
+    return next(iter(a.devices())) if getattr(a, "committed", False) else None
+
+
+def place_key(dpk: "DeviceProvingKey", device) -> "DeviceProvingKey":
+    """The key pinned to `device`.  Where it lives elsewhere, a copy,
+    device to device (a key is read from disk once whatever the number
+    of replicas): a new instance, whose resident h table and class
+    splits (`_h_table_cache`, `_split_cache`) its first batch builds on
+    that device.  Where it already lives there, the same instance with
+    the same buffers, pinned: the keys of a replica set are all pinned,
+    so their programs are lowered once between them (and compiled a
+    device)."""
+    if dpk.a_coeff.devices() != {device}:
+        return jax.device_put(dpk, device)
+    pin = lambda tree: jax.tree_util.tree_map(lambda x: jax.device_put(x, device), tree)  # noqa: E731
+    for f in _DPK_ARRAY_FIELDS:
+        setattr(dpk, f, pin(getattr(dpk, f)))
+    for cache in ("_h_table_cache", "_split_cache"):
+        if getattr(dpk, cache, None) is not None:
+            setattr(dpk, cache, pin(getattr(dpk, cache)))
+    return dpk
 
 
 def _rows_to_arrays(rows: Sequence[dict], m: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -586,24 +622,25 @@ def h_evals(dpk: DeviceProvingKey, w_mont: jnp.ndarray) -> jnp.ndarray:
     return FR.sub(FR.mul(a_cos, b_cos), c_cos)
 
 
-def _h_and_planes(dpk: DeviceProvingKey, w_mont: jnp.ndarray):
+def _h_and_planes(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h_window: int):
     h = h_evals(dpk, w_mont)
     with jax.named_scope("recode"):
-        return _recode(dpk, w_mont, h)
+        return _recode(dpk, w_mont, h, h_window)
 
 
-def _recode(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h: jnp.ndarray):
+def _recode(dpk: DeviceProvingKey, w_mont: jnp.ndarray, h: jnp.ndarray, h_window: int):
     """Witness and h scalars -> signed digit planes, most significant
     first: `((w_mags, w_negs), narrow), (h_mags, h_negs)`.  The witness
-    at MSM_WINDOW, h at the resident table's window where the key has
-    one.  `narrow` is the low NARROW_PLANES of the witness's w=4 recode:
+    at MSM_WINDOW, h at `h_window`: the window of the resident table
+    the key's device holds, MSM_WINDOW where it holds none (static: the
+    caller reads it off the table it passes to the h MSM).  `narrow` is the low NARROW_PLANES of the witness's w=4 recode:
     wires with width bounds <= 2^11 only populate those — the upper 61
     planes are provably zero and never reach an MSM; `()` for a key
     with no narrow class (zkey import): shapes are static under jit,
     so that prunes at trace time."""
     w_std = FR.from_mont(w_mont)
     w_mags, w_negs = signed_digit_planes_from_limbs(w_std, MSM_WINDOW)
-    h_mags, h_negs = signed_digit_planes_from_limbs(FR.from_mont(h), _h_table_window(dpk.log_m) or MSM_WINDOW)
+    h_mags, h_negs = signed_digit_planes_from_limbs(FR.from_mont(h), h_window)
     narrow = ()
     if int(dpk.a_nsel.shape[0]) > 0:
         # a recode of its own: the narrow MSMs run at w=4 whatever the
@@ -656,7 +693,7 @@ def _msm_h_resident(table, planes):
 # |b_sel|, c: |c_sel|), so jit re-specializes _msm_g1 per shape — the
 # ~50% runtime cut on b1/b2/c outweighs the extra first-proof compiles
 # (and the persistent cache amortises them across processes).
-_jit_h_planes = jax.jit(jax.vmap(_h_and_planes, in_axes=(None, 0)))
+_jit_h_planes = jax.jit(jax.vmap(_h_and_planes, in_axes=(None, 0, None)), static_argnums=2)
 _jit_msm_g1 = jax.jit(jax.vmap(_msm_g1, in_axes=(None, 0)))
 _jit_msm_g2 = jax.jit(jax.vmap(_msm_g2, in_axes=(None, 0)))
 _jit_msm_g1_narrow = jax.jit(jax.vmap(_msm_g1_narrow, in_axes=(None, 0)))
@@ -679,7 +716,7 @@ def _h_table(dpk: DeviceProvingKey) -> Optional[jnp.ndarray]:
     from ..utils.metrics import REGISTRY
     from ..utils.trace import trace
 
-    window, table = _h_table_window(dpk.log_m), None
+    window, table = _h_table_window(dpk.log_m, key_device(dpk)), None
     if window is not None:
         table = getattr(dpk, "_h_table_cache", None)
         if table is None:
@@ -788,7 +825,8 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
     measured ~2 min)."""
     unify = _on_tpu()
     h_table = _h_table(dpk)
-    (w_planes, w_narrow), h_planes = _jit_h_planes(dpk, w_mont)
+    h_window = MSM_WINDOW if h_table is None else int(h_table.shape[1]).bit_length()
+    (w_planes, w_narrow), h_planes = _jit_h_planes(dpk, w_mont, h_window)
     if watch is not None:
         # a few bytes that are ready when the stage is, cut from a plane by a
         # program of their own: the stage's program stays as it is, and no
@@ -801,7 +839,7 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
         over `n_to` bases where it shares the query MSMs' executable."""
         if h_table is not None:
             return _msm_enqueued(watch, "msm_h", _jit_msm_h_resident(h_table, h_planes),
-                             window=int(h_table.shape[1]).bit_length(), table="resident")
+                             window=h_window, table="resident")
         return _msm_enqueued(watch, "msm_h", _jit_msm_g1(*_pad_msm(dpk.h_bases, h_planes, n_to)),
                          window=MSM_WINDOW, table="scan")
 
@@ -974,7 +1012,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch
     )
 
 
-def _batch_chunk_size(log_m: Optional[int] = None) -> int:
+def _batch_chunk_size(log_m: Optional[int] = None, device=None) -> int:
     """Sub-batch size for prove_tpu_batch; 0 = whole batch in one vmap.
 
     "auto" chunks only on a real TPU, and there the chunk is a function
@@ -990,7 +1028,7 @@ def _batch_chunk_size(log_m: Optional[int] = None) -> int:
     overrides the rule."""
     auto = 0
     if _on_tpu():
-        auto = BATCH_CHUNK_MAX if log_m is None else batch_chunk_for(log_m, _hbm_bytes_limit())
+        auto = BATCH_CHUNK_MAX if log_m is None else batch_chunk_for(log_m, _hbm_bytes_limit(device))
     if BATCH_CHUNK == "auto":
         v = auto
     else:
@@ -1043,7 +1081,8 @@ def prove_tpu_batch(
             for wit in witnesses:
                 _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
             n = len(witnesses)
-            chunk = _batch_chunk_size(dpk.log_m)
+            key_dev = key_device(dpk)
+            chunk = _batch_chunk_size(dpk.log_m, key_dev)
             if chunk <= 0 or n <= chunk:
                 spans = [list(witnesses)]
             else:
@@ -1075,7 +1114,9 @@ def prove_tpu_batch(
                         watch.chunk = i
                         # one batched to_mont per chunk (not one device dispatch per
                         # witness); the h_planes stage includes it
-                        w = FR.to_mont(jnp.asarray(limbs))
+                        # beside a pinned key (the mesh road places its own)
+                        w = FR.to_mont(jnp.asarray(limbs) if key_dev is None or mesh is not None
+                                       else jax.device_put(limbs, key_dev))
                         parts.append(
                             _prove_batch_sharded(dpk, w, mesh, watch)
                             if mesh is not None

@@ -308,7 +308,16 @@ def _mis_arm_warnings(cfg, backend: str, arms: Dict[str, str], native_ok: bool) 
     return w
 
 
-def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
+def stamp_preflight(stamp: Dict) -> None:
+    """Publish `stamp` (a preflight report's `stamp`) as this process's
+    latest preflight: `preflight` does it itself unless told not to,
+    and a replica set (pipeline.replicas) does it once every replica's
+    loop is up, so whoever waits on `last_preflight` waits for all."""
+    global _preflight_report
+    _preflight_report = dict(stamp, ts=round(time.time(), 3))
+
+
+def preflight(workload: bool = True, log=None, cfg=None, stamp: bool = True) -> Dict:
     """Arm every gate, sample the backend, and return the preflight
     report (the `zkp2p-tpu doctor` payload; also hooked into bench.py
     and ProvingService.run so a mis-armed run warns before it proves
@@ -323,7 +332,9 @@ def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
     already run cfg.apply_env(): apply_env writes every knob back into
     the env, so a fresh load here would see every provenance as "env"
     and the explicit-request-only warning gates would fire on
-    defaults."""
+    defaults.
+    stamp: publish the report as `last_preflight`; False leaves that to
+    the caller (`stamp_preflight(report["stamp"])`)."""
     import jax
 
     from .config import load_config
@@ -470,13 +481,13 @@ def preflight(workload: bool = True, log=None, cfg=None) -> Dict:
     report["warnings"] = _mis_arm_warnings(cfg, backend, arms, native_ok)
     report["device_memory"] = sample_device_memory("preflight")
     report["execution_digest"] = execution_digest()
-    global _preflight_report
-    _preflight_report = {
-        "ts": report["ts"],
+    report["stamp"] = {
         "backend": backend,
         "warnings": len(report["warnings"]),
         "execution_digest": report["execution_digest"],
     }
+    if stamp:
+        stamp_preflight(report["stamp"])
     if log is not None:
         for msg in report["warnings"]:
             log(f"PREFLIGHT WARNING: {msg}")

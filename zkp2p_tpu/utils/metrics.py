@@ -53,6 +53,9 @@ METRIC_HELP: Dict[str, str] = {
     "zkp2p_service_emit_failures_total": "Proof-emit failures (transient ones defer the request)",
     "zkp2p_service_deferred_total": "Non-terminal sweep outcomes: claim released for a later sweep to retry",
     "zkp2p_service_takeovers_total": "Stale-claim steal attempts, by result (won|lost)",
+    "zkp2p_service_claim_lost_total": "Claim attempts that found a peer's fresh claim file",
+    "zkp2p_replica_batches_total": "Batches a replica of a set took into its prover, by replica",
+    "zkp2p_replicas_live": "Replicas of this process's set whose run loop is up",
     "zkp2p_service_batch_fill": "Live requests per batch handed to the prover (fill vs batch_size)",
     "zkp2p_service_backlog": "Open spool requests at the last time-series sample",
     "zkp2p_service_in_flight": "Open spool requests under a fresh claim at the last time-series sample",
