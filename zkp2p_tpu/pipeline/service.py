@@ -46,6 +46,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..formats.proof_json import dump
+from ..snark import witness_check
 from ..utils.audit import execution_digest, install_compile_listener, preflight, sample_device_memory
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import REGISTRY, JsonlSink, maybe_start_metrics_server, publish_native_stats, run_id, run_manifest
@@ -456,6 +457,9 @@ class ProvingService:
         batch prove and the exponential-backoff base (None = the
         ZKP2P_PROVE_RETRIES / ZKP2P_RETRY_BACKOFF_S defaults)."""
         self.cs = cs
+        # the self-check's plan, once a circuit (a second or two at 499k
+        # constraints), here and not under the first request's check
+        witness_check.plan_for(cs)
         self.dpk = dpk
         self.vk = vk
         self.witness_fn = witness_fn
@@ -1032,6 +1036,29 @@ class ProvingService:
             return None
         return req.t_submit + d
 
+    # ------------------------------------------------------ self-check
+
+    def _self_check(self, ws: list, records=None) -> None:
+        """Az∘Bz = Cz and every width tag, on EVERY witness of `ws`, before
+        its batch is queued for the prover: a witness that fails raises
+        `ConstraintSystem.check_witness`'s AssertionError, whichever path
+        found it (snark.witness_check: the native products where the
+        library is loaded and the witnesses carry their u64 rows, else
+        the Python loop).  `records`: the batch whose request records take
+        the span, where nothing else covers the check.  Without it (the
+        scalar tier, whose `witness` span is open around this one) the
+        span goes to the trace sink alone — two nested labels in the
+        records would claim the same idle seconds twice in a gap
+        attribution."""
+        path = witness_check.path_for(self.cs, ws)
+        attrs = {"n": len(ws), "path": path}
+        span = trace("service/witness_check", **attrs) if records is None else _span(records, "witness_check", **attrs)
+        checked = REGISTRY.counter("zkp2p_service_witness_check_total", {"path": path})
+        with span:
+            for w in ws:
+                checked.inc()
+                witness_check.check_witness(self.cs, w, path)
+
     # ------------------------------------------------------ terminal emit
 
     def _terminal_error(
@@ -1588,7 +1615,7 @@ class ProvingService:
                 with _span(req, "witness"):
                     fault_point("witness")
                     req.witness = self.witness_fn(req.payload)
-                    self.cs.check_witness(req.witness)
+                    self._self_check([req.witness])
                 return True
             except Exception as e:  # noqa: BLE001 — recorded, not silenced
                 if _is_transient(e):
@@ -1638,8 +1665,8 @@ class ProvingService:
                 # the scalar tier — only checking a sample would let an
                 # unsatisfying witness at index > 0 ship an invalid proof
                 # as done (the consumer pairing-verifies one sample too).
+                self._self_check(ws, records=batch)
                 for req, w in zip(batch, ws):
-                    self.cs.check_witness(w)
                     req.witness = w
                 return batch
             except Exception:  # noqa: BLE001 — batch tier is an optimization

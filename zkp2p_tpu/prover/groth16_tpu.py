@@ -69,6 +69,7 @@ from ..ops.ntt import LADDER as NTT_LADDER, coset_shift, intt, ntt
 
 from ..snark.groth16 import Proof, ProvingKey, coset_gen, domain_size_for, qap_rows
 from ..snark.r1cs import ConstraintSystem
+from ..snark.witness_check import unreduced_rows
 from ..utils.audit import record_arm as _record_arm
 from ..utils.config import load_config as _load_config
 from ..utils.jaxcfg import on_tpu as _on_tpu
@@ -542,26 +543,16 @@ def _is_u64_witness(witness) -> bool:
     )
 
 
-_R_U64 = np.frombuffer(R.to_bytes(32, "little"), dtype="<u8").copy()
-
-
 def _check_u64_reduced(rows: np.ndarray) -> None:
     """Reject (n, 4)-u64 witness rows >= R.  The fast path trusts its
     input to already be reduced (the .bench_cache contract) — an
     unreduced row would silently emit a wrong Montgomery form and an
-    unverifiable proof, so the boundary asserts it (8 vectorized
+    unverifiable proof, so the boundary asserts it (vectorized
     compares; negligible next to to_mont)."""
-    ge = np.zeros(rows.shape[0], dtype=bool)
-    eq = np.ones(rows.shape[0], dtype=bool)
-    for j in range(3, -1, -1):
-        col = rows[:, j]
-        ge |= eq & (col > _R_U64[j])
-        eq &= col == _R_U64[j]
-    ge |= eq  # exactly R is unreduced too
-    if ge.any():
-        i = int(np.flatnonzero(ge)[0])
+    bad = unreduced_rows(rows)
+    if bad.size:
         raise ValueError(
-            f"witness row {i} is not reduced below the Fr modulus: the "
+            f"witness row {int(bad[0])} is not reduced below the Fr modulus: the "
             f"(n, 4)-u64 fast path requires canonical scalars (< R); "
             f"reduce mod R before witness_to_device"
         )

@@ -34,9 +34,15 @@ class Witness(list):
     """A witness vector (Fr ints) that also carries ``u64``: the prover's
     standard-form (n, 4) little-endian u64 serialization, emitted at build
     time so the per-prove ``witness_convert`` stage collapses to an array
-    hand-off (gated by ``ZKP2P_WITNESS_U64``)."""
+    hand-off (gated by ``ZKP2P_WITNESS_U64``).  Assigning a wire drops the
+    rows: they are read in place of the values (the native prover's
+    hand-off, the service's self-check) only while they say the same."""
 
     u64 = None
+
+    def __setitem__(self, key, value):
+        self.u64 = None
+        super().__setitem__(key, value)
 
 
 _WITNESS_ROW_CLS = None
@@ -54,6 +60,10 @@ def _witness_row_cls():
             standard-form serialization (see :class:`Witness`)."""
 
             u64 = None
+
+            def __setitem__(self, key, value):
+                self.u64 = None
+                super().__setitem__(key, value)
 
             def __array_finalize__(self, obj):
                 u = getattr(obj, "u64", None)
@@ -233,6 +243,10 @@ class ConstraintSystem:
         # structure (the PR-13 discipline: every exception greppable,
         # justified where it lives).  An empty argument raises.
         self.audit_waivers: Dict[tuple, str] = {}
+        # snark.witness_check's plan of this system (A, B, C and the width
+        # tags as arrays), built on first use; a new constraint or a
+        # tighter tag drops it.
+        self._check_plan = None
 
     # ---------------------------------------------------------- allocation
 
@@ -261,6 +275,7 @@ class ConstraintSystem:
 
     def enforce(self, a: LCLike, b: LCLike, c: LCLike, tag: str = "") -> None:
         """<a,w> * <b,w> = <c,w>."""
+        self._check_plan = None
         self.constraints.append(
             Constraint(as_lc(a).terms, as_lc(b).terms, as_lc(c).terms, tag)
         )
@@ -287,6 +302,7 @@ class ConstraintSystem:
         pi stays on the curve but differs from the honest proof)."""
         cur = self.wire_width.get(w, 254)
         if bits < cur:
+            self._check_plan = None
             self.wire_width[w] = bits
 
     def require_width(self, w: int, bits: int, site: str) -> None:
