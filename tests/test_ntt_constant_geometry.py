@@ -120,7 +120,7 @@ def test_the_h_stage_is_the_host_quotient():
 
 def test_the_h_stage_under_the_batch_axis_is_the_host_quotient_of_each_witness():
     """The prover's own batching, `jax.vmap(h_evals, in_axes=(None, 0))`
-    (`_h_and_planes`' vmap, `_h_planes_pod_fn`), over the gather-free
+    (`_h_and_planes`' vmap, `_h_pod_fn`), over the gather-free
     ladder: every witness of a chunk of four gets its own quotient."""
     from zkp2p_tpu.prover import groth16_tpu as G
     from zkp2p_tpu.snark.groth16 import coset_quotient_evals
@@ -202,7 +202,7 @@ def test_the_h_planes_span_names_the_ladder_that_ran(monkeypatch):
 
 def test_the_mesh_road_s_h_planes_span_names_the_ladder_too(monkeypatch):
     """`_prove_batch_sharded` on the 1x4 virtual mesh: its h stage is
-    `h_evals` vmapped inside a shard_map (`_h_planes_pod_fn`), the same
+    `h_evals` vmapped inside a shard_map (`_h_pod_fn`), the same
     transforms, and its span says so (the pod MSMs stood in for)."""
     from zkp2p_tpu.curve.jcurve import G2J
     from zkp2p_tpu.parallel import mesh as pmesh
@@ -220,12 +220,12 @@ def test_the_mesh_road_s_h_planes_span_names_the_ladder_too(monkeypatch):
     monkeypatch.setattr(G, "BATCH_CHUNK", "0")
     monkeypatch.setattr(pmesh, "msm_pod_batched", infinity)
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
-    G._h_planes_pod_fn.cache_clear()  # traced here, under the spies
+    G._h_pod_fn.cache_clear()  # traced here, under the spies
     tr.reset()
     try:
         assert len(G.prove_tpu_batch(dpk, witnesses, rs=[1, 2, 3, 4], ss=[5, 6, 7, 8])) == 4
     finally:
-        G._h_planes_pod_fn.cache_clear()
+        G._h_pod_fn.cache_clear()
     (h_stage,) = [r for r in tr.records() if r["stage"].endswith("/stage/h_planes")]
     assert h_stage["ntt"] == jntt.LADDER and ran == [jntt.LADDER] * 6
     tr.reset()

@@ -105,6 +105,9 @@ def _sink(spool):
 
 @needs_native
 def test_four_replicas_serve_forty_requests_each_exactly_once(world, tmp_path):
+    from zkp2p_tpu.utils import trace
+
+    trace.reset()  # the ring is the process's: a span an earlier test's solo service left there is not this set's
     spool = str(tmp_path / "spool")
     os.makedirs(spool)
     rset = _set(world)

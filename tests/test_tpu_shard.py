@@ -284,18 +284,43 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
     tr.reset()
 
 
-def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
-    """`_prove_batch_sharded` recodes unsigned at MSM_WINDOW and runs
-    `msm_pod_batched`: it bypasses the key's resident h table by
-    construction.  The real road on the 1x4 virtual mesh, its two
-    compiled programs stood in for: no table is built, none is memoised
-    on the key, the gauge stays 0 and no `h_table` span is written."""
-    import dataclasses
-
+def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
+    """The mesh road's compiled programs stood in for (the h stage and
+    each pod MSM compile for minutes on XLA:CPU): zero h, infinity
+    accumulators, and `_assemble` handing the accumulators back.  The
+    key's placement, the upload, the exchange program and the read loop
+    that writes the spans are the real ones."""
     import numpy as np
 
     from zkp2p_tpu.curve.jcurve import G2J
     from zkp2p_tpu.parallel import mesh as pmesh
+    from zkp2p_tpu.prover import groth16_tpu as G
+
+    def fake_h_pod(mesh, log_m, split):
+        def run(rows, w_std):
+            time.sleep(sleep_s)
+            b = w_std.shape[0]
+            return np.zeros((b, 1 << log_m, 16), np.uint32), np.zeros((b,), np.uint32)
+        return run
+
+    def fake_msm_pod(curve, bases, planes, mesh, **kw):
+        time.sleep(sleep_s)
+        limbs = (2, 16) if curve is G2J else (16,)
+        return tuple(np.zeros((planes.shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
+
+    monkeypatch.setattr(G, "_h_pod_fn", fake_h_pod)
+    monkeypatch.setattr(pmesh, "msm_pod_batched", fake_msm_pod)
+    monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
+
+
+def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
+    """`_prove_batch_sharded` recodes unsigned at MSM_WINDOW and runs
+    `msm_pod_batched`: it bypasses the key's resident h table by
+    construction.  The real road on the 1x4 virtual mesh, its h program
+    and its pod MSMs stood in for: no table is built, none is memoised
+    on the key, the gauge stays 0 and no `h_table` span is written."""
+    import dataclasses
+
     from zkp2p_tpu.prover import groth16_tpu as G
     from zkp2p_tpu.utils import trace as tr
     from zkp2p_tpu.utils.audit import gate_arms
@@ -307,23 +332,8 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
     monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")
     monkeypatch.setattr(G, "BATCH_CHUNK", "0")
-    n_planes = 256 // G.MSM_WINDOW
-
-    def fake_h_planes_pod(mesh):
-        def run(dpk_, w_mont):
-            b = w_mont.shape[0]
-            return (np.zeros((b, n_planes, dpk_.n_wires), np.uint32),
-                    np.zeros((b, n_planes, 1 << dpk_.log_m), np.uint32), np.zeros((b,), np.uint32))
-        return run
-
-    def fake_msm_pod(curve, bases, planes, mesh, **kw):
-        limbs = (2, 16) if curve is G2J else (16,)
-        return tuple(np.zeros((planes.shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
-
-    monkeypatch.setattr(G, "_h_planes_pod_fn", fake_h_planes_pod)
-    monkeypatch.setattr(pmesh, "msm_pod_batched", fake_msm_pod)
+    _stand_in_for_the_mesh_programs(monkeypatch)
     monkeypatch.setattr(G, "_jit_h_table", lambda *a, **k: pytest.fail("the mesh road built a table"))
-    monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
     REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0)
     tr.reset()
     out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3, 4], ss=[5, 6, 7, 8])
@@ -334,6 +344,84 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
     assert "table" not in h_stage and "window" not in h_stage and h_stage["add"] == "complete_projective"
     assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == 0
     assert not hasattr(dpk, "_h_table_cache")
+    tr.reset()
+
+
+@pytest.mark.parametrize("mesh_spec,n_wits,chunk,proofs_a_chip,crossed", [
+    ("1x4", 4, "0", 1, True),    # a chunk of four on 1x4: one proof's h a chip
+    ("2x2", 4, "0", 1, True),    # two groups of two chips: two proofs a group, one a chip
+    ("4x1", 4, "0", 1, False),   # a chip a group: nothing to exchange
+    ("1x4", 3, "0", 3, False),   # the mesh does not divide the chunk: every chip computes all three
+    ("1x4", 1, "0", 1, False),   # a batch of one, the same rule
+    ("1x4", 6, "4", 1, True),    # two chunks, the second padded to four
+])
+def test_the_mesh_road_writes_seven_stages_that_partition_device_and_places_its_key_once(
+        toy_keys, monkeypatch, mesh_spec, n_wits, chunk, proofs_a_chip, crossed):
+    """prove_tpu_batch on the mesh road, its h program and pod MSMs
+    stood in for: `prep` + `device` + `finish` partition
+    `tpu/prove_batch`, and seven stages a chunk partition `device`, in
+    the order the road enqueues them, `exchange` between `h_planes` and
+    `msm_a`, each with `mesh`.  `h_planes` says how many proofs a chip
+    computed (the chunk over the whole mesh where the group's chips
+    divide its share, else every chip of a group its group's proofs),
+    `exchange` the bytes that crossed.  The key is placed by the first
+    batch, under a `tpu/place_key` span outside the batch's own, and a
+    second batch on the placed key grows `zkp2p_key_placed_bytes_total`
+    by 0."""
+    import dataclasses
+
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.utils import trace as tr
+    from zkp2p_tpu.utils.audit import gate_arms
+    from zkp2p_tpu.utils.metrics import REGISTRY
+
+    cs, _pk, _vk, dpk, x, y = toy_keys
+    dpk = dataclasses.replace(dpk)  # placed by this test's first batch
+    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11), (1, 1), (4, 9), (6, 6)][:n_wits])
+    monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
+    monkeypatch.setenv("ZKP2P_TPU_MESH", mesh_spec)
+    monkeypatch.setattr(G, "BATCH_CHUNK", chunk)
+    _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.01)
+    placed_total = REGISTRY.counter("zkp2p_key_placed_bytes_total")
+    before = placed_total.value
+    tr.reset()
+    pinned = list(range(1, n_wits + 1))
+    out = G.prove_tpu_batch(dpk, wits, rs=pinned, ss=pinned)
+    assert len(out) == n_wits and gate_arms()["tpu_shard"] == mesh_spec
+    recs = tr.records()
+    (placing,) = [r for r in recs if r["stage"].endswith("place_key")]
+    assert placing["stage"] == "tpu/place_key" and placing["parent"] is None and placing["mesh"] == mesh_spec
+    assert placing["bytes"] == placed_total.value - before > 0
+    by = {}
+    for r in recs:
+        by.setdefault(r["stage"], []).append(r)
+    (batch,), (prep,), (device,), (finish,) = (by["tpu/prove_batch" + s] for s in ("", "/prep", "/device", "/finish"))
+    assert placing["t0"] + placing["ms"] / 1e3 <= batch["t0"] + 1e-3  # before the batch's span, not in it
+    assert prep["ms"] + device["ms"] + finish["ms"] == pytest.approx(batch["ms"], rel=0.01, abs=1.0)
+    n_chunks = batch["n_chunks"]
+    stages = sorted((r for r in recs if "/stage/" in r["stage"]), key=lambda r: r["id"])
+    assert G.MESH_STAGES == ("h_planes", "exchange", "msm_a", "msm_b1", "msm_b2", "msm_c", "msm_h")
+    assert [r["stage"].rsplit("/", 1)[1] for r in stages] == list(G.MESH_STAGES) * n_chunks
+    assert [r["chunk"] for r in stages] == [c for c in range(n_chunks) for _ in G.MESH_STAGES]
+    assert all(r["mesh"] == mesh_spec and r["parent"] == device["id"] for r in stages)
+    assert [r["proofs_a_chip"] for r in stages if r["stage"].endswith("/h_planes")] == [proofs_a_chip] * n_chunks
+    assert all("proofs_a_chip" not in r for r in stages if not r["stage"].endswith("/h_planes"))
+    assert all((r["bytes"] > 0) == crossed for r in stages if r["stage"].endswith("/exchange"))
+    assert all("bytes" not in r for r in stages if not r["stage"].endswith("/exchange"))
+    assert stages[0]["t0"] == pytest.approx(device["t0"], abs=1e-3)
+    for a, b in zip(stages, stages[1:]):
+        assert b["t0"] == pytest.approx(a["t0"] + a["ms"] / 1e3, abs=1e-5)  # abutting: they partition `device`
+    if n_chunks == 1:
+        assert sum(r["ms"] for r in stages) == pytest.approx(device["ms"], rel=0.01, abs=20.0)
+    # the placed key is the one the next batch reads: nothing placed, no span, no bytes
+    at = placed_total.value
+    tr.reset()
+    assert len(G.prove_tpu_batch(dpk, wits, rs=pinned, ss=pinned)) == n_wits
+    assert placed_total.value == at and not [r for r in tr.records() if r["stage"].endswith("place_key")]
+    # and a key handed in already placed is read as it is
+    placed = G._key_on_mesh(dpk, G._shard_mesh())
+    assert G.key_mesh(placed) is not None and G.key_device(placed) is None and G.key_mesh(dpk) is None
+    assert len(G.prove_tpu_batch(placed, wits, rs=pinned, ss=pinned)) == n_wits and placed_total.value == at
     tr.reset()
 
 
@@ -402,13 +490,13 @@ needs_warm_cache = pytest.mark.skipif(
 
 
 @needs_warm_cache
-def test_sharded_batch_matches_host_oracle(toy_keys, monkeypatch):
-    """THE acceptance: ZKP2P_TPU_SHARD=on on the 2x4 virtual pod mesh,
-    batch of 4 -> every proof byte-identical to prove_host under the
-    same (witness, r, s), and pairing-verified.  Covers the batch case
-    AND the single case (a 1-witness call pads to the mesh batch width
-    is NOT done — B=2 groups need 2+ witnesses, so single rides a
-    (1x4) mesh)."""
+@pytest.mark.parametrize("mesh_spec", ["2x4", "1x4"])
+def test_sharded_batch_matches_host_oracle(toy_keys, monkeypatch, mesh_spec):
+    """THE acceptance: ZKP2P_TPU_SHARD=on on a virtual pod mesh, batch
+    of 4 -> every proof byte-identical to prove_host under the same
+    (witness, r, s), and pairing-verified.  On 2x4 a group's two proofs
+    are computed on each of its four chips; on 1x4 (the benchmark's
+    shape) the chunk is split one proof a chip and exchanged.  A single witness rides a (1x4) mesh, below."""
     from zkp2p_tpu.prover import groth16_tpu as G
     from zkp2p_tpu.snark.groth16 import prove_host, verify
     from zkp2p_tpu.utils.audit import gate_arms
@@ -418,11 +506,11 @@ def test_sharded_batch_matches_host_oracle(toy_keys, monkeypatch):
     wits, pubs = _toy_wits(cs, x, y, cases)
 
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
-    monkeypatch.setenv("ZKP2P_TPU_MESH", "2x4")
+    monkeypatch.setenv("ZKP2P_TPU_MESH", mesh_spec)
     rs = [1000 + 2 * i for i in range(len(wits))]
     ss = [1001 + 2 * i for i in range(len(wits))]
     proofs = G.prove_tpu_batch(dpk, wits, rs=rs, ss=ss)
-    assert gate_arms()["tpu_shard"] == "2x4"
+    assert gate_arms()["tpu_shard"] == mesh_spec
     for i, (proof, pub) in enumerate(zip(proofs, pubs)):
         assert proof == prove_host(pk, cs, wits[i], r=rs[i], s=ss[i]), f"proof {i} != oracle"
         assert verify(vk, proof, pub)
@@ -467,7 +555,7 @@ def test_per_device_bucket_partials_match_unsharded():
     from zkp2p_tpu.curve.jcurve import G1J, g1_jac_to_host, g1_to_affine_arrays
     from zkp2p_tpu.field.jfield import int_to_limbs
     from zkp2p_tpu.ops import msm as jmsm
-    from zkp2p_tpu.parallel.mesh import make_pod_mesh, msm_pod_batched, pad_to_multiple
+    from zkp2p_tpu.parallel.mesh import make_pod_mesh, msm_pod_batched
 
     n_ici, lanes, window = 4, 2, 4
     rng = np.random.default_rng(11)
@@ -498,7 +586,7 @@ def test_per_device_bucket_partials_match_unsharded():
             for row in batch_scalars
         ]
     )
-    bases, _ = pad_to_multiple(g1_to_affine_arrays(pts), planes[0], n_ici * lanes)
+    bases = g1_to_affine_arrays(pts)  # n is a multiple of n_ici * lanes: nothing to pad
     acc = msm_pod_batched(G1J, bases, planes, mesh, lanes=lanes, window=window)
     got = g1_jac_to_host(acc)
     for i, row in enumerate(batch_scalars):
@@ -530,7 +618,7 @@ def test_msm_pod_batched_dcn_axis():
     from zkp2p_tpu.curve.jcurve import G1J, g1_jac_to_host, g1_to_affine_arrays
     from zkp2p_tpu.field.jfield import int_to_limbs
     from zkp2p_tpu.ops import msm as jmsm
-    from zkp2p_tpu.parallel.mesh import make_pod_mesh, msm_pod_batched, pad_to_multiple
+    from zkp2p_tpu.parallel.mesh import make_pod_mesh, msm_pod_batched
 
     n = 11  # deliberately not a multiple of any mesh size (exercises padding)
     mesh = make_pod_mesh(2, 4)  # 2 slices x 4-wide ICI on the 8 vdevs
@@ -546,9 +634,9 @@ def test_msm_pod_batched_dcn_axis():
             for sc in batch_scalars
         ]
     )
-    bases, planes = pad_to_multiple(g1_to_affine_arrays(pts), planes[0], 8)[0], planes
-    # pad the plane N axis to the padded base count
-    pad = bases[0].shape[0] - n
+    # infinity bases and zero digit columns up to a multiple of the mesh width, as `place_key` pads a key
+    pad = (-n) % 8
+    bases = tuple(jax.numpy.pad(c, [(0, pad), (0, 0)]) for c in g1_to_affine_arrays(pts))
     planes = jax.numpy.pad(planes, [(0, 0), (0, 0), (0, pad)])
     acc = msm_pod_batched(G1J, bases, planes, mesh, lanes=8, window=4)
     got = g1_jac_to_host(acc)
@@ -591,8 +679,9 @@ dpk = G.DeviceProvingKey(
     a_nsel=sel, a_wsel=sel, b_nsel=sel, b_wsel=sel, c_nsel=sel, c_wsel=sel,
     alpha_1=None, beta_1=None, beta_2=None, delta_1=None, delta_2=None)
 mesh = make_pod_mesh(1, 4, names=("batch", "shard"))
-w = S((4, nw, 16), u32, sharding=NamedSharding(mesh, P("batch")))
-text = G._h_planes_pod_fn(mesh).trace(dpk, w).lower(lowering_platforms=("tpu",)).as_text()
+w = S((4, nw, 16), u32, sharding=NamedSharding(mesh, P(("batch", "shard"))))  # one proof a chip
+rows = tuple(getattr(dpk, f) for f in G._QAP_ROWS)
+text = G._h_pod_fn(mesh, log_m, True).trace(rows, w).lower(lowering_platforms=("tpu",)).as_text()
 print("KERNELS", text.count("tpu_custom_call"))
 try:
     jax.jit(jax.vmap(G.h_evals, in_axes=(None, 0))).trace(dpk, w).lower(lowering_platforms=("tpu",))
@@ -634,9 +723,11 @@ comp = []
 jax.monitoring.register_event_duration_secs_listener(
     lambda name, dur, **kw: comp.append(dur) if name.endswith("backend_compile_duration") else None)
 def ladder(x):
-    # forty sorts: a cold compile of ~1 s, so that a warm read of 10-30 ms on a loaded runner stays 10x below it
-    for i in range(40):
-        x = jnp.sort(jnp.tanh(x @ x.T) + jnp.sin(x + i) * jnp.cos(x), axis=i % 2)
+    # four hundred elementwise steps that fuse into one loop: its body costs LLVM ~2 s cold on a busy or an idle
+    # runner, and the executable is small to read back (50 ms), so the warm read stays well over 10x below it
+    # (forty sorts read 0.34-1.6 s cold by how warm the machine was, and 9.1x at the end of a whole run)
+    for i in range(400):
+        x = jnp.sin(x + i % 10) * jnp.cos(x * 0.5 + i // 10) + jnp.tanh(x)
     return x.sum()
 jax.jit(ladder)(jnp.ones((256, 256))).block_until_ready()
 print("COMPILE_S", sum(comp), len(comp))

@@ -20,7 +20,7 @@ the driver's `dryrun_multichip` exercises it on virtual CPU devices.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -108,23 +108,12 @@ def msm_pod_batched(
     only DCN traffic being one proof point per batch element.
 
     planes_batch: (B, n_planes, N) digit planes, B divisible by the dcn
-    width, N by the ici width.  Returns (B,)-batched projective points,
-    replicated everywhere."""
+    width, N by the ici width (a key placed on the mesh is padded to it
+    once, `prover.groth16_tpu.place_key`; the prover hands both over
+    already laid out as the program's `in_specs` want them, so nothing
+    is resharded).  Returns (B,)-batched projective points, replicated
+    everywhere."""
     B = planes_batch.shape[0]
     assert B % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"
     assert bases[0].shape[0] % mesh.shape[ici_axis] == 0, "pad the base axis first"
     return _msm_pod_fn(curve, len(bases), mesh, dcn_axis, ici_axis, lanes, window)(bases, planes_batch)
-
-
-def pad_to_multiple(bases: AffPoint, planes: jnp.ndarray, multiple: int) -> Tuple[AffPoint, jnp.ndarray]:
-    """Pad the MSM base axis (and the matching LAST plane axis) up to a
-    multiple of the mesh width: (0, 0) infinity bases and zero digit
-    columns contribute nothing.  Planes may be (n_planes, N) single-proof
-    or (B, n_planes, N) batched (msm_pod_batched): the pad is
-    rank-generic on the last axis either way."""
-    n = bases[0].shape[0]
-    pad = (-n) % multiple
-    if pad:
-        bases = tuple(jnp.pad(c, [(0, pad)] + [(0, 0)] * (c.ndim - 1)) for c in bases)
-        planes = jnp.pad(planes, [(0, 0)] * (planes.ndim - 1) + [(0, pad)])
-    return bases, planes

@@ -17,7 +17,10 @@ Dataflow (six stage programs a chunk of witnesses, SURVEY.md §7 step 6):
 
 Two roads, both behind `prove_tpu_batch` (`prove_tpu` is a batch of
 one): `_prove_device` on one chip, and `_prove_batch_sharded` on a
-("batch", "shard") pod mesh where ZKP2P_TPU_SHARD=on.
+("batch", "shard") pod mesh where ZKP2P_TPU_SHARD=on: the key placed on
+the mesh once (`place_key`), the h stage a chip its own proofs, an
+exchange, then the MSMs over each chip's share of the bases (seven
+stage programs a chunk).
 
 Determinism contract: given the same (witness, r, s) this emits the exact
 proof `snark.groth16.prove_host` does — the two provers are diffed
@@ -211,7 +214,7 @@ def _shard_mesh():
 
         mesh = make_pod_mesh(b, s, names=("batch", "shard"))
         _POD_MESH_CACHE[(b, s)] = mesh
-    _record_arm("tpu_shard", f"{b}x{s}")
+    _record_arm("tpu_shard", mesh_name(mesh))
     return mesh
 
 
@@ -301,20 +304,82 @@ def key_device(dpk: "DeviceProvingKey"):
     proves on the process's default device, through the programs a
     one-chip service has always run (an argument pinned to a device is
     lowered with a sharding attribute, so pinned and unpinned keys do
-    not share programs: PERF.md, PR 30)."""
+    not share programs: PERF.md, PR 30).  None too for a key placed on
+    a mesh (`key_mesh`)."""
     a = dpk.a_coeff
-    return next(iter(a.devices())) if getattr(a, "committed", False) else None
+    return next(iter(a.devices())) if getattr(a, "committed", False) and key_mesh(dpk) is None else None
 
 
-def place_key(dpk: "DeviceProvingKey", device) -> "DeviceProvingKey":
-    """The key pinned to `device`.  Where it lives elsewhere, a copy,
+def key_mesh(dpk: "DeviceProvingKey"):
+    """The pod mesh a key is placed on (`place_key(dpk, mesh)`): only
+    `_prove_batch_sharded` on that mesh reads such a key.  None for
+    every other key."""
+    from jax.sharding import NamedSharding
+
+    sharding = getattr(dpk.a_coeff, "sharding", None)
+    return sharding.mesh if isinstance(sharding, NamedSharding) else None
+
+
+def mesh_name(mesh) -> str:
+    """A ("batch", "shard") pod mesh by its shape, "1x4": the `mesh`
+    attribute of the mesh road's spans, and the `tpu_shard` gate's arm."""
+    return f"{mesh.shape['batch']}x{mesh.shape['shard']}"
+
+
+def pod_lanes(n: int, n_ici: int) -> int:
+    """The step width of a pod MSM over `n` bases, padded or not, in
+    `n_ici` shards: 64, or a shard's whole share where that is less
+    (tiny CI circuits stay at lanes ~ n/S instead of padding 16x to a
+    64-lane step).  `place_key` pads the bases to a multiple of
+    `n_ici * pod_lanes`, so every device sees whole steps."""
+    return max(1, min(64, -(-n // n_ici)))
+
+
+_POD_BASES = ("a_bases", "b1_bases", "b2_bases", "c_bases", "h_bases")
+_QAP_ROWS = ("a_coeff", "a_wire", "a_row", "b_coeff", "b_wire", "b_row")
+
+
+def place_key(dpk: "DeviceProvingKey", where) -> "DeviceProvingKey":
+    """The key placed on `where`, a device or a ("batch", "shard") pod
+    mesh; one `tpu/place_key` span a call, and its bytes counted by
+    `zkp2p_key_placed_bytes_total`.
+
+    A device: the key pinned to it.  Where it lives elsewhere, a copy,
     device to device (a key is read from disk once whatever the number
     of replicas): a new instance, whose resident h table and class
     splits (`_h_table_cache`, `_split_cache`) its first batch builds on
     that device.  Where it already lives there, the same instance with
     the same buffers, pinned: the keys of a replica set are all pinned,
     so their programs are lowered once between them (and compiled a
-    device)."""
+    device).
+
+    A mesh: a new instance that only the mesh road reads
+    (`_prove_batch_sharded`; `key_mesh` tells).  Its five base arrays
+    are padded with infinity bases to whole steps of every shard
+    (`pod_lanes`) and committed `P("shard")`, a quarter a chip on 1x4;
+    `b_sel` / `c_sel` are padded to their bases' length (the filler
+    names wire 0, against an infinity base) and committed the same way,
+    so a chip holds the wire of each base it holds; the QAP rows, which
+    the h program reads whole, are committed to every chip.  The class
+    splits are empty: the mesh road has no narrow class.  After this no
+    batch moves a byte of key."""
+    from jax.sharding import Mesh
+
+    from ..utils.metrics import REGISTRY
+    from ..utils.trace import trace
+
+    on_mesh = isinstance(where, Mesh)
+    with trace("tpu/place_key", **({"mesh": mesh_name(where)} if on_mesh else {"device": str(where)})) as span:
+        placed = _place_on_mesh(dpk, where) if on_mesh else _pin_to_device(dpk, where)
+        span["bytes"] = sum(
+            shard.data.nbytes
+            for f in _DPK_ARRAY_FIELDS for x in jax.tree_util.tree_leaves(getattr(placed, f))
+            for shard in x.addressable_shards)
+    REGISTRY.counter("zkp2p_key_placed_bytes_total").inc(span["bytes"])
+    return placed
+
+
+def _pin_to_device(dpk: "DeviceProvingKey", device) -> "DeviceProvingKey":
     if dpk.a_coeff.devices() != {device}:
         return jax.device_put(dpk, device)
     pin = lambda tree: jax.tree_util.tree_map(lambda x: jax.device_put(x, device), tree)  # noqa: E731
@@ -324,6 +389,44 @@ def place_key(dpk: "DeviceProvingKey", device) -> "DeviceProvingKey":
         if getattr(dpk, cache, None) is not None:
             setattr(dpk, cache, pin(getattr(dpk, cache)))
     return dpk
+
+
+def _place_on_mesh(dpk: "DeviceProvingKey", mesh) -> "DeviceProvingKey":
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    n_ici = mesh.shape["shard"]
+    sharded, whole = NamedSharding(mesh, P("shard")), NamedSharding(mesh, P())
+
+    def in_shards(x, n_to):
+        return jax.device_put(jnp.pad(x, [(0, n_to - x.shape[0])] + [(0, 0)] * (x.ndim - 1)), sharded)
+
+    fields = {f: jax.device_put(getattr(dpk, f), whole) for f in _QAP_ROWS}
+    for f in _POD_BASES:
+        n = getattr(dpk, f)[0].shape[0]
+        n_to = n + (-n) % (n_ici * pod_lanes(n, n_ici))
+        fields[f] = tuple(in_shards(c, n_to) for c in getattr(dpk, f))
+    fields["b_sel"] = in_shards(dpk.b_sel, fields["b1_bases"][0].shape[0])
+    fields["c_sel"] = in_shards(dpk.c_sel, fields["c_bases"][0].shape[0])
+    none = jax.device_put(jnp.zeros((0,), jnp.int32), whole)
+    for f in ("a_nsel", "a_wsel", "b_nsel", "b_wsel", "c_nsel", "c_wsel"):
+        fields[f] = none
+    return dataclasses.replace(dpk, **fields)
+
+
+def _key_on_mesh(dpk: "DeviceProvingKey", mesh) -> "DeviceProvingKey":
+    """`dpk` as the mesh road reads it: itself where it was placed on
+    `mesh`, else its placed form, made by the first batch that needs it
+    and memoised on the instance like the h table (the benchmark hands
+    the service the key `load_dpk` gave it)."""
+    if key_mesh(dpk) == mesh:
+        return dpk
+    memo = getattr(dpk, "_mesh_key_cache", None)
+    if memo is None or memo[0] != mesh:
+        memo = (mesh, place_key(dpk, mesh))
+        setattr(dpk, "_mesh_key_cache", memo)
+    return memo[1]
 
 
 def _rows_to_arrays(rows: Sequence[dict], m: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -735,6 +838,7 @@ def _pad_msm(bases, planes, n_to: int):
 
 
 STAGES = ("h_planes", "msm_a", "msm_b1", "msm_b2", "msm_c", "msm_h")
+MESH_STAGES = ("h_planes", "exchange") + STAGES[1:]  # the mesh road's seven
 
 
 class _StageWatch:
@@ -934,71 +1038,159 @@ def prove_tpu(
     return prove_tpu_batch(dpk, [witness], None if r is None else [r], None if s is None else [s])[0]
 
 
-# Batched sharded-arm h stage: h_evals vmapped over this batch group's
-# share of the witness chunk, and the UNSIGNED digit-plane recode per
-# witness ((B, n_planes, n) — the layout msm_pod_batched's shard_map
-# consumes), as ONE shard_map over the pod mesh.  It must be a
-# shard_map, not a jit over mesh-sharded inputs: on a real mesh JAX
-# refuses to partition a Mosaic kernel automatically ("wrap the call in
-# a shard_map" — found on the four-chip host, PERF.md PR 21), and every
-# field product here is one.  The sharded MSMs use the unsigned
-# formulation: group arithmetic is exact, so the proof bytes match the
-# signed vmap arm regardless.
-@lru_cache(maxsize=None)
-def _h_planes_pod_fn(mesh):
+# The mesh road's two programs before its MSMs, each ONE shard_map over
+# the pod mesh.  They must be shard_maps, not jits over mesh-sharded
+# inputs: on a real mesh JAX refuses to partition a Mosaic kernel
+# automatically ("wrap the call in a shard_map" — found on the four-chip
+# host, PERF.md PR 21), and every field product of the h stage is one.
+# Two layouts, and the exchange between them.  The h stage wants whole
+# witnesses, a chip its own: the proofs of a batch group (B over the
+# mesh's "batch" axis) are `split` over the group's S chips where S
+# divides them — one proof a chip for a chunk of four on 1x4 — and
+# otherwise every chip of the group computes them all (a batch of one,
+# a chunk of two on 1x4: what the road did for every chunk before PR
+# 32).  The MSMs want, on each chip, the scalars of the bases it holds,
+# for every proof of the group: the exchange delivers them and recodes
+# them.  One rule for every chunk and mesh; it follows from their
+# sizes.  The sharded MSMs use the unsigned formulation: group
+# arithmetic is exact, so the proof bytes match the one-chip road's.
+def _pod_split(mesh, n_proofs: int) -> bool:
+    """Whether a chunk of `n_proofs` is split over each group's chips."""
+    return (n_proofs // mesh.shape["batch"]) % mesh.shape["shard"] == 0
+
+
+def _pod_chunk_spec(mesh, split: bool):
     from jax.sharding import PartitionSpec as P
 
-    def local(dpk, w_mont):  # w_mont: (B_local, n_wires, 16)
-        planes = jax.vmap(lambda x: digit_planes_from_limbs(FR.from_mont(x), MSM_WINDOW))
-        h = jax.vmap(h_evals, in_axes=(None, 0))(dpk, w_mont)
-        with jax.named_scope("recode"):
-            # the last is `done`: one limb of h a witness, ready when the stage is (_StageWatch waits on it)
-            return planes(w_mont), planes(h), h[:, 0, 0]
+    return P(("batch", "shard")) if split else P("batch")
 
+
+@lru_cache(maxsize=None)
+def _h_pod_fn(mesh, log_m: int, split: bool):
+    """w (B, n_wires, 16) standard-form limbs -> h (B, m, 16) standard-
+    form limbs, each chip its share of the chunk."""
+    from types import SimpleNamespace
+
+    from jax.sharding import PartitionSpec as P
+
+    def local(rows, w_std):
+        key = SimpleNamespace(log_m=log_m, **dict(zip(_QAP_ROWS, rows)))
+        h = FR.from_mont(jax.vmap(h_evals, in_axes=(None, 0))(key, FR.to_mont(w_std)))
+        # the last is `done`: one limb of h a witness, ready when the stage is (_StageWatch waits on it)
+        return h, h[:, 0, 0]
+
+    chunk = _pod_chunk_spec(mesh, split)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), chunk), out_specs=(chunk, chunk), check_vma=False))
+
+
+@lru_cache(maxsize=None)
+def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
+    """From the h stage's layout to the MSMs': over ICI the group's
+    witnesses are all-gathered (b_sel / c_sel name any wire) and each
+    chip's h goes out by `all_to_all`, so that a chip holds, for every
+    proof of its group, the domain columns of its h bases; then each
+    chip cuts the wires of its a, b and c bases out of the witnesses
+    and recodes its columns, and only those, to unsigned digit planes
+    (B, 256 / MSM_WINDOW, n) — the layout `msm_pod_batched` consumes,
+    already where the bases are.  `n_a` and `n_h` are the placed key's
+    padded a and h base counts.  Without `split` nothing crosses: every
+    chip of a group holds the group's witnesses and h whole."""
+    from jax.sharding import PartitionSpec as P
+
+    n_ici = mesh.shape["shard"]
+
+    def planes(cols):  # (B, n, 16) -> (B, n_planes, n)
+        return jnp.moveaxis(digit_planes_from_limbs(cols, MSM_WINDOW), 0, 1)
+
+    def padded(x, n_to):
+        return jnp.pad(x, [(0, 0), (0, n_to - x.shape[1]), (0, 0)])
+
+    def mine(x):  # this chip's columns of a (B, n, 16) array whole on it
+        n = x.shape[1] // n_ici
+        return jax.lax.dynamic_slice_in_dim(x, jax.lax.axis_index("shard") * n, n, axis=1)
+
+    def local(sels, w_std, h_std):
+        h_std = padded(h_std, n_h)
+        if split:
+            w_std = jax.lax.all_gather(w_std, "shard", axis=0, tiled=True)
+            # each chip's S-th of the columns to the chip that holds their bases, the split
+            # axis leading (split along the columns in place, the same all_to_all compiles
+            # for two minutes at 2^19 and plans half a gigabyte: PERF.md, PR 32)
+            b_loc, n_loc = h_std.shape[0], n_h // n_ici
+            h_mine = jax.lax.all_to_all(
+                h_std.reshape(b_loc, n_ici, n_loc, 16).swapaxes(0, 1), "shard", split_axis=0, concat_axis=0, tiled=True,
+            ).reshape(n_ici * b_loc, n_loc, 16)
+        else:
+            h_mine = mine(h_std)
+        b_sel, c_sel = sels
+        with jax.named_scope("recode"):
+            out = (planes(mine(padded(w_std, n_a))), planes(jnp.take(w_std, b_sel, axis=1)),
+                   planes(jnp.take(w_std, c_sel, axis=1)), planes(h_mine))
+        return out + (out[3][:1, 0, 0],)  # `done`, a digit a chip: ready when the stage is
+
+    chunk, cols = _pod_chunk_spec(mesh, split), P("batch", None, "shard")
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P(), P("batch")),
-        out_specs=(P("batch"), P("batch"), P("batch")), check_vma=False,
+        local, mesh=mesh, in_specs=(P("shard"), chunk, chunk),
+        out_specs=(cols, cols, cols, cols, P(("batch", "shard"))), check_vma=False,
     ))
 
 
-def _prove_batch_sharded(dpk: DeviceProvingKey, w_mont: jnp.ndarray, mesh, watch: Optional[_StageWatch] = None):
-    """One prove_tpu_batch chunk on a ("batch", "shard") pod mesh: the
-    (B, n_wires, 16) witness chunk is placed batch-sharded
-    (`NamedSharding(mesh, P("batch"))` — each batch group proves its
-    share of the chunk), and every MSM runs base-axis-sharded over the
-    inner "shard" axis with per-device bucket partial sums combined by
-    ONE group-op allreduce (all_gather + projective fold — ICI on real
-    hardware, host rings on the virtual CPU mesh; parallel.mesh.
-    msm_pod_batched).  Returns the same five (B,)-batched accumulators
-    `_prove_device` emits, so chunks from either arm concatenate
-    identically downstream."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..parallel.mesh import msm_pod_batched, pad_to_multiple
-
+def exchange_bytes(mesh, n_proofs: int, n_wires: int, n_h: int) -> int:
+    """What the exchange of a chunk of `n_proofs` moves between chips,
+    summed over the chips that receive it: each of a group's S chips
+    takes the other S-1 chips' witnesses whole and an S-th of their h
+    (`n_h` columns, padded), 64 B a scalar; 0 where the chunk is not
+    split."""
     n_ici = mesh.shape["shard"]
-    w_mont = jax.device_put(w_mont, NamedSharding(mesh, P("batch")))
-    w_planes, h_planes, done = _h_planes_pod_fn(mesh)(dpk, w_mont)
-    _enqueued(watch, "h_planes", done, ntt=NTT_LADDER)
+    if not _pod_split(mesh, n_proofs):
+        return 0
+    return n_proofs * (n_ici - 1) * (n_wires + n_h // n_ici) * 64
+
+
+def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: Optional[_StageWatch] = None):
+    """One prove_tpu_batch chunk on a ("batch", "shard") pod mesh, over
+    a key placed on it (`place_key`): nothing of the key moves.  The
+    chunk's witnesses, `limbs` (B, n_wires, 16) standard-form on the
+    host, are uploaded once each to the chip that computes their h
+    (`_h_pod_fn`: the group's proofs spread over its chips where they
+    divide, `proofs_a_chip` on the stage's span); the exchange
+    (`_exchange_pod_fn`, a stage of its own, `bytes` over ICI) leaves
+    on every chip the digit planes of the columns whose bases it holds;
+    and every MSM runs base-axis-sharded over the inner "shard" axis
+    with per-device bucket partial sums combined by ONE group-op
+    allreduce (all_gather + projective fold — ICI on real hardware,
+    host rings on the virtual CPU mesh; parallel.mesh.msm_pod_batched).
+    Seven stages, each span with `mesh`.  Returns the same five
+    (B,)-batched accumulators `_prove_device` emits, so chunks from
+    either arm concatenate identically downstream."""
+    from jax.sharding import NamedSharding
+
+    from ..parallel.mesh import msm_pod_batched
+
+    n_ici, on = mesh.shape["shard"], mesh_name(mesh)
+    n_proofs, n_wires = limbs.shape[0], limbs.shape[1]
+    split = _pod_split(mesh, n_proofs)
+    w_std = jax.device_put(limbs, NamedSharding(mesh, _pod_chunk_spec(mesh, split)))
+    h_std, done = _h_pod_fn(mesh, dpk.log_m, split)(tuple(getattr(dpk, f) for f in _QAP_ROWS), w_std)
+    _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on,
+              proofs_a_chip=n_proofs // (mesh.size if split else mesh.shape["batch"]))
+    n_a, n_h = dpk.a_bases[0].shape[0], dpk.h_bases[0].shape[0]
+    a_planes, b_planes, c_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_a, n_h)(
+        (dpk.b_sel, dpk.c_sel), w_std, h_std)
+    _enqueued(watch, "exchange", done, mesh=on, bytes=exchange_bytes(mesh, n_proofs, n_wires, n_h))
+    del w_std, h_std  # the MSMs read the planes alone
 
     def msm(name, curve, bases, planes):
-        # lanes sized to the per-device slice (tiny CI circuits stay at
-        # lanes ~ n/S instead of padding 16x to a 64-lane step); bases
-        # pad to a multiple of S * lanes so every device sees whole steps.
-        n = bases[0].shape[0]
-        lanes = max(1, min(64, -(-n // n_ici)))
-        b, p = pad_to_multiple(bases, planes, n_ici * lanes)
         return _msm_enqueued(watch, name, msm_pod_batched(
-            curve, b, p, mesh,
-            dcn_axis="batch", ici_axis="shard", lanes=lanes, window=MSM_WINDOW,
-        ))
+            curve, bases, planes, mesh, dcn_axis="batch", ici_axis="shard",
+            lanes=pod_lanes(bases[0].shape[0], n_ici), window=MSM_WINDOW,
+        ), mesh=on)
 
-    b_planes = jnp.take(w_planes, dpk.b_sel, axis=-1)
     return (
-        msm("msm_a", G1J, dpk.a_bases, w_planes),
+        msm("msm_a", G1J, dpk.a_bases, a_planes),
         msm("msm_b1", G1J, dpk.b1_bases, b_planes),
         msm("msm_b2", G2J, dpk.b2_bases, b_planes),
-        msm("msm_c", G1J, dpk.c_bases, jnp.take(w_planes, dpk.c_sel, axis=-1)),
+        msm("msm_c", G1J, dpk.c_bases, c_planes),
         msm("msm_h", G1J, dpk.h_bases, h_planes),
     )
 
@@ -1052,9 +1244,11 @@ def prove_tpu_batch(
     same compiled executable.
 
     With ZKP2P_TPU_SHARD=on (and a satisfiable ZKP2P_TPU_MESH) each
-    chunk runs the pod-mesh program instead (_prove_batch_sharded):
-    batch data-parallel over the mesh's "batch" axis, MSM bucket partial
-    sums allreduced over "shard".  The arm is decided ONCE per call —
+    chunk runs the pod-mesh programs instead (_prove_batch_sharded),
+    over the key as `place_key` lays it on the mesh (done by the first
+    such call of a key, unless the key came placed): batch data-parallel
+    over the mesh's "batch" axis, MSM bucket partial sums allreduced
+    over "shard".  The arm is decided ONCE per call —
     a chunk size indivisible by the mesh's batch width records the
     `tpu_shard` arm as "fallback" and the whole call takes the vmap
     path, so every chunk of a call shares one executable either way."""
@@ -1062,6 +1256,23 @@ def prove_tpu_batch(
     from ..utils.metrics import REGISTRY
     from ..utils.trace import trace
 
+    # The road, decided once a call; the mesh road reads the key as
+    # `place_key` lays it on the mesh, which the first batch of a key
+    # does here, before the batch's own span (`tpu/place_key`).
+    n = len(witnesses)
+    key_dev = key_device(dpk)
+    chunk = _batch_chunk_size(dpk.log_m, key_dev)
+    if chunk <= 0 or n <= chunk:
+        spans = [list(witnesses)]
+    else:
+        spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
+        spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
+    mesh = _shard_mesh()
+    if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
+        _record_arm("tpu_shard", "fallback")
+        mesh = None
+    if mesh is not None:
+        dpk = _key_on_mesh(dpk, mesh)
     # Spans (utils.trace): `prep`, `device` and `finish` partition
     # `tpu/prove_batch`.  `device` runs from the batch's first enqueue to
     # the instant its last stage's result is ready; `dispatch` and one
@@ -1071,21 +1282,9 @@ def prove_tpu_batch(
             sample_device_memory("tpu/prove_batch")  # entry watermark
             for wit in witnesses:
                 _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
-            n = len(witnesses)
-            key_dev = key_device(dpk)
-            chunk = _batch_chunk_size(dpk.log_m, key_dev)
-            if chunk <= 0 or n <= chunk:
-                spans = [list(witnesses)]
-            else:
-                spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
-                spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
             # the size chosen (0: the whole batch as one) and how many ran
             batch_span.update(chunk=chunk, n_chunks=len(spans))
             REGISTRY.gauge("zkp2p_prove_chunk").set(chunk)
-            mesh = _shard_mesh()
-            if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
-                _record_arm("tpu_shard", "fallback")
-                mesh = None
             limbs = np.stack([_witness_std_limbs(wit) for wit in spans[0]])
         with trace("device", leaf=True) as device:
             if mesh is None:
@@ -1103,16 +1302,13 @@ def prove_tpu_batch(
                             # buffers planned beside it — wait the last one out
                             jax.block_until_ready(parts[-1])
                         watch.chunk = i
-                        # one batched to_mont per chunk (not one device dispatch per
-                        # witness); the h_planes stage includes it
-                        # beside a pinned key (the mesh road places its own)
-                        w = FR.to_mont(jnp.asarray(limbs) if key_dev is None or mesh is not None
-                                       else jax.device_put(limbs, key_dev))
-                        parts.append(
-                            _prove_batch_sharded(dpk, w, mesh, watch)
-                            if mesh is not None
-                            else _prove_device(dpk, w, watch=watch)
-                        )
+                        if mesh is not None:
+                            parts.append(_prove_batch_sharded(dpk, limbs, mesh, watch))
+                        else:
+                            # one batched to_mont per chunk (not one device dispatch per
+                            # witness); the h_planes stage includes it; beside a pinned key
+                            w = FR.to_mont(jnp.asarray(limbs) if key_dev is None else jax.device_put(limbs, key_dev))
+                            parts.append(_prove_device(dpk, w, watch=watch))
                         # sub-chunk HBM watermark: the batched pipeline's peak is
                         # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16
                         # with no telemetry) — sample per chunk so the staircase is

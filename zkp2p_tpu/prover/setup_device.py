@@ -18,8 +18,8 @@ object usable by `snark.groth16.verify` and the Solidity export.
 
 The same key feeds both device roads unchanged: the one-chip batch and
 the pod-mesh batch (ZKP2P_TPU_SHARD=on, docs/TPU.md).  The pruned b/c query lanes emitted here are NOT padded
-to any mesh width — the sharded MSMs pad bases and digit planes with
-infinity lanes per-mesh at trace time (parallel.mesh.pad_to_multiple),
+to any mesh width — `groth16_tpu.place_key` pads the bases with
+infinity lanes once a key and a mesh, as it lays the key on that mesh,
 so one key serves every mesh shape.
 """
 
