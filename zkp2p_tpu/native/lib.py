@@ -338,9 +338,14 @@ def _scalars_to_u64(scalars: Sequence[int]) -> np.ndarray:
     return np.frombuffer(buf, dtype="<u8").reshape(len(scalars), 4)
 
 
-def _u64_to_limbs16(a: np.ndarray) -> np.ndarray:
-    """(..., 4) u64 -> (..., 16) u32 of 16-bit limbs (the jfield layout)."""
-    return np.ascontiguousarray(a).view("<u2").astype(np.uint32).reshape(*a.shape[:-1], 16)
+def _u64_to_limbs16(a: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """(..., 4) u64 -> (..., 16) u32 of 16-bit limbs (the jfield layout),
+    widened straight into `out` where the caller has the place for them."""
+    limbs = np.ascontiguousarray(a).view("<u2").reshape(*a.shape[:-1], 16)
+    if out is None:
+        return limbs.astype(np.uint32)
+    out[...] = limbs
+    return out
 
 
 # rows a call of a fixed-base batch kernel: each call builds its own window

@@ -25,6 +25,7 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+from ..snark.r1cs import Witness, _std_u64
 from ..utils.audit import install_compile_listener, record_arm, stamp_preflight
 from ..utils.metrics import REGISTRY, run_id
 from ..utils.trace import drain as drain_trace, record, set_context
@@ -111,7 +112,10 @@ class ReplicaSet:
         from ..prover.groth16_tpu import prove_tpu_batch
 
         def one(svc) -> None:
-            witness = [1] + [0] * (svc.dpk.n_wires - 1)  # the shapes are what is warmed, not the values
+            # the shapes are what is warmed, not the values; carrying its rows as a
+            # builder's witness does, so the warm batch takes the host path a served one takes
+            witness = Witness([1] + [0] * (svc.dpk.n_wires - 1))
+            witness.u64 = _std_u64(witness)
             set_context(replica=svc.replica)  # the warm batch's spans are that replica's too
             try:
                 t0 = time.time()

@@ -72,7 +72,7 @@ from ..ops.ntt import LADDER as NTT_LADDER, coset_shift, intt, ntt
 
 from ..snark.groth16 import Proof, ProvingKey, coset_gen, domain_size_for, qap_rows
 from ..snark.r1cs import ConstraintSystem
-from ..snark.witness_check import unreduced_rows
+from ..snark.witness_check import rows_of, unreduced_rows
 from ..utils.audit import record_arm as _record_arm
 from ..utils.config import load_config as _load_config
 from ..utils.jaxcfg import on_tpu as _on_tpu
@@ -634,16 +634,15 @@ def device_pk_from_rows(
     )
 
 
-def _is_u64_witness(witness) -> bool:
-    """The (n, 4) uint64 standard-form limb layout (the .bench_cache
-    witness format, prove_native's view) — the only ndarray form the
-    vectorized paths and _check_inferred_widths' w_std view accept."""
-    return (
-        isinstance(witness, np.ndarray)
-        and witness.dtype == np.uint64
-        and witness.ndim == 2
-        and witness.shape[-1] == 4
-    )
+def _witness_rows(witness) -> Optional[np.ndarray]:
+    """The witness as its standard-form (n, 4) u64 rows, where it arrives
+    as them: the array itself (the .bench_cache format, prove_native's
+    view) or the `u64` its builder attached (`snark.r1cs.Witness`,
+    `WitnessRow`), under the guard the service's self-check reads them by
+    (`witness_check.rows_of`).  None for anything else (a plain list,
+    rows an assignment dropped, rows of another shape or dtype): that
+    takes the `int(w) % R` path.  Observed from the input, never set."""
+    return rows_of(witness, getattr(witness, "u64", witness))
 
 
 def _check_u64_reduced(rows: np.ndarray) -> None:
@@ -661,17 +660,38 @@ def _check_u64_reduced(rows: np.ndarray) -> None:
         )
 
 
-def _witness_std_limbs(witness) -> np.ndarray:
-    """Host witness (int sequence or (n, 4) u64 limb rows) -> (n, 16)
-    u32 standard-form 16-bit limbs, fully vectorized (one C-speed bytes
-    pack + a numpy view; never a per-wire Python bigint loop)."""
+def _witness_std_limbs(witness, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """Host witness -> (n, 16) u32 standard-form 16-bit limbs, into `out`
+    where given.  A witness that is or carries its rows (`_witness_rows`)
+    is those rows, checked canonical: numpy from end to end.  Any other
+    sequence goes a wire at a time through `int(w) % R` and one bytes
+    join: the oracle the rows are compared against."""
     from ..native.lib import _scalars_to_u64, _u64_to_limbs16
 
-    if not _is_u64_witness(witness):
-        witness = _scalars_to_u64([int(w) % R for w in witness])
+    rows = _witness_rows(witness)
+    if rows is None:
+        rows = _scalars_to_u64([int(w) % R for w in witness])
     else:
-        _check_u64_reduced(witness)
-    return _u64_to_limbs16(witness)
+        _check_u64_reduced(rows)
+    return _u64_to_limbs16(rows, out)
+
+
+def _chunk_limbs(span: Sequence) -> np.ndarray:
+    """One chunk's witnesses as the (chunk, n, 16) u32 array its upload
+    takes: each witness's limbs written once, straight into its place (no
+    list of arrays and a stack: a second pass over 128 MB at 2^19 x 4).  A
+    witness that is in the chunk again (a short batch padded by repeating
+    its last) is converted once and copied.  A fresh array a chunk: the
+    upload may still be reading the last one."""
+    limbs = np.empty((len(span), len(span[0]), 16), dtype=np.uint32)
+    first: Dict[int, int] = {}
+    for i, wit in enumerate(span):
+        j = first.setdefault(id(wit), i)
+        if j == i:
+            _witness_std_limbs(wit, out=limbs[i])
+        else:
+            limbs[i] = limbs[j]
+    return limbs
 
 
 def witness_to_device(witness) -> jnp.ndarray:
@@ -1238,6 +1258,12 @@ def prove_tpu_batch(
     with them the batch emits byte for byte what prove_native /
     prove_host emit for the same (witness, r, s).
 
+    A witness that is, or carries, its standard-form (n, 4) u64 rows
+    (`_witness_rows`: the `u64` both builders of `snark.r1cs` attach,
+    which the service's self-check has just held to the constraints) is
+    proved AS those rows; any other sequence a wire at a time through
+    `int(w) % R`.  The `prep` span's `witness_form` says which.
+
     Large batches run as shape-stable sub-chunks (see _batch_chunk_size;
     the last chunk pads by repeating its final witness) so device memory
     is bounded by the chunk, not the batch, and every chunk reuses the
@@ -1278,14 +1304,23 @@ def prove_tpu_batch(
     # the instant its last stage's result is ready; `dispatch` and one
     # span per device stage (per chunk, _StageWatch) lie inside it.
     with trace("tpu/prove_batch", n=len(witnesses), log_m=dpk.log_m) as batch_span:
-        with trace("prep"):
+        with trace("prep") as prep:
             sample_device_memory("tpu/prove_batch")  # entry watermark
-            for wit in witnesses:
-                _check_inferred_widths(dpk, wit, w_std=wit if _is_u64_witness(wit) else None)
+            # which form each witness arrives in, observed (`_witness_rows`);
+            # one that is in the batch again (padding) is looked at once
+            forms: Dict[str, int] = {}
+            for wit in {id(w): w for w in witnesses}.values():
+                rows = _witness_rows(wit)
+                _check_inferred_widths(dpk, wit, w_std=rows)
+                form = "ints" if rows is None else "rows"
+                forms[form] = forms.get(form, 0) + 1
+            prep.update(witness_form="mixed" if len(forms) > 1 else "".join(forms))
+            for form, seen in forms.items():
+                REGISTRY.counter("zkp2p_prove_witness_form_total", {"form": form}).inc(seen)
             # the size chosen (0: the whole batch as one) and how many ran
             batch_span.update(chunk=chunk, n_chunks=len(spans))
             REGISTRY.gauge("zkp2p_prove_chunk").set(chunk)
-            limbs = np.stack([_witness_std_limbs(wit) for wit in spans[0]])
+            limbs = _chunk_limbs(spans[0])
         with trace("device", leaf=True) as device:
             if mesh is None:
                 _h_table(dpk)  # the first batch of a key builds it: one `tpu/prove_batch/h_table` span
@@ -1295,7 +1330,7 @@ def prove_tpu_batch(
                     parts = []
                     for i, span in enumerate(spans):
                         if i:
-                            limbs = np.stack([_witness_std_limbs(wit) for wit in span])
+                            limbs = _chunk_limbs(span)
                         if i and chunk < BATCH_CHUNK_MAX:
                             # fewer than four at a time: the device's memory is the
                             # ceiling, and a chunk enqueued behind another has its

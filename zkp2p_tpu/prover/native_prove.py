@@ -39,6 +39,7 @@ from ..field.bn254 import (
 from ..field.tower import Fq2
 from ..native.lib import _scalars_to_u64, get_lib
 from ..snark.groth16 import Proof, coset_gen
+from ..snark.witness_check import rows_of
 from .groth16_tpu import DeviceProvingKey, _assemble, _check_inferred_widths
 
 _u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -390,9 +391,9 @@ def _witness_std_u64(
     (pinned in tests/test_nonmsm.py)."""
     n = len(witness)
     if builder_u64:
-        u = getattr(witness, "u64", None)
-        if u is not None and getattr(u, "shape", None) == (n, 4):
-            return np.ascontiguousarray(u)
+        rows = rows_of(witness, getattr(witness, "u64", None))
+        if rows is not None:
+            return rows
     if fast and n:
         try:
             arr = np.zeros((n, 4), dtype=np.uint64)

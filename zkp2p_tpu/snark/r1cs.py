@@ -31,12 +31,16 @@ Coeffs = Dict[int, int]  # wire index -> Fr coefficient
 
 
 class Witness(list):
-    """A witness vector (Fr ints) that also carries ``u64``: the prover's
+    """A witness vector (Fr ints) that also carries ``u64``: the provers'
     standard-form (n, 4) little-endian u64 serialization, emitted at build
     time so the per-prove ``witness_convert`` stage collapses to an array
-    hand-off (gated by ``ZKP2P_WITNESS_U64``).  Assigning a wire drops the
-    rows: they are read in place of the values (the native prover's
-    hand-off, the service's self-check) only while they say the same."""
+    hand-off.  Assigning a wire drops the rows: they are read in place of
+    the values only while they say the same.  Three readers: the native
+    prover's hand-off (gated by ``ZKP2P_WITNESS_U64``), the service's
+    self-check and the device prover's ``prep``; the last two are not
+    gated: each observes whether the rows are there, under one guard
+    (``snark.witness_check.rows_of``), so what is checked and what is
+    proved is one array."""
 
     u64 = None
 

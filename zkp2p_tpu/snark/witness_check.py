@@ -214,18 +214,21 @@ def plan_for(cs) -> Optional[CheckPlan]:
     return plan
 
 
+def rows_of(w, rows) -> Optional[np.ndarray]:
+    """`rows` where they are `w`'s standard form in the one layout every
+    reader of them takes (the library, this check, the device prover):
+    an ndarray of one (4,) u64 row a wire of `w`; else None.  THE guard:
+    what a reader observes of its input, and all it observes."""
+    if isinstance(rows, np.ndarray) and rows.dtype == np.uint64 and rows.shape == (len(w), 4):
+        return np.ascontiguousarray(rows)
+    return None
+
+
 def witness_rows(cs, w) -> Optional[np.ndarray]:
     """The standard-form rows a builder attached to `w`, if they are in
     the layout the library reads (one (4,) u64 row a wire of `cs`)."""
-    rows = getattr(w, "u64", None)
-    if (
-        isinstance(rows, np.ndarray)
-        and rows.dtype == np.uint64
-        and rows.shape == (cs.num_wires, 4)
-        and len(w) == cs.num_wires
-    ):
-        return np.ascontiguousarray(rows)
-    return None
+    rows = rows_of(w, getattr(w, "u64", None))
+    return rows if rows is not None and len(rows) == cs.num_wires else None
 
 
 def path_for(cs, ws: Sequence) -> str:
