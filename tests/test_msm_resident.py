@@ -384,7 +384,6 @@ def test_a_batch_above_and_below_the_chunk_gives_the_proofs_of_one_at_a_time(mon
     from zkp2p_tpu.prover import groth16_tpu as G
     from zkp2p_tpu.snark.groth16 import prove_host
     from zkp2p_tpu.utils import trace as tr
-    from zkp2p_tpu.utils.metrics import REGISTRY
 
     cs, pk, dpk, wits = _toy_world(monkeypatch)
     monkeypatch.setattr(G, "BATCH_CHUNK", "2")
@@ -394,7 +393,7 @@ def test_a_batch_above_and_below_the_chunk_gives_the_proofs_of_one_at_a_time(mon
     assert proofs == [prove_host(pk, cs, wits[i], r=rs[i], s=ss[i]) for i in range(n)]
     (batch,) = [r for r in tr.records() if r["stage"] == "tpu/prove_batch"]
     assert (batch["n"], batch["chunk"], batch["n_chunks"], batch["log_m"]) == (n, 2, n_chunks, dpk.log_m)
-    assert REGISTRY.gauge("zkp2p_prove_chunk").value == 2
+    assert [r["chunk"] for r in tr.records() if r["stage"].endswith("/upload")] == list(range(n_chunks))  # a put a chunk
     h_stages = [r for r in tr.records() if r["stage"].endswith("/stage/msm_h")]
     assert [r["chunk"] for r in h_stages] == list(range(n_chunks))
     tr.reset()

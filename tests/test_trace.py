@@ -10,6 +10,8 @@ import json
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
+import pytest
+
 from zkp2p_tpu.utils import trace as tr
 
 
@@ -244,3 +246,93 @@ def test_an_open_span_is_a_trace_annotation_only_where_jax_is_imported(monkeypat
     assert len(seen) == 4 and not hasattr(tr, "jax_profile")
     src = open(tr.__file__).read()
     assert "import jax" not in src and "JAX_TRACE_DIR" not in src
+
+
+def _burn(cpu_s):
+    """Keep this thread on a CPU until it has spent `cpu_s` there."""
+    import time
+
+    end = time.thread_time() + cpu_s
+    while time.thread_time() < end:
+        pass
+
+
+def test_a_closed_span_carries_the_cpu_time_its_thread_spent_in_it():
+    import time
+
+    with tr.trace("busy") as busy:
+        _burn(0.03)
+    with tr.trace("asleep") as asleep:
+        time.sleep(0.05)
+    tr.record("past", 1.0, 2.0)
+    tr.record("past_with_a_reading", 1.0, 2.0, cpu_ms=12.5)
+    assert 30.0 <= busy["cpu_ms"] <= busy["ms"] + 1.0  # on a CPU all the while: cpu_ms reads about ms
+    assert asleep["ms"] >= 50.0 and asleep["cpu_ms"] < 10.0  # waiting: next to none
+    by = {r["stage"]: r for r in tr.records()}
+    assert "cpu_ms" not in by["past"] and by["past_with_a_reading"]["cpu_ms"] == 12.5
+
+
+def test_the_thread_tally_holds_each_span_s_self_time_by_its_last_path_element():
+    import time
+
+    mark = tr.thread_tally()
+    with tr.trace("svc/outer"):
+        time.sleep(0.02)
+        with tr.trace("inner"):  # svc/outer/inner
+            _burn(0.03)
+        inside = tr.thread_tally()  # `outer` is open: its part so far counts, less what `inner` covered
+        with tr.trace("svc/inner"):  # another path, the same last element: one entry
+            time.sleep(0.01)
+    after = tr.thread_tally()
+
+    def since(reading, name, i=0):
+        return reading.get(name, (0.0, 0.0))[i] - mark.get(name, (0.0, 0.0))[i]
+
+    assert 20.0 <= since(inside, "outer") < 20.0 + 15.0 and since(inside, "outer", 1) < 10.0
+    assert 30.0 <= since(inside, "inner") and 29.0 <= since(inside, "inner", 1) <= since(inside, "inner") + 1.0
+    assert 40.0 <= since(after, "inner") and since(after, "inner", 1) < since(after, "inner") - 5.0
+    # self time: the whole of `outer` less both children, wall and cpu
+    outer = [r for r in tr.records() if r["stage"] == "svc/outer"][0]
+    kids = [r for r in tr.records() if r["stage"].endswith("inner")]
+    assert since(after, "outer") == pytest.approx(outer["ms"] - sum(r["ms"] for r in kids), abs=0.5)
+    assert since(after, "outer", 1) == pytest.approx(outer["cpu_ms"] - sum(r["cpu_ms"] for r in kids), abs=0.5)
+    # two readings partition the thread's time between them: nothing is counted twice
+    assert sum(since(after, n) for n in ("outer", "inner")) == pytest.approx(outer["ms"], abs=0.5)
+
+
+def test_the_tally_is_a_thread_s_own_and_a_record_feeds_it_unless_told_not_to():
+    import time
+
+    def worker(out):
+        with tr.trace("w/job"):
+            time.sleep(0.02)
+        out.update(tr.thread_tally())
+
+    mark, theirs = tr.thread_tally(), {}
+    with tr.trace("main/job"):
+        th = threading.Thread(target=worker, args=(theirs,))
+        th.start()
+        th.join()
+        now = time.time()
+        tr.record("waited", now - 0.004, now)  # a part of `job`, read from clocks: job's self time loses it
+        gap = tr.record("gap", now - 5.0, now, tally=False)  # an account of time other spans cover
+        tr.record("gap/part", now - 5.0, now - 4.0, parent=gap, tally=False, cpu_ms=1.0)
+    mine = tr.thread_tally()
+    assert 20.0 <= theirs["job"][0] and "waited" not in theirs  # a fresh thread's tally is its own spans'
+    assert mine["waited"][0] - mark.get("waited", (0.0, 0.0))[0] == pytest.approx(4.0, abs=0.01)
+    assert "gap" not in mine and "part" not in mine
+    job = [r for r in tr.records() if r["stage"] == "main/job"][0]
+    assert mine["job"][0] - mark.get("job", (0.0, 0.0))[0] == pytest.approx(job["ms"] - 4.0, abs=0.5)
+    by = {r["stage"]: r for r in tr.records()}
+    assert by["main/job/gap/part"]["parent"] == gap == by["main/job/gap"]["id"] and by["main/job/gap"]["parent"] == job["id"]
+    assert by["main/job/gap/part"]["cpu_ms"] == 1.0
+
+
+def test_a_backdated_span_tallies_from_where_it_opened():
+    import time
+
+    mark = tr.thread_tally().get("sweep", (0.0, 0.0))[0]
+    with tr.trace("svc/sweep", t0=time.time() - 0.5) as sweep:
+        time.sleep(0.01)
+    assert sweep["ms"] >= 510.0  # the record runs from t0
+    assert 10.0 <= tr.thread_tally()["sweep"][0] - mark < 100.0  # the tally, from the `with`: the time before it was some other span's

@@ -298,7 +298,10 @@ def test_a_served_sweep_writes_sweep_and_starved_and_leaves_request_spans_as_the
     # every span of the pass is the sweep's descendant, under the path it always had
     for name in ("service/witness", "service/prove", "service/verify", "service/emit", "service/starved"):
         assert by[name] and all(r["parent"] == sweep["id"] for r in by[name]), name
-    assert all(sweep["t0"] <= r["t0"] for r in stages if r is not sweep)
+    # ... but what the loop did before it opened it, which is written beside it (PR 35)
+    beside = [r for r in stages if r["stage"] in ("service/handover", "service/poll")]
+    assert beside and all(r["parent"] is None and r["t0"] <= sweep["t0"] for r in beside)
+    assert all(sweep["t0"] <= r["t0"] for r in stages if r is not sweep and r not in beside)
     # the producer's spans are on another thread than the proving thread's
     assert {r["tid"] for r in by["service/witness"]} != {r["tid"] for r in by["service/prove"]}
     # exactly today's entries in the request records

@@ -278,6 +278,38 @@ class Request:
     spans: List[Dict] = field(default_factory=list)
 
 
+class _BetweenSweeps:
+    """What the proving thread does between one sweep's end and the next
+    one's opening, as two spans: `service/poll`, the sleep of the poll
+    (`run`'s `_drain.wait`), and `service/handover`, the rest (the line
+    printed, the sink's flush, the sampler's and the fleet's tick, the
+    spool's scan, the scheduler).  Both are timed in laps and written by
+    the pass that opens a sweep, before it opens it: what the passes
+    before it spent finding nothing is folded in, so a service that polls
+    an empty spool writes nothing.  A folded span starts where its first
+    piece started and lasts as long as its pieces together."""
+
+    def __init__(self):
+        self._at: Optional[Tuple] = None  # the last lap: (thread, time, perf_counter, thread_time)
+        self._laps: Dict[str, List[float]] = {}  # stage -> [t0 of its first piece, seconds, cpu seconds]
+
+    def lap(self, stage: Optional[str]) -> None:
+        """This thread's time since its last lap counts to `stage` (None:
+        to neither; it was a sweep's, or nothing was timed yet)."""
+        now = (threading.current_thread(), time.time(), time.perf_counter(), time.thread_time())
+        last, self._at = self._at, now
+        if stage is not None and last is not None and last[0] is now[0]:
+            lap = self._laps.setdefault(stage, [last[1], 0.0, 0.0])
+            lap[1] += now[2] - last[2]
+            lap[2] += now[3] - last[3]
+
+    def write(self) -> None:
+        for stage, (t0, secs, cpu) in self._laps.items():
+            # a sum of pieces, not one interval: no `tid`, so the Perfetto view draws none
+            record("service/" + stage, t0, t0 + secs, cpu_ms=round(cpu * 1e3, 3), tid=None)
+        self._laps.clear()
+
+
 class TimeseriesSampler:
     """Periodic service time-series: one `{"type": "timeseries", ...}`
     line per interval (ZKP2P_TS_SAMPLE_S; 0 = off) appended to the
@@ -535,6 +567,7 @@ class ProvingService:
         self.busy_s = 0.0
         self.n_batches = 0
         self.n_done = 0
+        self._between = _BetweenSweeps()
         self._perf_book = None
         self._perf_lock = threading.Lock()
         self._perf_hb: Optional[Dict] = None
@@ -1756,6 +1789,11 @@ class ProvingService:
 
         # One span around a pass that found pending requests (leaf: the
         # spans under it keep their paths and name it as their parent).
+        # What this thread did since the last one closed is written first,
+        # beside it (`service/handover`, `service/poll`).
+        if pending:
+            self._between.lap("handover")
+            self._between.write()
         sweep = (
             trace("service/sweep", leaf=True, t0=t_sweep, n_pending=len(pending))
             if pending else contextlib.nullcontext({})
@@ -1772,6 +1810,8 @@ class ProvingService:
                 stop_hb.set()
                 hb.join()
             producer.join()
+        if pending:
+            self._between.lap(None)  # the sweep's own time
         if producer_error:
             # Requests after the failure point got no witness, no proof
             # and no record this sweep — the claim-file discipline means
@@ -2072,6 +2112,7 @@ class ProvingService:
         deadline = (time.time() + max_seconds) if max_seconds else None
         sweeps = 0
         why = "sweeps"
+        self._between.lap(None)  # between sweeps from here on
         while max_sweeps is None or sweeps < max_sweeps:
             if deadline is not None and time.time() > deadline:
                 why = "timeout"
@@ -2111,7 +2152,10 @@ class ProvingService:
             # interruptible sleep: a SIGTERM mid-poll exits promptly
             # instead of burning up to poll_s — by this point the sweep
             # above already finished every claim it held
-            if self._drain.wait(poll_s):
+            self._between.lap("handover")
+            drained = self._drain.wait(poll_s)
+            self._between.lap("poll")
+            if drained:
                 why = "drained"
                 break
         # exit flush: whatever the reason, buffered spans and native

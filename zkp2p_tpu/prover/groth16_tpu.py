@@ -40,7 +40,7 @@ import threading
 import time
 from dataclasses import dataclass
 from functools import lru_cache
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -874,12 +874,22 @@ class _StageWatch:
     the later one.  A thread of its own, because the runtime bounds the
     programs in flight and holds the enqueuing thread inside `dispatch`
     while the first stages retire (PERF.md, PR 24): read from there,
-    their ends would all read as dispatch's."""
+    their ends would all read as dispatch's.
+
+    A chunk's witnesses on their way to the device are no stage
+    (`uploaded`): the `upload` span runs from where the chunk's first
+    stage may start (`t0`; a later chunk's, the instant the host had
+    enqueued the chunk before) to the limbs being ready there, beside
+    `stage/h_planes` and inside it, and moves no stage's start.
+
+    `t_ready` is when the last stage waited for was ready: after `close`,
+    the instant the device had nothing left."""
 
     def __init__(self, t0: float):
         from ..utils.trace import adopt_context, adopt_stack, current_context, current_stack
 
         self.chunk = 0
+        self.t_ready: Optional[float] = None
         self._t = t0
         self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         stack, ctx = current_stack(), current_context()
@@ -895,8 +905,12 @@ class _StageWatch:
                     jax.block_until_ready(value)
                 except Exception:  # noqa: BLE001 — the proving thread meets it where it reads the accumulator
                     return
+                if name == "upload":  # the chunk's witnesses, not a stage: the stage clock stays
+                    record(name, t_enqueue, time.time(), chunk=chunk, **attrs)
+                    continue
                 t_start, t_ready = max(t_ready, t_enqueue), time.time()
                 record("stage/" + name, t_start, t_ready, chunk=chunk, **attrs)
+                self.t_ready = t_ready
 
         self._thread = threading.Thread(target=run, name="zkp2p-stage-watch", daemon=True)
         self._thread.start()
@@ -904,6 +918,13 @@ class _StageWatch:
     def enqueued(self, name: str, value, **attrs) -> None:
         self._q.put((name, self.chunk, self._t, value, attrs))
         self._t = time.time()
+
+    def uploaded(self, limbs, nbytes: int):
+        """The chunk's witnesses, put to the device before its first stage
+        is enqueued: `limbs`, which it returns, is ready when they have
+        arrived."""
+        self._q.put(("upload", self.chunk, self._t, limbs, {"bytes": nbytes}))
+        return limbs
 
     def close(self) -> None:
         """Returns when the last stage enqueued has its result ready and
@@ -1191,6 +1212,8 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
     n_proofs, n_wires = limbs.shape[0], limbs.shape[1]
     split = _pod_split(mesh, n_proofs)
     w_std = jax.device_put(limbs, NamedSharding(mesh, _pod_chunk_spec(mesh, split)))
+    if watch is not None:
+        watch.uploaded(w_std, limbs.nbytes)
     h_std, done = _h_pod_fn(mesh, dpk.log_m, split)(tuple(getattr(dpk, f) for f in _QAP_ROWS), w_std)
     _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on,
               proofs_a_chip=n_proofs // (mesh.size if split else mesh.shape["batch"]))
@@ -1213,6 +1236,71 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
         msm("msm_c", G1J, dpk.c_bases, c_planes),
         msm("msm_h", G1J, dpk.h_bases, h_planes),
     )
+
+
+# What the device waited for between two batches, by what the thread that
+# feeds it was doing (`tpu/prove_batch/device_idle`): each cause, in the order
+# its span is written, with the spans of that thread it sums (their last path
+# elements, as `utils.trace.thread_tally` keys them).  `other` is the rest.
+IDLE_CAUSES: Dict[str, Tuple[str, ...]] = {
+    "finish": ("finish",),  # tpu/prove_batch/finish
+    "verify": ("verify",),  # service/verify
+    "emit": ("emit",),  # service/emit
+    "handover": ("handover", "sweep"),  # service/handover, and service/sweep's own time
+    "poll": ("poll",),  # service/poll
+    "starved": ("starved",),  # service/starved
+    "prep": ("prep",),  # tpu/prove_batch/prep
+}
+# the causes that are work, not a wait the thread chose: what of them was not
+# on a CPU is `device_idle/offcpu`
+_IDLE_WORK = ("finish", "verify", "emit", "prep", "handover")
+# a key's placement -> (the thread that fed it last, when that batch's last
+# stage was ready, that thread's tally and CPU clock as it learned so)
+_fed_last: Dict[Any, Tuple[threading.Thread, float, Dict[str, Tuple[float, float]], float]] = {}
+
+
+def _idle_since_fed(placement, t_fed: float):
+    """The gap a batch closes as its `device` span opens at `t_fed`: since
+    this thread's last batch on `placement` left the device empty.  None for
+    a placement's first batch and for one another thread fed last (a warm-up
+    batch before a service's first): no one thread's spans account for that."""
+    from ..utils.trace import thread_tally
+
+    last = _fed_last.pop(placement, None)
+    if last is None or last[0] is not threading.current_thread():
+        return None
+    _, t_ready, tally0, cpu0 = last
+    return t_ready, t_fed, tally0, thread_tally(), (time.thread_time() - cpu0) * 1e3
+
+
+def _record_idle(gap, n: int) -> None:
+    """One `device_idle` span from the gap `_idle_since_fed` read, `n` the
+    batch it precedes, and under it one span a cause, zero included (a mean
+    over spans is a mean over batches), laid end to end from the gap's start
+    in `IDLE_CAUSES`' order then `other`: an account of the gap, not when
+    each happened.  `ms` and `cpu_ms` are the feeding thread's self time in
+    that cause's spans inside the gap; `other` is what is left of each (time
+    in no span, and in spans that are no cause or still open), so the eight
+    partition the gap.  `offcpu`, beside them and no part of the sum, is
+    what the thread spent in `_IDLE_WORK`'s spans off a CPU."""
+    from ..utils.trace import record
+
+    t_ready, t_fed, tally0, tally1, cpu_ms = gap
+    none = (0.0, 0.0)
+    parts = {
+        cause: tuple(sum(tally1.get(nm, none)[i] - tally0.get(nm, none)[i] for nm in names) for i in (0, 1))
+        for cause, names in IDLE_CAUSES.items()
+    }
+    parts["other"] = ((t_fed - t_ready) * 1e3 - sum(ms for ms, _ in parts.values()),
+                      cpu_ms - sum(cpu for _, cpu in parts.values()))
+    account = {"tally": False, "tid": None}  # the device's time, and sums: no interval of this thread
+    idle = record("device_idle", t_ready, t_fed, cpu_ms=round(cpu_ms, 3), n=n, **account)
+    t = t_ready
+    for cause, (ms, cpu) in parts.items():
+        record("device_idle/" + cause, t, t + ms / 1e3, cpu_ms=round(cpu, 3), parent=idle, **account)
+        t += ms / 1e3
+    offcpu = sum(parts[c][0] - parts[c][1] for c in _IDLE_WORK)
+    record("device_idle/offcpu", t_ready, t_ready + offcpu / 1e3, parent=idle, **account)
 
 
 def _batch_chunk_size(log_m: Optional[int] = None, device=None) -> int:
@@ -1277,10 +1365,16 @@ def prove_tpu_batch(
     over "shard".  The arm is decided ONCE per call —
     a chunk size indivisible by the mesh's batch width records the
     `tpu_shard` arm as "fallback" and the whole call takes the vmap
-    path, so every chunk of a call shares one executable either way."""
+    path, so every chunk of a call shares one executable either way.
+
+    What the device waited before this batch is written as a span of this
+    one (`device_idle`, `_record_idle`), per key placement and feeding
+    thread.  A batch of several chunks that waits one chunk out before it
+    enqueues the next (`chunk < BATCH_CHUNK_MAX`) leaves the device empty
+    between them too: that wait is inside `device` and is not accounted."""
     from ..utils.audit import sample_device_memory
     from ..utils.metrics import REGISTRY
-    from ..utils.trace import trace
+    from ..utils.trace import thread_tally, trace
 
     # The road, decided once a call; the mesh road reads the key as
     # `place_key` lays it on the mesh, which the first batch of a key
@@ -1302,7 +1396,11 @@ def prove_tpu_batch(
     # Spans (utils.trace): `prep`, `device` and `finish` partition
     # `tpu/prove_batch`.  `device` runs from the batch's first enqueue to
     # the instant its last stage's result is ready; `dispatch` and one
-    # span per device stage (per chunk, _StageWatch) lie inside it.
+    # span per device stage (per chunk, _StageWatch) lie inside it, and
+    # one `upload` a chunk.  `device_idle`, written under `device` once the
+    # batch is enqueued, is the time BEFORE it: from the last stage of this
+    # thread's last batch on the same placement to this `device`'s start.
+    placement = mesh_name(mesh) if mesh is not None else key_dev
     with trace("tpu/prove_batch", n=len(witnesses), log_m=dpk.log_m) as batch_span:
         with trace("prep") as prep:
             sample_device_memory("tpu/prove_batch")  # entry watermark
@@ -1319,9 +1417,9 @@ def prove_tpu_batch(
                 REGISTRY.counter("zkp2p_prove_witness_form_total", {"form": form}).inc(seen)
             # the size chosen (0: the whole batch as one) and how many ran
             batch_span.update(chunk=chunk, n_chunks=len(spans))
-            REGISTRY.gauge("zkp2p_prove_chunk").set(chunk)
             limbs = _chunk_limbs(spans[0])
         with trace("device", leaf=True) as device:
+            gap = _idle_since_fed(placement, device["t0"])
             if mesh is None:
                 _h_table(dpk)  # the first batch of a key builds it: one `tpu/prove_batch/h_table` span
             watch = _StageWatch(device["t0"])
@@ -1342,7 +1440,8 @@ def prove_tpu_batch(
                         else:
                             # one batched to_mont per chunk (not one device dispatch per
                             # witness); the h_planes stage includes it; beside a pinned key
-                            w = FR.to_mont(jnp.asarray(limbs) if key_dev is None else jax.device_put(limbs, key_dev))
+                            w = FR.to_mont(watch.uploaded(
+                                jnp.asarray(limbs) if key_dev is None else jax.device_put(limbs, key_dev), limbs.nbytes))
                             parts.append(_prove_device(dpk, w, watch=watch))
                         # sub-chunk HBM watermark: the batched pipeline's peak is
                         # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16
@@ -1354,10 +1453,14 @@ def prove_tpu_batch(
                         if len(parts) == 1
                         else jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
                     )
+                if gap is not None:
+                    _record_idle(gap, n)  # here, with the batch enqueued: the device waits for none of it
                 # these go to the host while the device is on a later stage
                 a, b1, c = (g1_jac_to_host(accs[i]) for i in (0, 1, 3))
             finally:
                 watch.close()  # the last stage's result is ready: the device has nothing left
+            if watch.t_ready is not None:
+                _fed_last[placement] = (threading.current_thread(), watch.t_ready, thread_tally(), time.thread_time())
         with trace("finish"):
             hq = g1_jac_to_host(accs[4])
             b2 = g2_jac_to_host(accs[2])

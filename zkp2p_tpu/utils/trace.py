@@ -16,9 +16,17 @@ on the wall clock (`t0`, `time.time()` — the clock of the spool's
 mtimes and of the request records), an `id` unique in the process and
 the `id` of the span that was open on its thread when it opened
 (`parent`; `adopt_stack` carries it to worker threads), the thread that
-closed it (`tid`), plus the ambient
+closed it (`tid`), the CPU time that thread spent inside it (`cpu_ms`,
+`time.thread_time()` at its two ends: `ms` less `cpu_ms` is what the
+thread waited, for a lock, a core, a device or a sleep), plus the ambient
 context (`request_id`) and its own attributes.  `record()` writes a span
-whose ends were read from clocks instead of bracketed by a `with`.
+whose ends were read from clocks instead of bracketed by a `with`; it
+carries a `cpu_ms` only where its caller read one.
+
+Each thread also keeps a tally of what its spans cost it: the self time
+of every span it closed (its time less what its children covered), by
+the span's last path element, wall and CPU (`thread_tally`).  Two
+readings partition the thread's time between them by span name.
 
 While a span is open it is also a `jax.profiler.TraceAnnotation` of the
 same path, so a profiler capture shows the program's spans in the host
@@ -118,6 +126,48 @@ def _open(stage: str, leaf: bool = False):
     return stack, (path, next(_ids), prefix if leaf else path), parent_id
 
 
+def _tallied(name: str, secs: float, cpu: float, kids_secs: float = 0.0, kids_cpu: float = 0.0) -> None:
+    """A span of this thread has closed: its self time to the thread's
+    tally under `name`, its whole time to what the span open around it
+    has covered by children."""
+    tally = getattr(_tls, "tally", None)
+    if tally is None:
+        tally = _tls.tally = {}
+    own = tally.get(name)
+    if own is None:
+        tally[name] = [secs - kids_secs, cpu - kids_cpu]
+    else:
+        own[0] += secs - kids_secs
+        own[1] += cpu - kids_cpu
+    opened = getattr(_tls, "opened", None)
+    if opened:
+        opened[-1][3] += secs
+        opened[-1][4] += cpu
+
+
+def thread_tally() -> Dict[str, Tuple[float, float]]:
+    """{a span's last path element: (self ms, self cpu_ms)}, summed over
+    the spans THIS thread has closed since it started, and over the part
+    so far of those it has open.  A span's self time is its time less
+    what the spans that closed under it on this thread covered (one that
+    a worker thread closes under an adopted stack is that thread's); a
+    span opened with `t0=` counts from where it was opened, a `record()`
+    its whole interval.  So the difference of two readings partitions
+    the thread's time between them by name, and what no name claims was
+    spent in no span."""
+    out = {name: (own[0] * 1e3, own[1] * 1e3) for name, own in (getattr(_tls, "tally", None) or {}).items()}
+    opened = getattr(_tls, "opened", None)
+    if opened:
+        # an open span's self time so far: since it opened, less its closed
+        # children and the child that is open now
+        end, end_cpu = time.perf_counter(), time.thread_time()
+        for name, p0, c0, kids_secs, kids_cpu in reversed(opened):
+            ms, cpu_ms = out.get(name, (0.0, 0.0))
+            out[name] = (ms + (end - p0 - kids_secs) * 1e3, cpu_ms + (end_cpu - c0 - kids_cpu) * 1e3)
+            end, end_cpu = p0, c0  # what the span around it has spent in this one
+    return out
+
+
 def _close(rec: Dict[str, Any]) -> None:
     for k, v in (getattr(_tls, "ctx", None) or {}).items():
         rec.setdefault(k, v)  # explicit attributes win over the ambient context
@@ -154,26 +204,53 @@ def trace(stage: str, *, leaf: bool = False, t0: Optional[float] = None, **attrs
     stack.append(frame)
     rec = {"stage": frame[0], "ms": None, "t0": round(time.time() if t0 is None else t0, 6),
            "id": frame[1], "parent": parent, "tid": threading.get_ident(), **attrs}
-    p0 = time.perf_counter()
+    opened = getattr(_tls, "opened", None)
+    if opened is None:
+        opened = _tls.opened = []
+    p0, c0 = time.perf_counter(), time.thread_time()
+    # the span's account in the thread's tally: name, the two clocks at its
+    # opening, and what its closed children have covered of each
+    own = [stage.rpartition("/")[2], p0, c0, 0.0, 0.0]
+    opened.append(own)
     ann = _annotation(frame[0])
     try:
         yield rec
     finally:
-        secs = time.perf_counter() - p0 if t0 is None else time.time() - t0
+        p1, cpu = time.perf_counter(), time.thread_time() - c0
+        secs = p1 - p0 if t0 is None else time.time() - t0
         if ann is not None:
             ann.__exit__(None, None, None)
-        rec["ms"] = round(secs * 1e3, 3)
+        rec["ms"], rec["cpu_ms"] = round(secs * 1e3, 3), round(cpu * 1e3, 3)
         stack.pop()
+        opened.pop()
+        _tallied(own[0], p1 - p0, cpu, own[3], own[4])
         _close(rec)
 
 
-def record(stage: str, t0: float, t1: float, **attrs) -> None:
+def record(stage: str, t0: float, t1: float, *, cpu_ms: Optional[float] = None,
+           parent: Optional[int] = None, tally: bool = True, **attrs) -> int:
     """A span whose ends were read from clocks (`time.time()`), not
     bracketed by a `with`: it nests under the span open on this thread
-    like any other, and being in the past gets no TraceAnnotation."""
-    _stack, frame, parent = _open(stage)
-    _close({"stage": frame[0], "ms": round((t1 - t0) * 1e3, 3), "t0": round(t0, 6),
-            "id": frame[1], "parent": parent, "tid": threading.get_ident(), **attrs})
+    like any other, and being in the past gets no TraceAnnotation.
+    Returns its `id`.
+
+    cpu_ms: the CPU time this thread spent in the interval, where the
+    caller read `time.thread_time()` at its ends too.
+    parent: the `id` of a span already written that this one is a part
+    of, where that is not the span open on this thread.
+    tally: False for an interval that accounts for time other spans
+    already cover (a gap and its parts): it feeds no thread's tally.
+    An account that is a sum and no one interval of a thread is written
+    with `tid=None`: the Perfetto view (tools/trace_report.py), which
+    draws a thread's spans on a row where they must nest, draws none."""
+    _stack, frame, open_id = _open(stage)
+    if cpu_ms is not None:
+        attrs["cpu_ms"] = cpu_ms
+    if tally:
+        _tallied(stage.rpartition("/")[2], t1 - t0, (cpu_ms or 0.0) / 1e3)
+    _close({"stage": frame[0], "ms": round((t1 - t0) * 1e3, 3), "t0": round(t0, 6), "id": frame[1],
+            "parent": open_id if parent is None else parent, "tid": threading.get_ident(), **attrs})
+    return frame[1]
 
 
 def current_path() -> str:
