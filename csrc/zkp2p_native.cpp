@@ -6650,3 +6650,519 @@ void g2_msm_pippenger(const u64 *bases, const u64 *scalars, long n,
 }
 
 }  // extern "C"
+
+// ===================================================================
+// Fq6 / Fq12 and the optimal ate pairing: the Groth16 verification
+// equation, whole.  The proving service verifies one sample proof a
+// batch on the proving thread with the device empty; the Python pairing
+// (pairing/pairing.py over field/tower.py) is ~0.47 s of big-int tower
+// arithmetic there, this is a few milliseconds and the ctypes call
+// releases the interpreter.  The Python function stays the oracle
+// (snark/native_verify.py): every `0` from here is re-decided by it.
+//
+// Same tower as field/tower.py:  Fq6 = Fq2[v]/(v^3 - xi), xi = 9 + u;
+// Fq12 = Fq6[w]/(w^2 - v).  Same Miller loop as pairing/pairing.py —
+// affine points, lines through the untwisted psi(x, y) = (x w^2, y w^3)
+// — so f is the SAME element of Fq12 before the final exponentiation,
+// not one that differs by a subfield factor.  Written for obviousness,
+// not speed: generic Fq12 products for the sparse lines, a plain
+// square-and-multiply for the hard part of the exponent.
+// ===================================================================
+
+static inline void fp_neg(u64 out[4], const u64 a[4]) { sub_mod(out, ZERO, a); }
+static inline void fp2_neg(Fp2 &r, const Fp2 &a) {
+  fp_neg(r.c0, a.c0);
+  fp_neg(r.c1, a.c1);
+}
+static inline void fp2_conj(Fp2 &r, const Fp2 &a) {
+  memcpy(r.c0, a.c0, 32);
+  fp_neg(r.c1, a.c1);
+}
+static inline void fp2_scale(Fp2 &r, const Fp2 &a, const u64 s[4]) {
+  mont_mul(r.c0, a.c0, s);
+  mont_mul(r.c1, a.c1, s);
+}
+static inline bool fp2_eq(const Fp2 &a, const Fp2 &b) {
+  return !memcmp(a.c0, b.c0, 32) && !memcmp(a.c1, b.c1, 32);
+}
+// a * xi, xi = 9 + u:  (9 a0 - a1) + (a0 + 9 a1) u
+static void fp2_mul_xi(Fp2 &r, const Fp2 &a) {
+  Fp2 t;
+  fp2_add(t, a, a);
+  fp2_add(t, t, t);
+  fp2_add(t, t, t);
+  fp2_add(t, t, a);  // 9 a
+  Fp2 out;
+  sub_mod(out.c0, t.c0, a.c1);
+  add_mod(out.c1, t.c1, a.c0);
+  r = out;
+}
+
+struct Fp6 {
+  Fp2 c0, c1, c2;
+};
+struct Fp12 {
+  Fp6 c0, c1;
+};
+
+static inline void fp6_add(Fp6 &r, const Fp6 &a, const Fp6 &b) {
+  fp2_add(r.c0, a.c0, b.c0);
+  fp2_add(r.c1, a.c1, b.c1);
+  fp2_add(r.c2, a.c2, b.c2);
+}
+static inline void fp6_sub(Fp6 &r, const Fp6 &a, const Fp6 &b) {
+  fp2_sub(r.c0, a.c0, b.c0);
+  fp2_sub(r.c1, a.c1, b.c1);
+  fp2_sub(r.c2, a.c2, b.c2);
+}
+static inline void fp6_neg(Fp6 &r, const Fp6 &a) {
+  fp2_neg(r.c0, a.c0);
+  fp2_neg(r.c1, a.c1);
+  fp2_neg(r.c2, a.c2);
+}
+// (c0, c1, c2) * v = (xi c2, c0, c1)
+static inline void fp6_mul_v(Fp6 &r, const Fp6 &a) {
+  Fp2 t;
+  fp2_mul_xi(t, a.c2);
+  r.c2 = a.c1;
+  r.c1 = a.c0;
+  r.c0 = t;
+}
+static void fp6_mul(Fp6 &r, const Fp6 &a, const Fp6 &b) {
+  Fp2 t0, t1, t2, s, u, c0, c1, c2;
+  fp2_mul(t0, a.c0, b.c0);
+  fp2_mul(t1, a.c1, b.c1);
+  fp2_mul(t2, a.c2, b.c2);
+  fp2_add(s, a.c1, a.c2);
+  fp2_add(u, b.c1, b.c2);
+  fp2_mul(c0, s, u);
+  fp2_sub(c0, c0, t1);
+  fp2_sub(c0, c0, t2);
+  fp2_mul_xi(c0, c0);
+  fp2_add(c0, c0, t0);
+  fp2_add(s, a.c0, a.c1);
+  fp2_add(u, b.c0, b.c1);
+  fp2_mul(c1, s, u);
+  fp2_sub(c1, c1, t0);
+  fp2_sub(c1, c1, t1);
+  fp2_mul_xi(s, t2);
+  fp2_add(c1, c1, s);
+  fp2_add(s, a.c0, a.c2);
+  fp2_add(u, b.c0, b.c2);
+  fp2_mul(c2, s, u);
+  fp2_sub(c2, c2, t0);
+  fp2_sub(c2, c2, t2);
+  fp2_add(c2, c2, t1);
+  r.c0 = c0;
+  r.c1 = c1;
+  r.c2 = c2;
+}
+static void fp6_inv(Fp6 &r, const Fp6 &a) {
+  Fp2 t0, t1, t2, s, d;
+  fp2_sqr(t0, a.c0);
+  fp2_mul(s, a.c1, a.c2);
+  fp2_mul_xi(s, s);
+  fp2_sub(t0, t0, s);
+  fp2_sqr(t1, a.c2);
+  fp2_mul_xi(t1, t1);
+  fp2_mul(s, a.c0, a.c1);
+  fp2_sub(t1, t1, s);
+  fp2_sqr(t2, a.c1);
+  fp2_mul(s, a.c0, a.c2);
+  fp2_sub(t2, t2, s);
+  fp2_mul(d, a.c0, t0);
+  fp2_mul(s, a.c2, t1);
+  fp2_mul_xi(s, s);
+  fp2_add(d, d, s);
+  fp2_mul(s, a.c1, t2);
+  fp2_mul_xi(s, s);
+  fp2_add(d, d, s);
+  fp2_inv(d, d);
+  fp2_mul(r.c0, t0, d);
+  fp2_mul(r.c1, t1, d);
+  fp2_mul(r.c2, t2, d);
+}
+
+static void fp12_mul(Fp12 &r, const Fp12 &a, const Fp12 &b) {
+  Fp6 t0, t1, s, u, c1;
+  fp6_mul(t0, a.c0, b.c0);
+  fp6_mul(t1, a.c1, b.c1);
+  fp6_add(s, a.c0, a.c1);
+  fp6_add(u, b.c0, b.c1);
+  fp6_mul(c1, s, u);
+  fp6_sub(c1, c1, t0);
+  fp6_sub(c1, c1, t1);
+  fp6_mul_v(t1, t1);
+  fp6_add(r.c0, t0, t1);
+  r.c1 = c1;
+}
+static void fp12_sqr(Fp12 &r, const Fp12 &a) {
+  // (a0 + a1 w)^2 = (a0 + a1)(a0 + v a1) - t - v t + 2 t w,  t = a0 a1
+  Fp6 t, s, u, vt;
+  fp6_mul(t, a.c0, a.c1);
+  fp6_add(s, a.c0, a.c1);
+  fp6_mul_v(u, a.c1);
+  fp6_add(u, u, a.c0);
+  fp6_mul(s, s, u);
+  fp6_sub(s, s, t);
+  fp6_mul_v(vt, t);
+  fp6_sub(r.c0, s, vt);
+  fp6_add(r.c1, t, t);
+}
+static void fp12_inv(Fp12 &r, const Fp12 &a) {
+  Fp6 d, s;
+  fp6_mul(d, a.c0, a.c0);
+  fp6_mul(s, a.c1, a.c1);
+  fp6_mul_v(s, s);
+  fp6_sub(d, d, s);
+  fp6_inv(d, d);
+  fp6_mul(r.c0, a.c0, d);
+  fp6_mul(s, a.c1, d);
+  fp6_neg(r.c1, s);
+}
+static void fp12_one(Fp12 &r) {
+  memset(&r, 0, sizeof(r));
+  memcpy(r.c0.c0.c0, ONE_MONT, 32);
+}
+static bool fp12_is_one(const Fp12 &a) {
+  Fp12 one;
+  fp12_one(one);
+  return !memcmp(&a, &one, sizeof(one));  // every limb is canonical (< p)
+}
+
+// What the curve fixes, computed (not transcribed) on first use.  An
+// element of Fq12 is sum a_i w^i over Fq2 (a_i = c0.c0, c1.c0, c0.c1,
+// c1.c1, c0.c2, c1.c2) and w^6 = xi, so x -> x^p sends a_i w^i to
+// conj(a_i) g^i w^i with g = xi^((p-1)/6), and x -> x^(p^2) sends it to
+// a_i N(g)^i w^i, N(g) = g conj(g) in Fq.
+struct PairingConsts {
+  u64 b[4];        // 3: the curve is y^2 = x^3 + 3
+  Fp2 twist_b;     // 3 / xi: the twist is y^2 = x^3 + 3/xi
+  Fp2 frob[6];     // g^i
+  u64 frob2[6][4]; // N(g)^i
+};
+static PairingConsts make_pairing_consts() {
+  PairingConsts k;
+  Fp2 xi, three;
+  memset(&xi, 0, sizeof(xi));
+  memcpy(xi.c0, ONE_MONT, 32);
+  fp2_mul_xi(xi, xi);
+  add_mod(k.b, ONE_MONT, ONE_MONT);
+  add_mod(k.b, k.b, ONE_MONT);
+  memset(&three, 0, sizeof(three));
+  memcpy(three.c0, k.b, 32);
+  fp2_inv(k.twist_b, xi);
+  fp2_mul(k.twist_b, k.twist_b, three);
+  // e = (p - 1) / 6, by long division from the top limb
+  u64 e[4];
+  u128 rem = 0;
+  for (int i = 3; i >= 0; --i) {
+    u128 cur = (rem << 64) | (i ? P[i] : P[i] - 1);
+    e[i] = (u64)(cur / 6);
+    rem = cur % 6;
+  }
+  Fp2 g;
+  memset(&g, 0, sizeof(g));
+  memcpy(g.c0, ONE_MONT, 32);
+  for (int i = 255; i >= 0; --i) {
+    fp2_sqr(g, g);
+    if ((e[i / 64] >> (i % 64)) & 1) fp2_mul(g, g, xi);
+  }
+  memset(&k.frob[0], 0, sizeof(Fp2));
+  memcpy(k.frob[0].c0, ONE_MONT, 32);
+  for (int i = 1; i < 6; ++i) fp2_mul(k.frob[i], k.frob[i - 1], g);
+  for (int i = 0; i < 6; ++i) {
+    Fp2 c, n;
+    fp2_conj(c, k.frob[i]);
+    fp2_mul(n, k.frob[i], c);
+    memcpy(k.frob2[i], n.c0, 32);
+  }
+  return k;
+}
+static const PairingConsts &pairing_consts() {
+  static const PairingConsts k = make_pairing_consts();  // thread-safe: C++11 static
+  return k;
+}
+
+static void fp12_frobenius2(Fp12 &r, const Fp12 &a) {
+  const PairingConsts &k = pairing_consts();
+  fp2_scale(r.c0.c0, a.c0.c0, k.frob2[0]);
+  fp2_scale(r.c1.c0, a.c1.c0, k.frob2[1]);
+  fp2_scale(r.c0.c1, a.c0.c1, k.frob2[2]);
+  fp2_scale(r.c1.c1, a.c1.c1, k.frob2[3]);
+  fp2_scale(r.c0.c2, a.c0.c2, k.frob2[4]);
+  fp2_scale(r.c1.c2, a.c1.c2, k.frob2[5]);
+}
+
+// (p^4 - p^2 + 1) / r, 761 bits, little-endian limbs: the hard part of
+// the final exponent, as pairing.py's final_exponentiation computes it.
+// A wrong limb makes every valid product read "not one": the load-time
+// self-check of native/lib.py and tests/test_pairing.py hold it.
+static const u64 FINAL_EXP_HARD[12] = {
+    0xe81bb482ccdf42b1ULL, 0x5abf5cc4f49c36d4ULL, 0xf1154e7e1da014fdULL,
+    0xdcc7b44c87cdbacfULL, 0xaaa441e3954bcf8aULL, 0x6b887d56d5095f23ULL,
+    0x79581e16f3fd90c6ULL, 0x3b1b1355d189227dULL, 0x4e529a5861876f6bULL,
+    0x6c0eb522d5b12278ULL, 0x331ec15183177fafULL, 0x01baaa710b0759adULL};
+
+// f^((p^12 - 1) / r) == 1
+static bool final_exponentiation_is_one(const Fp12 &f) {
+  Fp12 f1, f2, t, acc;
+  // easy: f^((p^6 - 1)(p^2 + 1)); x -> x^(p^6) negates the w coefficient
+  fp12_inv(t, f);
+  f1.c0 = f.c0;
+  fp6_neg(f1.c1, f.c1);
+  fp12_mul(f1, f1, t);
+  fp12_frobenius2(f2, f1);
+  fp12_mul(f2, f2, f1);
+  fp12_one(acc);
+  for (int i = 760; i >= 0; --i) {
+    fp12_sqr(acc, acc);
+    if ((FINAL_EXP_HARD[i / 64] >> (i % 64)) & 1) fp12_mul(acc, acc, f2);
+  }
+  return fp12_is_one(acc);
+}
+
+struct G2Aff {
+  Fp2 x, y;  // Montgomery; all-zero = infinity
+};
+static inline bool g2aff_is_inf(const G2Aff &q) {
+  return fp2_is_zero(q.x) && fp2_is_zero(q.y);
+}
+
+// Invert d[0..n) in place with one field inversion; false if any is 0.
+static bool fp2_batch_inv(Fp2 *d, int n) {
+  std::vector<Fp2> pre(n);
+  Fp2 acc;
+  memset(&acc, 0, sizeof(acc));
+  memcpy(acc.c0, ONE_MONT, 32);
+  for (int i = 0; i < n; ++i) {
+    if (fp2_is_zero(d[i])) return false;
+    pre[i] = acc;
+    fp2_mul(acc, acc, d[i]);
+  }
+  fp2_inv(acc, acc);
+  for (int i = n - 1; i >= 0; --i) {
+    Fp2 inv;
+    fp2_mul(inv, acc, pre[i]);
+    fp2_mul(acc, acc, d[i]);
+    d[i] = inv;
+  }
+  return true;
+}
+
+// One step of every pair's Miller loop: T_i <- T_i + S_i (or 2 T_i where
+// S is null), and f <- f * prod_i line_i(P_i).  With lambda the slope in
+// Fq2 of the chord (tangent) on the twist, the line through the
+// untwisted points evaluated at P = (xP, yP) is
+//     yP  -  lambda xP w  +  (lambda xT - yT) w^3
+// (pairing.py's `_line`: (py - y1) - lam (px - x1) with x1 = xT w^2,
+// y1 = yT w^3 and lam = lambda w).  False on a vertical line or a tangent
+// at y = 0: no step of a loop over points of order r meets either, and
+// pairing.py has no answer there either.
+static bool miller_step(Fp12 &f, std::vector<G2Aff> &T, const G2Aff *S, const G1Aff *P,
+                        bool update) {
+  int n = (int)T.size();
+  std::vector<Fp2> den(n);
+  for (int i = 0; i < n; ++i) {
+    if (S) {
+      fp2_sub(den[i], S[i].x, T[i].x);
+    } else {
+      fp2_add(den[i], T[i].y, T[i].y);
+    }
+  }
+  if (!fp2_batch_inv(den.data(), n)) return false;
+  for (int i = 0; i < n; ++i) {
+    Fp2 lam, t;
+    if (S) {
+      fp2_sub(lam, S[i].y, T[i].y);
+    } else {
+      fp2_sqr(t, T[i].x);
+      fp2_add(lam, t, t);
+      fp2_add(lam, lam, t);
+    }
+    fp2_mul(lam, lam, den[i]);
+    Fp12 line;
+    memset(&line, 0, sizeof(line));
+    memcpy(line.c0.c0.c0, P[i].y, 32);
+    fp2_scale(t, lam, P[i].x);
+    fp2_neg(line.c1.c0, t);
+    fp2_mul(t, lam, T[i].x);
+    fp2_sub(line.c1.c1, t, T[i].y);
+    fp12_mul(f, f, line);
+    if (update) {
+      Fp2 x3, y3;
+      fp2_sqr(x3, lam);
+      fp2_sub(x3, x3, T[i].x);
+      fp2_sub(x3, x3, S ? S[i].x : T[i].x);
+      fp2_sub(t, T[i].x, x3);
+      fp2_mul(y3, lam, t);
+      fp2_sub(y3, y3, T[i].y);
+      T[i].x = x3;
+      T[i].y = y3;
+    }
+  }
+  return true;
+}
+
+// prod_i e(P_i, Q_i) == 1 over affine Montgomery points (all-zero =
+// infinity: that pair contributes 1, as pairing.py's miller_loop has
+// it), one final exponentiation for all.  Points are taken as given: the
+// caller has checked what it needs of them.  1 = one, 0 = not one,
+// -1 = a degenerate step (see miller_step).
+static int pairing_product_is_one(const G1Aff *Ps, const G2Aff *Qs, int n) {
+  const PairingConsts &k = pairing_consts();
+  std::vector<G1Aff> P;
+  std::vector<G2Aff> Q, Q1, Q2;
+  for (int i = 0; i < n; ++i) {
+    if ((is_zero4(Ps[i].x) && is_zero4(Ps[i].y)) || g2aff_is_inf(Qs[i])) continue;
+    P.push_back(Ps[i]);
+    Q.push_back(Qs[i]);
+    // pi(Q) = (conj(x) g^2, conj(y) g^3);  -pi^2(Q) = (x N(g)^2, -y N(g)^3)
+    G2Aff q1, q2;
+    fp2_conj(q1.x, Qs[i].x);
+    fp2_mul(q1.x, q1.x, k.frob[2]);
+    fp2_conj(q1.y, Qs[i].y);
+    fp2_mul(q1.y, q1.y, k.frob[3]);
+    fp2_scale(q2.x, Qs[i].x, k.frob2[2]);
+    fp2_scale(q2.y, Qs[i].y, k.frob2[3]);
+    fp2_neg(q2.y, q2.y);
+    Q1.push_back(q1);
+    Q2.push_back(q2);
+  }
+  Fp12 f;
+  fp12_one(f);
+  if (Q.empty()) return 1;
+  std::vector<G2Aff> T = Q;
+  // 6u + 2 = 29793968203157093288 (65 bits), from the bit under the top
+  static const u64 ATE_LOOP_LOW = 0x9d797039be763ba8ULL;
+  for (int bit = 63; bit >= 0; --bit) {
+    fp12_sqr(f, f);
+    if (!miller_step(f, T, nullptr, P.data(), true)) return -1;
+    if ((ATE_LOOP_LOW >> bit) & 1) {
+      if (!miller_step(f, T, Q.data(), P.data(), true)) return -1;
+    }
+  }
+  if (!miller_step(f, T, Q1.data(), P.data(), true)) return -1;
+  if (!miller_step(f, T, Q2.data(), P.data(), false)) return -1;
+  return final_exponentiation_is_one(f) ? 1 : 0;
+}
+
+// Standard-form limbs -> Montgomery, refusing a coordinate >= p.
+static bool fp_load(u64 out[4], const u64 *in) {
+  if (geq(in, P)) return false;
+  mont_mul(out, in, R2P);
+  return true;
+}
+static bool g1_load(G1Aff &p, const u64 *in) {
+  return fp_load(p.x, in) && fp_load(p.y, in + 4);
+}
+static bool g2_load(G2Aff &q, const u64 *in) {
+  return fp_load(q.x.c0, in) && fp_load(q.x.c1, in + 4) && fp_load(q.y.c0, in + 8) &&
+         fp_load(q.y.c1, in + 12);
+}
+static bool g1_on_curve(const G1Aff &p) {
+  if (is_zero4(p.x) && is_zero4(p.y)) return true;
+  u64 l[4], r[4];
+  mont_sqr(l, p.y);
+  mont_sqr(r, p.x);
+  mont_mul(r, r, p.x);
+  add_mod(r, r, pairing_consts().b);
+  return !memcmp(l, r, 32);
+}
+static bool g2_on_twist(const G2Aff &q) {
+  if (g2aff_is_inf(q)) return true;
+  Fp2 l, r;
+  fp2_sqr(l, q.y);
+  fp2_sqr(r, q.x);
+  fp2_mul(r, r, q.x);
+  fp2_add(r, r, pairing_consts().twist_b);
+  return fp2_eq(l, r);
+}
+// [r] Q == O: the twist's cofactor is large, so on the twist is not in G2
+static bool g2_in_subgroup(const G2Aff &q) {
+  G2Jac acc, t;
+  memset(&acc, 0, sizeof(acc));
+  for (int i = 253; i >= 0; --i) {
+    g2_double(t, acc);
+    acc = t;
+    if ((R_MOD[i / 64] >> (i % 64)) & 1) {
+      g2_add_mixed(t, acc, q.x, q.y);
+      acc = t;
+    }
+  }
+  return fp2_is_zero(acc.Z);
+}
+
+extern "C" {
+
+// prod_i e(P_i, Q_i) == 1 (see pairing_product_is_one above) on
+// standard-form affine points: g1s n * 8 u64 (x, y), g2s n * 16 u64
+// (x.c0, x.c1, y.c0, y.c1), all-zero = infinity.  Nothing is checked of
+// the points but that every coordinate is below p.  1 = one, 0 = not
+// one, -1 = a coordinate >= p or a degenerate step.
+int bn254_pairing_product_is_one(const u64 *g1s, const u64 *g2s, int n) {
+  std::vector<G1Aff> Ps(n > 0 ? n : 0);
+  std::vector<G2Aff> Qs(n > 0 ? n : 0);
+  for (int i = 0; i < n; ++i) {
+    if (!g1_load(Ps[i], g1s + 8 * i) || !g2_load(Qs[i], g2s + 16 * i)) return -1;
+  }
+  return pairing_product_is_one(Ps.data(), Qs.data(), n);
+}
+
+// The whole of snark/groth16.py's verify: 1 where
+//   e(-A, B) e(alpha, beta) e(vk_x, gamma) e(C, delta) = 1
+// with A, C on the curve, B on the twist and [r] B = O,
+// vk_x = ic[0] + sum_i pub[i] ic[i + 1]; else 0.  0 also wherever this
+// function does not decide — a wrong number of public inputs, a
+// coordinate >= p, a key point off its curve, a degenerate Miller step —
+// and the caller asks the Python function, whose answer (or exception)
+// is the service's.  vk: alpha (8 u64), beta, gamma, delta (16 each),
+// then n_ic ic points (8 each); proof: a (8), b (16), c (8); pub:
+// n_pub * 4 u64, any 256-bit value.  Standard form, all-zero = infinity.
+int groth16_verify_bn254(const u64 *vk, int n_public, int n_ic, const u64 *proof,
+                         const u64 *pub, int n_pub) {
+  if (n_pub != n_public || n_pub < 0 || n_ic < n_pub + 1) return 0;
+  G1Aff A, C, alpha, vkx;
+  G2Aff B, beta, gamma, delta;
+  if (!g1_load(A, proof) || !g2_load(B, proof + 8) || !g1_load(C, proof + 24)) return 0;
+  if (!g1_load(alpha, vk) || !g2_load(beta, vk + 8) || !g2_load(gamma, vk + 24) ||
+      !g2_load(delta, vk + 40))
+    return 0;
+  const u64 *ic_std = vk + 56;
+  std::vector<G1Aff> ic(n_pub + 1);
+  for (int i = 0; i <= n_pub; ++i) {
+    if (!g1_load(ic[i], ic_std + 8 * i) || !g1_on_curve(ic[i])) return 0;
+  }
+  if (!g1_on_curve(A) || !g1_on_curve(C) || !g1_on_curve(alpha)) return 0;
+  if (!g2_on_twist(B) || !g2_in_subgroup(B)) return 0;
+  if (!g2_on_twist(beta) || !g2_on_twist(gamma) || !g2_on_twist(delta)) return 0;
+  // vk_x: one doubling chain for all the public inputs
+  G1Jac acc, t;
+  memset(&acc, 0, sizeof(acc));
+  for (int bit = 255; bit >= 0; --bit) {
+    jac_double(t, acc);
+    acc = t;
+    for (int i = 0; i < n_pub; ++i) {
+      if ((pub[4 * i + bit / 64] >> (bit % 64)) & 1) {
+        jac_add_mixed(t, acc, ic[i + 1].x, ic[i + 1].y);
+        acc = t;
+      }
+    }
+  }
+  jac_add_mixed(t, acc, ic[0].x, ic[0].y);
+  memset(&vkx, 0, sizeof(vkx));
+  if (!is_zero4(t.Z)) {
+    u64 zi[4], zi2[4], zi3[4];
+    mont_inv(zi, t.Z);
+    mont_sqr(zi2, zi);
+    mont_mul(zi3, zi2, zi);
+    mont_mul(vkx.x, t.X, zi2);
+    mont_mul(vkx.y, t.Y, zi3);
+  }
+  fp_neg(A.y, A.y);
+  const G1Aff Ps[4] = {A, alpha, vkx, C};
+  const G2Aff Qs[4] = {B, beta, gamma, delta};
+  return pairing_product_is_one(Ps, Qs, 4) == 1 ? 1 : 0;
+}
+
+}  // extern "C"

@@ -105,3 +105,177 @@ def test_setup_uses_native_and_matches():
     assert pk1.a_query == pk2.a_query
     assert vk1.ic == vk2.ic
     assert pk1.h_query == pk2.h_query
+
+
+# ------------------------------------------------ the sample verify, native
+#
+# csrc groth16_verify_bn254 against snark.groth16.verify (the oracle): the
+# library's answer alone (`verify_native`), so an agreement here is not the
+# oracle agreeing with itself.
+
+
+def _toy(seed: str):
+    """(vk, a valid proof, its public inputs) of a two-public toy circuit."""
+    from zkp2p_tpu.snark.groth16 import prove_host, setup
+    from zkp2p_tpu.snark.r1cs import LC, ConstraintSystem
+
+    r = random.Random(seed)
+    cs = ConstraintSystem("verify-" + seed)
+    out, sq = cs.new_public("out"), cs.new_public("sq")
+    x, y = cs.new_wire("x"), cs.new_wire("y")
+    cs.enforce(LC.of(x), LC.of(y), LC.of(out), "mul")
+    cs.enforce(LC.of(x), LC.of(x), LC.of(sq), "sq")
+    xv, yv = r.randrange(R), r.randrange(R)
+    public = [xv * yv % R, xv * xv % R]
+    w = cs.witness(public, {x: xv, y: yv})
+    pk, vk = setup(cs, seed=seed)
+    return vk, prove_host(pk, cs, w, r=r.randrange(1, R), s=r.randrange(1, R)), public
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return _toy("toy")
+
+
+def _off_subgroup_twist_point():
+    """A point of the twist that [R] does not kill (the cofactor is 2p - r:
+    almost every point of the twist is one)."""
+    from zkp2p_tpu.curve.host import TWIST_B, g2_mul
+    from zkp2p_tpu.field.tower import Fq2
+    from zkp2p_tpu.snark.ceremony import _fq2_sqrt
+
+    x0 = 1
+    while True:
+        x = Fq2(x0, 1)
+        y = _fq2_sqrt(x * x * x + TWIST_B)
+        if y is not None and g2_mul((x, y), R) is not None:
+            return (x, y)
+        x0 += 1
+
+
+def _tampered(case, vk, proof, public):
+    from dataclasses import replace
+
+    from zkp2p_tpu.curve.host import G2_GENERATOR, g1_add, g2_add
+    from zkp2p_tpu.field.tower import Fq2
+
+    if case == "valid":
+        return proof, public
+    if case == "A+G":
+        return replace(proof, a=g1_add(proof.a, G1_GENERATOR)), public
+    if case == "B+G":
+        return replace(proof, b=g2_add(proof.b, G2_GENERATOR)), public
+    if case == "C+G":
+        return replace(proof, c=g1_add(proof.c, G1_GENERATOR)), public
+    if case == "public+1":
+        return proof, [public[0], public[1] + 1]
+    if case == "public+R":  # the same input mod R, as the oracle reduces it
+        return proof, [public[0] + R, public[1] - R]
+    if case == "A-off-curve":
+        return replace(proof, a=(proof.a[0], (proof.a[1] + 1) % P)), public
+    if case == "C-off-curve":
+        return replace(proof, c=((proof.c[0] + 1) % P, proof.c[1])), public
+    if case == "B-off-twist":
+        return replace(proof, b=(proof.b[0], proof.b[1] + Fq2(1, 0))), public
+    if case == "B-off-subgroup":
+        return replace(proof, b=_off_subgroup_twist_point()), public
+    if case == "A-infinity":
+        return replace(proof, a=None), public
+    if case == "B-infinity":
+        return replace(proof, b=None), public
+    if case == "C-infinity":
+        return replace(proof, c=None), public
+    if case == "A-zero-zero":  # not the point at infinity: (0, 0) is off the curve
+        return replace(proof, a=(0, 0)), public
+    if case == "A-unreduced":  # the same point to the oracle, a coordinate the library refuses
+        return replace(proof, a=(proof.a[0] + P, proof.a[1])), public
+    if case == "too-few":
+        return proof, public[:1]
+    if case == "too-many":
+        return proof, public + [1]
+    raise AssertionError(case)
+
+
+_CASES = {
+    "valid": True, "A+G": False, "B+G": False, "C+G": False, "public+1": False, "public+R": True,
+    "A-off-curve": False, "C-off-curve": False, "B-off-twist": False, "B-off-subgroup": False,
+    "A-infinity": False, "B-infinity": False, "C-infinity": False, "A-zero-zero": False,
+    "A-unreduced": True, "too-few": False, "too-many": False,
+}
+
+
+@pytest.mark.parametrize("case", list(_CASES))
+def test_native_verify_answers_as_the_oracle(toy, case):
+    """Every way a sample can be wrong: the library refuses it (it never
+    raises), and the chooser hands the caller the oracle's answer."""
+    from zkp2p_tpu.snark import native_verify
+    from zkp2p_tpu.snark.groth16 import verify
+
+    vk, proof, public = toy
+    proof, public = _tampered(case, vk, proof, public)
+    want = verify(vk, proof, public)
+    assert want is _CASES[case]
+    got = native_verify.verify_native(native.get_lib(), vk, proof, public)
+    # the one case the library leaves to the oracle though the proof is good
+    assert got is (want and case != "A-unreduced")
+    assert native_verify.verify(vk, proof, public, "native") == (want, want and not got)
+    assert native_verify.verify(vk, proof, public, "python") == (want, False)
+
+
+@pytest.mark.parametrize("case", ["alpha-off-curve", "gamma-off-twist", "ic-off-curve", "ic-short"])
+def test_native_verify_leaves_a_bad_key_to_the_oracle(toy, case):
+    from dataclasses import replace
+
+    from zkp2p_tpu.field.tower import Fq2
+    from zkp2p_tpu.snark import native_verify
+
+    vk, proof, public = toy
+    if case == "alpha-off-curve":
+        bad = replace(vk, alpha_1=(vk.alpha_1[0], (vk.alpha_1[1] + 1) % P))
+    elif case == "gamma-off-twist":
+        bad = replace(vk, gamma_2=(vk.gamma_2[0], vk.gamma_2[1] + Fq2(0, 1)))
+    elif case == "ic-off-curve":
+        bad = replace(vk, ic=[vk.ic[0], vk.ic[1], (vk.ic[2][0], (vk.ic[2][1] + 1) % P)])
+    else:
+        bad = replace(vk, ic=vk.ic[:2])
+    assert native_verify.verify_native(native.get_lib(), bad, proof, public) is False
+
+
+@pytest.mark.parametrize("seed", range(16))
+def test_native_verify_agrees_over_seeded_triples(seed):
+    """A fresh (vk, proof, public) a seed, valid and tampered one of four
+    ways: thirty-two triples, the two functions asked each."""
+    from zkp2p_tpu.snark import native_verify
+    from zkp2p_tpu.snark.groth16 import verify
+
+    vk, proof, public = _toy(f"triple-{seed}")
+    lib = native.get_lib()
+    assert native_verify.verify_native(lib, vk, proof, public) is True
+    assert verify(vk, proof, public) is True
+    case = ("A+G", "B+G", "C+G", "public+1")[seed % 4]
+    bad_proof, bad_public = _tampered(case, vk, proof, public)
+    assert native_verify.verify_native(lib, vk, bad_proof, bad_public) is False, case
+    assert verify(vk, bad_proof, bad_public) is False, case
+
+
+def test_verify_key_of_another_setup_is_refused(toy):
+    from zkp2p_tpu.snark import native_verify
+
+    vk, proof, public = toy
+    other_vk, _, _ = _toy("another")
+    assert native_verify.verify_native(native.get_lib(), other_vk, proof, public) is False
+
+
+def test_load_time_self_check_refuses_a_wrong_tower(monkeypatch):
+    """`get_lib` asks the library one pairing identity both ways: a
+    library whose product check answers wrongly is no library."""
+    import zkp2p_tpu.native.lib as nl
+    from zkp2p_tpu.snark import native_verify
+
+    for wrong in (lambda lib, pairs: True, lambda lib, pairs: False):
+        monkeypatch.setattr(native_verify, "pairing_product_is_one", wrong)
+        monkeypatch.setattr(nl, "_lib", None)
+        monkeypatch.setattr(nl, "_tried", False)
+        assert nl.get_lib() is None
+    monkeypatch.undo()
+    assert nl.get_lib() is not None

@@ -5,8 +5,10 @@ and runs a small-but-representative G1 MSM parity check against the host
 oracle INSIDE it: enough points and window width to drive the
 batch-affine bucket fill (its shared-inversion scratch buffers are the
 new-code risk this guards), the Jacobian A/B arm, the GLV driver, and
-the persistent worker pool — all under `-fno-sanitize-recover`, so any
-ASan/UBSan report aborts the subprocess and fails the test.
+the persistent worker pool, and the service's sample verify
+(`groth16_verify_bn254`: the Fq12 tower and the Miller loops) — all
+under `-fno-sanitize-recover`, so any ASan/UBSan report aborts the
+subprocess and fails the test.
 
 The python interpreter is NOT instrumented, so the library must be
 loaded with libasan LD_PRELOADed — hence the subprocess (slow tier; run
@@ -309,6 +311,38 @@ ref8 = r8lad[("0", "1")]
 for key, d in r8lad.items():
     assert np.array_equal(d, ref8), ("radix8 ladder diverged", key)
 print("ok ntt_radix8", flush=True)
+
+# the sample verify (groth16_verify_bn254): the Fq12 tower, the Miller
+# loops' per-step vectors and the lazily built curve constants are its
+# allocation surface.  An instance made from known exponents, so nothing
+# but the host curve oracle is imported: A = r G1, B = s G2 and
+# C = (r s - alpha beta - vk_x gamma) / delta G1 satisfy the equation.
+from zkp2p_tpu.curve.host import G2_GENERATOR, g1_add, g2_mul
+from zkp2p_tpu.snark.groth16 import Proof, VerifyingKey
+from zkp2p_tpu.snark.native_verify import pairing_product_is_one, verify_native
+lib.bn254_pairing_product_is_one.argtypes = [u64p, u64p, ctypes.c_int]
+lib.groth16_verify_bn254.argtypes = [u64p, ctypes.c_int, ctypes.c_int, u64p, u64p, ctypes.c_int]
+va, vb, vg, vd, vr, vs = (rng.randrange(1, R) for _ in range(6))
+v_ic = [rng.randrange(1, R) for _ in range(3)]
+v_pub = [rng.randrange(R), 0]
+v_x = (v_ic[0] + v_pub[0] * v_ic[1] + v_pub[1] * v_ic[2]) % R
+v_c = (vr * vs - va * vb - v_x * vg) * pow(vd, -1, R) % R
+v_vk = VerifyingKey(
+    n_public=2, alpha_1=g1_mul(G1_GENERATOR, va), beta_2=g2_mul(G2_GENERATOR, vb),
+    gamma_2=g2_mul(G2_GENERATOR, vg), delta_2=g2_mul(G2_GENERATOR, vd),
+    ic=[g1_mul(G1_GENERATOR, k) for k in v_ic])
+v_good = Proof(a=g1_mul(G1_GENERATOR, vr), b=g2_mul(G2_GENERATOR, vs), c=g1_mul(G1_GENERATOR, v_c))
+v_bad = Proof(a=v_good.a, b=v_good.b, c=g1_add(v_good.c, G1_GENERATOR))
+v_inf = Proof(a=None, b=None, c=None)
+assert verify_native(lib, v_vk, v_good, v_pub) is True
+assert verify_native(lib, v_vk, v_bad, v_pub) is False
+assert verify_native(lib, v_vk, v_inf, v_pub) is False
+assert verify_native(lib, v_vk, v_good, v_pub[:1]) is False
+assert verify_native(lib, v_vk, v_good, [v_pub[0] + 1, 0]) is False
+assert pairing_product_is_one(lib, [])
+assert pairing_product_is_one(lib, [(None, G2_GENERATOR), (G1_GENERATOR, None)])
+assert not pairing_product_is_one(lib, [(G1_GENERATOR, G2_GENERATOR)])
+print("ok groth16_verify", flush=True)
 
 lib.zkp2p_pool_shutdown()
 print("ASAN-PARITY-GREEN", flush=True)

@@ -23,7 +23,10 @@ oracle so a silently-wrong result fails even where no race is reported:
   * pool-parallel NTT stages + fused coset ladder (ZKP2P_NTT_POOL=1);
   * segmented matvec at threads=2 (conflict-free by construction — the
     claim TSan now checks);
-  * multi-column MSM from two concurrent submitters.
+  * multi-column MSM from two concurrent submitters;
+  * the sample verify (`groth16_verify_bn254`) from two concurrent
+    submitters: four replicas of one process call it at once, and its
+    curve constants are built on first use.
 
 The python interpreter is NOT instrumented, so libtsan must be
 LD_PRELOADed (same pattern as the ASan smoke; TSan only tracks
@@ -213,6 +216,44 @@ for r8 in ("1", "0"):
     r8lad[r8] = d
 assert np.array_equal(r8lad["1"], r8lad["0"]), "radix-8 ladder != radix-4 ladder"
 print("ok ntt_radix8", flush=True)
+
+# the sample verify (groth16_verify_bn254): the Fq12 tower, the Miller
+# loops' per-step vectors and the lazily built curve constants are its
+# allocation surface.  An instance made from known exponents, so nothing
+# but the host curve oracle is imported: A = r G1, B = s G2 and
+# C = (r s - alpha beta - vk_x gamma) / delta G1 satisfy the equation.
+from zkp2p_tpu.curve.host import G2_GENERATOR, g1_add, g2_mul
+from zkp2p_tpu.snark.groth16 import Proof, VerifyingKey
+from zkp2p_tpu.snark.native_verify import pairing_product_is_one, verify_native
+lib.bn254_pairing_product_is_one.argtypes = [u64p, u64p, ctypes.c_int]
+lib.groth16_verify_bn254.argtypes = [u64p, ctypes.c_int, ctypes.c_int, u64p, u64p, ctypes.c_int]
+va, vb, vg, vd, vr, vs = (rng.randrange(1, R) for _ in range(6))
+v_ic = [rng.randrange(1, R) for _ in range(3)]
+v_pub = [rng.randrange(R), 0]
+v_x = (v_ic[0] + v_pub[0] * v_ic[1] + v_pub[1] * v_ic[2]) % R
+v_c = (vr * vs - va * vb - v_x * vg) * pow(vd, -1, R) % R
+v_vk = VerifyingKey(
+    n_public=2, alpha_1=g1_mul(G1_GENERATOR, va), beta_2=g2_mul(G2_GENERATOR, vb),
+    gamma_2=g2_mul(G2_GENERATOR, vg), delta_2=g2_mul(G2_GENERATOR, vd),
+    ic=[g1_mul(G1_GENERATOR, k) for k in v_ic])
+v_good = Proof(a=g1_mul(G1_GENERATOR, vr), b=g2_mul(G2_GENERATOR, vs), c=g1_mul(G1_GENERATOR, v_c))
+v_bad = Proof(a=v_good.a, b=v_good.b, c=g1_add(v_good.c, G1_GENERATOR))
+v_inf = Proof(a=None, b=None, c=None)
+# two submitters at once: the first calls race to build the constants (a
+# C++11 static) and every call after shares them read-only
+def verifier(tag):
+    try:
+        for _ in range(3):
+            assert verify_native(lib, v_vk, v_good, v_pub) is True, tag
+            assert verify_native(lib, v_vk, v_bad, v_pub) is False, tag
+    except Exception as e:  # noqa: BLE001
+        errors.append((tag, e))
+
+ts = [threading.Thread(target=verifier, args=(f"verify{i}",)) for i in range(2)]
+for t in ts: t.start()
+for t in ts: t.join()
+assert not errors, errors
+print("ok groth16_verify", flush=True)
 
 stop.set()
 rd.join()

@@ -726,3 +726,137 @@ def test_terminal_output_wins_over_stale_claim(world, tmp_path):
     assert not any(stats.values())
     assert os.path.getmtime(os.path.join(spool, "r0.proof.json")) == proof_mtime
     assert not os.path.exists(claim)
+
+
+# ------------------------------------------------------ the sample verify
+#
+# One check a prover call, by the path the service observes (the native
+# library loaded, or not): snark.native_verify.  The Python function is
+# the oracle on both.
+
+PATHS = ["native", "python"]
+
+
+@pytest.fixture
+def verify_path(request, monkeypatch):
+    """The path under test, and every call the Python oracle gets."""
+    from zkp2p_tpu.snark import native_verify
+
+    if request.param == "python":
+        monkeypatch.setattr(native_verify, "_native", lambda: None)
+    spoken = []
+    oracle = native_verify.verify_python
+
+    def listening(vk, proof, public):
+        spoken.append(oracle(vk, proof, public))
+        return spoken[-1]
+
+    monkeypatch.setattr(native_verify, "verify_python", listening)
+    return request.param, spoken
+
+
+def _bad_first_proof(dpk_, wits):
+    """Every proof good but the sample: A moved off by the generator."""
+    from dataclasses import replace
+
+    from zkp2p_tpu.curve.host import G1_GENERATOR, g1_add
+
+    proofs = _prove_batch(dpk_, wits)
+    return [replace(proofs[0], a=g1_add(proofs[0].a, G1_GENERATOR))] + proofs[1:]
+
+
+def _verify_spans(spool):
+    return [s for r in _records(spool) for s in r["spans"] if s["name"] == "verify"]
+
+
+@pytest.mark.parametrize("verify_path", PATHS, indirect=True)
+def test_sample_verify_says_which_path_ran(world, tmp_path, verify_path):
+    path, spoken = verify_path
+    spool = str(tmp_path)
+    _write_reqs(spool, [(3, 5), (2, 7)])
+    v0 = _counter("zkp2p_service_verify_total", path=path)
+    d0 = _counter("zkp2p_service_verify_disagree_total")
+    assert _mk(world).process_dir(spool)["done"] == 2
+    assert _counter("zkp2p_service_verify_total", path=path) - v0 == 1  # one a prover call, not one a proof
+    assert _counter("zkp2p_service_verify_disagree_total") == d0
+    spans = _verify_spans(spool)
+    assert len(spans) == 2 and all(s["path"] == path for s in spans)  # the batch's one span, on both records
+    # a native True is accepted: the oracle is asked only where it is the check
+    assert spoken == ([] if path == "native" else [True])
+
+
+@pytest.mark.parametrize("verify_path", PATHS, indirect=True)
+def test_bad_sample_is_refused_with_the_oracle_having_spoken(world, tmp_path, verify_path):
+    """The parent's error and the parent's ladder: the pair fails, is
+    bisected, each single fails, and nothing is written for either."""
+    path, spoken = verify_path
+    spool = str(tmp_path)
+    _write_reqs(spool, [(3, 5), (2, 7)])
+    b0 = _counter("zkp2p_service_bisections_total")
+    d0 = _counter("zkp2p_service_verify_disagree_total")
+    stats = _mk(world, prover_fn=_bad_first_proof).process_dir(spool)
+    assert stats["error-failed-to-prove"] == 2 and stats["done"] == 0
+    assert _counter("zkp2p_service_bisections_total") - b0 == 1
+    assert _counter("zkp2p_service_verify_disagree_total") == d0
+    assert spoken == [False, False, False]  # the pair, then each half
+    for i in range(2):
+        with open(os.path.join(spool, f"r{i}.error.json")) as f:
+            assert "sample proof failed verification" in json.load(f)["error"]
+        assert not os.path.exists(os.path.join(spool, f"r{i}.proof.json"))
+    assert all(s["path"] == path for s in _verify_spans(spool))
+
+
+@pytest.mark.parametrize("verify_path", PATHS, indirect=True)
+def test_bad_sample_walks_the_degradation_ladder(world, tmp_path, verify_path):
+    """A prover whose fast path yields a wrong sample is rescued on the
+    rung that turns the fast path off, as a prover that raises is."""
+    path, spoken = verify_path
+    spool = str(tmp_path)
+    _write_reqs(spool, [(3, 5)])
+
+    def multi_wrong_prover(dpk_, wits):
+        good = os.environ.get("ZKP2P_MSM_MULTI") == "0"
+        return (_prove_batch if good else _bad_first_proof)(dpk_, wits)
+
+    multi_wrong_prover.reads_msm_knobs = True
+    g0 = _counter("zkp2p_service_degraded_total", rung="no-multi")
+    v0 = _counter("zkp2p_service_verify_total", path=path)
+    stats = _mk(world, prover_fn=multi_wrong_prover, batch_size=1).process_dir(spool)
+    assert stats["done"] == 1
+    assert _counter("zkp2p_service_degraded_total", rung="no-multi") - g0 == 1
+    (rec,) = _records(spool)
+    assert rec["state"] == "done" and rec["degraded_rung"] == "no-multi"
+    # the plain call, the rung that changes nothing for this prover, the rung that passed
+    assert _counter("zkp2p_service_verify_total", path=path) - v0 == 3
+    assert spoken == ([False, False] if path == "native" else [False, False, True])
+    assert os.path.exists(os.path.join(spool, "r0.proof.json"))
+
+
+def test_native_false_the_oracle_overrules_is_counted_and_emitted(world, tmp_path, monkeypatch, capfd):
+    from zkp2p_tpu.snark import native_verify
+
+    monkeypatch.setattr(native_verify, "verify_native", lambda lib, vk, proof, public: False)
+    spool = str(tmp_path)
+    _write_reqs(spool, [(3, 5), (2, 7)])
+    d0 = _counter("zkp2p_service_verify_disagree_total")
+    assert _mk(world).process_dir(spool)["done"] == 2
+    assert _counter("zkp2p_service_verify_disagree_total") - d0 == 1
+    assert "the two disagree" in capfd.readouterr().err
+    assert all(os.path.exists(os.path.join(spool, f"r{i}.proof.json")) for i in range(2))
+
+
+@pytest.mark.parametrize("verify_path", PATHS, indirect=True)
+def test_verify_fault_site_fires_before_the_check(world, tmp_path, monkeypatch, verify_path):
+    path, spoken = verify_path
+    spool = str(tmp_path)
+    _write_reqs(spool, [(3, 5), (2, 7)])
+    monkeypatch.setenv("ZKP2P_FAULTS", "verify:raise:n=1")
+    faults.reset()
+    r0 = _counter("zkp2p_service_retries_total")
+    v0 = _counter("zkp2p_service_verify_total", path=path)
+    assert _mk(world, retries=1).process_dir(spool)["done"] == 2
+    assert _counter("zkp2p_service_retries_total") - r0 == 1
+    # the attempt the fault took never reached the check
+    assert _counter("zkp2p_service_verify_total", path=path) - v0 == 1
+    spans = _verify_spans(spool)
+    assert len(spans) == 4 and all(s["path"] == path for s in spans)

@@ -58,10 +58,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
     lib.g1_fixed_base_batch_mont.argtypes = [u64p, u64p, ctypes.c_int, u64p]
     lib.g2_fixed_base_batch_mont.argtypes = [u64p, u64p, ctypes.c_int, u64p]
     lib.fp_mul_std.argtypes = [u64p, u64p, u64p]
-    # Self-check before trusting it: one field mul against Python ints AND
-    # one fixed-base scalar mul against the host curve oracle, so a library
-    # with subtly wrong curve ops (used for trusted-setup point generation)
-    # is rejected, not just one with a broken multiplier.
+    lib.bn254_pairing_product_is_one.argtypes = [u64p, u64p, ctypes.c_int]
+    lib.bn254_pairing_product_is_one.restype = ctypes.c_int
+    lib.groth16_verify_bn254.argtypes = [u64p, ctypes.c_int, ctypes.c_int, u64p, u64p, ctypes.c_int]
+    lib.groth16_verify_bn254.restype = ctypes.c_int
+    # Self-check before trusting it: one field mul against Python ints, one
+    # fixed-base scalar mul against the host curve oracle AND one pairing
+    # identity, so a library with subtly wrong curve ops (used for
+    # trusted-setup point generation) or a wrong tower (used for the
+    # service's sample verify) is rejected, not just one with a broken
+    # multiplier.
     from ..field.bn254 import P
 
     a, b = 0x1234567890ABCDEF << 120 | 0x42, P - 12345
@@ -77,6 +83,17 @@ def get_lib() -> Optional[ctypes.CDLL]:
     k = 0xDEADBEEFCAFEF00D1234567890ABCDEF
     got = g1_fixed_base_batch(G1_GEN, [k])
     if got is None or got[0] != g1_mul(G1_GEN, k):
+        _lib = None
+        return None
+    # e(a G1, b G2) e(-ab G1, G2) = 1, and not with ab + 1
+    from ..curve.host import G2_GENERATOR, g1_neg, g2_mul
+    from ..snark.native_verify import pairing_product_is_one
+
+    a, b = 0xA5A5, 0x5A5B
+    left = (g1_mul(G1_GEN, a), g2_mul(G2_GENERATOR, b))
+    if not pairing_product_is_one(lib, [left, (g1_neg(g1_mul(G1_GEN, a * b)), G2_GENERATOR)]) or (
+        pairing_product_is_one(lib, [left, (g1_neg(g1_mul(G1_GEN, a * b + 1)), G2_GENERATOR)])
+    ):
         _lib = None
         return None
     return _lib

@@ -39,6 +39,7 @@ import json
 import os
 import queue
 import re
+import sys
 import threading
 import time
 import traceback
@@ -46,7 +47,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..formats.proof_json import dump
-from ..snark import witness_check
+from ..snark import native_verify, witness_check
 from ..utils.audit import execution_digest, install_compile_listener, preflight, sample_device_memory
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import REGISTRY, JsonlSink, maybe_start_metrics_server, publish_native_stats, run_id, run_manifest
@@ -1145,7 +1146,6 @@ class ProvingService:
         on the request waterfall (failed attempts included — the span
         closes on the way out of the exception)."""
         from ..prover.groth16_tpu import prove_tpu_batch
-        from ..snark.groth16 import verify
 
         span_attrs: Dict = {"n": len(batch)}
         if attempt:
@@ -1175,10 +1175,23 @@ class ProvingService:
                 f"prover returned {len(proofs)} proofs for a batch of {len(witnesses)}"
             )
         del proofs[len(batch):]  # what the padding proved
-        with _span(batch, "verify"):
+        # snark.native_verify: the whole equation in the native library
+        # where it is loaded, else snark.groth16.verify, which also speaks
+        # for every native False
+        path = native_verify.path_for()
+        with _span(batch, "verify", path=path):
             fault_point("verify")
             sample_pub = self.public_fn(batch[0].witness)
-            if not verify(self.vk, proofs[0], sample_pub):
+            REGISTRY.counter("zkp2p_service_verify_total", {"path": path}).inc()
+            ok, overruled = native_verify.verify(self.vk, proofs[0], sample_pub, path)
+            if overruled:
+                REGISTRY.counter("zkp2p_service_verify_disagree_total").inc()
+                print(
+                    f"[service] the native verify refused the sample proof of {batch[0].rid} and "
+                    "snark.groth16.verify accepts it: the two disagree, the Python answer stands",
+                    file=sys.stderr, flush=True,
+                )
+            if not ok:
                 raise RuntimeError("sample proof failed verification")
         return proofs
 
@@ -2030,8 +2043,6 @@ class ProvingService:
         # here, not show up in the numbers after.  Preflight initialises
         # the JAX backend; the CLI pins a `--prover native` worker's JAX
         # to the host platform first, so it never takes the chip.
-        import sys
-
         rep = preflight(
             workload=False,
             log=lambda m: print(f"[service] {m}", file=sys.stderr, flush=True),
