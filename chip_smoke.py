@@ -162,7 +162,8 @@ def sha2b_world():
         "name": "sha2b", "cs": cs, "payload": payload, "make_service": make_service,
         "reduced": "mesh mode smokes the road on the 54,608-constraint sha2b circuit: four chips are charged "
                    "four times over; venmo 256/192 runs on the same road in the benchmark's cell "
-                   "venmo-256-192-mesh4.bulk",
+                   "venmo-256-192-mesh4.bulk, and the uncut circuit (2^23, each proof's h stage shared by the "
+                   "chips) in venmo-full-mesh4.single",
     }
 
 

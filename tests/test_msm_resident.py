@@ -176,7 +176,7 @@ def test_window_rule_is_a_function_of_size_bytes_a_base_and_limit(log_m, limit, 
 # what a 16 GiB chip is given at each domain: today's three cells (2^16 and 2^19: a chunk
 # of four, w=8) and the published EmailVerify (2^22: one proof at a time, the w=4 table)
 @pytest.mark.parametrize("log_m,chunk,window", [
-    (16, 4, 8), (17, 4, 8), (18, 4, 8), (19, 4, 8), (20, 4, 4), (21, 2, 4), (22, 1, 4), (23, 1, None),
+    (16, 4, 8), (17, 4, 8), (18, 4, 8), (19, 4, 8), (20, 4, 4), (21, 2, 4), (22, 1, 4),
 ])
 @pytest.mark.parametrize("limit", [16 * GIB, int(15.75 * GIB)])
 def test_chunk_and_window_are_functions_of_the_keys_size_and_the_devices_memory(log_m, chunk, window, limit):
@@ -206,7 +206,7 @@ def test_the_knob_overrides_the_rule_and_the_arm_records_the_size_chosen(monkeyp
     assert audit.gate_arms()["batch_chunk"] == str(want)
 
 
-@pytest.mark.parametrize("log_m,want", [(16, 8), (20, 4), (23, None)])
+@pytest.mark.parametrize("log_m,want", [(16, 8), (20, 4), (22, 4)])
 def test_the_process_takes_the_rule_and_nothing_else(monkeypatch, log_m, want):
     """`_h_table_window` is `h_table_window` at the device's memory and
     the chunk planned for the key: only the key's size and the device's

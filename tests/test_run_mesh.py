@@ -78,3 +78,26 @@ def test_the_mesh_cell_end_to_end_and_traced(capsys, mesh_root, mesh_road_with_t
     assert res["metrics"]["stage_exchange_ms"]["value"] > 0
     # the warm-up batch placed the key: the window's batches moved none of it
     assert res["metrics"]["key_placed_bytes_in_window"]["value"] == 0
+
+
+def test_the_mesh_cell_under_single_shares_each_proof_s_h_stage(capsys, mesh_root, mesh_road_with_the_oracle_s_proofs):
+    """The cell venmo-full-mesh4.single's shape: the same toy key under the
+    mix `single`, batches of one, which the four chips do not divide, so
+    they share each proof's h stage (`h_shards` on its span) and the run's
+    line carries what that moved over ICI, the counter's growth over the
+    window: the `ici_bytes` of the window's `h_planes` spans, summed."""
+    rc = bench_run.main(["--workload", "toy-mesh4.single", "--seed", str(2**31 + 42), "--seconds", "3", "--trace", "1"],
+                        chip=StubChip(), root=mesh_root)
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and res["correct"] is True and res["attempted"] >= 1
+    assert 1 in mesh_road_with_the_oracle_s_proofs and 4 not in mesh_road_with_the_oracle_s_proofs
+    sink = os.path.join(mesh_root, ".bench_runs", f"toy-mesh4.single-s{2**31 + 42}-t1", "spool.metrics.jsonl")
+    with open(sink) as f:
+        h_stages = [r for r in map(json.loads, f) if r.get("type") == "stage" and r["stage"].endswith("/stage/h_planes")]
+    assert h_stages and all(r["h_shards"] == 4 and r["proofs_a_chip"] == 1 and r["mesh"] == "1x4" for r in h_stages)
+    assert {"h_ici_bytes_in_window", "stage_h_planes_ms", "stage_exchange_ms", "key_placed_bytes_in_window"} <= set(res["metrics"])
+    # a batch of one a request: the window's batches are the requests taken on (a batch served in set-up is in the sink too)
+    per_batch = h_stages[0]["ici_bytes"]
+    assert per_batch > 0 and all(r["ici_bytes"] == per_batch for r in h_stages) and len(h_stages) >= res["attempted"]
+    assert res["metrics"]["h_ici_bytes_in_window"]["value"] == per_batch * res["attempted"]
+    assert res["metrics"]["key_placed_bytes_in_window"]["value"] == 0

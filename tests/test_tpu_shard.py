@@ -168,6 +168,9 @@ def test_batch_arm_selection_and_fallback(toy_keys, monkeypatch):
     assert arm_for(3, "on", "1x4") == ("sharded", "1x4")  # B=1 divides anything
     # on + indivisible batch (3 % 2): fallback recorded, vmap dispatched
     assert arm_for(3, "on", "2x4") == ("vmap", "fallback")
+    # on + a chunk eight chips would share over the toy's domain of four points: no blocks to give them
+    assert arm_for(4, "on", "1x8") == ("vmap", "fallback")
+    assert arm_for(4, "on", "1x4") == ("sharded", "1x4")  # a proof a chip: nothing shared, any domain
 
 
 # ------------------------------------------- the batch's spans (stubbed)
@@ -296,12 +299,15 @@ def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
     from zkp2p_tpu.parallel import mesh as pmesh
     from zkp2p_tpu.prover import groth16_tpu as G
 
-    def fake_h_pod(mesh, log_m, split):
+    def fake_h_pod(mesh, log_m):
         def run(rows, w_std):
             time.sleep(sleep_s)
             b = w_std.shape[0]
             return np.zeros((b, 1 << log_m, 16), np.uint32), np.zeros((b,), np.uint32)
         return run
+
+    def fake_h_shard(mesh, log_m, most):
+        return lambda rows, starts, w_std: fake_h_pod(mesh, log_m)(rows, w_std)
 
     def fake_msm_pod(curve, bases, planes, mesh, **kw):
         time.sleep(sleep_s)
@@ -309,6 +315,7 @@ def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
         return tuple(np.zeros((planes.shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
 
     monkeypatch.setattr(G, "_h_pod_fn", fake_h_pod)
+    monkeypatch.setattr(G, "_h_shard_fn", fake_h_shard)
     monkeypatch.setattr(pmesh, "msm_pod_batched", fake_msm_pod)
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
 
@@ -347,27 +354,34 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
     tr.reset()
 
 
-@pytest.mark.parametrize("mesh_spec,n_wits,chunk,proofs_a_chip,crossed", [
-    ("1x4", 4, "0", 1, True),    # a chunk of four on 1x4: one proof's h a chip
-    ("2x2", 4, "0", 1, True),    # two groups of two chips: two proofs a group, one a chip
-    ("4x1", 4, "0", 1, False),   # a chip a group: nothing to exchange
-    ("1x4", 3, "0", 3, False),   # the mesh does not divide the chunk: every chip computes all three
-    ("1x4", 1, "0", 1, False),   # a batch of one, the same rule
-    ("1x4", 6, "4", 1, True),    # two chunks, the second padded to four
+@pytest.mark.parametrize("mesh_spec,n_wits,chunk,proofs_a_chip,h_shards,crossed", [
+    ("1x4", 4, "0", 1, None, True),    # a chunk of four on 1x4: one proof's h a chip
+    ("2x2", 4, "0", 1, None, True),    # two groups of two chips: two proofs a group, one a chip
+    ("4x1", 4, "0", 1, None, False),   # a chip a group: nothing to exchange
+    ("1x4", 3, "0", 3, 4, False),      # the mesh does not divide the chunk: the four chips share each proof's h stage
+    ("1x4", 1, "0", 1, 4, False),      # a batch of one, the same rule: the form a 2^23 key takes
+    ("2x2", 2, "0", 1, 2, False),      # a proof a group, shared by the group's two chips
+    ("1x4", 6, "4", 1, None, True),    # two chunks, the second padded to four
 ])
 def test_the_mesh_road_writes_seven_stages_that_partition_device_and_places_its_key_once(
-        toy_keys, monkeypatch, mesh_spec, n_wits, chunk, proofs_a_chip, crossed):
+        toy_keys, monkeypatch, mesh_spec, n_wits, chunk, proofs_a_chip, h_shards, crossed):
     """prove_tpu_batch on the mesh road, its h program and pod MSMs
-    stood in for: `prep` + `device` + `finish` partition
+    stood in for: `prep`, `device` and `finish` lie in that order inside
     `tpu/prove_batch`, and seven stages a chunk partition `device`, in
     the order the road enqueues them, `exchange` between `h_planes` and
     `msm_a`, each with `mesh`.  `h_planes` says how many proofs a chip
-    computed (the chunk over the whole mesh where the group's chips
-    divide its share, else every chip of a group its group's proofs),
-    `exchange` the bytes that crossed.  The key is placed by the first
-    batch, under a `tpu/place_key` span outside the batch's own, and a
-    second batch on the placed key grows `zkp2p_key_placed_bytes_total`
-    by 0."""
+    took part in (the chunk over the whole mesh where the group's chips
+    divide its share, each chip whole proofs of its own; else every
+    proof of its group, an `h_shards`-th of each, with the `ici_bytes`
+    the shared transforms moved, which `zkp2p_h_ici_bytes_total` grows
+    by), `exchange` the bytes that crossed.  The key is placed by the
+    first batch, under a `tpu/place_key` span outside the batch's own,
+    and a second batch on the placed key grows
+    `zkp2p_key_placed_bytes_total` by 0.
+
+    The partition is held to the spans' own ends (ids, parents, a stage
+    starting where the last one ended), not to a loaded machine's clock:
+    a worker beside five others waits milliseconds between two spans."""
     import dataclasses
 
     from zkp2p_tpu.prover import groth16_tpu as G
@@ -382,8 +396,8 @@ def test_the_mesh_road_writes_seven_stages_that_partition_device_and_places_its_
     monkeypatch.setenv("ZKP2P_TPU_MESH", mesh_spec)
     monkeypatch.setattr(G, "BATCH_CHUNK", chunk)
     _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.01)
-    placed_total = REGISTRY.counter("zkp2p_key_placed_bytes_total")
-    before = placed_total.value
+    placed_total, ici_total = REGISTRY.counter("zkp2p_key_placed_bytes_total"), REGISTRY.counter("zkp2p_h_ici_bytes_total")
+    before, ici_before = placed_total.value, ici_total.value
     tr.reset()
     pinned = list(range(1, n_wits + 1))
     out = G.prove_tpu_batch(dpk, wits, rs=pinned, ss=pinned)
@@ -396,23 +410,32 @@ def test_the_mesh_road_writes_seven_stages_that_partition_device_and_places_its_
     for r in recs:
         by.setdefault(r["stage"], []).append(r)
     (batch,), (prep,), (device,), (finish,) = (by["tpu/prove_batch" + s] for s in ("", "/prep", "/device", "/finish"))
-    assert placing["t0"] + placing["ms"] / 1e3 <= batch["t0"] + 1e-3  # before the batch's span, not in it
-    assert prep["ms"] + device["ms"] + finish["ms"] == pytest.approx(batch["ms"], rel=0.01, abs=1.0)
+    end = lambda r: r["t0"] + r["ms"] / 1e3  # noqa: E731
+    eps = 2e-6  # `ms` is written to the microsecond
+    assert placing["id"] < batch["id"] and end(placing) <= batch["t0"] + eps  # before the batch's span, not in it
+    assert all(r["parent"] == batch["id"] for r in (prep, device, finish))
+    assert batch["t0"] <= prep["t0"] and end(prep) <= device["t0"] + eps
+    assert end(device) <= finish["t0"] + eps and end(finish) <= end(batch) + eps
+    assert batch["chunk"] == int(chunk)
     n_chunks = batch["n_chunks"]
     stages = sorted((r for r in recs if "/stage/" in r["stage"]), key=lambda r: r["id"])
     assert G.MESH_STAGES == ("h_planes", "exchange", "msm_a", "msm_b1", "msm_b2", "msm_c", "msm_h")
     assert [r["stage"].rsplit("/", 1)[1] for r in stages] == list(G.MESH_STAGES) * n_chunks
     assert [r["chunk"] for r in stages] == [c for c in range(n_chunks) for _ in G.MESH_STAGES]
     assert all(r["mesh"] == mesh_spec and r["parent"] == device["id"] for r in stages)
-    assert [r["proofs_a_chip"] for r in stages if r["stage"].endswith("/h_planes")] == [proofs_a_chip] * n_chunks
-    assert all("proofs_a_chip" not in r for r in stages if not r["stage"].endswith("/h_planes"))
+    h_stages = [r for r in stages if r["stage"].endswith("/h_planes")]
+    assert [r["proofs_a_chip"] for r in h_stages] == [proofs_a_chip] * n_chunks
+    assert [r.get("h_shards") for r in h_stages] == [h_shards] * n_chunks
+    shared = 6 * n_wits * (h_shards - 1) * (64 << dpk.log_m) if h_shards else 0  # six all_gathers a proof
+    assert [r.get("ici_bytes") for r in h_stages] == [shared if h_shards else None] * n_chunks
+    assert ici_total.value - ici_before == shared * n_chunks
+    assert all(k not in r for k in ("proofs_a_chip", "h_shards", "ici_bytes") for r in stages if r not in h_stages)
     assert all((r["bytes"] > 0) == crossed for r in stages if r["stage"].endswith("/exchange"))
     assert all("bytes" not in r for r in stages if not r["stage"].endswith("/exchange"))
-    assert stages[0]["t0"] == pytest.approx(device["t0"], abs=1e-3)
+    assert stages[0]["t0"] == device["t0"]  # the first stage starts where `device` does
     for a, b in zip(stages, stages[1:]):
-        assert b["t0"] == pytest.approx(a["t0"] + a["ms"] / 1e3, abs=1e-5)  # abutting: they partition `device`
-    if n_chunks == 1:
-        assert sum(r["ms"] for r in stages) == pytest.approx(device["ms"], rel=0.01, abs=20.0)
+        assert abs(b["t0"] - end(a)) <= eps  # abutting: they partition `device`
+    assert end(stages[-1]) <= end(device) + eps  # and end inside it: what is left is the host's, reading the accumulators
     # the placed key is the one the next batch reads: nothing placed, no span, no bytes
     at = placed_total.value
     tr.reset()
@@ -681,8 +704,14 @@ dpk = G.DeviceProvingKey(
 mesh = make_pod_mesh(1, 4, names=("batch", "shard"))
 w = S((4, nw, 16), u32, sharding=NamedSharding(mesh, P(("batch", "shard"))))  # one proof a chip
 rows = tuple(getattr(dpk, f) for f in G._QAP_ROWS)
-text = G._h_pod_fn(mesh, log_m, True).trace(rows, w).lower(lowering_platforms=("tpu",)).as_text()
+text = G._h_pod_fn(mesh, log_m).trace(rows, w).lower(lowering_platforms=("tpu",)).as_text()
 print("KERNELS", text.count("tpu_custom_call"))
+from zkp2p_tpu.parallel.ntt import TABLE_SPECS
+one = S((1, nw, 16), u32, sharding=NamedSharding(mesh, P("batch")))  # a batch of one: the four chips share its h stage
+tabs = {k: S(((1 << log_m) // (1 if spec == P("shard") else 8), 16), u32, sharding=NamedSharding(mesh, spec)) for k, spec in TABLE_SPECS.items()}
+starts = (S((4,), i32), S((4,), i32))
+shared = G._h_shard_fn(mesh, log_m, (nnz, nnz)).program.trace(rows, starts, tabs, one).lower(lowering_platforms=("tpu",)).as_text()
+print("SHARED_KERNELS", shared.count("tpu_custom_call"), "ALL_GATHERS", shared.count("stablehlo.all_gather"))
 try:
     jax.jit(jax.vmap(G.h_evals, in_axes=(None, 0))).trace(dpk, w).lower(lowering_platforms=("tpu",))
     print("PLAIN_JIT lowered")
@@ -704,6 +733,9 @@ def test_sharded_h_stage_lowers_for_a_real_mesh():
     ).stdout
     kernels = [ln for ln in out.splitlines() if ln.startswith("KERNELS")][0]
     assert int(kernels.split()[1]) > 0, out
+    # and the form in which a group's chips share a proof's h stage: kernels inside the shard_map, two all_gathers
+    shared = [ln for ln in out.splitlines() if ln.startswith("SHARED_KERNELS")][0].split()
+    assert int(shared[1]) > 0 and int(shared[3]) == 2, out
     assert "cannot be automatically partitioned" in out, out
 
 

@@ -159,7 +159,7 @@ class HostDevice:
     def _one_chip(self, dpk, w_std, watch=None):
         return self._accumulators(_limbs16_to_u64(w_std))
 
-    def _h_pod(self, mesh, log_m, split):
+    def _h_pod(self, mesh, log_m):
         def run(rows, w_std):
             self._pod = iter(self._accumulators(_limbs16_to_u64(w_std)))
             b = w_std.shape[0]

@@ -113,23 +113,64 @@ KEY_BYTES_A_POINT = 1 << 10
 PROOF_BYTES_A_POINT = 3 << 9
 BATCH_CHUNK_MAX = 4
 NOMINAL_HBM_BYTES = 16 << 30
+# A key placed on a mesh (`place_key`): of KEY_BYTES_A_POINT the QAP rows,
+# which every chip holds whole, are KEY_ROWS_BYTES_A_POINT (163 MB at 2^19:
+# 311 B a point, rounded up); the bases, in S-ths, the rest.  A proof whose
+# h stage is shared by the S chips of its group (`_h_shard_fn`) costs a
+# chip an S-th of PROOF_BYTES_A_POINT and, whatever S, what it holds whole:
+# the witness and the all_gathers of three vectors.  Calibrated on the
+# program compiled for a described v5e:2x2 at 2^23 (PERF.md, PR 37:
+# `memory_analysis` plans 6.92 GB of temporaries, 0.13 GB of h and a
+# 0.31 GB witness a chip for one proof, 878 B a point, of which 384 are the
+# quarter): 494, rounded up.
+KEY_ROWS_BYTES_A_POINT = 3 << 7
+SHARED_WHOLE_BYTES_A_POINT = 1 << 9
 
 
 def work_bytes_a_point(chunk: int) -> int:
     """Device bytes a domain point that the key and `chunk` proofs in
-    flight are planned to take."""
+    flight are planned to take on one chip."""
     return KEY_BYTES_A_POINT + chunk * PROOF_BYTES_A_POINT
 
 
-def batch_chunk_for(log_m: int, bytes_limit: int) -> int:
+class KeyDoesNotFit(ValueError):
+    """No chunk of a key's proofs fits beside the key where it is placed."""
+
+
+def chip_bytes_a_point(chunk: int, n_batch: int = 1, n_shard: int = 1) -> float:
+    """Device bytes a domain point planned on the FULLEST chip of an
+    `n_batch` x `n_shard` placement for the key and a chunk of `chunk`
+    proofs: `work_bytes_a_point(chunk)` on one chip.  On a mesh
+    (`place_key`) a chip holds the QAP rows whole and an S-th of the
+    bases, and of its group's chunk / n_batch proofs either whole proofs
+    (the chunk is split, `_pod_split`: a proof's h stage on one chip) or
+    an S-th of each with a whole witness beside it (`_h_shard_fn`)."""
+    a_group = chunk // n_batch
+    if a_group % n_shard == 0:
+        proofs = a_group // n_shard * PROOF_BYTES_A_POINT
+    else:
+        proofs = a_group * (PROOF_BYTES_A_POINT / n_shard + SHARED_WHOLE_BYTES_A_POINT)
+    return KEY_ROWS_BYTES_A_POINT + (KEY_BYTES_A_POINT - KEY_ROWS_BYTES_A_POINT) / n_shard + proofs
+
+
+def batch_chunk_for(log_m: int, bytes_limit: int, n_batch: int = 1, n_shard: int = 1) -> int:
     """Proofs a chunk of `prove_tpu_batch` for a key of 2^log_m domain
-    points on a device of `bytes_limit`: BATCH_CHUNK_MAX where that many
-    working sets fit beside the key, half as many while they do not, and
-    never fewer than one."""
-    chunk = BATCH_CHUNK_MAX
-    while chunk > 1 and work_bytes_a_point(chunk) << log_m > HBM_PLAN_FRACTION * bytes_limit:
+    points placed on `n_batch` x `n_shard` devices of `bytes_limit` each
+    (one chip: 1 x 1): BATCH_CHUNK_MAX where the fullest chip's share
+    (`chip_bytes_a_point`) fits under HBM_PLAN_FRACTION of it, half as
+    many while it does not, never fewer than a proof a group; and where
+    that does not fit either, `KeyDoesNotFit` with the bytes."""
+    chunk = max(BATCH_CHUNK_MAX, n_batch)
+    while True:
+        planned = int(chip_bytes_a_point(chunk, n_batch, n_shard) * (1 << log_m))
+        if planned <= HBM_PLAN_FRACTION * bytes_limit:
+            return chunk
+        if chunk // 2 < n_batch or (chunk // 2) % n_batch:
+            raise KeyDoesNotFit(
+                f"a key of 2^{log_m} domain points fits no chunk on {n_batch}x{n_shard} devices of {bytes_limit} B: "
+                f"a chunk of {chunk} plans {planned} B on the fullest chip, over {HBM_PLAN_FRACTION:g} of it "
+                f"({int(HBM_PLAN_FRACTION * bytes_limit)} B); place the key on a mesh with more shards (ZKP2P_TPU_MESH)")
         chunk //= 2
-    return chunk
 
 
 def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int, chunk: int = BATCH_CHUNK_MAX) -> Optional[int]:
@@ -149,6 +190,22 @@ def _hbm_bytes_limit(device=None) -> int:
     one, the process's first device."""
     stats = (device or jax.devices()[0]).memory_stats() or {}
     return int(stats.get("bytes_limit") or NOMINAL_HBM_BYTES)
+
+
+def key_arrays_home(log_m: int):
+    """Where a key of 2^log_m domain points lives as `load_dpk` and
+    `setup_device` hand it over: `jnp.asarray` (the process's default
+    device, as every key has) where one device can prove from it, else
+    `np.asarray`, the host: a key no single chip holds beside one proof
+    (`batch_chunk_for` raises: 2^23 on a v5e) can only be placed on a
+    mesh, and `place_key` lays it there from the host a shard a chip,
+    so chip 0 never holds the 4.6 GB whole beside its own share.
+    `prove_native` reads either as it is."""
+    try:
+        batch_chunk_for(log_m, _hbm_bytes_limit())
+    except KeyDoesNotFit:
+        return np.asarray
+    return jnp.asarray
 
 
 def _h_table_window(log_m: int, device=None) -> Optional[int]:
@@ -326,13 +383,21 @@ def mesh_name(mesh) -> str:
     return f"{mesh.shape['batch']}x{mesh.shape['shard']}"
 
 
-def pod_lanes(n: int, n_ici: int) -> int:
+def pod_lanes(n: int, n_ici: int, proofs_a_group: int = BATCH_CHUNK_MAX) -> int:
     """The step width of a pod MSM over `n` bases, padded or not, in
-    `n_ici` shards: 64, or a shard's whole share where that is less
-    (tiny CI circuits stay at lanes ~ n/S instead of padding 16x to a
-    64-lane step).  `place_key` pads the bases to a multiple of
-    `n_ici * pod_lanes`, so every device sees whole steps."""
-    return max(1, min(64, -(-n // n_ici)))
+    `n_ici` shards, under a chunk of `proofs_a_group` proofs a batch
+    group: 64 for a chunk of BATCH_CHUNK_MAX, or a shard's whole share
+    where that is less (tiny CI circuits stay at lanes ~ n/S instead of
+    padding 16x to a 64-lane step).  `place_key` pads the bases to a
+    multiple of `n_ici * pod_lanes(n, n_ici)`, so every device sees
+    whole steps.  A smaller chunk takes steps as many times wider: a
+    step builds its table of multiples once whatever the chunk (15 mixed
+    adds in a row on `lanes` points: latency, not work), so a chunk of
+    one at 64 lanes would pay a chunk of four's steps for a quarter of
+    its accumulate; at 256 it runs a quarter of the steps, its
+    accumulate (planes x lanes x proofs) what a chunk of four's is
+    (`msm_windowed` pads a share that is no whole number of them)."""
+    return max(1, min(64, -(-n // n_ici))) * max(1, BATCH_CHUNK_MAX // proofs_a_group)
 
 
 _POD_BASES = ("a_bases", "b1_bases", "b2_bases", "c_bases", "h_bases")
@@ -380,7 +445,7 @@ def place_key(dpk: "DeviceProvingKey", where) -> "DeviceProvingKey":
 
 
 def _pin_to_device(dpk: "DeviceProvingKey", device) -> "DeviceProvingKey":
-    if dpk.a_coeff.devices() != {device}:
+    if not isinstance(dpk.a_coeff, jax.Array) or dpk.a_coeff.devices() != {device}:
         return jax.device_put(dpk, device)
     pin = lambda tree: jax.tree_util.tree_map(lambda x: jax.device_put(x, device), tree)  # noqa: E731
     for f in _DPK_ARRAY_FIELDS:
@@ -400,7 +465,8 @@ def _place_on_mesh(dpk: "DeviceProvingKey", mesh) -> "DeviceProvingKey":
     sharded, whole = NamedSharding(mesh, P("shard")), NamedSharding(mesh, P())
 
     def in_shards(x, n_to):
-        return jax.device_put(jnp.pad(x, [(0, n_to - x.shape[0])] + [(0, 0)] * (x.ndim - 1)), sharded)
+        pad = np.pad if isinstance(x, np.ndarray) else jnp.pad  # a key on the host goes to its chips from there
+        return jax.device_put(pad(x, [(0, n_to - x.shape[0])] + [(0, 0)] * (x.ndim - 1)), sharded)
 
     fields = {f: jax.device_put(getattr(dpk, f), whole) for f in _QAP_ROWS}
     for f in _POD_BASES:
@@ -429,10 +495,12 @@ def _key_on_mesh(dpk: "DeviceProvingKey", mesh) -> "DeviceProvingKey":
     return memo[1]
 
 
-def _rows_to_arrays(rows: Sequence[dict], m: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Sparse QAP rows -> (coeff mont limbs, wire ids, row ids).  The
-    coefficient conversion is the vectorized bytes->limbs path — at
-    venmo-scale nnz counts a per-element limb loop costs minutes."""
+def _rows_to_arrays(rows: Sequence[dict], m: int, home=jnp.asarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Sparse QAP rows -> (coeff mont limbs, wire ids, row ids), row
+    after row (`_row_blocks` counts on the order), each through `home`
+    (`key_arrays_home`).  The coefficient conversion is the vectorized
+    bytes->limbs path — at venmo-scale nnz counts a per-element limb
+    loop costs minutes."""
     vals: List[int] = []
     wires: List[int] = []
     row_ids: List[int] = []
@@ -444,9 +512,9 @@ def _rows_to_arrays(rows: Sequence[dict], m: int) -> Tuple[jnp.ndarray, jnp.ndar
     if not vals:  # degenerate all-zero matrix
         vals, wires, row_ids = [0], [0], [m - 1]
     return (
-        jnp.asarray(FR.array_to_mont_host_fast(vals)),
-        jnp.asarray(np.array(wires, dtype=np.int32)),
-        jnp.asarray(np.array(row_ids, dtype=np.int32)),
+        home(FR.array_to_mont_host_fast(vals)),
+        home(np.array(wires, dtype=np.int32)),
+        home(np.array(row_ids, dtype=np.int32)),
     )
 
 
@@ -1085,19 +1153,31 @@ def prove_tpu(
 # automatically ("wrap the call in a shard_map" — found on the four-chip
 # host, PERF.md PR 21), and every field product of the h stage is one.
 # Two layouts, and the exchange between them.  The h stage wants whole
-# witnesses, a chip its own: the proofs of a batch group (B over the
-# mesh's "batch" axis) are `split` over the group's S chips where S
-# divides them — one proof a chip for a chunk of four on 1x4 — and
-# otherwise every chip of the group computes them all (a batch of one,
-# a chunk of two on 1x4: what the road did for every chunk before PR
-# 32).  The MSMs want, on each chip, the scalars of the bases it holds,
-# for every proof of the group: the exchange delivers them and recodes
-# them.  One rule for every chunk and mesh; it follows from their
-# sizes.  The sharded MSMs use the unsigned formulation: group
+# witnesses: the proofs of a batch group (B over the mesh's "batch"
+# axis) are `split` over the group's S chips where S divides them — one
+# proof a chip for a chunk of four on 1x4, `_h_pod_fn` — and otherwise
+# the group's chips SHARE each proof's h stage, a chip an S-th of every
+# vector (a batch of one, a chunk of two on 1x4: `_h_shard_fn`, the form
+# a 2^23 domain needs, where one proof's h stage fits no chip).  The MSMs
+# want, on each chip, the scalars of the bases it holds, for every proof
+# of the group: the exchange delivers them and recodes them (a shared h
+# comes out in those columns already).  One rule for every chunk and
+# mesh, and no knob; it follows from their sizes.  The sharded MSMs use
+# the unsigned formulation: group
 # arithmetic is exact, so the proof bytes match the one-chip road's.
 def _pod_split(mesh, n_proofs: int) -> bool:
     """Whether a chunk of `n_proofs` is split over each group's chips."""
     return (n_proofs // mesh.shape["batch"]) % mesh.shape["shard"] == 0
+
+
+def _pod_takes(mesh, n_proofs: int, log_m: int) -> bool:
+    """Whether the mesh road can run a chunk of `n_proofs` over a 2^log_m
+    domain: the groups divide the chunk, and the chunk is split or each
+    group's chips divide the domain a shared h stage gives them in
+    blocks (not a shard width that is no power of two, nor a toy domain
+    smaller than it)."""
+    n_batch, n_ici = mesh.shape["batch"], mesh.shape["shard"]
+    return n_proofs % n_batch == 0 and (_pod_split(mesh, n_proofs) or (1 << log_m) % n_ici == 0)
 
 
 def _pod_chunk_spec(mesh, split: bool):
@@ -1107,9 +1187,10 @@ def _pod_chunk_spec(mesh, split: bool):
 
 
 @lru_cache(maxsize=None)
-def _h_pod_fn(mesh, log_m: int, split: bool):
-    """w (B, n_wires, 16) standard-form limbs -> h (B, m, 16) standard-
-    form limbs, each chip its share of the chunk."""
+def _h_pod_fn(mesh, log_m: int):
+    """The h stage of a chunk split over the chips, whole proofs a chip:
+    w (B, n_wires, 16) standard-form limbs -> h (B, m, 16) standard-form
+    limbs, each chip its share of the chunk."""
     from types import SimpleNamespace
 
     from jax.sharding import PartitionSpec as P
@@ -1120,8 +1201,95 @@ def _h_pod_fn(mesh, log_m: int, split: bool):
         # the last is `done`: one limb of h a witness, ready when the stage is (_StageWatch waits on it)
         return h, h[:, 0, 0]
 
-    chunk = _pod_chunk_spec(mesh, split)
+    chunk = _pod_chunk_spec(mesh, True)
     return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), chunk), out_specs=(chunk, chunk), check_vma=False))
+
+
+def _row_blocks(dpk: "DeviceProvingKey", n_ici: int):
+    """Where each chip's rows of the QAP matrices lie in the row-sorted
+    entry arrays (`_rows_to_arrays` writes row after row), for a domain
+    in `n_ici` blocks of rows: `((a_starts, a_most), (b_starts, b_most))`,
+    `starts[c]` the first entry of block c's rows and `most` the most
+    entries any block has, the static length `_h_shard_fn` slices.  Read
+    off the key once and memoised on the instance like the h table."""
+    memo = getattr(dpk, "_row_blocks_cache", None)
+    if memo is None or memo[0] != n_ici:
+        bounds = np.arange(n_ici + 1, dtype=np.int64) << dpk.log_m >> (n_ici.bit_length() - 1)
+
+        def blocks(rows):
+            starts = np.searchsorted(np.asarray(rows), bounds).astype(np.int32)
+            return starts[:-1], int(np.diff(starts).max())
+
+        memo = (n_ici, (blocks(dpk.a_row), blocks(dpk.b_row)))
+        setattr(dpk, "_row_blocks_cache", memo)
+    return memo[1]
+
+
+def _matvec_block(coeff, wire, row, start, most: int, w_mont, first_row, n_rows: int):
+    """One block of `_matvec`'s rows, `n_rows` from `first_row`, over a
+    matrix whole on the chip: the block's entries are a slice of `most`
+    from `start` (clamped at the arrays' end, so it may begin among an
+    earlier block's entries), and an entry of another block's row is
+    dropped by its row id."""
+    cut = lambda x: jax.lax.dynamic_slice_in_dim(x, start, most)  # noqa: E731
+    local = cut(row) - first_row
+    vals = FR.mul(cut(coeff), w_mont[cut(wire)])
+    return lazy_segment_sum_mod(FR, vals, jnp.where((local >= 0) & (local < n_rows), local, n_rows), n_rows)
+
+
+@lru_cache(maxsize=None)
+def _h_shard_fn(mesh, log_m: int, most: Tuple[int, int]):
+    """The h stage of proofs their group's chips SHARE: w (B, n_wires,
+    16) standard-form limbs, whole on every chip of a group -> h (B, m,
+    16) standard-form limbs, a chip its block of m / S columns of each
+    of its group's proofs: the block whose h bases `place_key` gave it.
+    One program over the mesh (`parallel.ntt`): each chip sums its own
+    block of rows of the two matvecs, the six transforms cross the chips
+    once each, the quotient is pointwise.  `most`: `_row_blocks`' static
+    slice lengths.  Bit-equal to `h_evals` (tests/test_h_sharded.py)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.ntt import TABLE_SPECS, intt_block_to_strided, ntt_strided_to_block, shard_tables
+
+    n_ici = mesh.shape["shard"]
+    n_rows = (1 << log_m) // n_ici
+
+    def local(rows, starts, tables, w_std):
+        chip = jax.lax.axis_index("shard")
+        w_mont = FR.to_mont(w_std)
+        with jax.named_scope("matvec"):
+            def matvec(matrix, start, n):
+                return jax.vmap(lambda w: _matvec_block(*matrix, start[chip], n, w, chip * n_rows, n_rows))(w_mont)
+
+            a_ev, b_ev = matvec(rows[:3], starts[0], most[0]), matvec(rows[3:], starts[1], most[1])
+            evals = jnp.stack([a_ev, b_ev, FR.mul(a_ev, b_ev)], axis=1)  # (B, 3, m / S, 16)
+        with jax.named_scope("intt"):
+            coeffs = intt_block_to_strided(evals, tables, n_ici)
+        with jax.named_scope("coset_ntt"):
+            cos = ntt_strided_to_block(coeffs, tables, n_ici)
+        h = FR.from_mont(FR.sub(FR.mul(cos[:, 0], cos[:, 1]), cos[:, 2]))
+        return h, h[:, :1, 0]  # `done`, a limb a chip: ready when the stage is
+
+    cols = P("batch", "shard")
+    program = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(), TABLE_SPECS, P("batch")), out_specs=(cols, cols), check_vma=False))
+
+    def run(rows, starts, w_std):
+        return program(rows, starts, shard_tables(mesh, log_m), w_std)
+
+    run.program = program  # (rows, starts, tables, w_std): what a test lowers from shapes alone
+    return run
+
+
+def h_ici_bytes(mesh, n_proofs: int, log_m: int) -> int:
+    """What the h stage of a chunk of `n_proofs` moves between chips,
+    summed over the chips that receive it: six transforms a proof over
+    the S chips of its group (`parallel.ntt.ici_bytes_a_transform`)
+    where the chunk's proofs are shared, 0 where each chip computes
+    whole proofs of its own."""
+    from ..parallel.ntt import ici_bytes_a_transform
+
+    return 0 if _pod_split(mesh, n_proofs) else 6 * n_proofs * ici_bytes_a_transform(log_m, mesh.shape["shard"])
 
 
 @lru_cache(maxsize=None)
@@ -1151,8 +1319,8 @@ def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
         return jax.lax.dynamic_slice_in_dim(x, jax.lax.axis_index("shard") * n, n, axis=1)
 
     def local(sels, w_std, h_std):
-        h_std = padded(h_std, n_h)
         if split:
+            h_std = padded(h_std, n_h)
             w_std = jax.lax.all_gather(w_std, "shard", axis=0, tiled=True)
             # each chip's S-th of the columns to the chip that holds their bases, the split
             # axis leading (split along the columns in place, the same all_to_all compiles
@@ -1162,7 +1330,7 @@ def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
                 h_std.reshape(b_loc, n_ici, n_loc, 16).swapaxes(0, 1), "shard", split_axis=0, concat_axis=0, tiled=True,
             ).reshape(n_ici * b_loc, n_loc, 16)
         else:
-            h_mine = mine(h_std)
+            h_mine = h_std
         b_sel, c_sel = sels
         with jax.named_scope("recode"):
             out = (planes(mine(padded(w_std, n_a))), planes(jnp.take(w_std, b_sel, axis=1)),
@@ -1171,7 +1339,7 @@ def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
 
     chunk, cols = _pod_chunk_spec(mesh, split), P("batch", None, "shard")
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P("shard"), chunk, chunk),
+        local, mesh=mesh, in_specs=(P("shard"), chunk, chunk if split else P("batch", "shard")),
         out_specs=(cols, cols, cols, cols, P(("batch", "shard"))), check_vma=False,
     ))
 
@@ -1194,7 +1362,10 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
     chunk's witnesses, `limbs` (B, n_wires, 16) standard-form on the
     host, are uploaded once each to the chip that computes their h
     (`_h_pod_fn`: the group's proofs spread over its chips where they
-    divide, `proofs_a_chip` on the stage's span); the exchange
+    divide, `proofs_a_chip` on the stage's span), or whole to every
+    chip of a group that shares each proof's h stage (`_h_shard_fn`,
+    where they do not: `h_shards` and `ici_bytes` on the span, and
+    `zkp2p_h_ici_bytes_total`); the exchange
     (`_exchange_pod_fn`, a stage of its own, `bytes` over ICI) leaves
     on every chip the digit planes of the columns whose bases it holds;
     and every MSM runs base-axis-sharded over the inner "shard" axis
@@ -1208,15 +1379,26 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
 
     from ..parallel.mesh import msm_pod_batched
 
+    from ..utils.metrics import REGISTRY
+
     n_ici, on = mesh.shape["shard"], mesh_name(mesh)
     n_proofs, n_wires = limbs.shape[0], limbs.shape[1]
     split = _pod_split(mesh, n_proofs)
     w_std = jax.device_put(limbs, NamedSharding(mesh, _pod_chunk_spec(mesh, split)))
     if watch is not None:
         watch.uploaded(w_std, limbs.nbytes)
-    h_std, done = _h_pod_fn(mesh, dpk.log_m, split)(tuple(getattr(dpk, f) for f in _QAP_ROWS), w_std)
-    _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on,
-              proofs_a_chip=n_proofs // (mesh.size if split else mesh.shape["batch"]))
+    rows = tuple(getattr(dpk, f) for f in _QAP_ROWS)
+    if split:
+        h_std, done = _h_pod_fn(mesh, dpk.log_m)(rows, w_std)
+        _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on, proofs_a_chip=n_proofs // mesh.size)
+    else:
+        (a_starts, a_most), (b_starts, b_most) = _row_blocks(dpk, n_ici)
+        h_std, done = _h_shard_fn(mesh, dpk.log_m, (a_most, b_most))(rows, (a_starts, b_starts), w_std)
+        crossed = h_ici_bytes(mesh, n_proofs, dpk.log_m)
+        # a chip takes part in every proof of its group, a share of each
+        _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on, proofs_a_chip=n_proofs // mesh.shape["batch"],
+                  h_shards=n_ici, ici_bytes=crossed)
+        REGISTRY.counter("zkp2p_h_ici_bytes_total").inc(crossed)
     n_a, n_h = dpk.a_bases[0].shape[0], dpk.h_bases[0].shape[0]
     a_planes, b_planes, c_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_a, n_h)(
         (dpk.b_sel, dpk.c_sel), w_std, h_std)
@@ -1226,7 +1408,7 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
     def msm(name, curve, bases, planes):
         return _msm_enqueued(watch, name, msm_pod_batched(
             curve, bases, planes, mesh, dcn_axis="batch", ici_axis="shard",
-            lanes=pod_lanes(bases[0].shape[0], n_ici), window=MSM_WINDOW,
+            lanes=pod_lanes(bases[0].shape[0], n_ici, n_proofs // mesh.shape["batch"]), window=MSM_WINDOW,
         ), mesh=on)
 
     return (
@@ -1303,7 +1485,7 @@ def _record_idle(gap, n: int) -> None:
     record("device_idle/offcpu", t_ready, t_ready + offcpu / 1e3, parent=idle, **account)
 
 
-def _batch_chunk_size(log_m: Optional[int] = None, device=None) -> int:
+def _batch_chunk_size(log_m: Optional[int] = None, device=None, mesh=None) -> int:
     """Sub-batch size for prove_tpu_batch; 0 = whole batch in one vmap.
 
     "auto" chunks only on a real TPU, and there the chunk is a function
@@ -1315,11 +1497,16 @@ def _batch_chunk_size(log_m: Optional[int] = None, device=None) -> int:
     the v5e's 15.75 G.  Four up to 2^20, two at 2^21, one from 2^22 on a
     16 GB chip; every chunk reuses ONE compiled executable.  Without a
     key (`log_m` None: preflight arms the gate before any key is loaded)
-    the answer is the largest chunk.  ZKP2P_BATCH_CHUNK, when a number,
-    overrides the rule."""
+    the answer is the largest chunk.  For a batch on the mesh road
+    (`mesh`) the same rule plans the fullest chip's share of the key as
+    `place_key` lays it on that mesh and of the chunk (four at 2^22 on
+    1x4, one at 2^23), and a key that fits no chunk raises
+    `KeyDoesNotFit`.  ZKP2P_BATCH_CHUNK, when a number, overrides the
+    rule."""
     auto = 0
     if _on_tpu():
-        auto = BATCH_CHUNK_MAX if log_m is None else batch_chunk_for(log_m, _hbm_bytes_limit(device))
+        shape = (1, 1) if mesh is None else (mesh.shape["batch"], mesh.shape["shard"])
+        auto = BATCH_CHUNK_MAX if log_m is None else batch_chunk_for(log_m, _hbm_bytes_limit(device), *shape)
     if BATCH_CHUNK == "auto":
         v = auto
     else:
@@ -1363,9 +1550,11 @@ def prove_tpu_batch(
     such call of a key, unless the key came placed): batch data-parallel
     over the mesh's "batch" axis, MSM bucket partial sums allreduced
     over "shard".  The arm is decided ONCE per call —
-    a chunk size indivisible by the mesh's batch width records the
-    `tpu_shard` arm as "fallback" and the whole call takes the vmap
-    path, so every chunk of a call shares one executable either way.
+    a chunk size indivisible by the mesh's batch width, or a chunk whose
+    proofs a group's chips would share (`_h_shard_fn`) over a domain
+    those chips do not divide, records the `tpu_shard` arm as "fallback"
+    and the whole call takes the vmap path, so every chunk of a call
+    shares one executable either way.
 
     What the device waited before this batch is written as a span of this
     one (`device_idle`, `_record_idle`), per key placement and feeding
@@ -1381,16 +1570,22 @@ def prove_tpu_batch(
     # does here, before the batch's own span (`tpu/place_key`).
     n = len(witnesses)
     key_dev = key_device(dpk)
-    chunk = _batch_chunk_size(dpk.log_m, key_dev)
-    if chunk <= 0 or n <= chunk:
-        spans = [list(witnesses)]
-    else:
+
+    def in_chunks(chunk: int):
+        if chunk <= 0 or n <= chunk:
+            return [list(witnesses)]
         spans = [list(witnesses[i : i + chunk]) for i in range(0, n, chunk)]
         spans[-1] += [spans[-1][-1]] * (chunk - len(spans[-1]))
+        return spans
+
     mesh = _shard_mesh()
-    if mesh is not None and len(spans[0]) % mesh.shape["batch"]:
+    chunk = _batch_chunk_size(dpk.log_m, key_dev, mesh)
+    spans = in_chunks(chunk)
+    if mesh is not None and not _pod_takes(mesh, len(spans[0]), dpk.log_m):
         _record_arm("tpu_shard", "fallback")
         mesh = None
+        chunk = _batch_chunk_size(dpk.log_m, key_dev)
+        spans = in_chunks(chunk)
     if mesh is not None:
         dpk = _key_on_mesh(dpk, mesh)
     # Spans (utils.trace): `prep`, `device` and `finish` partition

@@ -10,13 +10,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..curve.host import G1Point, G2Point
 from ..field.tower import Fq2
 from ..snark.groth16 import VerifyingKey
-from .groth16_tpu import _DPK_ARRAY_FIELDS, DeviceProvingKey
+from .groth16_tpu import _DPK_ARRAY_FIELDS, DeviceProvingKey, key_arrays_home
 
 # Bump whenever _DPK_ARRAY_FIELDS / the npz layout changes: a cache written
 # by an older schema must fail fast here (triggering re-setup upstream),
@@ -130,20 +129,21 @@ def load_dpk(path: str, digest: str = "") -> Tuple[DeviceProvingKey, VerifyingKe
                 f"{path}: circuit digest {had} != rebuilt circuit {digest} "
                 f"(wire/constraint order changed); re-run setup"
             )
+    n_public, n_wires, log_m = (int(v) for v in z["meta"])
+    home = key_arrays_home(log_m)  # the default device, or the host for a key only a mesh can take
     arrays = {}
     for f in _DPK_ARRAY_FIELDS:
         if f in z:
-            arrays[f] = jnp.asarray(z[f])
+            arrays[f] = home(z[f])
         else:
             parts = []
             i = 0
             while f"{f}.{i}" in z:
-                parts.append(jnp.asarray(z[f"{f}.{i}"]))
+                parts.append(home(z[f"{f}.{i}"]))
                 i += 1
             if not parts:
                 raise KeyCacheSchemaError(f"{path}: missing field {f!r}; re-run setup")
             arrays[f] = tuple(parts)
-    n_public, n_wires, log_m = (int(v) for v in z["meta"])
     dpk = DeviceProvingKey(
         n_public=n_public,
         n_wires=n_wires,

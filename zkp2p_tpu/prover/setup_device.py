@@ -27,7 +27,6 @@ from __future__ import annotations
 
 from typing import List, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..curve.host import G1_GENERATOR, G2_GENERATOR, g1_gen_mul, g2_gen_mul
@@ -35,7 +34,7 @@ from ..field.bn254 import R, fr_domain_root, fr_inv
 from ..native.lib import g1_fixed_base_batch_mont_limbs, g2_fixed_base_batch_mont_limbs
 from ..snark.groth16 import VerifyingKey, _batch_inv, _seeded_scalars, coset_gen, domain_size_for, qap_rows
 from ..snark.r1cs import ConstraintSystem
-from .groth16_tpu import DeviceProvingKey, _rows_to_arrays
+from .groth16_tpu import DeviceProvingKey, _rows_to_arrays, key_arrays_home
 
 
 def setup_device(cs: ConstraintSystem, seed: str = "zkp2p-tpu-dev") -> Tuple[DeviceProvingKey, VerifyingKey]:
@@ -115,8 +114,9 @@ def setup_device(cs: ConstraintSystem, seed: str = "zkp2p-tpu-dev") -> Tuple[Dev
 
     ic = g1_gen_mul_batch(scaled[: cs.num_public + 1])
 
-    a_arr = _rows_to_arrays([t[0] for t in rows], m)
-    b_arr = _rows_to_arrays([t[1] for t in rows], m)
+    home = key_arrays_home(m.bit_length() - 1)  # the default device, or the host for a key only a mesh can take
+    a_arr = _rows_to_arrays([t[0] for t in rows], m, home)
+    b_arr = _rows_to_arrays([t[1] for t in rows], m, home)
 
     # Width-classed MSM split — THE shared rule from groth16_tpu
     # (class_sels), so this dev-setup path and the pk-import path can
@@ -134,16 +134,16 @@ def setup_device(cs: ConstraintSystem, seed: str = "zkp2p-tpu-dev") -> Tuple[Dev
         log_m=m.bit_length() - 1,
         a_coeff=a_arr[0], a_wire=a_arr[1], a_row=a_arr[2],
         b_coeff=b_arr[0], b_wire=b_arr[1], b_row=b_arr[2],
-        a_bases=tuple(jnp.asarray(x) for x in a_bases),
-        b1_bases=tuple(jnp.asarray(x) for x in b1_bases),
-        b2_bases=tuple(jnp.asarray(x) for x in b2_bases),
-        c_bases=tuple(jnp.asarray(x) for x in cq_bases),
-        h_bases=tuple(jnp.asarray(x) for x in h_bases),
-        b_sel=jnp.asarray(b_sel),
-        c_sel=jnp.asarray(c_sel),
-        a_nsel=jnp.asarray(a_nsel), a_wsel=jnp.asarray(a_wsel),
-        b_nsel=jnp.asarray(b_nsel), b_wsel=jnp.asarray(b_wsel),
-        c_nsel=jnp.asarray(c_nsel), c_wsel=jnp.asarray(c_wsel),
+        a_bases=tuple(home(x) for x in a_bases),
+        b1_bases=tuple(home(x) for x in b1_bases),
+        b2_bases=tuple(home(x) for x in b2_bases),
+        c_bases=tuple(home(x) for x in cq_bases),
+        h_bases=tuple(home(x) for x in h_bases),
+        b_sel=home(b_sel),
+        c_sel=home(c_sel),
+        a_nsel=home(a_nsel), a_wsel=home(a_wsel),
+        b_nsel=home(b_nsel), b_wsel=home(b_wsel),
+        c_nsel=home(c_nsel), c_wsel=home(c_wsel),
         alpha_1=g1_gen_mul(alpha),
         beta_1=g1_gen_mul(beta),
         beta_2=g2_gen_mul(beta),
