@@ -132,6 +132,7 @@ def test_gate_arms_after_a_prove(monkeypatch):
     _cs, _pk, dpk, wits = _toy_world(monkeypatch)
     dpk = _no_narrow_class(dpk)  # one MSM a query: no curve add between two classes to run op by op
     for name in ("_jit_h_planes", "_jit_h_table", "_jit_msm_h_resident"):
+        getattr(g, name).clear_cache()  # an arm is recorded at a trace: not by a program another file's test left traced
         monkeypatch.setattr(g, name, traced(getattr(g, name)))
     monkeypatch.setattr(g, "_assemble", lambda dpk_, acc, r, s: acc)
     monkeypatch.setattr(audit, "_arms", {})

@@ -141,18 +141,20 @@ def test_a_block_s_matvec_drops_its_neighbours_entries():
         assert [FR.from_mont_host(v) for v in np.asarray(got)] == want[c * 8:(c + 1) * 8]
 
 
-def test_a_chunk_of_one_on_1x4_proves_the_bytes_of_the_host_and_the_native_prover(monkeypatch):
+@pytest.mark.parametrize("n_proofs", [1, 4], ids=["a-batch-of-one", "a-batch-of-four"])
+def test_a_chunk_of_one_on_1x4_proves_the_bytes_of_the_host_and_the_native_prover(monkeypatch, n_proofs):
     """`prove_tpu_batch` with ZKP2P_TPU_MESH=1x4 and one witness: the key
-    placed on the mesh, the shared h stage and the exchange the real
-    programs, each pod MSM answered on the host from the digit planes the
-    exchange left on the chips and the key's own points (the programs of
-    the curve compile for minutes on XLA:CPU).  The proof is `prove_host`'s
-    and `prove_native`'s for the same (witness, r, s), the `h_planes` span
-    says four chips shared it, and the chunk was not exchanged."""
-    from test_witness_forms import _proj_g1, _proj_g2
+    placed on the mesh in its classes, the shared h stage and the exchange
+    the real programs, each pod MSM answered on the host from the signed
+    digit planes the exchange left on the chips and the placed key's
+    classes of bases (the programs of the curve compile for minutes on
+    XLA:CPU).  The proof is `prove_host`'s and `prove_native`'s for the
+    same (witness, r, s), the `h_planes` span says four chips shared it,
+    and the chunk was not exchanged.  And a batch of four: one proof's h
+    stage a chip (`_h_pod_fn`, the real program), exchanged over the
+    chips, the same bytes."""
+    from test_mesh_exchange import host_pod_msm
 
-    from zkp2p_tpu.curve.host import g1_msm, g2_msm
-    from zkp2p_tpu.curve.jcurve import G2J
     from zkp2p_tpu.parallel import mesh as pmesh
     from zkp2p_tpu.prover.native_prove import prove_native
     from zkp2p_tpu.snark.groth16 import prove_host, setup
@@ -163,38 +165,29 @@ def test_a_chunk_of_one_on_1x4_proves_the_bytes_of_the_host_and_the_native_prove
     cs, _wires = _chain(n)
     pk, _vk = setup(cs)
     dpk = G.device_pk(pk, cs)
-    assert dpk.log_m == 6
-    wit = _chain_witness(n, 7, R - 11)
-    b_sel, c_sel = np.asarray(dpk.b_sel), np.asarray(dpk.c_sel)
-    queries = iter([  # the order the road runs its MSMs in
-        list(pk.a_query), [pk.b1_query[i] for i in b_sel], [pk.b2_query[i] for i in b_sel],
-        [pk.c_query[i] for i in c_sel], list(pk.h_query) + [None] * ((1 << dpk.log_m) - len(pk.h_query))])
-
-    def host_msm(curve, bases, planes, mesh, **kw):
-        points, digits = next(queries), np.asarray(planes)  # (1, 64, n padded), most significant digit first
-        scalars = [functools.reduce(lambda k, d: 16 * k + int(d), digits[0, :, j], 0) for j in range(len(points))]
-        # past the key's own points the planes' filler lanes sit against infinity bases (b_sel's filler names wire 0)
-        live = [(p, k) for p, k in zip(points, scalars) if p is not None]
-        msm, proj = (g2_msm, _proj_g2) if curve is G2J else (g1_msm, _proj_g1)
-        return proj([msm([p for p, _ in live], [k for _, k in live])])
-
+    assert dpk.log_m == 6 and int(dpk.a_nsel.shape[0]) > 0  # a key with a narrow class
+    wits = [_chain_witness(n, 7 + 3 * i, R - 11 - i) for i in range(n_proofs)]
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
     monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")
     monkeypatch.setattr(G, "BATCH_CHUNK", "0")
-    monkeypatch.setattr(pmesh, "msm_pod_batched", host_msm)
-    monkeypatch.setattr(G, "_h_pod_fn", lambda *a: pytest.fail("a chunk of one took the split form"))
-    r, s = 1234567, R - 7654321
+    monkeypatch.setattr(pmesh, "msm_pod_batched", host_pod_msm)
+    other = "_h_pod_fn" if n_proofs == 1 else "_h_shard_fn"
+    monkeypatch.setattr(G, other, lambda *a: pytest.fail(f"a chunk of {n_proofs} took the other form of the h stage"))
+    rs, ss = [1234567 + i for i in range(n_proofs)], [R - 7654321 - i for i in range(n_proofs)]
     tr.reset()
-    (got,) = G.prove_tpu_batch(dataclasses.replace(dpk), [wit], rs=[r], ss=[s])
+    got = G.prove_tpu_batch(dataclasses.replace(dpk), wits, rs=rs, ss=ss)
     assert gate_arms()["tpu_shard"] == "1x4"
-    assert got == prove_host(pk, cs, wit, r=r, s=s)
-    native = prove_native(dpk, wit, r, s)
-    assert native is None or got == native  # None: the native library did not build
+    assert got == [prove_host(pk, cs, w, r=r, s=s) for w, r, s in zip(wits, rs, ss)]
+    native = prove_native(dpk, wits[0], rs[0], ss[0])
+    assert native is None or got[0] == native  # None: the native library did not build
     (h_stage,) = [rec for rec in tr.records() if rec["stage"].endswith("/stage/h_planes")]
-    assert h_stage["h_shards"] == 4 and h_stage["proofs_a_chip"] == 1
-    assert h_stage["ici_bytes"] == G.h_ici_bytes(G._shard_mesh(), 1, dpk.log_m) == 6 * 3 * (64 << 6)
     (exchange,) = [rec for rec in tr.records() if rec["stage"].endswith("/stage/exchange")]
-    assert exchange["bytes"] == 0
+    if n_proofs == 1:
+        assert h_stage["h_shards"] == 4 and h_stage["proofs_a_chip"] == 1
+        assert h_stage["ici_bytes"] == G.h_ici_bytes(G._shard_mesh(), 1, dpk.log_m) == 6 * 3 * (64 << 6)
+        assert exchange["bytes"] == 0
+    else:
+        assert "h_shards" not in h_stage and h_stage["proofs_a_chip"] == 1 and exchange["bytes"] > 0
     tr.reset()
 
 
@@ -276,23 +269,36 @@ def test_a_key_on_the_host_reaches_the_mesh_a_shard_a_chip_and_round_trips(tmp_p
     assert native is None or native == prove_native(on_device, wit, 5, 7)
 
 
+class _OneIsEnough(Exception):
+    pass
+
+
 @pytest.mark.parametrize("a_group,lanes", [(4, 64), (3, 64), (2, 128), (1, 256), (8, 64)])
 def test_a_smaller_chunk_takes_wider_pod_msm_steps(monkeypatch, a_group, lanes):
-    """`pod_lanes`: a chunk of four proofs a group steps 64 bases at a
-    time, as the key is padded for; a chunk of one 256, a quarter of the
-    steps, because a step's table of multiples costs the same whatever
-    the chunk.  `_prove_batch_sharded` hands every pod MSM that width."""
+    """`pod_lanes`: a chunk of four proofs a group steps 64 bases of a
+    wide class (and of h) at a time, as the key is padded for; a chunk of
+    one 256, a quarter of the steps, because a step's table of multiples
+    costs the same whatever the chunk.  `pod_narrow_lanes`: a narrow class
+    steps a sixteenth of a chip's share, under its curve's cap, and no
+    narrower than makes its three planes' accumulate the wide class's
+    16,384 adds.  `_prove_batch_sharded` hands every pod MSM those widths."""
     from zkp2p_tpu.parallel import mesh as pmesh
 
     assert G.pod_lanes(1 << 23, 4, a_group) == lanes and G.pod_lanes(1 << 23, 4) == 64
     assert G.pod_lanes(6, 4, a_group) == 2 * max(1, 4 // a_group)  # a toy's whole share, as many times over
+    floor = -(-64 * lanes // 3)  # three planes against 64: 1,366 lanes for a chunk of four, 5,462 for one
+    assert G.pod_narrow_lanes(1 << 23, 4, a_group) == 16384 and G.pod_narrow_lanes(1 << 23, 4, a_group, cap=4096) == 4096
+    assert G.pod_narrow_lanes(491361, 4, a_group) == max(7678, floor)  # venmo-256-192's a: sixteen steps of its quarter
+    assert G.pod_narrow_lanes(53617, 4, a_group) == floor and G.pod_narrow_lanes(4 * 1000, 4, a_group) == 1000  # sha2b's; a whole share
+    n_to = 491361 + (-491361) % (4 * 7678)
+    assert G.pod_narrow_lanes(n_to, 4) == 7678 == G.pod_narrow_lanes(491361, 4)  # a padded class answers the same
     if a_group > 4:
         return
     seen = []
 
     def first_msm(curve, bases, planes, mesh, **kw):
         seen.append(kw["lanes"])
-        raise StopIteration  # one is enough: the five are handed the same rule
+        raise _OneIsEnough  # the five are handed the same rule
 
     monkeypatch.setattr(pmesh, "msm_pod_batched", first_msm)
     monkeypatch.setattr(G, "_h_pod_fn", lambda mesh, log_m: lambda rows, w: (np.zeros((a_group, 8, 16), np.uint32), np.zeros((a_group,), np.uint32)))
@@ -302,6 +308,7 @@ def test_a_smaller_chunk_takes_wider_pod_msm_steps(monkeypatch, a_group, lanes):
 
     placed = G.place_key(G.device_pk(setup(cs)[0], cs), _mesh(1, 4))
     limbs = np.stack([G._witness_std_limbs(_chain_witness(6, 2 + i, 3)) for i in range(a_group)])
-    with pytest.raises(StopIteration):
+    with pytest.raises(_OneIsEnough):
         G._prove_batch_sharded(placed, limbs, _mesh(1, 4))
-    assert seen == [G.pod_lanes(placed.a_bases[0].shape[0], 4, a_group)]
+    n_narrow, n_wide = (cls[0].shape[0] for cls in placed.a_bases)
+    assert n_narrow and seen == [(G.pod_narrow_lanes(n_narrow, 4, a_group), G.pod_lanes(n_wide, 4, a_group))]

@@ -213,7 +213,7 @@ def test_the_mesh_road_s_h_planes_span_names_the_ladder_too(monkeypatch):
     ran = _spy_on_the_ladders(monkeypatch)
 
     def infinity(curve, bases, planes, mesh, **kw):
-        return tuple(np.zeros((planes.shape[0],) + ((2, 16) if curve is G2J else (16,)), np.uint32) for _ in range(3))
+        return tuple(np.zeros((planes[0][0].shape[0],) + ((2, 16) if curve is G2J else (16,)), np.uint32) for _ in range(3))
 
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
     monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")

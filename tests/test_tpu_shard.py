@@ -312,7 +312,7 @@ def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
     def fake_msm_pod(curve, bases, planes, mesh, **kw):
         time.sleep(sleep_s)
         limbs = (2, 16) if curve is G2J else (16,)
-        return tuple(np.zeros((planes.shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
+        return tuple(np.zeros((planes[0][0].shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
 
     monkeypatch.setattr(G, "_h_pod_fn", fake_h_pod)
     monkeypatch.setattr(G, "_h_shard_fn", fake_h_shard)
@@ -321,11 +321,12 @@ def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
 
 
 def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
-    """`_prove_batch_sharded` recodes unsigned at MSM_WINDOW and runs
-    `msm_pod_batched`: it bypasses the key's resident h table by
-    construction.  The real road on the 1x4 virtual mesh, its h program
-    and its pod MSMs stood in for: no table is built, none is memoised
-    on the key, the gauge stays 0 and no `h_table` span is written."""
+    """`_prove_batch_sharded` recodes h at MSM_WINDOW and runs
+    `msm_pod_batched`, which builds a step's multiples in its scan: it
+    bypasses the key's resident h table by construction.  The real road
+    on the 1x4 virtual mesh, its h program and its pod MSMs stood in
+    for: no table is built, none is memoised on the key, the gauge stays
+    0 and no `h_table` span is written."""
     import dataclasses
 
     from zkp2p_tpu.prover import groth16_tpu as G
@@ -445,6 +446,58 @@ def test_the_mesh_road_writes_seven_stages_that_partition_device_and_places_its_
     placed = G._key_on_mesh(dpk, G._shard_mesh())
     assert G.key_mesh(placed) is not None and G.key_device(placed) is None and G.key_mesh(dpk) is None
     assert len(G.prove_tpu_batch(placed, wits, rs=pinned, ss=pinned)) == n_wits and placed_total.value == at
+    tr.reset()
+
+
+@pytest.mark.parametrize("classed", [True, False], ids=["classed", "no-widths"])
+def test_the_mesh_road_s_query_spans_say_their_classes_and_digits(toy_keys, monkeypatch, classed):
+    """The counter that says the narrow class engaged: on the mesh road
+    `stage/msm_a`, `msm_b1`, `msm_b2` and `msm_c` carry `narrow` and
+    `wide`, the bases a chip holds in each class, padding included (a, b1
+    and c the same: they are padded to one count and share a program),
+    `narrow` 0 for a key without widths; all five MSM stages carry
+    `digits` = "signed"; `exchange` and `h_planes` neither.  What
+    `msm_pod_batched` is handed agrees: a class a span counts 0 bases in
+    takes no part, a narrow class comes with its low planes alone."""
+    import dataclasses
+
+    from test_msm_resident import _no_narrow_class
+
+    from zkp2p_tpu.parallel import mesh as pmesh
+    from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.utils import trace as tr
+
+    cs, _pk, _vk, dpk, x, y = toy_keys
+    dpk = dataclasses.replace(dpk) if classed else _no_narrow_class(dpk)
+    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11), (1, 1)])
+    monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
+    monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")
+    monkeypatch.setattr(G, "BATCH_CHUNK", "0")
+    _stand_in_for_the_mesh_programs(monkeypatch)
+    fake, handed = pmesh.msm_pod_batched, []
+
+    def spy(curve, bases, planes, mesh, **kw):
+        handed.append([(cls[0].shape[0], mags.shape[1], lanes) for cls, (mags, _negs), lanes in zip(bases, planes, kw["lanes"])])
+        return fake(curve, bases, planes, mesh, **kw)
+
+    monkeypatch.setattr(pmesh, "msm_pod_batched", spy)
+    tr.reset()
+    assert len(G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3, 4], ss=[5, 6, 7, 8])) == 4
+    stages = {r["stage"].rsplit("/", 1)[1]: r for r in tr.records() if "/stage/" in r["stage"]}
+    placed = G._key_on_mesh(dpk, G._shard_mesh())
+    for (name, q), classes in zip((("msm_a", "a"), ("msm_b1", "b1"), ("msm_b2", "b2"), ("msm_c", "c")), handed):
+        n_narrow, n_wide = (cls[0].shape[0] for cls in getattr(placed, q + "_bases"))
+        span = stages[name]
+        assert (span["narrow"], span["wide"], span["digits"]) == (n_narrow // 4, n_wide // 4, "signed") and span["wide"] > 0
+        assert bool(span["narrow"]) == (classed and q != "b2")  # the toy's B rows name no narrow wire
+        want = ([(n_narrow, G.NARROW_PLANES, G.pod_narrow_lanes(n_narrow, 4, 4, 4096 if q == "b2" else 16384))] if n_narrow else []) + [
+            (n_wide, 64, G.pod_lanes(n_wide, 4, 4))]
+        assert classes == want
+    assert stages["msm_a"]["narrow"] == stages["msm_b1"]["narrow"] == stages["msm_c"]["narrow"]
+    assert stages["msm_a"]["wide"] == stages["msm_b1"]["wide"] == stages["msm_c"]["wide"]
+    assert stages["msm_h"]["digits"] == "signed" and handed[4] == [(placed.h_bases[0].shape[0], 64, G.pod_lanes(placed.h_bases[0].shape[0], 4, 4))]
+    assert all(k not in stages[name] for k in ("narrow", "wide") for name in ("msm_h", "exchange", "h_planes"))
+    assert all("digits" not in stages[name] for name in ("exchange", "h_planes"))
     tr.reset()
 
 
@@ -601,16 +654,11 @@ def test_per_device_bucket_partials_match_unsharded():
 
     # the pod-mesh executable agrees with the same oracle
     mesh = make_pod_mesh(2, n_ici)
-    planes = jnp.stack(
-        [
-            jmsm.digit_planes_from_limbs(
-                jnp.asarray(np.stack([int_to_limbs(s) for s in row])), window
-            )
-            for row in batch_scalars
-        ]
-    )
+    # one class: signed digits (planes, B, n) -> (B, planes, n)
+    planes = tuple(jnp.moveaxis(p, 0, 1) for p in jmsm.signed_digit_planes_from_limbs(
+        jnp.asarray(np.stack([[int_to_limbs(s) for s in row] for row in batch_scalars])), window))
     bases = g1_to_affine_arrays(pts)  # n is a multiple of n_ici * lanes: nothing to pad
-    acc = msm_pod_batched(G1J, bases, planes, mesh, lanes=lanes, window=window)
+    acc = msm_pod_batched(G1J, (bases,), (planes,), mesh, lanes=(lanes,), window=window)
     got = g1_jac_to_host(acc)
     for i, row in enumerate(batch_scalars):
         assert got[i] == g1_msm(pts, row), f"batch element {i}"
@@ -649,19 +697,14 @@ def test_msm_pod_batched_dcn_axis():
     pts = [g1_mul(G1_GENERATOR, int(k)) for k in rng.integers(1, 2**62, n)]
     rng = np.random.default_rng(7)
     batch_scalars = [[int(s) for s in rng.integers(1, 2**62, n)] for _ in range(4)]
-    planes = jax.numpy.stack(
-        [
-            jmsm.digit_planes_from_limbs(
-                jax.numpy.asarray(np.stack([int_to_limbs(s) for s in sc])), 4
-            )
-            for sc in batch_scalars
-        ]
-    )
     # infinity bases and zero digit columns up to a multiple of the mesh width, as `place_key` pads a key
     pad = (-n) % 8
+    planes = tuple(
+        jax.numpy.pad(jax.numpy.moveaxis(p, 0, 1), [(0, 0), (0, 0), (0, pad)])
+        for p in jmsm.signed_digit_planes_from_limbs(
+            jax.numpy.asarray(np.stack([[int_to_limbs(s) for s in sc] for sc in batch_scalars])), 4))
     bases = tuple(jax.numpy.pad(c, [(0, pad), (0, 0)]) for c in g1_to_affine_arrays(pts))
-    planes = jax.numpy.pad(planes, [(0, 0), (0, 0), (0, pad)])
-    acc = msm_pod_batched(G1J, bases, planes, mesh, lanes=8, window=4)
+    acc = msm_pod_batched(G1J, (bases,), (planes,), mesh, lanes=(8,), window=4)
     got = g1_jac_to_host(acc)
     for i, sc in enumerate(batch_scalars):
         assert got[i] == g1_msm(pts, sc), f"batch element {i}"

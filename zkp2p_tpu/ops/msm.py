@@ -212,9 +212,44 @@ def _msm_windowed_impl(
     window: int,
 ) -> ProjPoint:
     """Shared body of `msm_windowed` (negs=None: unsigned 2^w - 1 table +
-    masked accumulate — the mesh road's, parallel.mesh) and
-    `msm_windowed_signed` (half table + Y negation — the one-chip
-    road's)."""
+    masked accumulate — since PR 38 no road's: tools and tests call it)
+    and `msm_windowed_signed` (half table + Y negation — the one-chip
+    road's): the planes' partials, Horner over the planes lane by lane,
+    then the lanes' tree."""
+    partials, lanes = _window_partials(curve, bases, planes_in, negs, lanes, window)
+    per_lane = horner_fold_planes(curve, curve.infinity((lanes,)), partials, window)
+    return tree_reduce(curve, per_lane, lanes)
+
+
+def msm_plane_sums(
+    curve: JCurve,
+    bases: AffPoint,
+    mags: jnp.ndarray,
+    negs: jnp.ndarray,
+    lanes: int = 64,
+    window: int = 4,
+) -> ProjPoint:
+    """The signed windowed MSM up to its last fold: the sum of every
+    plane, (n_digits,) points, most significant first —
+    `msm_windowed_signed`'s table and accumulate, then each plane's
+    lanes folded (`tree_reduce`).  `horner_fold_planes` over them gives
+    the point `msm_windowed_signed` gives, which folds the other way
+    round (Horner lane by lane, then the lanes).  What differs is what a
+    program has to lower: this Horner's double and add have a point's
+    shape whatever the lanes and the planes, so a program over several
+    classes of bases (the mesh road's, `parallel.mesh`: a narrow class
+    at thousands of lanes beside a wide one at 64) lowers one pair for
+    all of them, where a fold at each class's lanes is three kernel
+    instances a class, each ~1 s of Python for G1 and ~3 s for G2 at
+    every start (PERF.md, PR 38)."""
+    partials, lanes = _window_partials(curve, bases, mags, negs, lanes, window)
+    return tree_reduce(curve, partials, lanes)
+
+
+def _window_partials(curve, bases, planes_in, negs, lanes, window):
+    """The accumulate of the windowed MSMs: `(partials, lanes)`, the
+    planes' partial sums lane by lane, (n_digits, lanes) points, and the
+    lanes a step took (no more than there are bases)."""
     signed = negs is not None
     n_digits = planes_in.shape[0]
     n = bases[0].shape[0]
@@ -273,11 +308,7 @@ def _msm_windowed_impl(
     else:
         xs_in = (pts, planes)
     partials, _ = jax.lax.scan(accumulate, curve.infinity((n_digits, lanes)), xs_in)
-
-    per_lane = horner_fold_planes(
-        curve, curve.infinity((lanes,)), tuple(c for c in partials), window
-    )
-    return tree_reduce(curve, per_lane, lanes)
+    return partials, lanes
 
 
 # ---------------------------------------------------------------------------

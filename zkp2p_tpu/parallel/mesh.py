@@ -20,7 +20,7 @@ the driver's `dryrun_multichip` exercises it on virtual CPU devices.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +28,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..curve.jcurve import AffPoint, ProjPoint, JCurve
-from ..ops.msm import msm, msm_windowed
+from ..ops.msm import horner_fold_planes, msm_plane_sums
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "shard") -> Mesh:
@@ -53,16 +53,19 @@ def make_pod_mesh(n_dcn: int, n_ici: Optional[int] = None, names=("dcn", "shard"
 
 
 @lru_cache(maxsize=None)
-def _msm_pod_fn(curve: JCurve, n_bases: int, mesh: Mesh, dcn_axis: str, ici_axis: str, lanes: int, window: int):
-    def local(bs, pl):
-        # pl: (B_local, n_planes, n_local) — this slice's share of the
-        # proof batch over its shard of the base axis
-        def one(p):
-            if window:
-                return msm_windowed(curve, bs, p, lanes=lanes, window=window)
-            return msm(curve, bs, p, lanes=lanes)
-
-        part = jax.vmap(one)(pl)
+def _msm_pod_fn(curve: JCurve, mesh: Mesh, dcn_axis: str, ici_axis: str, lanes: Tuple[int, ...], window: int):
+    def local(bases, planes):
+        # one entry a class: this chip's shard of the class's bases, and
+        # (mags, negs), each (B_local, n_planes, n_local): this slice's
+        # share of the proof batch over those bases
+        part = None
+        for bs, (mags, negs), width in zip(bases, planes, lanes):
+            sums = jax.vmap(lambda m, n: msm_plane_sums(curve, bs, m, n, lanes=width, window=window))(mags, negs)
+            # Horner over the planes for the whole batch at once: its kernels have the
+            # shape of the fold below, whatever the class
+            acc = horner_fold_planes(
+                curve, curve.infinity(mags.shape[:1]), tuple(jnp.moveaxis(c, 1, 0) for c in sums), window)
+            part = acc if part is None else curve.add(part, acc)
         # ICI allreduce within the slice: combine base-axis partials
         gathered = jax.lax.all_gather(part, ici_axis, axis=1)
         acc = _fold_gathered_batched(curve, gathered, mesh.shape[ici_axis])
@@ -71,12 +74,10 @@ def _msm_pod_fn(curve: JCurve, n_bases: int, mesh: Mesh, dcn_axis: str, ici_axis
         # the make_pod_mesh contract of data-parallel-only over dcn)
         return tuple(jax.lax.all_gather(c, dcn_axis, axis=0, tiled=True) for c in acc)
 
-    in_specs = (
-        tuple(P(ici_axis) for _ in range(n_bases)),
-        P(dcn_axis, None, ici_axis),
-    )
-    out_specs = tuple(P() for _ in range(3))
-    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
+    # a spec is a prefix of its argument's tree: every coordinate of every
+    # class's bases, every class's mags and negs
+    in_specs = (P(ici_axis), P(dcn_axis, None, ici_axis))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False))
 
 
 def _fold_gathered_batched(curve: JCurve, gathered: ProjPoint, n: int) -> ProjPoint:
@@ -93,12 +94,12 @@ def _fold_gathered_batched(curve: JCurve, gathered: ProjPoint, n: int) -> ProjPo
 
 def msm_pod_batched(
     curve: JCurve,
-    bases: AffPoint,
-    planes_batch: jnp.ndarray,
+    bases: Sequence[AffPoint],
+    planes: Sequence[Tuple[jnp.ndarray, jnp.ndarray]],
     mesh: Mesh,
     dcn_axis: str = "dcn",
     ici_axis: str = "shard",
-    lanes: int = 64,
+    lanes: Sequence[int] = (64,),
     window: int = 4,
 ) -> ProjPoint:
     """Batched MSM over a pod mesh (`make_pod_mesh`): the proof batch is
@@ -107,13 +108,22 @@ def msm_pod_batched(
     `shard` axis — the v5e-256 configuration of BASELINE.json, with the
     only DCN traffic being one proof point per batch element.
 
-    planes_batch: (B, n_planes, N) digit planes, B divisible by the dcn
-    width, N by the ici width (a key placed on the mesh is padded to it
-    once, `prover.groth16_tpu.place_key`; the prover hands both over
-    already laid out as the program's `in_specs` want them, so nothing
-    is resharded).  Returns (B,)-batched projective points, replicated
-    everywhere."""
-    B = planes_batch.shape[0]
-    assert B % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"
-    assert bases[0].shape[0] % mesh.shape[ici_axis] == 0, "pad the base axis first"
-    return _msm_pod_fn(curve, len(bases), mesh, dcn_axis, ici_axis, lanes, window)(bases, planes_batch)
+    A shard's MSM is the one-chip road's on signed digits
+    (`msm_windowed_signed`'s table and accumulate, `ops.msm.msm_plane_sums`;
+    the lanes folded before the planes, so that every class's Horner
+    fold and the allreduce's fold are one pair of kernels to lower), and
+    the query comes in classes, one entry of
+    `bases`, `planes` and `lanes` each (the key's narrow class at its few
+    low planes and wide steps beside the wide one at all of them;
+    `prover.groth16_tpu.place_key`): a class's `planes` are `(mags,
+    negs)`, each (B, n_planes, N), B divisible by the dcn width, N by
+    the ici width (a placed key's classes are padded to it once, and the
+    prover hands everything over already laid out as the program's
+    `in_specs` want it, so nothing is resharded).  Each chip sums its
+    classes' partials before the one all_gather + fold.  Returns
+    (B,)-batched projective points, replicated everywhere."""
+    assert len(bases) == len(planes) == len(lanes) > 0
+    for bs, (mags, _negs) in zip(bases, planes):
+        assert mags.shape[0] % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"
+        assert bs[0].shape[0] % mesh.shape[ici_axis] == 0, "pad the base axis first"
+    return _msm_pod_fn(curve, mesh, dcn_axis, ici_axis, tuple(lanes), window)(tuple(bases), tuple(planes))

@@ -62,7 +62,6 @@ from ..field.jfield import FR, lazy_segment_sum_mod
 from ..ops.msm import (
     RESIDENT_ENTRY_BYTES,
     default_lanes,
-    digit_planes_from_limbs,
     msm_resident,
     msm_windowed_signed,
     resident_table,
@@ -82,8 +81,8 @@ from ..utils.jaxcfg import on_tpu as _on_tpu
 BATCH_CHUNK = _load_config().batch_chunk
 # The witness MSMs' digits: signed 4-bit windows, ~72 point-adds a base
 # against the 256 of the bit-plane formulation, an 8-entry multiples
-# table a scan step (a negative digit is (x, -y) for free).  The mesh
-# road recodes unsigned at the same window.
+# table a scan step (a negative digit is (x, -y) for free), on the mesh
+# road as on one chip.
 MSM_WINDOW = 4
 
 # The h MSM's window multiples live in a table resident with the key
@@ -384,23 +383,49 @@ def mesh_name(mesh) -> str:
 
 
 def pod_lanes(n: int, n_ici: int, proofs_a_group: int = BATCH_CHUNK_MAX) -> int:
-    """The step width of a pod MSM over `n` bases, padded or not, in
-    `n_ici` shards, under a chunk of `proofs_a_group` proofs a batch
-    group: 64 for a chunk of BATCH_CHUNK_MAX, or a shard's whole share
-    where that is less (tiny CI circuits stay at lanes ~ n/S instead of
-    padding 16x to a 64-lane step).  `place_key` pads the bases to a
-    multiple of `n_ici * pod_lanes(n, n_ici)`, so every device sees
-    whole steps.  A smaller chunk takes steps as many times wider: a
-    step builds its table of multiples once whatever the chunk (15 mixed
-    adds in a row on `lanes` points: latency, not work), so a chunk of
-    one at 64 lanes would pay a chunk of four's steps for a quarter of
-    its accumulate; at 256 it runs a quarter of the steps, its
-    accumulate (planes x lanes x proofs) what a chunk of four's is
-    (`msm_windowed` pads a share that is no whole number of them)."""
+    """The step width of a pod MSM's wide class and of its h MSM over
+    `n` bases, padded or not, in `n_ici` shards, under a chunk of
+    `proofs_a_group` proofs a batch group: 64 for a chunk of
+    BATCH_CHUNK_MAX, or a shard's whole share where that is less (tiny
+    CI circuits stay at lanes ~ n/S instead of padding 16x to a 64-lane
+    step).  `place_key` pads the bases to a multiple of `n_ici *
+    pod_lanes(n, n_ici)`, so every device sees whole steps.  A smaller
+    chunk takes steps as many times wider: a step builds its table of
+    multiples once whatever the chunk (8 mixed adds in a row on `lanes`
+    points: latency, not work), so a chunk of one at 64 lanes would pay
+    a chunk of four's steps for a quarter of its accumulate; at 256 it
+    runs a quarter of the steps, its accumulate (planes x lanes x
+    proofs) what a chunk of four's is (`msm_windowed_signed` pads a
+    share that is no whole number of them)."""
     return max(1, min(64, -(-n // n_ici))) * max(1, BATCH_CHUNK_MAX // proofs_a_group)
 
 
-_POD_BASES = ("a_bases", "b1_bases", "b2_bases", "c_bases", "h_bases")
+def pod_narrow_lanes(n: int, n_ici: int, proofs_a_group: int = BATCH_CHUNK_MAX, cap: int = 16384) -> int:
+    """The step width of a pod MSM's narrow class over `n` bases in
+    `n_ici` shards: the one-chip road's rule on a chip's share
+    (`default_lanes`: a sixteenth of it, rounded up so that a share
+    padded to whole steps answers the same; `cap` 16,384 for G1 as
+    `_msm_g1_narrow`, 4,096 for G2 as `_msm_g2_narrow`), and no narrower
+    than gives a step's accumulate, NARROW_PLANES x lanes x proofs, the
+    adds of the wide class's (every plane x `pod_lanes` x proofs:
+    16,384) — three planes at the wide class's 64 lanes would be 768
+    adds a step, on the latency floor — for a chunk of four and of one
+    alike; never more than the share."""
+    share = -(-n // n_ici)
+    wide_step = (256 // MSM_WINDOW) * pod_lanes(n, n_ici, proofs_a_group) * proofs_a_group
+    floor = -(-wide_step // (NARROW_PLANES * proofs_a_group))
+    return max(1, min(share, cap, max(default_lanes(share + 15, cap), floor)))
+
+
+_POD_QUERIES = ("a", "b1", "b2", "c")  # the witness MSMs: on a mesh each in two classes
+
+
+def _narrow_cap(query: str) -> int:
+    """The most lanes a step of `query`'s narrow class takes on a mesh:
+    `_msm_g1_narrow`'s, and `_msm_g2_narrow`'s for b2."""
+    return 4096 if query == "b2" else 16384
+
+
 _QAP_ROWS = ("a_coeff", "a_wire", "a_row", "b_coeff", "b_wire", "b_row")
 
 
@@ -419,15 +444,26 @@ def place_key(dpk: "DeviceProvingKey", where) -> "DeviceProvingKey":
     device).
 
     A mesh: a new instance that only the mesh road reads
-    (`_prove_batch_sharded`; `key_mesh` tells).  Its five base arrays
-    are padded with infinity bases to whole steps of every shard
-    (`pod_lanes`) and committed `P("shard")`, a quarter a chip on 1x4;
-    `b_sel` / `c_sel` are padded to their bases' length (the filler
-    names wire 0, against an infinity base) and committed the same way,
-    so a chip holds the wire of each base it holds; the QAP rows, which
-    the h program reads whole, are committed to every chip.  The class
-    splits are empty: the mesh road has no narrow class.  After this no
-    batch moves a byte of key."""
+    (`_prove_batch_sharded`; `key_mesh` tells).  Each witness query
+    (`a_bases`, `b1_bases`, `b2_bases`, `c_bases`) becomes a pair of
+    classes, `(narrow, wide)`, each `(x, y, wire)`: the bases the key's
+    own classing selects (`a_nsel` / `a_wsel`, `b_*`, `c_*`:
+    `class_sels`) with the wire of every base beside it, each base in
+    one of the two and the whole array kept nowhere; a key with no
+    narrow class (an imported zkey without widths) has every base in
+    the wide one and none in the narrow.  Every class, and `h_bases`,
+    is padded to whole steps of every shard (`pod_narrow_lanes`,
+    `pod_lanes`; the filler is an infinity base that names wire 0) and
+    committed `P("shard")`, a quarter a chip on 1x4, so each chip holds
+    an equal share of each class and the wire of each base it holds.
+    The three G1 queries' classes are padded to one base count a class,
+    so they run ONE program (`_prove_device`'s `unify`: b1, half of
+    a's bases, then costs what a does; a two-class program more to
+    lower costs a start-up more); b2, three times the work a base,
+    keeps its own count.  The eight selections are empty: the classes
+    hold what they said.  The QAP rows, which the h program reads
+    whole, are committed to every chip.  After this no batch moves a
+    byte of key."""
     from jax.sharding import Mesh
 
     from ..utils.metrics import REGISTRY
@@ -464,19 +500,37 @@ def _place_on_mesh(dpk: "DeviceProvingKey", mesh) -> "DeviceProvingKey":
     n_ici = mesh.shape["shard"]
     sharded, whole = NamedSharding(mesh, P("shard")), NamedSharding(mesh, P())
 
-    def in_shards(x, n_to):
-        pad = np.pad if isinstance(x, np.ndarray) else jnp.pad  # a key on the host goes to its chips from there
-        return jax.device_put(pad(x, [(0, n_to - x.shape[0])] + [(0, 0)] * (x.ndim - 1)), sharded)
+    def in_shards(x, n, lanes):  # padded to whole steps of `lanes` on every shard, of `n` bases
+        n_to = n + (-n) % (n_ici * lanes) if n else 0
+        return jax.device_put(np.pad(x, [(0, n_to - x.shape[0])] + [(0, 0)] * (x.ndim - 1)), sharded)
 
+    def classes(bases, sels, wire_of):
+        """The (narrow, wide) pair of a query, unpadded: the key's own
+        classing; without one (`a_nsel` empty) every base is wide.
+        `wire_of`: the wire of each base, None where they are in order.
+        Cut on the host, in numpy, wherever the key lives (a key no chip
+        holds is there already): an eager take or pad on the device is a
+        program to lower and compile every start, and eight classes
+        would be a few dozen."""
+        bases, sels = tuple(np.asarray(c) for c in bases), tuple(np.asarray(sel) for sel in sels)
+        if not int(dpk.a_nsel.shape[0]):
+            sels = (np.zeros((0,), np.int32), np.arange(bases[0].shape[0], dtype=np.int32))
+        return [tuple(c[sel] for c in bases) + (sel if wire_of is None else np.asarray(wire_of)[sel],) for sel in sels]
+
+    b_sels = (dpk.b_nsel, dpk.b_wsel)
+    pairs = {"a": classes(dpk.a_bases, (dpk.a_nsel, dpk.a_wsel), None), "b1": classes(dpk.b1_bases, b_sels, dpk.b_sel),
+             "b2": classes(dpk.b2_bases, b_sels, dpk.b_sel), "c": classes(dpk.c_bases, (dpk.c_nsel, dpk.c_wsel), dpk.c_sel)}
+    g1_most = [max(pairs[q][k][0].shape[0] for q in ("a", "b1", "c")) for k in (0, 1)]
     fields = {f: jax.device_put(getattr(dpk, f), whole) for f in _QAP_ROWS}
-    for f in _POD_BASES:
-        n = getattr(dpk, f)[0].shape[0]
-        n_to = n + (-n) % (n_ici * pod_lanes(n, n_ici))
-        fields[f] = tuple(in_shards(c, n_to) for c in getattr(dpk, f))
-    fields["b_sel"] = in_shards(dpk.b_sel, fields["b1_bases"][0].shape[0])
-    fields["c_sel"] = in_shards(dpk.c_sel, fields["c_bases"][0].shape[0])
-    none = jax.device_put(jnp.zeros((0,), jnp.int32), whole)
-    for f in ("a_nsel", "a_wsel", "b_nsel", "b_wsel", "c_nsel", "c_wsel"):
+    for q, pair in pairs.items():
+        n_narrow, n_wide = (cls[0].shape[0] for cls in pair) if q == "b2" else g1_most
+        steps = (pod_narrow_lanes(n_narrow, n_ici, cap=_narrow_cap(q)), pod_lanes(n_wide, n_ici))
+        fields[q + "_bases"] = tuple(
+            tuple(in_shards(c, n, lanes) for c in cls) for cls, n, lanes in zip(pair, (n_narrow, n_wide), steps))
+    n_h = dpk.h_bases[0].shape[0]
+    fields["h_bases"] = tuple(in_shards(np.asarray(c), n_h, pod_lanes(n_h, n_ici)) for c in dpk.h_bases)
+    none = jax.device_put(np.zeros((0,), np.int32), whole)
+    for f in ("b_sel", "c_sel", "a_nsel", "a_wsel", "b_nsel", "b_wsel", "c_nsel", "c_wsel"):
         fields[f] = none
     return dataclasses.replace(dpk, **fields)
 
@@ -1293,34 +1347,49 @@ def h_ici_bytes(mesh, n_proofs: int, log_m: int) -> int:
 
 
 @lru_cache(maxsize=None)
-def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
+def _exchange_pod_fn(mesh, split: bool, n_h: int):
     """From the h stage's layout to the MSMs': over ICI the group's
-    witnesses are all-gathered (b_sel / c_sel name any wire) and each
+    witnesses are all-gathered (a class's bases name any wire) and each
     chip's h goes out by `all_to_all`, so that a chip holds, for every
     proof of its group, the domain columns of its h bases; then each
-    chip cuts the wires of its a, b and c bases out of the witnesses
-    and recodes its columns, and only those, to unsigned digit planes
-    (B, 256 / MSM_WINDOW, n) — the layout `msm_pod_batched` consumes,
-    already where the bases are.  `n_a` and `n_h` are the placed key's
-    padded a and h base counts.  Without `split` nothing crosses: every
-    chip of a group holds the group's witnesses and h whole."""
+    chip cuts the wires of the bases it holds, class by class (`wires`:
+    a `(narrow, wide)` pair of wire ids a query, the placed key's, in
+    the shards of the bases), out of the witnesses and recodes its
+    columns, and only those, to SIGNED digit planes
+    (`signed_digit_planes_from_limbs` at MSM_WINDOW): `(mags, negs)`,
+    each (B, planes, n) — every plane for a wide class and for h, the
+    low NARROW_PLANES for a narrow class, whose wires' upper planes are
+    provably zero (`_recode`), recoded from the low limb alone — the
+    layout `msm_pod_batched` consumes, already where the bases are.
+    `n_h` is the placed key's padded h base count.  Without `split` nothing crosses: every chip of a group
+    holds the group's witnesses and h whole."""
     from jax.sharding import PartitionSpec as P
 
     n_ici = mesh.shape["shard"]
 
-    def planes(cols):  # (B, n, 16) -> (B, n_planes, n)
-        return jnp.moveaxis(digit_planes_from_limbs(cols, MSM_WINDOW), 0, 1)
+    def planes(cols):  # (B, n, 16) -> (mags, negs), each (B, 256 / MSM_WINDOW, n)
+        return tuple(jnp.moveaxis(p, 0, 1) for p in signed_digit_planes_from_limbs(cols, MSM_WINDOW))
 
-    def padded(x, n_to):
-        return jnp.pad(x, [(0, 0), (0, n_to - x.shape[1]), (0, 0)])
+    def narrow_planes(low):
+        """(B, n) low limbs -> (mags, negs), each (B, NARROW_PLANES, n):
+        the low NARROW_PLANES planes of `planes`, computed alone.  A
+        recoded digit depends on the digits below it only (one above
+        half a window borrows from the next), and NARROW_PLANES digits
+        lie in the low limb: three are recoded, not 64 to keep three,
+        and one limb of a narrow wire is gathered, not sixteen."""
+        assert NARROW_PLANES * MSM_WINDOW <= 16  # the low limb's bits
+        half, carry, mags, negs = 1 << (MSM_WINDOW - 1), 0, [], []
+        for k in range(NARROW_PLANES):  # least significant first
+            e = ((low >> (MSM_WINDOW * k)) & (2 * half - 1)) + carry
+            neg = e > half
+            mags.append(jnp.where(neg, 2 * half - e, e))
+            negs.append(neg)
+            carry = neg.astype(low.dtype)
+        return jnp.stack(mags[::-1], axis=1), jnp.stack(negs[::-1], axis=1)  # most significant first
 
-    def mine(x):  # this chip's columns of a (B, n, 16) array whole on it
-        n = x.shape[1] // n_ici
-        return jax.lax.dynamic_slice_in_dim(x, jax.lax.axis_index("shard") * n, n, axis=1)
-
-    def local(sels, w_std, h_std):
+    def local(wires, w_std, h_std):
         if split:
-            h_std = padded(h_std, n_h)
+            h_std = jnp.pad(h_std, [(0, 0), (0, n_h - h_std.shape[1]), (0, 0)])
             w_std = jax.lax.all_gather(w_std, "shard", axis=0, tiled=True)
             # each chip's S-th of the columns to the chip that holds their bases, the split
             # axis leading (split along the columns in place, the same all_to_all compiles
@@ -1331,16 +1400,18 @@ def _exchange_pod_fn(mesh, split: bool, n_a: int, n_h: int):
             ).reshape(n_ici * b_loc, n_loc, 16)
         else:
             h_mine = h_std
-        b_sel, c_sel = sels
         with jax.named_scope("recode"):
-            out = (planes(mine(padded(w_std, n_a))), planes(jnp.take(w_std, b_sel, axis=1)),
-                   planes(jnp.take(w_std, c_sel, axis=1)), planes(h_mine))
-        return out + (out[3][:1, 0, 0],)  # `done`, a digit a chip: ready when the stage is
+            low = w_std[..., 0]
+            queries = tuple(
+                (narrow_planes(jnp.take(low, narrow, axis=1)), planes(jnp.take(w_std, wide, axis=1)))
+                for narrow, wide in wires)
+            h = planes(h_mine)
+        return queries, h, h[0][:1, 0, 0]  # the last is `done`, a digit a chip: ready when the stage is
 
     chunk, cols = _pod_chunk_spec(mesh, split), P("batch", None, "shard")
     return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("shard"), chunk, chunk if split else P("batch", "shard")),
-        out_specs=(cols, cols, cols, cols, P(("batch", "shard"))), check_vma=False,
+        out_specs=(cols, cols, P(("batch", "shard"))), check_vma=False,
     ))
 
 
@@ -1367,12 +1438,22 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
     where they do not: `h_shards` and `ici_bytes` on the span, and
     `zkp2p_h_ici_bytes_total`); the exchange
     (`_exchange_pod_fn`, a stage of its own, `bytes` over ICI) leaves
-    on every chip the digit planes of the columns whose bases it holds;
-    and every MSM runs base-axis-sharded over the inner "shard" axis
-    with per-device bucket partial sums combined by ONE group-op
-    allreduce (all_gather + projective fold — ICI on real hardware,
-    host rings on the virtual CPU mesh; parallel.mesh.msm_pod_batched).
-    Seven stages, each span with `mesh`.  Returns the same five
+    on every chip the signed digit planes of the columns whose bases it
+    holds, class by class; and every MSM runs base-axis-sharded over
+    the inner "shard" axis, a shard's MSM the one-chip road's
+    (`ops.msm.msm_plane_sums`: `msm_windowed_signed`'s table and
+    accumulate): each of the four witness MSMs over the
+    key's narrow class (its low NARROW_PLANES planes, at
+    `pod_narrow_lanes`) and its wide class (every plane, at
+    `pod_lanes`, as h), the two partial sums added on the chip, then
+    ONE group-op allreduce a query (all_gather + projective fold — ICI
+    on real hardware, host rings on the virtual CPU mesh;
+    parallel.mesh.msm_pod_batched).  a, b1 and c share one program
+    (`place_key` pads their classes to one count), b2 and h have one
+    each.  Seven stages, each span with `mesh`; the four query spans
+    say `narrow` and `wide`, the bases a chip holds in each class
+    (padding included; `narrow` 0 for a key without widths), and all
+    five MSM spans `digits` ("signed").  Returns the same five
     (B,)-batched accumulators `_prove_device` emits, so chunks from
     either arm concatenate identically downstream."""
     from jax.sharding import NamedSharding
@@ -1399,25 +1480,32 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
         _enqueued(watch, "h_planes", done, ntt=NTT_LADDER, mesh=on, proofs_a_chip=n_proofs // mesh.shape["batch"],
                   h_shards=n_ici, ici_bytes=crossed)
         REGISTRY.counter("zkp2p_h_ici_bytes_total").inc(crossed)
-    n_a, n_h = dpk.a_bases[0].shape[0], dpk.h_bases[0].shape[0]
-    a_planes, b_planes, c_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_a, n_h)(
-        (dpk.b_sel, dpk.c_sel), w_std, h_std)
+    n_h, a_group = dpk.h_bases[0].shape[0], n_proofs // mesh.shape["batch"]
+    queries = tuple(getattr(dpk, q + "_bases") for q in _POD_QUERIES)  # a (narrow, wide) pair each, (x, y, wire) a class
+    w_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_h)(
+        tuple(tuple(cls[2] for cls in pair) for pair in queries), w_std, h_std)
     _enqueued(watch, "exchange", done, mesh=on, bytes=exchange_bytes(mesh, n_proofs, n_wires, n_h))
     del w_std, h_std  # the MSMs read the planes alone
 
-    def msm(name, curve, bases, planes):
+    def msm(name, curve, classes, **attrs):
+        """One pod MSM, enqueued, over `classes`: (bases, planes, lanes)
+        each; a class the key has no base in takes no part."""
+        bases, planes, lanes = zip(*(cls for cls in classes if cls[0][0].shape[0]))
         return _msm_enqueued(watch, name, msm_pod_batched(
-            curve, bases, planes, mesh, dcn_axis="batch", ici_axis="shard",
-            lanes=pod_lanes(bases[0].shape[0], n_ici, n_proofs // mesh.shape["batch"]), window=MSM_WINDOW,
-        ), mesh=on)
+            curve, bases, planes, mesh, dcn_axis="batch", ici_axis="shard", lanes=lanes, window=MSM_WINDOW,
+        ), mesh=on, digits="signed", **attrs)
 
-    return (
-        msm("msm_a", G1J, dpk.a_bases, a_planes),
-        msm("msm_b1", G1J, dpk.b1_bases, b_planes),
-        msm("msm_b2", G2J, dpk.b2_bases, b_planes),
-        msm("msm_c", G1J, dpk.c_bases, c_planes),
-        msm("msm_h", G1J, dpk.h_bases, h_planes),
-    )
+    def query(q, pair, planes):
+        """A witness MSM: the key's narrow class at its low planes and
+        wide steps, the wide class at every plane, one sum a chip."""
+        n_narrow, n_wide = (cls[0].shape[0] for cls in pair)
+        return msm("msm_" + q, G2J if q == "b2" else G1J, (
+            (pair[0][:2], planes[0], pod_narrow_lanes(n_narrow, n_ici, a_group, _narrow_cap(q))),
+            (pair[1][:2], planes[1], pod_lanes(n_wide, n_ici, a_group)),
+        ), narrow=n_narrow // n_ici, wide=n_wide // n_ici)
+
+    return tuple(query(*args) for args in zip(_POD_QUERIES, queries, w_planes)) + (
+        msm("msm_h", G1J, ((dpk.h_bases, h_planes, pod_lanes(n_h, n_ici, a_group)),)),)
 
 
 # What the device waited for between two batches, by what the thread that
