@@ -10,6 +10,8 @@ read returns None and the harness leaves the metric out of the line.
   memory           one memory_stats() dict per device, after the window
   phases           {name: seconds} of the harness's own set-up clock
   batch_size       the service's batch size
+  score            score.score_window's dict: what the end-to-end metrics were taken from
+  lateness         score.lateness's dict of an open loop's generator, None in a closed loop
 """
 
 from __future__ import annotations
@@ -58,10 +60,11 @@ def record_field(spec: Dict, run: Dict) -> Optional[float]:
 
 def counter(spec: Dict, run: Dict) -> Optional[float]:
     """A counter's growth over the window, or a histogram's mean over the
-    window's observations; `per` divides by a quantity of the run."""
+    window's observations; `per` divides by a quantity of the run; `absent`
+    is the reading where the program has not created the counter yet."""
     c = run["counters"].get(spec["counter"])
     if c is None or c["after"] is None:
-        return None
+        return spec.get("absent")  # a counter the program creates at its first count reads `absent` (0) until then
     before, after = c["before"] or {}, c["after"]
     if after.get("kind") == "histogram":
         n = after.get("count", 0) - before.get("count", 0)
@@ -91,10 +94,18 @@ def phase_s(spec: Dict, run: Dict) -> Optional[float]:
     return run["phases"].get(spec["phase"])
 
 
+def run_field(spec: Dict, run: Dict) -> Optional[float]:
+    """A number the harness itself took: `field` of the run's `of` dict
+    (`score` or `lateness`); nothing where the run has no such dict (a
+    closed loop has no lateness) or the field holds no number."""
+    value = (run.get(spec["of"]) or {}).get(spec["field"])
+    return None if value is None else float(value) * spec.get("scale", 1.0)
+
+
 READERS: Dict[str, Callable[[Dict, Dict], Optional[float]]] = {
     "span_ms": span_ms, "span_ms_per_n": span_ms_per_n, "record_field": record_field,
     "counter": counter, "monitoring_sum": monitoring_sum, "memory_stat": memory_stat,
-    "phase_s": phase_s,
+    "phase_s": phase_s, "run_field": run_field,
 }
 
 

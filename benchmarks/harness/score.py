@@ -43,6 +43,22 @@ def lateness(due: Sequence[float], sent: Sequence[float]) -> Dict[str, float]:
             "p95_s": percentile(late, 0.95), "max_s": max(late)}
 
 
+DEADLINE_STATE = "error-deadline-exceeded"
+CLOCK_SLACK_S = 0.05  # the service's deadline runs from the request file's mtime, ours from the instant after the rename
+
+
+def refused_at_deadline(r: Dict) -> Optional[bool]:
+    """For a request the service refused as past its deadline: True when its
+    own `deadline_s` really had passed by then (a miss, counted as failed
+    and nothing else), False when it had not (a wrong answer).  None for
+    every other request, and for a refusal of a request that carried no
+    deadline."""
+    deadline_s = (r.get("payload") or {}).get("deadline_s")
+    if r.get("state") != DEADLINE_STATE or not deadline_s:
+        return None
+    return r["t_terminal"] >= r["t_sent"] + float(deadline_s) - CLOCK_SLACK_S
+
+
 def score_window(requests: List[Dict], t_first: float) -> Dict:
     """requests: one dict per request submitted in the window, in the order
     sent, with `t_ref` (closed loop: the instant it entered the spool; open
@@ -56,15 +72,24 @@ def score_window(requests: List[Dict], t_first: float) -> Dict:
     and fails unless done and valid.  A request left without a terminal
     artifact counts nowhere if it was sent after every request that has
     one (the window closed before the service came to it), and fails if a
-    later one was served: the service passed over it."""
+    later one was served: the service passed over it.
+
+    A request that carried `deadline_s` and was refused as past it is
+    attempted and failed like any other error, and counted apart: at or
+    after its deadline (`refused_at_deadline`: the service honestly
+    missed), or before it (`refused_before_deadline`).  A proof that comes
+    out `done` after its deadline is a late proof: valid, scored, in the
+    tail."""
     terminal = [r for r in requests if r.get("t_terminal") is not None]
     good = [r for r in terminal if r["state"] == "done" and r.get("valid")]
     last_served = max((i for i, r in enumerate(requests) if r.get("t_terminal") is not None), default=-1)
     passed_over = [r for i, r in enumerate(requests) if r.get("t_terminal") is None and i < last_served]
+    refusals = [refused_at_deadline(r) for r in terminal]
     lat = [r["t_terminal"] - r["t_ref"] for r in good]
     out = {
         "submitted": len(requests), "attempted": len(terminal) + len(passed_over),
         "failed": len(terminal) - len(good) + len(passed_over), "passed_over": len(passed_over),
+        "refused_at_deadline": refusals.count(True), "refused_before_deadline": refusals.count(False),
         "unclaimed_at_end": len(requests) - len(terminal) - len(passed_over), "latency_samples": len(lat),
         "proofs_per_s": proofs_per_s(t_first, [r["t_terminal"] for r in good]),
     }

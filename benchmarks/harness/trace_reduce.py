@@ -120,11 +120,33 @@ def self_times(events: Sequence[Sequence]) -> Dict[str, float]:
     return out
 
 
+def plane_index(plane: str) -> Optional[int]:
+    """"/device:TPU:2" -> 2: the index a replica's spans carry as `replica`."""
+    tail = plane.rsplit(":", 1)[-1]
+    return int(tail) if tail.isdigit() else None
+
+
+def credit_innermost(left: List[Tuple[float, float]], spans: Sequence[Tuple[str, Tuple[float, float]]],
+                     gap_ns: Dict[str, float]) -> List[Tuple[float, float]]:
+    """Credit the idle intervals `left` to the spans of ONE rank and return
+    what none of them covers.  Where spans overlap (a span and the spans
+    nested in it), an instant goes to the innermost only: the one that
+    began last."""
+    for label, iv in sorted(spans, key=lambda sp: (-sp[1][0], sp[1][1])):
+        got = overlap_s(left, [iv])
+        if got > 0:
+            gap_ns[label] = gap_ns.get(label, 0.0) + got
+            left = subtract(left, [iv])
+    return left
+
+
 def reduce_events(events: Dict, host_spans: Sequence[Dict], wall_start_ns: Optional[int] = None,
                   wall_stop_ns: Optional[int] = None, top: int = 10) -> Optional[Dict]:
-    """host_spans: {"label", "t0_wall_s", "ms", "rank"}; lower rank claims a
-    gap first (the proving thread's spans before the witness thread's).
-    None when the trace holds no device operation."""
+    """host_spans: {"label", "t0_wall_s", "ms", "rank", "replica"}; lower rank
+    claims a gap first (the proving thread's spans before the witness
+    thread's), within a rank the innermost span; a span with a `replica`
+    is laid against that device's plane alone.  None when the trace holds
+    no device operation."""
     anchor = events.get("anchor")
     offset = (anchor["wall_ns"] - anchor["start_ns"]) if anchor else None
     per_device = {}
@@ -143,8 +165,9 @@ def reduce_events(events: Dict, host_spans: Sequence[Dict], wall_start_ns: Optio
     if offset is not None:
         for sp in host_spans:
             s = sp["t0_wall_s"] * 1e9 - offset
-            spans.append((sp.get("rank", 0), sp["label"], (s, s + sp["ms"] * 1e6)))
-    ranks = sorted({r for r, _, _ in spans})
+            if s < hi and s + sp["ms"] * 1e6 > lo:  # the sink holds the whole window's spans, the slice a few seconds
+                spans.append((sp.get("rank", 0), sp.get("replica"), sp["label"], (s, s + sp["ms"] * 1e6)))
+    ranks = sorted({r for r, _, _, _ in spans})
 
     busy_ns = 0.0
     op_ns: Dict[str, float] = {}
@@ -158,16 +181,11 @@ def reduce_events(events: Dict, host_spans: Sequence[Dict], wall_start_ns: Optio
             for op, ns in self_times(inside).items():
                 op_ns[op] = op_ns.get(op, 0.0) + ns
         left = subtract([(lo, hi)], busy)  # this device's idle gaps
-        for rank in ranks:
-            claimed: List[Tuple[float, float]] = []
-            for r, label, iv in spans:
-                if r != rank:
-                    continue
-                got = overlap_s(left, [iv])
-                if got > 0:
-                    gap_ns[label] = gap_ns.get(label, 0.0) + got
-                    claimed.append(iv)
-            left = subtract(left, merge(claimed))
+        index = plane_index(plane)
+        for rank in ranks:  # a solo service's spans against every plane, a replica's against its own device's
+            left = credit_innermost(
+                left, [(label, iv) for r, replica, label, iv in spans
+                       if r == rank and (replica is None or index is None or replica == index)], gap_ns)
         rest = sum(e - s for s, e in left)
         if rest > 0:
             gap_ns["unattributed"] = gap_ns.get("unattributed", 0.0) + rest

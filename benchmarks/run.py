@@ -27,7 +27,7 @@ import random  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Dict, List, Optional, Sequence  # noqa: E402
 
 GUARANTEE_COUNTERS = ("zkp2p_service_shed_total", "zkp2p_service_degraded_total", "zkp2p_service_retries_total")
 DRAIN_TIMEOUT_S = 150.0
@@ -127,11 +127,13 @@ class TraceSlice:
     it starts `start_s` after the first submission and stops
     `after_boundary_s` after the first batch boundary (a terminal artifact
     appearing) it sees, or after `max_s` — so it holds the end of one batch
-    and the gap to the next batch's first device operation."""
+    and the gap to the next batch's first device operation.  The profiler
+    is stopped on a thread of its own: collecting a slice takes seconds,
+    and an open loop's generator has requests due meanwhile."""
 
     def __init__(self, spec: Dict, out_dir: str):
         self.spec, self.out_dir = spec, out_dir
-        self.t_start = self.t_stop = self.t_boundary = None
+        self.t_start = self.t_stop = self.t_boundary = self.stopping = None
         self.done_at_start = 0
         self.wall_start_ns = self.wall_stop_ns = None
 
@@ -157,33 +159,51 @@ class TraceSlice:
             self.t_boundary = now
         if (self.t_boundary is not None and now - self.t_boundary >= self.spec["after_boundary_s"]) \
                 or now - self.t_start >= self.spec["max_s"]:
-            self.stop()
+            self.stop(wait=False)
 
-    def stop(self) -> None:
-        import jax
-
+    def stop(self, wait: bool = True) -> None:
+        """The slice ends now; `wait` until the profiler has written it."""
         if self.t_start is not None and self.t_stop is None:
-            self.wall_stop_ns = time.time_ns()
-            jax.profiler.stop_trace()
-            self.t_stop = time.time()
+            import jax
+
+            self.wall_stop_ns, self.t_stop = time.time_ns(), time.time()
+            self.stopping = threading.Thread(target=jax.profiler.stop_trace, name="bench-trace-stop")
+            self.stopping.start()
+        if wait and self.stopping is not None:
+            self.stopping.join()
 
     def xplane(self) -> Optional[str]:
         found = sorted(glob.glob(os.path.join(self.out_dir, "plugins", "profile", "*", "*.xplane.pb")))
         return found[-1] if found else None
 
 
-def host_spans(records: List[Dict]) -> List[Dict]:
-    """The service's per-request lifecycle spans (wall clock t0 + ms), one
-    per batch, as gap labels: the proving thread's first."""
+NOT_HOST_INTERVALS = ("device_idle", "stage", "upload")  # accounts of a gap laid end to end, and the device's own clock
+
+
+def host_spans(records: List[Dict], stage_spans: Sequence[Dict] = ()) -> List[Dict]:
+    """What the host was doing, as gap labels (wall clock t0 + ms): the
+    service's per-request lifecycle spans, one per batch, and the stage
+    spans of the sink (`service/sweep`, `/poll`, `/handover`, `/starved`,
+    `tpu/prove_batch/prep`, `/finish`, ...).  The proving thread's and the
+    run loop's first (rank 0), then the witness producers'.  A span of a
+    replica's loop carries its `replica`."""
     seen, out = set(), []
+
+    def add(label: str, t0: float, ms: float, replica) -> None:
+        if (label, t0) not in seen:
+            seen.add((label, t0))
+            out.append({"label": label, "t0_wall_s": t0, "ms": ms, "replica": replica,
+                        "rank": 1 if label.rsplit("/", 1)[-1].startswith(("witness", "inputs")) else 0})
+
     for rec in records:
         for sp in rec.get("spans") or []:
-            key = (sp["name"], sp["t0"])
-            if key in seen:
-                continue
-            seen.add(key)
-            out.append({"label": "service/" + sp["name"], "t0_wall_s": sp["t0"], "ms": sp["ms"],
-                        "rank": 1 if sp["name"].startswith(("witness", "inputs")) else 0})
+            add("service/" + sp["name"], sp["t0"], sp["ms"], rec.get("replica"))
+    for sp in stage_spans:
+        path = sp["stage"]
+        at = max(path.rfind("service/"), path.rfind("tpu/"))
+        if at >= 0 and not any(part in NOT_HOST_INTERVALS for part in path.split("/")):
+            # a path is the nesting of its thread ("service/prove/tpu/prove_batch/finish"): its last layer names it
+            add(path[at:], sp["t0"], sp["ms"], sp.get("replica"))
     return out
 
 
@@ -366,6 +386,13 @@ class Bench:
         sc = score.score_window(win["requests"], win["t_first"])
         numbers["requests_not_done_or_pairing_invalid_or_passed_over"] = sc["failed"] - untied
         numbers["proofs_with_signals_not_their_requests"] = untied
+        counted: Dict[str, int] = {}  # printed beside the numbers compared, with no limit
+        if traffic["deadline_s"]:
+            # a refusal once the request's own deadline has passed is a miss (it stays in `failed`), not a wrong answer
+            numbers["requests_not_done_or_pairing_invalid_or_passed_over"] -= (
+                sc["refused_at_deadline"] + sc["refused_before_deadline"])
+            numbers["requests_refused_before_their_deadline"] = sc["refused_before_deadline"]
+            counted["requests_refused_at_their_deadline"] = sc["refused_at_deadline"]
         numbers["requests_shed_degraded_or_retried"] = int(sum(
             counters_after[n]["value"] - counters_before.get(n, {"value": 0.0})["value"]
             for n in GUARANTEE_COUNTERS if n in counters_after))
@@ -373,6 +400,8 @@ class Bench:
         numbers["gate_arm_faults"] = len(arm_faults)
         for name, value in numbers.items():
             say(f"check: {name} = {value} (limit 0)")
+        for name, value in counted.items():
+            say(f"check: {name} = {value} (no limit: counted in failed)")
         if arm_faults:
             say(f"gate arms not as configured: {arm_faults}")
         say(f"gates: {json.dumps(arms, sort_keys=True)}")
@@ -380,8 +409,9 @@ class Bench:
         say(f"checked in {time.time() - t_check:.1f}s: of {sc['submitted']} requests the service took on "
             f"{sc['attempted']}, {sc['failed']} failed, {sc['unclaimed_at_end']} it had not claimed when the "
             f"window closed; {sc['latency_samples']} latency samples")
-        if win["lateness_due"]:
-            say(f"generator lateness: {json.dumps(score.lateness(win['lateness_due'], win['lateness_sent']))}")
+        late = score.lateness(win["lateness_due"], win["lateness_sent"]) if win["lateness_due"] else None
+        if late:
+            say(f"generator lateness: {json.dumps(late)}")
 
         # ----------------------------------------------------------- metrics
         values: Dict[str, Optional[float]] = {"setup_s": setup_s}
@@ -403,7 +433,7 @@ class Bench:
                 "counters": {n: {"before": counters_before.get(n), "after": counters_after.get(n)}
                              for n in counters_after},
                 "monitoring": monitor.events, "memory": memory, "phases": phases.seconds,
-                "batch_size": self.batch_size,
+                "batch_size": self.batch_size, "score": sc, "lateness": late,
             }
             for m in cell.per_layer:
                 v = readers.read_metric(m, run_data)
@@ -414,7 +444,7 @@ class Bench:
             if xplane:
                 say(f"trace: {os.path.getsize(xplane)} bytes, slice {slicer.t_stop - slicer.t_start:.1f}s")
                 events = trace_reduce.load_events(xplane)
-                spans = host_spans(run_data["request_records"])
+                spans = host_spans(run_data["request_records"], run_data["stage_spans"])
                 with open(os.path.join(run_dir, "trace_meta.json"), "w") as f:  # what cut_trace_fixture.py reads
                     json.dump({"xplane": xplane, "host_spans": spans, "wall_start_ns": slicer.wall_start_ns,
                                "wall_stop_ns": slicer.wall_stop_ns}, f)
@@ -428,6 +458,9 @@ class Bench:
                 result["breakdown"] = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
         result["metrics"] = metrics
         result["device"] = result_device
+        # each number compared beside its limit, last in the line
+        result["checks"] = dict({name: {"value": value, "limit": 0} for name, value in numbers.items()},
+                                **{name: {"value": value, "limit": None} for name, value in counted.items()})
         with open(os.path.join(run_dir, "result.json"), "w") as f:
             json.dump({"result": result, "score": sc, "numbers": numbers, "phases": phases.seconds,
                        "arms": arms}, f, indent=1)
@@ -452,7 +485,10 @@ def main(argv=None, chip=None, root: Optional[str] = None) -> int:
     root = os.path.abspath(root or os.getcwd())
     bench = Bench(load_cell(root, args.workload), chip or Chip(), root)
     warm = bench.warm_up(args.seed)
-    print(json.dumps(bench.measure(args.seed, args.seconds, args.trace, T_PROCESS, warm)), flush=True)
+    result = bench.measure(args.seed, args.seconds, args.trace, T_PROCESS, warm)
+    for name, check in result["checks"].items():  # and as the last lines of standard error
+        print(f"check: {name} = {check['value']} (limit {check['limit']})", file=sys.stderr, flush=True)
+    print(json.dumps(result), flush=True)
     return 0
 
 

@@ -5,9 +5,11 @@
 
 A sound seed must read `correct: true`.  A control seed runs the same
 window with the timed path broken underneath — the device prover's answer
-altered where it is produced: one limb of the last proof of every served
+altered where it is produced: one limb of the second proof of every served
 batch flipped (not the first: the service's own sample verify checks that
-one) — and must read `correct: false`.  The arithmetic is exact, so there is
+one; not the last: under an open loop a batch short of its size proves
+with its last witness repeated, and what the padding proved is dropped) —
+and must read `correct: false`.  The arithmetic is exact, so there is
 no lower precision to fall into: the control breaks the configuration's
 guarantee "every proof verifies under the key's vk".  The benchmark's own
 runs never run this.  Needs a TPU, like the command.
@@ -44,8 +46,7 @@ def main(argv=None) -> int:
     def prove(dpk, witnesses, rs=None, ss=None):
         proofs = real(dpk, witnesses, rs=rs, ss=ss)
         if state["tamper"] and rs is None and len(proofs) > 1:
-            last = proofs[-1]
-            proofs[-1] = dataclasses.replace(last, c=(last.c[0] ^ (1 << 64), last.c[1]))
+            proofs[1] = dataclasses.replace(proofs[1], c=(proofs[1].c[0] ^ (1 << 64), proofs[1].c[1]))
         return proofs
 
     groth16_tpu.prove_tpu_batch = prove

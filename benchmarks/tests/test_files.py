@@ -98,3 +98,38 @@ def test_files_under_paths_are_named_from_the_allowed_characters(bench):
     listed = subprocess.run(["git", "ls-files", "--cached", "--others", "--exclude-standard", "--"] + bench["paths"],
                             cwd=REPO, capture_output=True, text=True).stdout.split()
     assert listed and all(PATH.match(p) for p in listed), [p for p in listed if not PATH.match(p)]
+
+
+def test_the_open_cells_load_from_the_committed_files_as_stated(bench):
+    under, over = load_cell(REPO, "sha2b.open-80"), load_cell(REPO, "sha2b.open-120")
+    for cell in (under, over):
+        assert (cell.chips, cell.config_name, cell.traffic["loop"], cell.traffic["batch_size"]) == (1, "sha2b", "open", 4)
+        assert cell.traffic["poll_s"] == 0.2 and cell.traffic["max_wait_s"] is None
+    assert (under.traffic["arrival"], under.traffic["rate_per_s"], under.traffic["deadline_s"]) == ("uniform", 2.0, 10.0)
+    assert (over.traffic["arrival"], over.traffic["burst_size"], over.traffic["rate_per_s"], over.traffic["deadline_s"]) == ("burst", 16, 3.1, None)
+    # under the knee the tails are judged and the rate is the offered one; above it the rate is judged and the tails recorded
+    assert {m["name"] for m in under.end_to_end} == {"latency_p90_s", "setup_s"}
+    assert {m["name"] for m in over.end_to_end} == {"proofs_per_s", "setup_s"}
+    assert {"deadline_refusals_in_window", "unclaimed_at_close.open", "generator_late_p95_ms.open", "batch_fill.open"} <= {m["name"] for m in under.per_layer}
+    assert {"unclaimed_at_close", "generator_late_p95_ms", "backlog_latency_p90_s", "batch_fill"} <= {m["name"] for m in over.per_layer}
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 4 and len(bench["workloads"]) == 10
+
+
+def test_the_open_loops_metric_files_read_what_a_run_hands_them():
+    sc = {"unclaimed_at_end": 27, "latency_p50_s": 6.5, "latency_p90_s": 9.25}
+    run = {"score": sc, "lateness": {"n": 155, "mean_s": 0.001, "p95_s": 0.0042, "max_s": 0.02},
+           "counters": {"zkp2p_service_deadline_total": {"before": {"kind": "counter", "value": 1.0}, "after": {"kind": "counter", "value": 4.0}}}}
+    closed = {"score": dict(sc, unclaimed_at_end=0), "lateness": None, "counters": {}}
+    read = {m["name"]: m for cell in ("sha2b.open-80", "sha2b.open-120") for m in load_cell(REPO, cell).per_layer}
+    got = {n: readers.read_metric(read[n], run) for n in read if read[n]["reader"]["kind"] == "run_field" or "deadline" in n}
+    assert got == {"generator_late_p95_ms": pytest.approx(4.2), "generator_late_p95_ms.open": pytest.approx(4.2),
+                   "unclaimed_at_close": 27.0, "unclaimed_at_close.open": 27.0, "deadline_refusals_in_window": 3.0,
+                   "backlog_latency_p50_s": 6.5, "backlog_latency_p90_s": 9.25}
+    # a counter the program has not created reads 0; a loop with no schedule has no lateness to read
+    assert readers.read_metric(read["deadline_refusals_in_window"], closed) == 0.0
+    assert readers.read_metric(read["unclaimed_at_close"], closed) == 0.0
+    assert readers.read_metric(read["generator_late_p95_ms"], closed) is None
+    # a metric split by what its cells report reads what the unsplit one reads
+    for name, m in read.items():
+        if name.endswith(".open") and name[:-5] in read:
+            assert m["reader"] == read[name[:-5]]["reader"] and m["moves"] == "latency_p90_s", name

@@ -85,7 +85,7 @@ def test_the_idle_metrics_read_the_gap_its_causes_and_the_upload_from_a_toy_trac
     for fn in sorted(os.listdir(os.path.join(REPO, "benchmarks", "layer_metrics"))):
         with open(os.path.join(REPO, "benchmarks", "layer_metrics", fn)) as f:
             spec = json.load(f)
-        if spec["name"] in NEW_METRICS or "spans" not in spec["reader"]:
+        if spec["name"].removesuffix(".open") in NEW_METRICS or "spans" not in spec["reader"]:  # `.open`: the same reader, split by what it moves
             continue
         matched = readers._spans(run_data, spec["reader"]["spans"])
         assert not [r["stage"] for r in matched if _new_span(r["stage"])], spec["name"]

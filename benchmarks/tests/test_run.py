@@ -58,7 +58,9 @@ def test_a_run_end_to_end(capsys, toy_root, host_backed_device_prover, cell, tra
     # the warm-up batch is the oracle batch: one pinned call, then the window's
     assert host_backed_device_prover["calls"][0] == 4
     if cell == "toy.closed1":
-        assert set(host_backed_device_prover["calls"][1:]) == {1}
+        # one caller, so every batch holds one request, and proves at the size it was claimed
+        # for with that witness repeated (pipeline/service.py, since PR 30): no second shape
+        assert set(host_backed_device_prover["calls"][1:]) == {4} and res["attempted"] == len(host_backed_device_prover["calls"]) - 1
     if cell == "toy.open-poisson":
         assert any(line.startswith("[bench] generator lateness") for line in out)
         assert res["attempted"] >= 3  # 8 due in 2 s at 4/s

@@ -39,6 +39,29 @@ def test_score_window_counts_everything_the_service_took_on_through_the_drain():
     assert sc["latency_p50_s"] == 4.0 and sc["latency_p90_s"] == 9.0
 
 
+def test_a_refusal_at_the_deadline_is_a_miss_one_before_it_is_not_and_a_late_proof_is_a_proof():
+    def req(t_sent, **kw):
+        return dict({"t_ref": t_sent, "t_sent": t_sent + 0.01, "payload": {"msg": [1], "deadline_s": 10.0}}, **kw)
+
+    reqs = [
+        req(0.0, t_terminal=3.0, state="done", valid=True),
+        req(1.0, t_terminal=12.5, state="done", valid=True),                  # done after its deadline: late, valid, in the tail
+        req(2.0, t_terminal=12.6, state="error-deadline-exceeded"),           # refused 10.59 s after it was sent: a miss
+        req(3.0, t_terminal=12.97, state="error-deadline-exceeded"),          # 9.96 s: inside the two clocks' 50 ms
+        req(4.0, t_terminal=12.9, state="error-deadline-exceeded"),           # 8.89 s: the deadline had not passed
+        req(5.0, t_terminal=13.0, state="error-shed"),                        # any other error state: as before
+        req(6.0),                                                             # not claimed when the window closed
+    ]
+    sc = score.score_window(reqs, t_first=0.0)
+    assert (sc["submitted"], sc["attempted"], sc["failed"], sc["unclaimed_at_end"]) == (7, 6, 4, 1)
+    assert (sc["refused_at_deadline"], sc["refused_before_deadline"]) == (2, 1)
+    assert sc["latency_samples"] == 2 and sc["latency_p90_s"] == 11.5 and sc["proofs_per_s"] == pytest.approx(2 / 12.5)
+    # a refusal of a request that carried no deadline is no miss: it fails, and is counted in neither
+    bare = [{"t_ref": 0.0, "t_sent": 0.0, "payload": {"msg": [1]}, "t_terminal": 1.0, "state": "error-deadline-exceeded"}]
+    sc = score.score_window(bare, 0.0)
+    assert (sc["failed"], sc["refused_at_deadline"], sc["refused_before_deadline"]) == (1, 0, 0)
+
+
 def test_a_stall_behind_the_last_completion_lowers_the_rate():
     """Two batches of four; the second, claimed before the window closed, ends late."""
     first = [{"t_ref": 0.0, "t_terminal": 30.0, "state": "done", "valid": True}] * 4

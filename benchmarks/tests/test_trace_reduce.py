@@ -62,6 +62,48 @@ def test_busy_union_idle_top_operations_and_gap_attribution():
     assert r["device_ops"][0][0] == "copy.2" and r["idle_gaps"][0][0] == "service/prove"
 
 
+def test_a_gap_goes_to_the_innermost_span_of_a_rank_only():
+    """The stage spans nest (sweep > prove > prove_batch > finish) and sit beside the per-request
+    ones: an idle instant is credited once, to the span that began last, and the sums stay."""
+    nested = HOST + [
+        {"label": "service/sweep", "t0_wall_s": 1000.0 + 50e-9, "ms": 1350e-6, "rank": 0},            # 50..1400, encloses most
+        {"label": "tpu/prove_batch/finish", "t0_wall_s": 1000.0 + 600e-9, "ms": 300e-6, "rank": 0},    # 600..900, inside prove
+        {"label": "service/witness_check", "t0_wall_s": 1000.0 + 1250e-9, "ms": 100e-6, "rank": 1},    # 1250..1350, inside witness
+        {"label": "service/poll", "t0_wall_s": 1000.0 + 1800e-9, "ms": 200e-6, "rank": 0},             # 1800..2000
+    ]
+    r = tr.reduce_events(SYNTH, nested, wall_start_ns=1_000_000_000_000, wall_stop_ns=1_000_000_002_000)
+    flat = tr.reduce_events(SYNTH, HOST, wall_start_ns=1_000_000_000_000, wall_stop_ns=1_000_000_002_000)
+    assert (r["busy_s"], r["window_s"], r["device_ops"]) == (flat["busy_s"], flat["window_s"], flat["device_ops"])
+    # idle: [0,100) [500,700) [800,1500) [1750,2000).  prove began at 0, sweep at 50: prove is outside it and keeps
+    # [0,50); sweep, begun later, takes [50,100) [500,600); finish [600,700) [800,900); verify 300; what sweep still
+    # covers of 1200..1400 is its own (the witness thread comes second); poll [1800,2000); the rest 150
+    gaps = dict(r["idle_gaps"])
+    assert gaps == {"service/prove": pytest.approx(50e-9), "service/sweep": pytest.approx(350e-9),
+                    "tpu/prove_batch/finish": pytest.approx(200e-9), "service/verify": pytest.approx(300e-9),
+                    "service/poll": pytest.approx(200e-9), "unattributed": pytest.approx(150e-9)}
+    assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"])
+    # the witness thread's spans, once rank 0 is out of the way: the check inside the witness, once
+    r = tr.reduce_events(SYNTH, [sp for sp in nested if sp["rank"] == 1], 1_000_000_000_000, 1_000_000_002_000)
+    assert dict(r["idle_gaps"]) == {"service/witness": pytest.approx(300e-9), "service/witness_check": pytest.approx(100e-9),
+                                    "unattributed": pytest.approx(850e-9)}
+
+
+def test_a_replicas_plane_is_reduced_against_that_replicas_spans():
+    two = json.loads(json.dumps(SYNTH))
+    two["device"]["/device:TPU:1"] = {"XLA Ops": [["mosaic.msm", 0.0, 1000.0]]}   # idle 1000..2000
+    spans = [{"label": "service/verify", "t0_wall_s": 1000.0 + 1000e-9, "ms": 500e-6, "rank": 0, "replica": 0},   # 1000..1500
+             {"label": "service/starved", "t0_wall_s": 1000.0 + 1000e-9, "ms": 1000e-6, "rank": 0, "replica": 1}]  # 1000..2000
+    r = tr.reduce_events(two, spans, 1_000_000_000_000, 1_000_000_002_000)
+    # plane 0 idles 500 ns of 1000..1500 under ITS verify; plane 1 all of 1000..2000 under ITS wait; summed over
+    # the planes and averaged like busy_s, never one replica's span against another's device
+    assert dict(r["idle_gaps"]) == {"service/verify": pytest.approx(500e-9 / 2), "service/starved": pytest.approx(1000e-9 / 2),
+                                    "unattributed": pytest.approx(750e-9 / 2)}
+    solo = [dict(sp, replica=None) for sp in spans]   # a solo service (and a mesh): every plane against the one set of spans
+    r = tr.reduce_events(two, solo, 1_000_000_000_000, 1_000_000_002_000)
+    assert dict(r["idle_gaps"]) == {"service/verify": pytest.approx((500e-9 + 500e-9) / 2), "service/starved": pytest.approx((250e-9 + 500e-9) / 2),
+                                    "unattributed": pytest.approx(500e-9 / 2)}
+
+
 def test_busy_is_averaged_over_the_chips_used_and_nothing_is_reported_without_device_operations():
     two = json.loads(json.dumps(SYNTH))
     two["device"]["/device:TPU:1"] = {"XLA Ops": [["mosaic.msm", 0.0, 2000.0]]}
@@ -103,3 +145,23 @@ def test_a_slice_of_a_real_v5e_trace(path):
         assert r["window_s"] == pytest.approx(0.8771, abs=1e-4) and r["busy_s"] == pytest.approx(0.00792, abs=1e-5)
         assert [n for n, _ in r["idle_gaps"][:2]] == ["service/verify", "service/prove"]
         assert dict(r["idle_gaps"])["service/verify"] == pytest.approx(0.4684, abs=1e-3)
+
+
+def test_host_spans_takes_the_stage_spans_that_are_intervals_of_a_host_thread():
+    from benchmarks.run import host_spans
+
+    records = [{"replica": 2, "spans": [{"name": "prove", "t0": 10.0, "ms": 5.0}, {"name": "witness_batch", "t0": 9.0, "ms": 1.0}]},
+               {"replica": 2, "spans": [{"name": "prove", "t0": 10.0, "ms": 5.0}]}]      # one span a batch, on each of its requests
+    stages = [{"stage": "service/prove", "t0": 10.0, "ms": 5.0, "replica": 2},              # the same span, from the sink
+              {"stage": "service/prove/tpu/prove_batch/finish", "t0": 14.0, "ms": 0.9, "replica": 2},
+              {"stage": "service/witness/service/witness_check", "t0": 9.5, "ms": 0.2, "replica": 2},
+              {"stage": "service/poll", "t0": 15.0, "ms": 200.0, "tid": None},
+              {"stage": "service/starved", "t0": 9.0, "ms": 1.0},
+              {"stage": "service/prove/tpu/prove_batch/device_idle/poll", "t0": 15.0, "ms": 150.0},   # an account of a gap, laid end to end
+              {"stage": "service/prove/tpu/prove_batch/stage/msm_h", "t0": 11.0, "ms": 2.0},          # the device's clock
+              {"stage": "service/prove/tpu/prove_batch/upload", "t0": 10.1, "ms": 0.1},
+              {"stage": "replicas/idle", "t0": 0.0, "ms": 9000.0, "replica": 2}]                      # a sum over the window
+    got = {(sp["label"], sp["rank"], sp["replica"]) for sp in host_spans(records, stages)}
+    assert got == {("service/prove", 0, 2), ("service/witness_batch", 1, 2), ("tpu/prove_batch/finish", 0, 2),
+                   ("service/witness_check", 1, 2), ("service/poll", 0, None), ("service/starved", 0, None)}
+    assert len(host_spans(records, stages)) == 6
