@@ -173,7 +173,7 @@ def test_the_upload_span_lies_beside_h_planes_and_moves_no_stage(world, stood_in
 
 
 def _service(world, prove, **kw):
-    cs, dpk, vk, witness_fn = world
+    cs, dpk, vk, witness_fn, _ = world
     return ProvingService(cs, dpk, vk, witness_fn, public_fn=lambda w: [w[1]], prover_fn=prove,
                           retry_backoff_s=0.0, batch_size=2, **kw)
 
@@ -232,7 +232,7 @@ def test_two_sweeps_write_handover_and_poll_beside_the_sweep_and_idle_passes_wri
 def test_under_a_replica_set_each_gap_carries_its_replica_and_none_mixes_two(world, stood_in_device, tmp_path):
     spool = str(tmp_path / "spool")
     os.makedirs(spool)
-    cs, dpk, vk, witness_fn = world
+    cs, dpk, vk, witness_fn, _ = world
     rset = ReplicaSet(lambda key: ProvingService(cs, key, vk, witness_fn, public_fn=lambda w: [w[1]], retry_backoff_s=0.0,
                                                  prover_fn=lambda d, w: stood_in_device(d, w), batch_size=2), dpk, n=2)
     assert [G.key_device(s.dpk) for s in rset.replicas] == jax.local_devices()[:2]

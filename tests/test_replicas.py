@@ -1,7 +1,9 @@
 """A replica set (pipeline/replicas.py): n one-chip services of one
 process on one spool, on the eight virtual CPU devices conftest gives
 every test.  The served tests drive the REAL native prover on a
-2-constraint circuit through four `ProvingService.run` loops; the
+2-constraint circuit through four `ProvingService.run` loops, once wired
+with a `witness_fn` and once as `ProvingService.for_venmo` wires the
+flagship (`_from_inputs_fn`: whole batches through `cs.witness_batch`); the
 device tests drive the real `prove_tpu_batch` on the toy world of
 test_msm_resident.py (its h stage, table and resident h MSM are the real
 programs) with the key on another device than the first.  The checker of
@@ -11,6 +13,7 @@ program)."""
 
 import json
 import os
+import shutil
 import threading
 import time
 
@@ -18,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from benchmarks.harness.check import verify_many, vk_to_ints
+from benchmarks.harness.check import check_window, verify_many, vk_to_ints
 from benchmarks.reference import exactly_once
 from zkp2p_tpu.field.bn254 import R
 from zkp2p_tpu.native.lib import get_lib
@@ -44,11 +47,18 @@ def world():
     cs.compute(z, lambda a, b: a * b % R, [x, y])
     pk, vk = setup(cs, seed="replicas")
 
-    def witness_fn(payload):
+    def inputs_fn(payload):
         xv, yv = int(payload["x"]), int(payload["y"])
-        return cs.witness([pow(xv * yv, 2, R)], {x: xv, y: yv})
+        return [pow(xv * yv, 2, R)], {x: xv, y: yv}
 
-    return cs, device_pk(pk, cs), vk, witness_fn
+    return cs, device_pk(pk, cs), vk, lambda payload: cs.witness(*inputs_fn(payload)), inputs_fn
+
+
+@pytest.fixture(params=["witness_fn", "inputs_fn"])
+def tier(request):
+    """How a set's services come by a witness: a request at a time, or the
+    tier of the flagship's service (`inputs_fn` + `cs.witness_batch`)."""
+    return request.param
 
 
 def _prover(pause_s):
@@ -60,18 +70,23 @@ def _prover(pause_s):
     return prove
 
 
-def _set(world, n=4, pause_s=0.05, **kw):
-    cs, dpk, vk, witness_fn = world
-    kw.setdefault("batch_size", 4)
-    return ReplicaSet(lambda key: ProvingService(cs, key, vk, witness_fn, public_fn=lambda w: [w[1]],
-                                                 prover_fn=_prover(pause_s), retry_backoff_s=0.0, **kw), dpk, n=n)
+def _set(world, n=4, pause_s=0.05, tier="witness_fn", **kw):
+    cs, dpk, vk, witness_fn, inputs_fn = world
+    kw = dict({"batch_size": 4}, prover_fn=_prover(pause_s), retry_backoff_s=0.0, **kw)
+    if tier == "inputs_fn":
+        return ReplicaSet(lambda key: ProvingService._from_inputs_fn(cs, key, vk, inputs_fn, **kw), dpk, n=n)
+    return ReplicaSet(lambda key: ProvingService(cs, key, vk, witness_fn, public_fn=lambda w: [w[1]], **kw), dpk, n=n)
+
+
+def _payload(i):
+    return {"x": 2 + i, "y": 3 + 2 * i}
 
 
 def _write_reqs(spool, n, prefix="r"):
     for i in range(n):
         tmp = os.path.join(spool, f"{prefix}{i:03d}.tmp")
         with open(tmp, "w") as f:
-            json.dump({"x": 2 + i, "y": 3 + 2 * i}, f)
+            json.dump(_payload(i), f)
         os.replace(tmp, os.path.join(spool, f"{prefix}{i:03d}.req.json"))
 
 
@@ -104,13 +119,13 @@ def _sink(spool):
 
 
 @needs_native
-def test_four_replicas_serve_forty_requests_each_exactly_once(world, tmp_path):
+def test_four_replicas_serve_forty_requests_each_exactly_once(world, tier, tmp_path):
     from zkp2p_tpu.utils import trace
 
     trace.reset()  # the ring is the process's: a span an earlier test's solo service left there is not this set's
     spool = str(tmp_path / "spool")
     os.makedirs(spool)
-    rset = _set(world)
+    rset = _set(world, tier=tier)
     from zkp2p_tpu.prover.groth16_tpu import key_device
 
     assert [key_device(s.dpk) for s in rset.replicas] == jax.local_devices()[:4]
@@ -130,15 +145,40 @@ def test_four_replicas_serve_forty_requests_each_exactly_once(world, tmp_path):
     assert sorted(res["served"]) == ["0", "1", "2", "3"]  # every replica served: none holds more than two batches
     # every proof passes the benchmark's own pairing check under the key's vk
     paths = [(os.path.join(spool, f"r{i:03d}.proof.json"), os.path.join(spool, f"r{i:03d}.public.json")) for i in range(40)]
-    assert verify_many(vk_to_ints(world[2]), paths, workers=4) == [True] * 40
+    vk_ints = vk_to_ints(world[2])
+    assert verify_many(vk_ints, paths, workers=4) == [True] * 40
+    # and carries its own request's signal, worked out from the request alone; a pair of proofs
+    # that changed places still passes the pairing and reads as two failures
+    asked = [{"rid": f"r{i:03d}", "state": "done", "payload": _payload(i)} for i in range(40)]
+    tie = lambda p: {0: pow(p["x"] * p["y"], 2, R)}  # noqa: E731
+    assert check_window(vk_ints, spool, asked, 4, tie) == 0 and all(r["valid"] for r in asked)
+    swapped = str(tmp_path / "swapped")
+    shutil.copytree(spool, swapped)
+    for kind in ("proof", "public"):
+        a, b = (os.path.join(swapped, f"r{i:03d}.{kind}.json") for i in (7, 8))
+        os.replace(a, a + ".tmp")
+        os.replace(b, a)
+        os.replace(a + ".tmp", b)
+    assert check_window(vk_ints, swapped, asked, 4, tie) == 2
+    assert [r["rid"] for r in asked if not r["valid"]] == ["r007", "r008"]
 
     recs = _sink(spool)
     requests = [r for r in recs if r.get("type") == "request"]
     assert len(requests) == 40 and all(r["state"] == "done" and r["replica"] in (0, 1, 2, 3) for r in requests)
     spans = [r for r in recs if r.get("type") == "stage"]
-    for name in ("service/sweep", "service/starved", "service/witness", "service/prove", "service/verify", "service/emit"):
+    witness = "service/witness_batch" if tier == "inputs_fn" else "service/witness"  # the tier that ran
+    for name in ("service/sweep", "service/starved", witness, "service/prove", "service/verify", "service/emit"):
         under = [s for s in spans if s["stage"].endswith(name)]
         assert under and all(s.get("replica") in (0, 1, 2, 3) for s in under), name
+    # the members of a set take turns at the batched tier: no two of them inside `cs.witness_batch` at once
+    turns = [s for s in spans if s["stage"].endswith("service/witness_turn")]
+    inside = sorted((s["t0"], s["t0"] + s["ms"] / 1e3) for s in spans if s["stage"].endswith("service/witness_batch"))
+    assert len(turns) == len(inside) and all(s["replica"] in (0, 1, 2, 3) and s["n"] >= 1 for s in turns)
+    assert all(later[0] >= earlier[1] - 2e-3 for earlier, later in zip(inside, inside[1:]))
+    # the set's bring-up is in the sink too: a placement a replica, of which the first copies nothing
+    placed = sorted((s for s in spans if s["stage"] == "replicas/place"), key=lambda s: s["replica"])
+    assert [s["replica"] for s in placed] == [0, 1, 2, 3] and placed[0]["bytes"] == 0
+    assert all(s["bytes"] == placed[1]["bytes"] > 0 for s in placed[1:])
     idle = sorted((s for s in spans if s["stage"] == "replicas/idle"), key=lambda s: s["replica"])
     assert [s["replica"] for s in idle] == [0, 1, 2, 3] and sum(s["n"] for s in idle) == 40
     assert all(s["n"] > 0 and 0 <= s["ms"] <= s["span_s"] * 1e3 + 1 for s in idle)
@@ -150,10 +190,10 @@ def test_four_replicas_serve_forty_requests_each_exactly_once(world, tmp_path):
 
 
 @needs_native
-def test_drain_with_batches_in_flight_on_all_four_loses_and_duplicates_nothing(world, tmp_path):
+def test_drain_with_batches_in_flight_on_all_four_loses_and_duplicates_nothing(world, tier, tmp_path):
     spool = str(tmp_path / "spool")
     os.makedirs(spool)
-    rset = _set(world, pause_s=0.4)
+    rset = _set(world, pause_s=0.4, tier=tier)
     th, out = _serve(rset, spool)
     _write_reqs(spool, 64)
     _wait(lambda: all(s.n_batches >= 1 for s in rset.replicas))  # every replica has a batch in its prover or past it
@@ -166,7 +206,7 @@ def test_drain_with_batches_in_flight_on_all_four_loses_and_duplicates_nothing(w
     assert res["ok"], res
     assert 16 <= res["ended"] < 64  # what was claimed ended; what was not is free, with no claim left on it
     # a solo service takes the rest: still every request once, under one name each
-    cs, dpk, vk, witness_fn = world
+    cs, dpk, vk, witness_fn, _ = world
     solo = ProvingService(cs, dpk, vk, witness_fn, public_fn=lambda w: [w[1]], prover_fn=_prover(0.0), batch_size=4)
     while _ended(spool) < 64:
         solo.process_dir(spool)
@@ -175,16 +215,16 @@ def test_drain_with_batches_in_flight_on_all_four_loses_and_duplicates_nothing(w
 
 
 @needs_native
-def test_the_replicas_of_a_set_count_each_other_as_peers(world, tmp_path, monkeypatch):
+def test_the_replicas_of_a_set_count_each_other_as_peers(world, tier, tmp_path, monkeypatch):
     """`_live_peers` is the adaptive scheduler's `parallelism`: inside a set
     of four it is four (plus the other processes of a fleet), not one."""
     spool = str(tmp_path / "spool")
     os.makedirs(spool)
-    cs, dpk, vk, witness_fn = world
+    cs, dpk, vk, witness_fn, _ = world
     solo = ProvingService(cs, dpk, vk, witness_fn, public_fn=lambda w: [w[1]], prover_fn=_prover(0.0))
     solo._resolve_policy()
     assert solo._live_peers() == 1
-    rset = _set(world)
+    rset = _set(world, tier=tier)
     assert [s._live_peers() for s in rset.replicas] == [1] * 4  # no loop is up yet
     th, out = _serve(rset, spool)
     assert [s._live_peers() for s in rset.replicas] == [4] * 4
@@ -220,7 +260,7 @@ def test_a_short_batch_proves_at_the_size_it_was_claimed_for(world, tmp_path, mo
     three proofs.  A stand-in `prover_fn` is handed the three."""
     from zkp2p_tpu.prover import groth16_tpu
 
-    cs, dpk, vk, witness_fn = world
+    cs, dpk, vk, witness_fn, _ = world
     sizes = []
 
     def device_prover(dpk_, wits):
@@ -253,20 +293,24 @@ def _toy(monkeypatch):
     return _toy_world(monkeypatch)
 
 
-def test_a_pinned_proof_is_the_same_bytes_on_four_devices_and_from_prove_host(monkeypatch):
+def test_a_pinned_batch_is_the_same_bytes_on_four_devices_from_prove_host_and_from_prove_native(monkeypatch):
+    """What the replica cells' pinned warm-up batch is held to, at a toy size:
+    one batch with (r, s) pinned, proved where each replica's key lives."""
     from zkp2p_tpu.formats.proof_json import proof_to_json
     from zkp2p_tpu.prover import groth16_tpu as G
+    from zkp2p_tpu.prover.native_prove import prove_native
     from zkp2p_tpu.snark.groth16 import prove_host
 
     cs, pk, dpk, wits = _toy(monkeypatch)
-    r, s = 0x1234567, 0x7654321
-    want = proof_to_json(prove_host(pk, cs, wits[1], r=r, s=s))
+    batch, rs, ss = [wits[1], wits[2]], [0x1234567, 0x89abcde], [0x7654321, 0xedcba98]
+    want = [proof_to_json(prove_host(pk, cs, w, r=r, s=s)) for w, r, s in zip(batch, rs, ss)]
+    if get_lib() is not None:
+        assert [proof_to_json(prove_native(dpk, w, r, s)) for w, r, s in zip(batch, rs, ss)] == want
     assert G.key_device(dpk) is None  # as loaded: pinned nowhere
     for dev in jax.local_devices()[:4]:
         key = G.place_key(dpk, dev)
         assert G.key_device(key) == dev and (key is dpk) == (dev == jax.local_devices()[0])
-        (proof,) = G.prove_tpu_batch(key, [wits[1]], rs=[r], ss=[s])
-        assert proof_to_json(proof) == want, dev
+        assert [proof_to_json(p) for p in G.prove_tpu_batch(key, batch, rs=rs, ss=ss)] == want, dev
         assert key._h_table_cache.devices() == {dev}
 
 

@@ -11,7 +11,10 @@ key_device`).  The claim files arbitrate between the replicas exactly as
 between processes, so a set and a fleet can share a spool.
 
 What one process costs is the interpreter: witness, verify, `prep` and
-`finish` are Python, and four replicas take turns at it (PERF.md).
+`finish` are Python, and four replicas take turns at it (PERF.md).  At
+the batched witness tier they take turns in earnest: one member at a time
+inside `cs.witness_batch` (`ProvingService._in_witness_turn`), because
+four inside it at once take nine times one, not four.
 
 A set presents what a service presents: `run(spool, poll_s)`,
 `request_drain()`, `draining`, `cs`, `public_fn`, `inputs_fn`,
@@ -28,9 +31,18 @@ from typing import Callable, List, Optional
 from ..snark.r1cs import Witness, _std_u64
 from ..utils.audit import install_compile_listener, record_arm, stamp_preflight
 from ..utils.metrics import REGISTRY, run_id
-from ..utils.trace import drain as drain_trace, record, set_context
+from ..utils.trace import drain as drain_trace, record, set_context, thread_tally, trace
 
-# a replica's loop that has not come up after this long is a fault, not a slow start
+# A replica's loop that has not come up after this long is a fault, not a slow
+# start.  The clock starts when the loops' threads do, AFTER `warm()`: it covers n
+# preflights, not the set's bring-up, which a big key makes long and this does not
+# bound.  Four replicas of a 2^19 circuit (venmo 256/192: a 460 MB key and a 4.29 GB
+# h table a chip) on the chip: each of the three copies lands in 11-13 ms
+# (`replicas/place`); `warm()` takes 26 s with the programs in the compile cache
+# (replica 0's batch alone 7.9 s, then the three others' together 17.6-18.7 s,
+# `table_ms` 3.8 s of it) and 86-89 s in a checkout's first start (the three others'
+# 77.7-80.8 s: each device's compiles, `table_ms` 5.6-7.3 s); every loop is up within
+# a second of it (PERF.md, PR 41).
 LOOP_UP_TIMEOUT_S = 120.0
 
 
@@ -65,9 +77,15 @@ class ReplicaSet:
         sinks, sinks_lock = {}, threading.Lock()
         self._up: set = set()
         self._up_lock = threading.Lock()
+        witness_turn = threading.Lock()  # one member at a time in the batched witness tier (`_in_witness_turn`)
         for i, dev in enumerate(self.devices):
-            svc = make_service(place_key(dpk, dev))
-            svc.join_set(i, self.live, sinks, sinks_lock, self._loop_up)
+            # what the copy costs a start: `bytes` 0 for the replica that serves from the loaded key itself
+            with trace("replicas/place", replica=i) as span:
+                key = place_key(dpk, dev)
+                copied = () if key is dpk else jax.tree_util.tree_leaves(jax.block_until_ready(key))
+                span["bytes"] = sum(x.nbytes for x in copied)
+            svc = make_service(key)
+            svc.join_set(i, self.live, sinks, sinks_lock, self._loop_up, witness_turn)
             self.replicas.append(svc)
         first = self.replicas[0]
         self.cs, self.vk, self.batch_size = first.cs, first.vk, first.batch_size
@@ -111,6 +129,11 @@ class ReplicaSet:
         to warm under a stand-in `prover_fn`."""
         from ..prover.groth16_tpu import prove_tpu_batch
 
+        def in_h_table() -> float:
+            """This thread's milliseconds so far in `tpu/prove_batch/h_table`, the build of a key's
+            resident h table: what a warm batch spends there is its `table_ms` (0: the key came with one)."""
+            return thread_tally().get("h_table", (0.0, 0.0))[0]
+
         def one(svc) -> None:
             # the shapes are what is warmed, not the values; carrying its rows as a
             # builder's witness does, so the warm batch takes the host path a served one takes
@@ -118,9 +141,9 @@ class ReplicaSet:
             witness.u64 = _std_u64(witness)
             set_context(replica=svc.replica)  # the warm batch's spans are that replica's too
             try:
-                t0 = time.time()
+                t0, table0 = time.time(), in_h_table()
                 prove_tpu_batch(svc.dpk, [witness] * svc.batch_size)
-                record("replicas/warm", t0, time.time(), n=svc.batch_size)
+                record("replicas/warm", t0, time.time(), n=svc.batch_size, table_ms=round(in_h_table() - table0, 3))
             finally:
                 set_context(replica=None)
 
@@ -154,6 +177,7 @@ class ReplicaSet:
         install_compile_listener()
         REGISTRY.counter("zkp2p_service_claim_lost_total")  # 0 is a reading
         self.warm()
+        self._flush_spans(spool)  # the bring-up (`replicas/place`, `replicas/warm`) is in the sink before any loop claims
         whys: List[Optional[str]] = [None] * len(self.replicas)
         errors: List[BaseException] = []
 
@@ -162,7 +186,12 @@ class ReplicaSet:
                 whys[i] = svc.run(spool, poll_s=poll_s, **kw)
             except BaseException as e:  # noqa: BLE001 — raised on the caller's thread below
                 errors.append(e)
-                self.request_drain()  # a set with a dead replica is not the deployment: the peers finish and stop
+                # A set with a dead replica is not the deployment: the peers finish what they
+                # have claimed and stop, and the caller hears of it.  At 2^19 a replacement
+                # would be a table's build (3.8 s warm) and a warm batch (7.9 s) on a chip whose
+                # memory the dead loop's key and table (4.75 GB) may still hold: the restart is
+                # the operator's (or the fleet supervisor's), with a process to reclaim it.
+                self.request_drain()
             finally:
                 self._loop_down(i)
 
@@ -193,8 +222,8 @@ class ReplicaSet:
         """One `replicas/idle` span a replica: of the wall time between
         the set's first claim and its last terminal, the part in which
         that replica had no batch in its prover (`ms`), and the proofs it
-        served (`n`).  Written to the set's sink with whatever spans the
-        replicas' last flushes left."""
+        served (`n`).  Flushed with whatever spans the replicas' last
+        flushes left."""
         firsts = [s.t_first_claim for s in self.replicas if s.t_first_claim is not None]
         lasts = [s.t_last_terminal for s in self.replicas if s.t_last_terminal is not None]
         if firsts and lasts:
@@ -203,6 +232,10 @@ class ReplicaSet:
                 idle_s = max(0.0, (t1 - t0) - svc.busy_s)
                 record("replicas/idle", t0, t0 + idle_s, replica=svc.replica, n=svc.n_done,
                        span_s=round(t1 - t0, 6), batches=svc.n_batches)
+        self._flush_spans(spool)
+
+    def _flush_spans(self, spool: str) -> None:
+        """The spans in the process's ring, to the set's sink."""
         rid, pid = run_id(), os.getpid()
         try:
             self.replicas[0]._sink(spool).write_many(

@@ -560,6 +560,7 @@ class ProvingService:
         self.replica: Optional[int] = None
         self._set_live: Optional[Callable[[], int]] = None
         self._on_loop_up: Optional[Callable[[int, Dict], None]] = None
+        self._witness_turn: Optional[threading.Lock] = None  # the set's, `_in_witness_turn`
         # what a set reads when it has drained (`replicas/idle`): this
         # service's first claim and last terminal, the seconds it had a
         # batch in its prover, the batches and the proofs it served
@@ -575,17 +576,42 @@ class ProvingService:
         self._perf_agg: Dict[str, List[float]] = {}
 
     def join_set(self, replica: int, live: Callable[[], int], sinks: Dict, sinks_lock,
-                 on_loop_up: Callable[[int, Dict], None]) -> None:
+                 on_loop_up: Callable[[int, Dict], None], witness_turn: threading.Lock) -> None:
         """Made replica `replica` of a set (pipeline.replicas.ReplicaSet),
         before `run`: the set's members write one sink a path between
         them (four JsonlSink instances on one file would rotate against
-        each other), count each other as peers, and the set stamps
+        each other), count each other as peers, take turns at the
+        batched witness tier (`_in_witness_turn`), and the set stamps
         `last_preflight` itself, once every loop has called
         `on_loop_up`."""
         self.replica = replica
         self._set_live = live
         self._sinks, self._sinks_lock = sinks, sinks_lock
         self._on_loop_up = on_loop_up
+        self._witness_turn = witness_turn
+
+    @contextlib.contextmanager
+    def _in_witness_turn(self, n: int):
+        """One member of a set at a time inside `cs.witness_batch`.  That
+        tier is thousands of short numpy calls on object columns, Python
+        that holds the interpreter and hands it over at every call: four
+        producers of one process inside it at once took 7.4-8.6 s each
+        for what one alone does in 0.9-1.05 s (venmo 256/192, a batch of
+        four, on the chip's host: PERF.md, PR 41), and every proving
+        thread's `finish` waited behind them.  In turn the last of four
+        has its witnesses after four times one, not nine.  The wait is
+        the span `service/witness_turn` (`n`); a solo service waits for
+        nobody and writes none.  The self-check after it is native code
+        off the interpreter and stays outside the turn."""
+        if self._witness_turn is None:
+            yield
+            return
+        with trace("service/witness_turn", n=n):
+            self._witness_turn.acquire()
+        try:
+            yield
+        finally:
+            self._witness_turn.release()
 
     def request_drain(self) -> None:
         """Flip the drain flag: stop claiming, finish in-flight work,
@@ -1705,7 +1731,7 @@ class ProvingService:
             if not batch:
                 return []
             try:
-                with _span(batch, "witness_batch", n=len(batch)):
+                with self._in_witness_turn(len(batch)), _span(batch, "witness_batch", n=len(batch)):
                     ws = self.cs.witness_batch(inputs)
                 # EVERY witness gets the Az∘Bz=Cz self-check, exactly like
                 # the scalar tier — only checking a sample would let an
