@@ -7,6 +7,12 @@
 // the CPU runtime around it.  Exposed as extern "C" for ctypes
 // (zkp2p_tpu.native.lib); every entry point is batch-oriented.
 //
+// Two entry points take the proving thread's Python curve arithmetic
+// out of the interpreter, each with its Python function kept as oracle:
+// groth16_verify_bn254 (the sample verify's pairing equation, whole) and
+// groth16_assemble_bn254 (a proof's blinding and assembly from its five
+// MSM accumulators: pi_a, pi_b, pi_c).
+//
 // Field elements: 4 x 64-bit little-endian limbs, Montgomery form with
 // R = 2^256.  unsigned __int128 provides the 64x64->128 multiply.
 
@@ -7078,18 +7084,49 @@ static bool g2_on_twist(const G2Aff &q) {
   fp2_add(r, r, pairing_consts().twist_b);
   return fp2_eq(l, r);
 }
-// [r] Q == O: the twist's cofactor is large, so on the twist is not in G2
-static bool g2_in_subgroup(const G2Aff &q) {
+// k * p over all 256 bits of k (standard-form limbs, not reduced), a plain
+// double-and-add: no table, nothing shared
+static void g1_mul_plain(G1Jac &out, const G1Aff &p, const u64 k[4]) {
+  G1Jac acc, t;
+  memset(&acc, 0, sizeof(acc));
+  for (int bit = 255; bit >= 0; --bit) {
+    jac_double(t, acc);
+    acc = t;
+    if ((k[bit / 64] >> (bit % 64)) & 1) {
+      jac_add_mixed(t, acc, p.x, p.y);
+      acc = t;
+    }
+  }
+  out = acc;
+}
+static void g2_mul_plain(G2Jac &out, const G2Aff &q, const u64 k[4]) {
   G2Jac acc, t;
   memset(&acc, 0, sizeof(acc));
-  for (int i = 253; i >= 0; --i) {
+  for (int bit = 255; bit >= 0; --bit) {
     g2_double(t, acc);
     acc = t;
-    if ((R_MOD[i / 64] >> (i % 64)) & 1) {
+    if ((k[bit / 64] >> (bit % 64)) & 1) {
       g2_add_mixed(t, acc, q.x, q.y);
       acc = t;
     }
   }
+  out = acc;
+}
+// Jacobian -> affine, Montgomery; (0,0) = infinity
+static void g1_to_aff(G1Aff &out, const G1Jac &p) {
+  memset(&out, 0, sizeof(out));
+  if (is_zero4(p.Z)) return;
+  u64 zi[4], zi2[4], zi3[4];
+  mont_inv(zi, p.Z);
+  mont_sqr(zi2, zi);
+  mont_mul(zi3, zi2, zi);
+  mont_mul(out.x, p.X, zi2);
+  mont_mul(out.y, p.Y, zi3);
+}
+// [r] Q == O: the twist's cofactor is large, so on the twist is not in G2
+static bool g2_in_subgroup(const G2Aff &q) {
+  G2Jac acc;
+  g2_mul_plain(acc, q, R_MOD);
   return fp2_is_zero(acc.Z);
 }
 
@@ -7150,19 +7187,126 @@ int groth16_verify_bn254(const u64 *vk, int n_public, int n_ic, const u64 *proof
     }
   }
   jac_add_mixed(t, acc, ic[0].x, ic[0].y);
-  memset(&vkx, 0, sizeof(vkx));
-  if (!is_zero4(t.Z)) {
-    u64 zi[4], zi2[4], zi3[4];
-    mont_inv(zi, t.Z);
-    mont_sqr(zi2, zi);
-    mont_mul(zi3, zi2, zi);
-    mont_mul(vkx.x, t.X, zi2);
-    mont_mul(vkx.y, t.Y, zi3);
-  }
+  g1_to_aff(vkx, t);
   fp_neg(A.y, A.y);
   const G1Aff Ps[4] = {A, alpha, vkx, C};
   const G2Aff Qs[4] = {B, beta, gamma, delta};
   return pairing_product_is_one(Ps, Qs, 4) == 1 ? 1 : 0;
+}
+
+}  // extern "C"
+
+// ===================================================================
+// The proof's assembly: the ~10 group operations that blind the five
+// MSM accumulators with (r, s) and make (A, B, C) — what
+// prover/groth16_tpu.py::_assemble_host does on Python integers, 70-90
+// ms a proof of which five sixths is one double-and-add over Fq2
+// objects, on the proving thread with the device empty after every
+// batch.  The same expression in the same order on the Jacobian
+// primitives above, every addition complete (jac_add_mixed, g1_add_jac,
+// g2_add_mixed and g2_add each take equal points, opposite points and
+// infinity on either side), so the canonical affine bytes are the Python
+// form's: group arithmetic is exact.  A plain double-and-add a scalar
+// (g1_mul_plain, g2_mul_plain: by the 256 bits given, as the Python form
+// multiplies by the integer it is given), no table, no lock, nothing
+// shared or allocated: any number of threads may be in it at once.  The Python function stays the oracle
+// (snark/native_assemble.py): every `0` from here is computed by it.
+// ===================================================================
+
+// p + q of two affine points, as a Jacobian point
+static void g1_add_aff(G1Jac &out, const G1Aff &p, const G1Aff &q) {
+  G1Jac inf, t;
+  memset(&inf, 0, sizeof(inf));
+  jac_add_mixed(t, inf, p.x, p.y);
+  jac_add_mixed(out, t, q.x, q.y);
+}
+static void g1_store(u64 *out, const G1Aff &p) {
+  fp_from_mont(p.x, out, 1);
+  fp_from_mont(p.y, out + 4, 1);
+}
+
+extern "C" {
+
+// pi_a  = alpha_1 + a  + r delta_1
+// pi_b  = beta_2  + b2 + s delta_2
+// pi_b1 = beta_1  + b1 + s delta_1
+// pi_c  = c + h + s pi_a + r pi_b1 - (r s mod R) delta_1
+// key: alpha_1, beta_1, delta_1 (8 u64 each), beta_2, delta_2 (16 each);
+// acc: a, b1 (8 each), b2 (16), c, h (8 each), _assemble's order; rs: r
+// then s, 4 u64 each, any 256-bit value; out: pi_a (8), pi_b (16), pi_c
+// (8).  Standard form, canonical, all-zero = infinity, G2 as (x.c0,
+// x.c1, y.c0, y.c1).  1 = out is written; 0 = this function does not
+// decide (a coordinate >= p, a point off its curve) and the caller runs
+// the Python form.
+int groth16_assemble_bn254(const u64 *key, const u64 *acc, const u64 *rs, u64 *out) {
+  G1Aff alpha1, beta1, delta1, a, b1, c, h;
+  G2Aff beta2, delta2, b2;
+  if (!g1_load(alpha1, key) || !g1_load(beta1, key + 8) || !g1_load(delta1, key + 16) ||
+      !g2_load(beta2, key + 24) || !g2_load(delta2, key + 40))
+    return 0;
+  if (!g1_load(a, acc) || !g1_load(b1, acc + 8) || !g2_load(b2, acc + 16) ||
+      !g1_load(c, acc + 32) || !g1_load(h, acc + 40))
+    return 0;
+  if (!g1_on_curve(alpha1) || !g1_on_curve(beta1) || !g1_on_curve(delta1) || !g1_on_curve(a) ||
+      !g1_on_curve(b1) || !g1_on_curve(c) || !g1_on_curve(h))
+    return 0;
+  if (!g2_on_twist(beta2) || !g2_on_twist(delta2) || !g2_on_twist(b2)) return 0;
+  const u64 *r = rs, *s = rs + 4;
+  // r s mod R: both under R first (a 256-bit value is under 6 R), then
+  // two Montgomery products, the first by 2^512 mod R
+  u64 rr[4], sr[4], rsr[4];
+  memcpy(rr, r, 32);
+  memcpy(sr, s, 32);
+  while (geq(rr, R_MOD)) sub_nored(rr, rr, R_MOD);
+  while (geq(sr, R_MOD)) sub_nored(sr, sr, R_MOD);
+  fr_mul(rr, rr, R2R);
+  fr_mul(rsr, rr, sr);
+
+  G1Jac t, m;
+  G1Aff pi_a, pi_b1, pi_c;
+  g1_add_aff(t, alpha1, a);
+  g1_mul_plain(m, delta1, r);
+  g1_add_jac(t, m);
+  g1_to_aff(pi_a, t);
+
+  g1_add_aff(t, beta1, b1);
+  g1_mul_plain(m, delta1, s);
+  g1_add_jac(t, m);
+  g1_to_aff(pi_b1, t);
+
+  g1_add_aff(t, c, h);
+  g1_mul_plain(m, pi_a, s);
+  g1_add_jac(t, m);
+  g1_mul_plain(m, pi_b1, r);
+  g1_add_jac(t, m);
+  g1_mul_plain(m, delta1, rsr);
+  fp_neg(m.Y, m.Y);
+  g1_add_jac(t, m);
+  g1_to_aff(pi_c, t);
+
+  G2Jac t2, m2, inf2;
+  memset(&inf2, 0, sizeof(inf2));
+  g2_add_mixed(m2, inf2, beta2.x, beta2.y);
+  g2_add_mixed(t2, m2, b2.x, b2.y);
+  g2_mul_plain(m2, delta2, s);
+  g2_add(t2, m2);
+
+  g1_store(out, pi_a);
+  g1_store(out + 24, pi_c);
+  memset(out + 8, 0, 128);
+  if (!fp2_is_zero(t2.Z)) {
+    Fp2 zi, zi2, zi3, x, y;
+    fp2_inv(zi, t2.Z);
+    fp2_sqr(zi2, zi);
+    fp2_mul(zi3, zi2, zi);
+    fp2_mul(x, t2.X, zi2);
+    fp2_mul(y, t2.Y, zi3);
+    fp_from_mont(x.c0, out + 8, 1);
+    fp_from_mont(x.c1, out + 12, 1);
+    fp_from_mont(y.c0, out + 16, 1);
+    fp_from_mont(y.c1, out + 20, 1);
+  }
+  return 1;
 }
 
 }  // extern "C"

@@ -82,8 +82,10 @@ def _gaps(records):
 def test_two_batches_of_one_thread_give_one_gap_whose_eight_causes_sum_to_it(world, stood_in_device):
     dpk, wits = world[1], _wits(world)
     stood_in_device(dpk, wits)
+    t_verify = time.perf_counter()
     with tr.trace("service/verify"):
         time.sleep(0.03)
+    verify_wall_ms = (time.perf_counter() - t_verify) * 1e3  # the span lies inside it, however late the sleep woke
     with tr.trace("service/emit"):
         end = time.thread_time() + 0.01
         while time.thread_time() < end:
@@ -91,7 +93,8 @@ def test_two_batches_of_one_thread_give_one_gap_whose_eight_causes_sum_to_it(wor
     time.sleep(0.02)  # in no span
     t_wait = time.time()
     time.sleep(0.015)
-    tr.record("service/starved", t_wait, time.time())
+    t_woke = time.time()
+    tr.record("service/starved", t_wait, t_woke)
     stood_in_device(dpk, wits)
 
     recs = tr.records()
@@ -111,13 +114,16 @@ def test_two_batches_of_one_thread_give_one_gap_whose_eight_causes_sum_to_it(wor
     under_finish = sum(r["ms"] for r in recs if r["parent"] == finish["id"] and r["tid"] == finish["tid"])
     assert parts["finish"]["ms"] == pytest.approx(finish["ms"] - under_finish, abs=0.5)
     assert parts["prep"]["ms"] == pytest.approx(prep["ms"], abs=0.5)
-    assert 30.0 <= parts["verify"]["ms"] < 130.0 and parts["verify"]["cpu_ms"] < 10.0
+    # From above, only what this test measured around its own sleeps: a sleep on a loaded host (six workers)
+    # wakes late by any amount, and a thread that is runnable and not run is off a CPU too.
+    assert 30.0 <= parts["verify"]["ms"] <= verify_wall_ms + 0.5 and parts["verify"]["cpu_ms"] < 10.0
     assert 10.0 <= parts["emit"]["cpu_ms"] <= parts["emit"]["ms"] + 0.5
-    assert 15.0 <= parts["starved"]["ms"] < 115.0 and parts["starved"]["cpu_ms"] == 0.0
+    assert 15.0 <= parts["starved"]["ms"] == pytest.approx((t_woke - t_wait) * 1e3, abs=0.5) and parts["starved"]["cpu_ms"] == 0.0
     assert parts["handover"]["ms"] == parts["poll"]["ms"] == 0.0  # zero is written: a mean over spans is one over batches
-    assert 20.0 <= parts["other"]["ms"] < 120.0
+    assert 20.0 <= parts["other"]["ms"]  # the remainder: the sum above holds it from above, every other cause held from below
     # off the CPU in the causes that are work: the sleep inside `verify`, not the one the thread chose (`starved`)
-    assert 25.0 <= parts["offcpu"]["ms"] < 200.0 and "cpu_ms" not in parts["offcpu"]
+    work_off_cpu = sum(parts[c]["ms"] - parts[c]["cpu_ms"] for c in G._IDLE_WORK)
+    assert 25.0 <= parts["offcpu"]["ms"] == pytest.approx(work_off_cpu, abs=1.0) and "cpu_ms" not in parts["offcpu"]
     # laid end to end from the gap's start, an account and no more
     assert parts["finish"]["t0"] == gap["t0"] and parts["other"]["t0"] > parts["prep"]["t0"] >= parts["starved"]["t0"]
 

@@ -5,8 +5,9 @@ and runs a small-but-representative G1 MSM parity check against the host
 oracle INSIDE it: enough points and window width to drive the
 batch-affine bucket fill (its shared-inversion scratch buffers are the
 new-code risk this guards), the Jacobian A/B arm, the GLV driver, and
-the persistent worker pool, and the service's sample verify
-(`groth16_verify_bn254`: the Fq12 tower and the Miller loops) — all
+the persistent worker pool, the service's sample verify
+(`groth16_verify_bn254`: the Fq12 tower and the Miller loops) and a
+proof's assembly (`groth16_assemble_bn254`) — all
 under `-fno-sanitize-recover`, so any ASan/UBSan report aborts the
 subprocess and fails the test.
 
@@ -343,6 +344,20 @@ assert pairing_product_is_one(lib, [])
 assert pairing_product_is_one(lib, [(None, G2_GENERATOR), (G1_GENERATOR, None)])
 assert not pairing_product_is_one(lib, [(G1_GENERATOR, G2_GENERATOR)])
 print("ok groth16_verify", flush=True)
+
+# a proof's assembly (groth16_assemble_bn254): stack points and scalars
+# alone; full-width blinding, then every accumulator at infinity, then an
+# accumulator off its curve (no answer), each against the Python form
+from types import SimpleNamespace
+from zkp2p_tpu.snark.native_assemble import assemble_native, assemble_python
+lib.groth16_assemble_bn254.argtypes = [u64p, u64p, u64p, u64p]
+a_key = SimpleNamespace(alpha_1=v_vk.alpha_1, beta_1=g1_mul(G1_GENERATOR, vb), delta_1=g1_mul(G1_GENERATOR, vd),
+                        beta_2=v_vk.beta_2, delta_2=v_vk.delta_2)
+a_acc = [v_good.a, v_good.c, v_good.b, v_bad.c, v_vk.ic[0]]
+for acc in (a_acc, [None] * 5, [a_key.alpha_1] + a_acc[1:]):
+    assert assemble_native(lib, a_key, acc, vr, vs) == assemble_python(a_key, acc, vr, vs)
+assert assemble_native(lib, a_key, [(1, 1)] + a_acc[1:], vr, vs) is None
+print("ok groth16_assemble", flush=True)
 
 lib.zkp2p_pool_shutdown()
 print("ASAN-PARITY-GREEN", flush=True)

@@ -26,7 +26,9 @@ oracle so a silently-wrong result fails even where no race is reported:
   * multi-column MSM from two concurrent submitters;
   * the sample verify (`groth16_verify_bn254`) from two concurrent
     submitters: four replicas of one process call it at once, and its
-    curve constants are built on first use.
+    curve constants are built on first use;
+  * a proof's assembly (`groth16_assemble_bn254`) from four threads at
+    once, as those replicas' proving threads call it after every batch.
 
 The python interpreter is NOT instrumented, so libtsan must be
 LD_PRELOADed (same pattern as the ASan smoke; TSan only tracks
@@ -254,6 +256,29 @@ for t in ts: t.start()
 for t in ts: t.join()
 assert not errors, errors
 print("ok groth16_verify", flush=True)
+
+# a proof's assembly (groth16_assemble_bn254) from four threads at once,
+# as four replicas' proving threads call it: it shares nothing but the
+# curve constants the verify above built
+from types import SimpleNamespace
+from zkp2p_tpu.snark.native_assemble import assemble_native, assemble_python
+lib.groth16_assemble_bn254.argtypes = [u64p, u64p, u64p, u64p]
+a_key = SimpleNamespace(alpha_1=v_vk.alpha_1, beta_1=g1_mul(G1_GENERATOR, vb), delta_1=g1_mul(G1_GENERATOR, vd),
+                        beta_2=v_vk.beta_2, delta_2=v_vk.delta_2)
+a_acc = [v_good.a, v_good.c, v_good.b, v_bad.c, None]
+a_want = assemble_python(a_key, a_acc, vr, vs)
+def assembler(tag):
+    try:
+        for _ in range(3):
+            assert assemble_native(lib, a_key, a_acc, vr, vs) == a_want, tag
+    except Exception as e:  # noqa: BLE001
+        errors.append((tag, e))
+
+ts = [threading.Thread(target=assembler, args=(f"assemble{i}",)) for i in range(4)]
+for t in ts: t.start()
+for t in ts: t.join()
+assert not errors, errors
+print("ok groth16_assemble", flush=True)
 
 stop.set()
 rd.join()

@@ -62,13 +62,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
     lib.bn254_pairing_product_is_one.restype = ctypes.c_int
     lib.groth16_verify_bn254.argtypes = [u64p, ctypes.c_int, ctypes.c_int, u64p, u64p, ctypes.c_int]
     lib.groth16_verify_bn254.restype = ctypes.c_int
+    lib.groth16_assemble_bn254.argtypes = [u64p, u64p, u64p, u64p]
+    lib.groth16_assemble_bn254.restype = ctypes.c_int
     # Self-check before trusting it: one field mul against Python ints, one
-    # fixed-base scalar mul against the host curve oracle AND one pairing
-    # identity, so a library with subtly wrong curve ops (used for
-    # trusted-setup point generation) or a wrong tower (used for the
+    # fixed-base scalar mul against the host curve oracle, one pairing
+    # identity AND one proof's assembly, so a library with subtly wrong
+    # curve ops (used for trusted-setup point generation and for every
+    # device proof's pi_a, pi_b, pi_c) or a wrong tower (used for the
     # service's sample verify) is rejected, not just one with a broken
     # multiplier.
-    from ..field.bn254 import P
+    from ..field.bn254 import P, R
 
     a, b = 0x1234567890ABCDEF << 120 | 0x42, P - 12345
     av, bv, cv = _int_to_u64x4(a), _int_to_u64x4(b), np.zeros(4, dtype=np.uint64)
@@ -94,6 +97,21 @@ def get_lib() -> Optional[ctypes.CDLL]:
     if not pairing_product_is_one(lib, [left, (g1_neg(g1_mul(G1_GEN, a * b)), G2_GENERATOR)]) or (
         pairing_product_is_one(lib, [left, (g1_neg(g1_mul(G1_GEN, a * b + 1)), G2_GENERATOR)])
     ):
+        _lib = None
+        return None
+    # one assembly at full-width blinding, an accumulator at infinity among
+    # the five, against the Python form: its bytes or no library
+    from types import SimpleNamespace
+
+    from ..snark import native_assemble
+
+    key = SimpleNamespace(
+        alpha_1=g1_mul(G1_GEN, 3), beta_1=g1_mul(G1_GEN, 5), delta_1=g1_mul(G1_GEN, 7),
+        beta_2=g2_mul(G2_GENERATOR, 5), delta_2=g2_mul(G2_GENERATOR, 7),
+    )
+    acc = (g1_mul(G1_GEN, 11), g1_mul(G1_GEN, 13), g2_mul(G2_GENERATOR, 13), g1_mul(G1_GEN, 17), None)
+    r, s = R - 0xDEADBEEFCAFEF00D, R - 0x1234567890ABCDEF
+    if native_assemble.assemble_native(lib, key, acc, r, s) != native_assemble.assemble_python(key, acc, r, s):
         _lib = None
         return None
     return _lib

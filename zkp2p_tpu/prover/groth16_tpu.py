@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..curve.host import G1Point, G2Point, g1_add, g1_mul, g1_neg, g2_add, g2_mul
+from ..curve.host import G1Point, G2Point
 from ..curve.jcurve import (
     ADD_LAW,
     AffPoint,
@@ -68,7 +68,7 @@ from ..ops.msm import (
     signed_digit_planes_from_limbs,
 )
 from ..ops.ntt import LADDER as NTT_LADDER, coset_shift, intt, ntt
-
+from ..snark import native_assemble
 from ..snark.groth16 import Proof, ProvingKey, coset_gen, domain_size_for, qap_rows
 from ..snark.r1cs import ConstraintSystem
 from ..snark.witness_check import rows_of, unreduced_rows
@@ -1179,15 +1179,15 @@ def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_S
 
 
 def _assemble(dpk: DeviceProvingKey, acc, r: int, s: int) -> Proof:
-    a_acc, b1_acc, b2_acc, c_acc, h_acc = acc
-    pi_a = g1_add(g1_add(dpk.alpha_1, a_acc), g1_mul(dpk.delta_1, r))
-    pi_b = g2_add(g2_add(dpk.beta_2, b2_acc), g2_mul(dpk.delta_2, s))
-    pi_b1 = g1_add(g1_add(dpk.beta_1, b1_acc), g1_mul(dpk.delta_1, s))
-    pi_c = g1_add(c_acc, h_acc)
-    pi_c = g1_add(pi_c, g1_mul(pi_a, s))
-    pi_c = g1_add(pi_c, g1_mul(pi_b1, r))
-    pi_c = g1_add(pi_c, g1_neg(g1_mul(dpk.delta_1, r * s % R)))
-    return Proof(a=pi_a, b=pi_b, c=pi_c)
+    """A proof from its accumulators (affine host points) and its blinding: the native
+    library's one call where it is loaded, else the oracle (`snark/native_assemble.py`)."""
+    from ..utils.metrics import REGISTRY
+    proof, path = native_assemble.assemble(dpk, acc, r, s)
+    REGISTRY.counter("zkp2p_assemble_total", {"path": path}).inc()
+    return proof
+
+
+_assemble_host = native_assemble.assemble_python  # the oracle, on Python integers: `prove_native` assembles with it
 
 
 def prove_tpu(
@@ -1744,7 +1744,7 @@ def prove_tpu_batch(
                 watch.close()  # the last stage's result is ready: the device has nothing left
             if watch.t_ready is not None:
                 _fed_last[placement] = (threading.current_thread(), watch.t_ready, thread_tally(), time.thread_time())
-        with trace("finish"):
+        with trace("finish", assemble=native_assemble.path_for()):
             hq = g1_jac_to_host(accs[4])
             b2 = g2_jac_to_host(accs[2])
             proofs = [

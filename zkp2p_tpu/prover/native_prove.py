@@ -40,7 +40,7 @@ from ..field.tower import Fq2
 from ..native.lib import _scalars_to_u64, get_lib
 from ..snark.groth16 import Proof, coset_gen
 from ..snark.witness_check import rows_of
-from .groth16_tpu import DeviceProvingKey, _assemble, _check_inferred_widths
+from .groth16_tpu import DeviceProvingKey, _assemble_host, _check_inferred_widths
 
 _u64p = ctypes.POINTER(ctypes.c_uint64)
 _u32p = ctypes.POINTER(ctypes.c_uint32)
@@ -791,7 +791,7 @@ def prove_native(
         b2_acc = msm_g2(dpk.b2_bases, np.ascontiguousarray(w_std[b_sel]), "b2")
         c_acc = msm_g1(dpk.c_bases, np.ascontiguousarray(w_std[c_sel]), "c")
         h_acc = msm_g1(dpk.h_bases, d_std, "h")
-    proof = _assemble(dpk, (a_acc, b1_acc, b2_acc, c_acc, h_acc), r, s)
+    proof = _assemble_host(dpk, (a_acc, b1_acc, b2_acc, c_acc, h_acc), r, s)
     # publish into the process registry: prove count + a refresh of the
     # native runtime's counter block (one ctypes read of ~20 slots —
     # noise next to a prove), so a Prometheus scrape or the service's
@@ -1023,7 +1023,7 @@ def prove_native_batch(
         h_accs = msm_g1_multi(dpk.h_bases, d_cols, "h")
 
     proofs = [
-        _assemble(dpk, (a_accs[s], b1_accs[s], b2_accs[s], c_accs[s], h_accs[s]), rs[s], ss[s])
+        _assemble_host(dpk, (a_accs[s], b1_accs[s], b2_accs[s], c_accs[s], h_accs[s]), rs[s], ss[s])
         for s in range(S)
     ]
     from ..utils.metrics import REGISTRY, publish_native_stats
