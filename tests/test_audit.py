@@ -84,7 +84,7 @@ def _stand_in_stages(monkeypatch, g, dpk):
     monkeypatch.setattr(g, "_jit_msm_g2_narrow", msm("g2_narrow", (2, 16)))
     monkeypatch.setattr(g, "G1J", adds)
     monkeypatch.setattr(g, "G2J", adds)
-    monkeypatch.setattr(g, "_h_table_window", lambda log_m, device=None: None)  # the scan road: h through the G1 program too
+    monkeypatch.setattr(g, "_h_table_window", lambda log_m, device=None, mesh=None: None)  # the scan road: h through the G1 program too
     return counts
 
 

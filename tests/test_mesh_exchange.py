@@ -69,7 +69,7 @@ def _exchanged(dpk, mesh, limbs, h_std):
     chunk = NamedSharding(mesh, G._pod_chunk_spec(mesh, split))
     # whole proofs a chip where the chunk is split; else the shared h stage's: a chip its columns of each proof
     h_layout = chunk if split else NamedSharding(mesh, P("batch", "shard"))
-    fn = G._exchange_pod_fn(mesh, split, placed.h_bases[0].shape[0])
+    fn = G._exchange_pod_fn(mesh, split, placed.h_bases[0].shape[0], G.MSM_WINDOW)
     w_planes, h_planes, _done = fn(placed_wires(placed), jax.device_put(limbs, chunk), jax.device_put(h_std, h_layout))
     return placed, w_planes, h_planes
 
@@ -290,7 +290,8 @@ def test_the_chips_msm_partials_fold_to_the_whole_msm(toy, b, s):
 # A group that is cheap to compile where the real programs of the curve take minutes on XLA:CPU: the integers mod
 # P_LIN under addition, an element carried as the Y of a point.  Affine (x, y) stands for y, the (0, 0) filler for 0;
 # a negated point is (x, -y), as on the curve; the MSMs, the recode, the classes, the lanes, the shards and the fold
-# are the program's own.
+# are the program's own.  A resident table's words hold it too: an element is under 2^16, one limb of x beside one
+# of y, and the field under the coordinates (the build divides by Z, here 1) is the integers mod P_LIN.
 P_LIN = 65521
 
 
@@ -299,9 +300,26 @@ class _LinField:
         import jax.numpy as jnp
 
         self.zero_limbs = jnp.zeros(elem, jnp.uint32)
+        self.one_mont = jnp.ones(elem, jnp.uint32)
 
     def neg(self, y):
         return (P_LIN - y) % P_LIN
+
+    def mul(self, a, b):
+        return a * b % P_LIN
+
+    def inv_fused(self, a):
+        import jax.numpy as jnp
+
+        return jnp.ones_like(a)  # of a product of Z's, each 1
+
+    def is_zero(self, a):
+        return (a == 0).all(axis=-1)
+
+    def select(self, cond, a, b):
+        import jax.numpy as jnp
+
+        return jnp.where(cond[..., None], a, b)
 
 
 class _LinCurve:
@@ -327,10 +345,12 @@ class _LinCurve:
         return self.add(p, p)
 
 
-def _linear_world(monkeypatch, classed, seed):
+def _linear_world(monkeypatch, classed, seed, h_window=None):
     """A synthetic key over the stand-in group, a fake h stage the two
     roads share (h = the first m wires: any function of the witness
-    does), fresh programs on both roads, and what each MSM must sum to."""
+    does), fresh programs on both roads, and what each MSM must sum to.
+    `h_window`: the window both roads' rule answers for the resident h
+    table (None: the multiples in the scan)."""
     import jax
     import jax.numpy as jnp
 
@@ -342,10 +362,11 @@ def _linear_world(monkeypatch, classed, seed):
     monkeypatch.setattr(G, "G1J", g1)
     monkeypatch.setattr(G, "G2J", g2)
     monkeypatch.setattr(G, "h_evals", lambda dpk, w_mont: w_mont[: 1 << log_m])
-    monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None: None)  # the h MSM builds its multiples in the scan
+    monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None, mesh=None: h_window)
     for name, fn in (("_jit_msm_g1", G._msm_g1), ("_jit_msm_g2", G._msm_g2), ("_jit_msm_g1_narrow", G._msm_g1_narrow),
-                     ("_jit_msm_g2_narrow", G._msm_g2_narrow)):
+                     ("_jit_msm_g2_narrow", G._msm_g2_narrow), ("_jit_msm_h_resident", G._msm_h_resident)):
         monkeypatch.setattr(G, name, jax.jit(jax.vmap(fn, in_axes=(None, 0))))  # traced here, over the stand-in
+    monkeypatch.setattr(G, "_jit_h_table", jax.jit(G._h_table_fn, static_argnames="window"))
     monkeypatch.setattr(G, "_jit_h_planes", jax.jit(jax.vmap(G._h_and_planes, in_axes=(None, 0, None)), static_argnums=2))
     G._h_pod_fn.cache_clear()
 
@@ -390,23 +411,28 @@ def _linear_world(monkeypatch, classed, seed):
     return dpk, witness, sums
 
 
+@pytest.mark.parametrize("h_window", [None, 4, 8], ids=["h-scan", "h-table4", "h-table8"])
 @pytest.mark.parametrize("classed", [True, False], ids=["classed", "no-widths"])
 @pytest.mark.parametrize("b,s,n_proofs", [(1, 4, 4), (1, 4, 1), (2, 2, 4), (2, 2, 2)],
                          ids=["1x4-split", "1x4-shared", "2x2-split", "2x2-shared"])
-def test_the_mesh_road_s_accumulators_are_the_one_chip_road_s(monkeypatch, b, s, n_proofs, classed):
+def test_the_mesh_road_s_accumulators_are_the_one_chip_road_s(monkeypatch, b, s, n_proofs, classed, h_window):
     """`_prove_batch_sharded` beside `_prove_device` on the same key and
     witnesses, the curve stood in for by a group that compiles in
     seconds: the five accumulators are equal element for element, and
     are the sums of scalar x point — for a key with a narrow class and
     for one without, on 1x4 and 2x2, the chunk split over a group's
     chips (a chunk of four) and shared by them (a batch of one on 1x4, a
-    proof a group on 2x2: the step widths a chunk of one takes)."""
+    proof a group on 2x2: the step widths a chunk of one takes), the h
+    MSM with its multiples in the scan and against a resident table at
+    either window: on the mesh each chip's table of its own shard of
+    the h bases (`resident_table_pod`), h exchanged at the table's
+    window, one build a placed key."""
     import jax.numpy as jnp
 
     from zkp2p_tpu.field.jfield import FR
     from zkp2p_tpu.prover import groth16_tpu as G
 
-    dpk, witness, sums = _linear_world(monkeypatch, classed, seed=31 + n_proofs)
+    dpk, witness, sums = _linear_world(monkeypatch, classed, seed=31 + n_proofs, h_window=h_window)
     try:
         mesh = _mesh(b, s)
         wits = [witness() for _ in range(n_proofs)]
@@ -415,6 +441,19 @@ def test_the_mesh_road_s_accumulators_are_the_one_chip_road_s(monkeypatch, b, s,
         placed = G.place_key(dpk, mesh)
         assert bool(placed.a_bases[0][0].shape[0]) == classed
         on_mesh = G._prove_batch_sharded(placed, limbs, mesh)
+        table = getattr(placed, "_h_table_cache", None)
+        if h_window is None:
+            assert table is None
+        else:  # a chip its own shard's multiples, 2^(w-1) a base, in steps: sharded as the bases are
+            n_h = placed.h_bases[0].shape[0]
+            lanes = G.pod_table_lanes(n_h, s)
+            assert table.shape == (n_h // lanes, 1 << (h_window - 1), lanes, 1)
+            by_device = {sh.device: sh.index[0] for sh in placed.h_bases[0].addressable_shards}
+            for sh in table.addressable_shards:
+                rows = by_device[sh.device]
+                assert (sh.index[0].start * lanes, sh.index[0].stop * lanes) == (rows.start or 0, rows.stop or n_h)
+            G._prove_batch_sharded(placed, limbs, mesh)
+            assert placed._h_table_cache is table  # memoised on the placed key
         one_chip = G._prove_device(dpk, FR.to_mont(jnp.asarray(limbs)))
         for name, got, want, elem in zip(G.STAGES[1:], on_mesh, one_chip, ((1,), (1,), (2, 1), (1,), (1,))):
             assert got[1].shape == want[1].shape == (n_proofs,) + elem, name

@@ -186,7 +186,7 @@ def test_the_h_planes_span_names_the_ladder_that_ran(monkeypatch):
 
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "off")
     monkeypatch.setattr(G, "BATCH_CHUNK", "0")
-    monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None: None)
+    monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None, mesh=None: None)
     monkeypatch.setattr(G, "_jit_h_planes", jax.jit(jax.vmap(G._h_and_planes, in_axes=(None, 0, None)), static_argnums=2))
     monkeypatch.setattr(G, "_jit_msm_g1", infinity((16,)))
     monkeypatch.setattr(G, "_jit_msm_g2", infinity((2, 16)))

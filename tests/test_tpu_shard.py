@@ -238,7 +238,7 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
     monkeypatch.setattr(G, "_jit_h_table", fake_h_table)
     monkeypatch.setattr(G, "_jit_msm_h_resident", fake_msm_resident)
     if h_road == "scan":
-        monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None: None)
+        monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None, mesh=None: None)
     monkeypatch.setattr(G, "_jit_msm_g2", fake_msm((2, 16)))
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
     tr.reset()
@@ -288,11 +288,15 @@ def test_prove_batch_writes_phases_that_partition_it_and_six_stages_inside_devic
 
 
 def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
-    """The mesh road's compiled programs stood in for (the h stage and
-    each pod MSM compile for minutes on XLA:CPU): zero h, infinity
-    accumulators, and `_assemble` handing the accumulators back.  The
-    key's placement, the upload, the exchange program and the read loop
-    that writes the spans are the real ones."""
+    """The mesh road's compiled programs stood in for (the h stage, the
+    h table's build and each pod MSM compile for minutes on XLA:CPU):
+    zero h, a table of zeros in the real one's shape and shards,
+    infinity accumulators, and `_assemble` handing the accumulators
+    back.  The key's placement, the window rule, the upload, the
+    exchange program and the read loop that writes the spans are the
+    real ones.  Returns the windows of the tables built, in order."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
     import numpy as np
 
     from zkp2p_tpu.curve.jcurve import G2J
@@ -314,21 +318,46 @@ def _stand_in_for_the_mesh_programs(monkeypatch, sleep_s=0.0):
         limbs = (2, 16) if curve is G2J else (16,)
         return tuple(np.zeros((planes[0][0].shape[0],) + limbs, np.uint32) for _ in range(3))  # Z = 0: infinity
 
+    built = []
+
+    def fake_table_pod(curve, bases, mesh, window, lanes, **kw):
+        built.append(window)
+        share = bases[0].shape[0] // mesh.shape["shard"]
+        lanes = min(lanes, share)
+        steps = mesh.shape["shard"] * -(-share // lanes)
+        return jax.device_put(np.zeros((steps, 1 << (window - 1), lanes, 16), np.uint32), NamedSharding(mesh, P("shard")))
+
+    def fake_msm_pod_resident(curve, table, planes, mesh, **kw):
+        assert planes[0].shape[1] == 256 // int(table.shape[1]).bit_length()  # h came at the table's window
+        return fake_msm_pod(curve, None, (planes,), mesh)
+
     monkeypatch.setattr(G, "_h_pod_fn", fake_h_pod)
     monkeypatch.setattr(G, "_h_shard_fn", fake_h_shard)
     monkeypatch.setattr(pmesh, "msm_pod_batched", fake_msm_pod)
+    monkeypatch.setattr(pmesh, "resident_table_pod", fake_table_pod)
+    monkeypatch.setattr(pmesh, "msm_pod_resident", fake_msm_pod_resident)
     monkeypatch.setattr(G, "_assemble", lambda dpk_, acc, r, s: acc)
+    return built
 
 
-def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
-    """`_prove_batch_sharded` recodes h at MSM_WINDOW and runs
-    `msm_pod_batched`, which builds a step's multiples in its scan: it
-    bypasses the key's resident h table by construction.  The real road
-    on the 1x4 virtual mesh, its h program and its pod MSMs stood in
-    for: no table is built, none is memoised on the key, the gauge stays
-    0 and no `h_table` span is written."""
+@pytest.mark.parametrize("mesh_spec,n_wits,window", [("1x4", 4, 8), ("1x4", 1, 8), ("2x2", 4, 8), ("1x4", 4, None)],
+                         ids=["1x4-split", "1x4-shared", "2x2-split", "no-window"])
+def test_the_mesh_road_builds_its_h_table_once_a_placed_key(toy_keys, monkeypatch, mesh_spec, n_wits, window):
+    """`_prove_batch_sharded` reads a resident h table in shards: the
+    first batch of a placed key builds it (`resident_table_pod`, each
+    chip its own shard's), under one `tpu/prove_batch/h_table` span
+    inside `device` with `mesh`, `window` and `bytes` (every chip's), and
+    memoises it on the PLACED key, never on the key handed in; the next
+    batch builds nothing.  `zkp2p_msm_h_table_bytes` reads the table's
+    bytes, and the `msm_h` stage's span says `window`, `table` =
+    "resident" and `add` = "mixed" (the accumulate is `add_mixed`).
+    Where the rule gives no window the road keeps the in-scan form:
+    nothing built, the gauge 0, h at MSM_WINDOW through
+    `msm_pod_batched`, the span `table` = "scan" with the curve's law.
+    The real road on the virtual mesh, its programs stood in for."""
     import dataclasses
 
+    from zkp2p_tpu.parallel import mesh as pmesh
     from zkp2p_tpu.prover import groth16_tpu as G
     from zkp2p_tpu.utils import trace as tr
     from zkp2p_tpu.utils.audit import gate_arms
@@ -336,22 +365,50 @@ def test_the_mesh_road_builds_no_h_table(toy_keys, monkeypatch):
 
     cs, _pk, _vk, dpk, x, y = toy_keys
     dpk = dataclasses.replace(dpk)  # a key instance of its own: nothing memoised on it yet
-    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11), (1, 1)])
+    wits, _ = _toy_wits(cs, x, y, [(3, 5), (2, 7), (10, 11), (1, 1)][:n_wits])
     monkeypatch.setenv("ZKP2P_TPU_SHARD", "on")
-    monkeypatch.setenv("ZKP2P_TPU_MESH", "1x4")
+    monkeypatch.setenv("ZKP2P_TPU_MESH", mesh_spec)
     monkeypatch.setattr(G, "BATCH_CHUNK", "0")
-    _stand_in_for_the_mesh_programs(monkeypatch)
-    monkeypatch.setattr(G, "_jit_h_table", lambda *a, **k: pytest.fail("the mesh road built a table"))
-    REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0)
-    tr.reset()
-    out = G.prove_tpu_batch(dpk, wits, rs=[1, 2, 3, 4], ss=[5, 6, 7, 8])
-    assert len(out) == 4 and gate_arms()["tpu_shard"] == "1x4"
-    recs = tr.records()
-    assert not [r for r in recs if r["stage"].endswith("/h_table")]
-    (h_stage,) = [r for r in recs if r["stage"].endswith("/stage/msm_h")]
-    assert "table" not in h_stage and "window" not in h_stage and h_stage["add"] == "complete_projective"
-    assert REGISTRY.gauge("zkp2p_msm_h_table_bytes").value == 0
-    assert not hasattr(dpk, "_h_table_cache")
+    built = _stand_in_for_the_mesh_programs(monkeypatch)
+    monkeypatch.setattr(G, "_jit_h_table", lambda *a, **k: pytest.fail("the mesh road built a one-chip table"))
+    if window is None:
+        monkeypatch.setattr(G, "_h_table_window", lambda log_m, device=None, mesh=None: None)
+    fake, scanned = pmesh.msm_pod_batched, []
+    monkeypatch.setattr(pmesh, "msm_pod_batched", lambda curve, bases, planes, mesh, **kw: (
+        scanned.append(planes[0][0].shape[1]), fake(curve, bases, planes, mesh, **kw))[1])
+    gauge = REGISTRY.gauge("zkp2p_msm_h_table_bytes")
+    gauge.set(-1)
+    pinned = list(range(1, n_wits + 1))
+    for batch in (0, 1):
+        tr.reset()
+        out = G.prove_tpu_batch(dpk, wits, rs=pinned, ss=pinned)
+        assert len(out) == n_wits and gate_arms()["tpu_shard"] == mesh_spec
+        recs = tr.records()
+        (device,) = [r for r in recs if r["stage"] == "tpu/prove_batch/device"]
+        (dispatch,) = [r for r in recs if r["stage"] == "tpu/prove_batch/dispatch"]
+        tables = [r for r in recs if r["stage"].endswith("/h_table")]
+        (h_stage,) = [r for r in recs if r["stage"].endswith("/stage/msm_h")]
+        placed = G._key_on_mesh(dpk, G._shard_mesh())
+        assert not hasattr(dpk, "_h_table_cache")
+        if window is None:
+            assert not tables and not built and gauge.value == 0 and not hasattr(placed, "_h_table_cache")
+            assert (h_stage["window"], h_stage["table"], h_stage["add"]) == (G.MSM_WINDOW, "scan", "complete_projective")
+            assert scanned[4::5] == [64] * (batch + 1)  # the fifth pod MSM of a batch: h, at MSM_WINDOW's planes
+            continue
+        table = placed._h_table_cache
+        n_chips = len(table.addressable_shards)
+        m_pad = placed.h_bases[0].shape[0]
+        want_bytes = m_pad * 128 * 64 * (n_chips // int(mesh_spec[-1]))  # 2^(w-1) entries of 64 B a base, a replica a group
+        assert built == [window] and gauge.value == want_bytes and len(scanned) == 4 * (batch + 1)
+        assert (h_stage["window"], h_stage["table"], h_stage["add"], h_stage["digits"], h_stage["mesh"]) == (
+            window, "resident", "mixed", "signed", mesh_spec)
+        if batch:
+            assert not tables  # memoised: the second batch builds nothing
+        else:
+            (built_span,) = tables
+            assert built_span["stage"] == "tpu/prove_batch/h_table" and built_span["parent"] == device["id"]
+            assert (built_span["window"], built_span["mesh"], built_span["bytes"]) == (window, mesh_spec, want_bytes)
+            assert built_span["id"] < dispatch["id"]  # before the batch is enqueued: no stage waits for it
     tr.reset()
 
 
@@ -495,7 +552,7 @@ def test_the_mesh_road_s_query_spans_say_their_classes_and_digits(toy_keys, monk
         assert classes == want
     assert stages["msm_a"]["narrow"] == stages["msm_b1"]["narrow"] == stages["msm_c"]["narrow"]
     assert stages["msm_a"]["wide"] == stages["msm_b1"]["wide"] == stages["msm_c"]["wide"]
-    assert stages["msm_h"]["digits"] == "signed" and handed[4] == [(placed.h_bases[0].shape[0], 64, G.pod_lanes(placed.h_bases[0].shape[0], 4, 4))]
+    assert stages["msm_h"]["digits"] == "signed" and len(handed) == 4  # h reads its resident table: `msm_pod_resident`
     assert all(k not in stages[name] for k in ("narrow", "wide") for name in ("msm_h", "exchange", "h_planes"))
     assert all("digits" not in stages[name] for name in ("exchange", "h_planes"))
     tr.reset()

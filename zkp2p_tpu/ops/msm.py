@@ -399,8 +399,28 @@ def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp
     `add_mixed` passes through; equal and opposite points are lanes like
     any other to the complete formulas, so the sum is exact for every
     input."""
+    partials, lanes, window = _resident_partials(curve, table, mags, negs)
+    per_lane = horner_fold_planes(curve, curve.infinity((lanes,)), tuple(c for c in partials), window)
+    return tree_reduce(curve, per_lane, lanes)
+
+
+def resident_plane_sums(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp.ndarray) -> ProjPoint:
+    """`msm_resident` up to its last fold, as `msm_plane_sums` is
+    `msm_windowed_signed`'s: the sum of every plane, (n_digits,) points,
+    most significant first — the table's accumulate, then each plane's
+    lanes folded (`tree_reduce`).  `horner_fold_planes` over them at the
+    table's window gives the point `msm_resident` gives.  The mesh road's
+    h MSM (`parallel.mesh.msm_pod_resident`): its Horner has a point's
+    shape, the allreduce's fold's."""
+    partials, lanes, _window = _resident_partials(curve, table, mags, negs)
+    return tree_reduce(curve, partials, lanes)
+
+
+def _resident_partials(curve, table, mags, negs):
+    """The accumulate of the resident MSMs: `(partials, lanes, window)`,
+    the planes' partial sums lane by lane, (n_digits, lanes) points, and
+    the step width and the window read off the table's shape."""
     steps, n_table, lanes = table.shape[:3]
-    window = n_table.bit_length()
     n_digits, n = mags.shape
     pad = steps * lanes - n
     if pad:
@@ -421,8 +441,7 @@ def msm_resident(curve: JCurve, table: jnp.ndarray, mags: jnp.ndarray, negs: jnp
         return curve.add_mixed(acc, (words & low, y)), None
 
     partials, _ = jax.lax.scan(accumulate, curve.infinity((n_digits, lanes)), (table, digits, neg_t))
-    per_lane = horner_fold_planes(curve, curve.infinity((lanes,)), tuple(c for c in partials), window)
-    return tree_reduce(curve, per_lane, lanes)
+    return partials, lanes, n_table.bit_length()
 
 
 def msm(curve: JCurve, bases: AffPoint, bit_planes: jnp.ndarray, lanes: int = 64) -> ProjPoint:

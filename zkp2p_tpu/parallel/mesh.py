@@ -28,7 +28,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..curve.jcurve import AffPoint, ProjPoint, JCurve
-from ..ops.msm import horner_fold_planes, msm_plane_sums
+from ..ops.msm import horner_fold_planes, msm_plane_sums, resident_plane_sums, resident_table
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "shard") -> Mesh:
@@ -66,18 +66,23 @@ def _msm_pod_fn(curve: JCurve, mesh: Mesh, dcn_axis: str, ici_axis: str, lanes: 
             acc = horner_fold_planes(
                 curve, curve.infinity(mags.shape[:1]), tuple(jnp.moveaxis(c, 1, 0) for c in sums), window)
             part = acc if part is None else curve.add(part, acc)
-        # ICI allreduce within the slice: combine base-axis partials
-        gathered = jax.lax.all_gather(part, ici_axis, axis=1)
-        acc = _fold_gathered_batched(curve, gathered, mesh.shape[ici_axis])
-        # DCN all-gather across slices: assemble the full proof batch
-        # (one point per proof — the only cross-slice traffic, matching
-        # the make_pod_mesh contract of data-parallel-only over dcn)
-        return tuple(jax.lax.all_gather(c, dcn_axis, axis=0, tiled=True) for c in acc)
+        return _allreduce(curve, mesh, part, dcn_axis, ici_axis)
 
     # a spec is a prefix of its argument's tree: every coordinate of every
     # class's bases, every class's mags and negs
     in_specs = (P(ici_axis), P(dcn_axis, None, ici_axis))
     return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False))
+
+
+def _allreduce(curve: JCurve, mesh: Mesh, part: ProjPoint, dcn_axis: str, ici_axis: str) -> ProjPoint:
+    """A chip's (B_local,) partials -> the (B,) sums, on every chip."""
+    # ICI allreduce within the slice: combine base-axis partials
+    gathered = jax.lax.all_gather(part, ici_axis, axis=1)
+    acc = _fold_gathered_batched(curve, gathered, mesh.shape[ici_axis])
+    # DCN all-gather across slices: assemble the full proof batch
+    # (one point per proof — the only cross-slice traffic, matching
+    # the make_pod_mesh contract of data-parallel-only over dcn)
+    return tuple(jax.lax.all_gather(c, dcn_axis, axis=0, tiled=True) for c in acc)
 
 
 def _fold_gathered_batched(curve: JCurve, gathered: ProjPoint, n: int) -> ProjPoint:
@@ -127,3 +132,63 @@ def msm_pod_batched(
         assert mags.shape[0] % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"
         assert bs[0].shape[0] % mesh.shape[ici_axis] == 0, "pad the base axis first"
     return _msm_pod_fn(curve, mesh, dcn_axis, ici_axis, tuple(lanes), window)(tuple(bases), tuple(planes))
+
+
+# The h MSM of a key placed on the mesh: the bases are the key's, so each
+# chip keeps the window multiples of the shard it holds (`ops.msm`'s
+# resident table, one a chip: no base and no entry crosses ICI) and a
+# shard's MSM is `msm_resident`'s accumulate against it.
+
+
+@lru_cache(maxsize=None)
+def _resident_table_pod_fn(curve: JCurve, mesh: Mesh, ici_axis: str, window: int, lanes: int):
+    def local(bases):
+        return resident_table(curve, bases, window, lanes)
+
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(ici_axis),), out_specs=P(ici_axis), check_vma=False))
+
+
+def resident_table_pod(curve: JCurve, bases: AffPoint, mesh: Mesh, window: int, lanes: int, ici_axis: str = "shard") -> jnp.ndarray:
+    """`resident_table` of each chip's shard of `bases` (sharded over
+    `ici_axis`, N divisible by its width), built where the shard lies:
+    the layout of `ops.msm`, (steps, 2^(w-1), lanes, 16), sharded on
+    `steps`, a chip's share of the bases padded to whole steps of
+    `lanes`."""
+    assert bases[0].shape[0] % mesh.shape[ici_axis] == 0, "pad the base axis first"
+    return _resident_table_pod_fn(curve, mesh, ici_axis, window, lanes)(tuple(bases))
+
+
+@lru_cache(maxsize=None)
+def _msm_pod_resident_fn(curve: JCurve, mesh: Mesh, dcn_axis: str, ici_axis: str):
+    def local(table, mags, negs):
+        # this chip's table, unbatched under the proofs of its slice's share
+        sums = jax.vmap(lambda m, n: resident_plane_sums(curve, table, m, n))(mags, negs)
+        part = horner_fold_planes(
+            curve, curve.infinity(mags.shape[:1]), tuple(jnp.moveaxis(c, 1, 0) for c in sums), int(table.shape[1]).bit_length())
+        return _allreduce(curve, mesh, part, dcn_axis, ici_axis)
+
+    planes = P(dcn_axis, None, ici_axis)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(ici_axis), planes, planes), out_specs=P(), check_vma=False))
+
+
+def msm_pod_resident(
+    curve: JCurve,
+    table: jnp.ndarray,
+    planes: Tuple[jnp.ndarray, jnp.ndarray],
+    mesh: Mesh,
+    dcn_axis: str = "dcn",
+    ici_axis: str = "shard",
+) -> ProjPoint:
+    """`msm_pod_batched` over the one class of bases `table` was built
+    from (`resident_table_pod`), at the table's window: `planes` are
+    `(mags, negs)`, each (B, 256 / window, N), laid out as there.  A
+    shard's MSM is `msm_resident`'s accumulate (a select and one
+    `add_mixed` a plane a step; `ops.msm.resident_plane_sums`, the lanes
+    folded before the planes as `msm_pod_batched` folds them), vmapped
+    over the slice's proofs with the table unbatched; then the same ONE
+    all_gather + fold.  Returns (B,)-batched projective points,
+    replicated everywhere."""
+    mags, negs = planes
+    assert mags.shape[0] % mesh.shape[dcn_axis] == 0, "batch must divide the dcn axis"
+    assert mags.shape[2] % mesh.shape[ici_axis] == 0, "pad the base axis first"
+    return _msm_pod_resident_fn(curve, mesh, dcn_axis, ici_axis)(table, mags, negs)

@@ -90,11 +90,11 @@ MSM_WINDOW = 4
 # on the key alone, so the table is built once and the window is a
 # function of what the key's size lets the chip hold — w=8 (32 planes,
 # 128 multiples a base), else w=4 (64 planes, 8 multiples), else the
-# in-scan table of `_msm_g1`.  The batch chunk is a function of the same
-# two things: as many proofs at a time, up to four, as the device holds
-# beside the key.  Both rules plan `work_bytes_a_point(chunk)` a domain
-# point — the key and `chunk` proofs' working set — under
-# HBM_PLAN_FRACTION of the device's memory.
+# in-scan table (`_msm_g1`; on a mesh `msm_pod_batched`).  On a mesh a
+# chip keeps the table of the S-th of the bases it holds.  The batch
+# chunk follows the same two things: as many proofs at a time, up to
+# four, as the device holds beside the key.  Both rules plan the fullest
+# chip's `chip_bytes_a_point` under HBM_PLAN_FRACTION of its memory.
 # KEY_BYTES_A_POINT and PROOF_BYTES_A_POINT were calibrated on the
 # ledger's peak_hbm_bytes (PERF_LEDGER.jsonl, PR 24, a chunk of four,
 # before any table): 3,088,086,016 at 2^19 with a key of 459,873,288 is
@@ -112,6 +112,7 @@ KEY_BYTES_A_POINT = 1 << 10
 PROOF_BYTES_A_POINT = 3 << 9
 BATCH_CHUNK_MAX = 4
 NOMINAL_HBM_BYTES = 16 << 30
+POD_TABLE_LANES = 256
 # A key placed on a mesh (`place_key`): of KEY_BYTES_A_POINT the QAP rows,
 # which every chip holds whole, are KEY_ROWS_BYTES_A_POINT (163 MB at 2^19:
 # 311 B a point, rounded up); the bases, in S-ths, the rest.  A proof whose
@@ -172,13 +173,18 @@ def batch_chunk_for(log_m: int, bytes_limit: int, n_batch: int = 1, n_shard: int
         chunk //= 2
 
 
-def h_table_window(log_m: int, entry_bytes: int, bytes_limit: int, chunk: int = BATCH_CHUNK_MAX) -> Optional[int]:
+def h_table_window(
+    log_m: int, entry_bytes: int, bytes_limit: int, chunk: int = BATCH_CHUNK_MAX, n_batch: int = 1, n_shard: int = 1,
+) -> Optional[int]:
     """The widest signed window whose multiples table (2^(w-1) entries
-    of `entry_bytes` a base, 2^log_m bases) fits a device of
-    `bytes_limit` beside the key and a chunk of `chunk` proofs; None:
-    neither does."""
+    of `entry_bytes` a base, 2^log_m bases, an `n_shard`-th of them a
+    chip) fits a device of `bytes_limit` beside the fullest chip's share
+    of the key and of a chunk of `chunk` proofs on an `n_batch` x
+    `n_shard` placement (`chip_bytes_a_point`; one chip: 1 x 1, the
+    whole of each); None: neither does."""
     for window in (8, 4):
-        if ((entry_bytes << (window - 1)) + work_bytes_a_point(chunk)) << log_m <= HBM_PLAN_FRACTION * bytes_limit:
+        a_point = (entry_bytes << (window - 1)) / n_shard + chip_bytes_a_point(chunk, n_batch, n_shard)
+        if a_point * (1 << log_m) <= HBM_PLAN_FRACTION * bytes_limit:
             return window
     return None
 
@@ -207,15 +213,17 @@ def key_arrays_home(log_m: int):
     return jnp.asarray
 
 
-def _h_table_window(log_m: int, device=None) -> Optional[int]:
-    """The window at which a key of 2^log_m domain points on `device`
-    keeps a resident h table; None: the table does not fit, and the h
-    MSM builds its multiples in the scan (`_msm_g1`).  `_prove_device`
-    hands the window of the table it holds to the h stage's program."""
+def _h_table_window(log_m: int, device=None, mesh=None) -> Optional[int]:
+    """The window at which a key of 2^log_m domain points on `device`,
+    or placed on `mesh` (a chip's table: its shard's), keeps a resident h
+    table; None: the table does not fit, and the h MSM builds its
+    multiples in the scan (`_msm_g1`, `msm_pod_batched`).  Either road
+    hands the window of the table it holds to the program that recodes h."""
     limit = _hbm_bytes_limit(device)
+    shape = (1, 1) if mesh is None else (mesh.shape["batch"], mesh.shape["shard"])
     # off a TPU `auto` does not chunk (0): plan the chunk a TPU would take
-    chunk = _batch_chunk_size(log_m, device) or batch_chunk_for(log_m, limit)
-    return h_table_window(log_m, RESIDENT_ENTRY_BYTES, limit, chunk)
+    chunk = _batch_chunk_size(log_m, device, mesh) or batch_chunk_for(log_m, limit, *shape)
+    return h_table_window(log_m, RESIDENT_ENTRY_BYTES, limit, chunk, *shape)
 
 
 def _parse_mesh_spec(spec: str, n_devices: int) -> Optional[Tuple[int, int]]:
@@ -948,20 +956,43 @@ def _h_table(dpk: DeviceProvingKey) -> Optional[jnp.ndarray]:
     it and memoised on the instance like `_split_cache` (not a pytree
     field: its bytes never ride into a jitted stage as part of the key);
     None where `_h_table_window` says the h MSM builds its multiples in
-    the scan.  `zkp2p_msm_h_table_bytes` says which."""
+    the scan.  A key placed on a mesh (`key_mesh`) keeps it in shards:
+    each chip the table of the h bases it holds, built where they lie
+    (`resident_table_pod` at `pod_table_lanes`; the span says `mesh`).
+    `bytes` and `zkp2p_msm_h_table_bytes` count every chip's."""
     from ..utils.metrics import REGISTRY
     from ..utils.trace import trace
 
-    window, table = _h_table_window(dpk.log_m, key_device(dpk)), None
+    mesh = key_mesh(dpk)
+    window, table = _h_table_window(dpk.log_m, key_device(dpk), mesh), None
+    replicas = 1 if mesh is None else mesh.shape["batch"]  # every batch group holds the shards again
     if window is not None:
         table = getattr(dpk, "_h_table_cache", None)
         if table is None:
-            with trace("h_table", window=window) as span:
-                table = jax.block_until_ready(_jit_h_table(dpk.h_bases, window=window))
-                span["bytes"] = int(table.nbytes)
+            with trace("h_table", window=window, **({} if mesh is None else {"mesh": mesh_name(mesh)})) as span:
+                if mesh is None:
+                    table = _jit_h_table(dpk.h_bases, window=window)
+                else:
+                    from ..parallel.mesh import resident_table_pod
+
+                    n_h, n_ici = dpk.h_bases[0].shape[0], mesh.shape["shard"]
+                    table = resident_table_pod(G1J, dpk.h_bases, mesh, window, pod_table_lanes(n_h, n_ici))
+                table = jax.block_until_ready(table)
+                span["bytes"] = int(table.nbytes) * replicas
             setattr(dpk, "_h_table_cache", table)
-    REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0 if table is None else table.nbytes)
+    REGISTRY.gauge("zkp2p_msm_h_table_bytes").set(0 if table is None else table.nbytes * replicas)
     return table
+
+
+def pod_table_lanes(n: int, n_ici: int) -> int:
+    """The step width of a placed key's resident h table over `n` bases
+    in `n_ici` shards: POD_TABLE_LANES, and never more than the share.
+    The cap is the chip's reading of a share (PERF.md, PR 43): wider
+    steps cost more an add (256 lanes 640.7 ms for a 2^19 share x 4
+    proofs, 1,024 lanes 723.4, 4,096 887.7; a 2^21 share x 1 at w=4
+    4,939 / 5,009 / 5,551), and 64 and 256 lanes read within 4% of each
+    other, either way, at a 2^16 and a 2^19 share."""
+    return max(1, min(-(-n // n_ici), POD_TABLE_LANES))
 
 
 def _take_bases(bases, pos):
@@ -1067,7 +1098,7 @@ def _enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
 def _msm_enqueued(watch: Optional[_StageWatch], name: str, value, **attrs):
     """`_enqueued` for the five MSM stages: the span carries the curve's
     addition law (`add`), as `h_planes` carries its ladder."""
-    return _enqueued(watch, name, value, add=ADD_LAW, **attrs)
+    return _enqueued(watch, name, value, **{"add": ADD_LAW, **attrs})
 
 
 def _prove_device(dpk: DeviceProvingKey, w_mont: jnp.ndarray, watch: Optional[_StageWatch] = None):
@@ -1347,7 +1378,7 @@ def h_ici_bytes(mesh, n_proofs: int, log_m: int) -> int:
 
 
 @lru_cache(maxsize=None)
-def _exchange_pod_fn(mesh, split: bool, n_h: int):
+def _exchange_pod_fn(mesh, split: bool, n_h: int, h_window: int):
     """From the h stage's layout to the MSMs': over ICI the group's
     witnesses are all-gathered (a class's bases name any wire) and each
     chip's h goes out by `all_to_all`, so that a chip holds, for every
@@ -1356,19 +1387,21 @@ def _exchange_pod_fn(mesh, split: bool, n_h: int):
     a `(narrow, wide)` pair of wire ids a query, the placed key's, in
     the shards of the bases), out of the witnesses and recodes its
     columns, and only those, to SIGNED digit planes
-    (`signed_digit_planes_from_limbs` at MSM_WINDOW): `(mags, negs)`,
-    each (B, planes, n) — every plane for a wide class and for h, the
-    low NARROW_PLANES for a narrow class, whose wires' upper planes are
-    provably zero (`_recode`), recoded from the low limb alone — the
-    layout `msm_pod_batched` consumes, already where the bases are.
-    `n_h` is the placed key's padded h base count.  Without `split` nothing crosses: every chip of a group
+    (`signed_digit_planes_from_limbs` at MSM_WINDOW; h at `h_window`,
+    the window of the table the chip holds, `_recode`'s rule): `(mags,
+    negs)`, each (B, planes, n) — every plane for a wide class and for
+    h, the low NARROW_PLANES for a narrow class, whose wires' upper
+    planes are provably zero (`_recode`), recoded from the low limb
+    alone — the layout `msm_pod_batched` and `msm_pod_resident` consume,
+    already where the bases are.  `n_h` is the placed key's padded h
+    base count.  Without `split` nothing crosses: every chip of a group
     holds the group's witnesses and h whole."""
     from jax.sharding import PartitionSpec as P
 
     n_ici = mesh.shape["shard"]
 
-    def planes(cols):  # (B, n, 16) -> (mags, negs), each (B, 256 / MSM_WINDOW, n)
-        return tuple(jnp.moveaxis(p, 0, 1) for p in signed_digit_planes_from_limbs(cols, MSM_WINDOW))
+    def planes(cols, window=MSM_WINDOW):  # (B, n, 16) -> (mags, negs), each (B, 256 / window, n)
+        return tuple(jnp.moveaxis(p, 0, 1) for p in signed_digit_planes_from_limbs(cols, window))
 
     def narrow_planes(low):
         """(B, n) low limbs -> (mags, negs), each (B, NARROW_PLANES, n):
@@ -1405,7 +1438,7 @@ def _exchange_pod_fn(mesh, split: bool, n_h: int):
             queries = tuple(
                 (narrow_planes(jnp.take(low, narrow, axis=1)), planes(jnp.take(w_std, wide, axis=1)))
                 for narrow, wide in wires)
-            h = planes(h_mine)
+            h = planes(h_mine, h_window)
         return queries, h, h[0][:1, 0, 0]  # the last is `done`, a digit a chip: ready when the stage is
 
     chunk, cols = _pod_chunk_spec(mesh, split), P("batch", None, "shard")
@@ -1445,20 +1478,26 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
     accumulate): each of the four witness MSMs over the
     key's narrow class (its low NARROW_PLANES planes, at
     `pod_narrow_lanes`) and its wide class (every plane, at
-    `pod_lanes`, as h), the two partial sums added on the chip, then
+    `pod_lanes`), the two partial sums added on the chip, then
     ONE group-op allreduce a query (all_gather + projective fold — ICI
     on real hardware, host rings on the virtual CPU mesh;
-    parallel.mesh.msm_pod_batched).  a, b1 and c share one program
-    (`place_key` pads their classes to one count), b2 and h have one
-    each.  Seven stages, each span with `mesh`; the four query spans
-    say `narrow` and `wide`, the bases a chip holds in each class
-    (padding included; `narrow` 0 for a key without widths), and all
-    five MSM spans `digits` ("signed").  Returns the same five
+    parallel.mesh.msm_pod_batched).  The h MSM reads the placed key's
+    resident table, each chip the multiples of its own shard of the h
+    bases (`_h_table`; `parallel.mesh.msm_pod_resident`: h recoded at
+    the table's window, `msm_resident`'s accumulate, the same
+    allreduce), and where the window rule gives the placement none it is
+    a wide class of its own, its multiples in the scan.  a, b1 and c
+    share one program (`place_key` pads their classes to one count), b2
+    and h have one each.  Seven stages, each span with `mesh`; the four
+    query spans say `narrow` and `wide`, the bases a chip holds in each
+    class (padding included; `narrow` 0 for a key without widths), all
+    five MSM spans `digits` ("signed"), and `msm_h` its `window` and
+    `table` (`resident` | `scan`) as on one chip.  Returns the same five
     (B,)-batched accumulators `_prove_device` emits, so chunks from
     either arm concatenate identically downstream."""
     from jax.sharding import NamedSharding
 
-    from ..parallel.mesh import msm_pod_batched
+    from ..parallel.mesh import msm_pod_batched, msm_pod_resident
 
     from ..utils.metrics import REGISTRY
 
@@ -1481,8 +1520,10 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
                   h_shards=n_ici, ici_bytes=crossed)
         REGISTRY.counter("zkp2p_h_ici_bytes_total").inc(crossed)
     n_h, a_group = dpk.h_bases[0].shape[0], n_proofs // mesh.shape["batch"]
+    h_table = _h_table(dpk)  # this key's, in shards: built by the batch's own `_h_table` call, before `dispatch`
+    h_window = MSM_WINDOW if h_table is None else int(h_table.shape[1]).bit_length()
     queries = tuple(getattr(dpk, q + "_bases") for q in _POD_QUERIES)  # a (narrow, wide) pair each, (x, y, wire) a class
-    w_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_h)(
+    w_planes, h_planes, done = _exchange_pod_fn(mesh, split, n_h, h_window)(
         tuple(tuple(cls[2] for cls in pair) for pair in queries), w_std, h_std)
     _enqueued(watch, "exchange", done, mesh=on, bytes=exchange_bytes(mesh, n_proofs, n_wires, n_h))
     del w_std, h_std  # the MSMs read the planes alone
@@ -1504,8 +1545,18 @@ def _prove_batch_sharded(dpk: DeviceProvingKey, limbs: np.ndarray, mesh, watch: 
             (pair[1][:2], planes[1], pod_lanes(n_wide, n_ici, a_group)),
         ), narrow=n_narrow // n_ici, wide=n_wide // n_ici)
 
-    return tuple(query(*args) for args in zip(_POD_QUERIES, queries, w_planes)) + (
-        msm("msm_h", G1J, ((dpk.h_bases, h_planes, pod_lanes(n_h, n_ici, a_group)),)),)
+    def msm_h():
+        """The h stage, enqueued: each chip against the table of its
+        shard (a select and one `add_mixed` a plane a step: `add` says
+        `mixed`); where no table fits, the multiples in the scan, as a
+        wide class."""
+        if h_table is None:
+            return msm("msm_h", G1J, ((dpk.h_bases, h_planes, pod_lanes(n_h, n_ici, a_group)),), window=MSM_WINDOW, table="scan")
+        return _msm_enqueued(
+            watch, "msm_h", msm_pod_resident(G1J, h_table, h_planes, mesh, dcn_axis="batch", ici_axis="shard"),
+            mesh=on, digits="signed", window=h_window, table="resident", add="mixed")
+
+    return tuple(query(*args) for args in zip(_POD_QUERIES, queries, w_planes)) + (msm_h(),)
 
 
 # What the device waited for between two batches, by what the thread that
@@ -1703,8 +1754,7 @@ def prove_tpu_batch(
             limbs = _chunk_limbs(spans[0])
         with trace("device", leaf=True) as device:
             gap = _idle_since_fed(placement, device["t0"])
-            if mesh is None:
-                _h_table(dpk)  # the first batch of a key builds it: one `tpu/prove_batch/h_table` span
+            _h_table(dpk)  # the first batch of a key builds it, on either road: one `tpu/prove_batch/h_table` span
             watch = _StageWatch(device["t0"])
             try:
                 with trace("dispatch"):
