@@ -4,7 +4,7 @@
 # virtual devices, interpret-mode kernel differentials).  The chip is
 # reached by `make chip-smoke` — one process per chip.
 
-.PHONY: native native-asan native-tsan lint circuit-audit test test-slow metrics-smoke precomp-smoke precomp-cache chaos-smoke loadgen-smoke nonmsm-smoke prove-floor-smoke fleet-smoke fleet-obs-smoke fleet-chaos sched-smoke tune-smoke perf-smoke flame-smoke tpu-shard-smoke warm-cache doctor chip-smoke rehearsal-dryrun fullsize-proof
+.PHONY: native native-asan native-tsan lint circuit-audit test test-slow metrics-smoke precomp-smoke precomp-cache chaos-smoke loadgen-smoke nonmsm-smoke prove-floor-smoke fleet-smoke fleet-obs-smoke fleet-chaos sched-smoke tune-smoke tpu-shard-smoke warm-cache doctor chip-smoke rehearsal-dryrun fullsize-proof
 
 native:
 	$(MAKE) -C csrc
@@ -145,27 +145,6 @@ sched-smoke: native
 # tiny-shape budgeted sweep end to end.  ~5 s on the 1-core box.
 tune-smoke: native
 	python -m pytest tests/test_tune.py -q
-
-# Perf-regression sentry smoke (fast; tier-1 resident;
-# docs/OBSERVABILITY.md §perf sentry): ledger append/round-trip,
-# foreign-fingerprint + tampered-entry + schema-drift refusal, budget
-# derivation windows, overrun counting through a real service sweep
-# with a seeded `prove:hang` slowdown (and a clean replay that stays
-# quiet), alert fire/hold/clear hysteresis, gate fails-closed, and
-# ledger-on/off digest distinguishability.
-perf-smoke: native
-	python -m pytest tests/test_perfledger.py -q
-
-# Flame-sampler smoke (fast; tier-1 resident; docs/OBSERVABILITY.md
-# §flame profiler): gate off = no thread/no captures + digest
-# distinguishability, collapsed-stack folding of a hot Python loop,
-# synthetic native-frame stitching from stats-block deltas, trigger/
-# cooldown/capture_n controller behavior, atomic capture writes with
-# fail-closed loading, the overrun->capture closed loop through a real
-# service sweep, fleet `top` capture-pointer rendering, and the
-# trace_report --flame track.
-flame-smoke: native
-	python -m pytest tests/test_flameprof.py -q
 
 # Sharded-TPU-arm smoke (tier-1 resident; docs/TPU.md): the pjit
 # batch-axis prover on the 8-virtual-device CPU mesh — toy-circuit

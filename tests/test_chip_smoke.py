@@ -125,9 +125,8 @@ def host_backed_device_prover(monkeypatch):
     return seen
 
 
-def test_steps_control_flow_at_a_tiny_shape(tmp_path, host_backed_device_prover, monkeypatch):
+def test_steps_control_flow_at_a_tiny_shape(tmp_path, host_backed_device_prover):
     out = str(tmp_path)
-    monkeypatch.setenv("ZKP2P_PERF_LEDGER", "0")  # no ledger stamp into .bench_cache from a test
     chip = StubChip()
     res = chip_smoke.step_serve(out, chip, make_world=toy_world)
     assert chip.calls == ["require", "kernel_differential", "rebuild_native",
@@ -149,10 +148,9 @@ def test_steps_control_flow_at_a_tiny_shape(tmp_path, host_backed_device_prover,
     json.dumps(again)
 
 
-def test_a_request_that_does_not_end_done_fails_the_wave(tmp_path, host_backed_device_prover, monkeypatch):
+def test_a_request_that_does_not_end_done_fails_the_wave(tmp_path, host_backed_device_prover):
     """The smoke counts terminal states itself: a worker that exits 0
     with a request in error-* must not pass."""
-    monkeypatch.setenv("ZKP2P_PERF_LEDGER", "0")
     world = toy_world()
     bad = dict(world, payload=lambda i: {"x": "not-a-number", "y": 1} if i == 1 else world["payload"](i))
     from zkp2p_tpu.prover.setup_device import setup_device

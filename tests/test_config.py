@@ -98,13 +98,6 @@ def test_env_overrides_every_knob():
         "ZKP2P_TPU_SHARD": "on",
         "ZKP2P_TPU_MESH": "2x4",
         "ZKP2P_WORKER_TIER": "sharded",
-        "ZKP2P_PERF_LEDGER": "0",
-        "ZKP2P_PERF_TOLERANCE": "2.25",
-        "ZKP2P_PERF_WINDOW": "12",
-        "ZKP2P_FLAME": "1",
-        "ZKP2P_FLAME_HZ": "31",
-        "ZKP2P_FLAME_CAPTURE_N": "3",
-        "ZKP2P_FLAME_COOLDOWN_S": "15",
     }
     cfg = load_config(environ=env)
     assert cfg.msm_glv is True
@@ -147,10 +140,6 @@ def test_env_overrides_every_knob():
     assert cfg.tune_budget_s == 45.0 and cfg.tune_arms == "geometry,columns"
     assert cfg.tpu_shard == "on" and cfg.tpu_mesh == "2x4"
     assert cfg.worker_tier == "sharded"
-    assert cfg.perf_ledger is False and cfg.perf_tolerance == 2.25
-    assert cfg.perf_window == 12
-    assert cfg.flame is True and cfg.flame_hz == 31.0
-    assert cfg.flame_capture_n == 3 and cfg.flame_cooldown_s == 15.0
     assert all(v == "env" for v in cfg.provenance.values())
 
 
@@ -237,19 +226,6 @@ def test_reader_matched_parsers():
     assert load_config(environ={"ZKP2P_SCALE_UP_S": "-1"}).scale_up_s == 0.0
     assert load_config(environ={"ZKP2P_SCALE_DOWN_S": "junk"}).scale_down_s == 30.0
     assert load_config(environ={}).sched_priority_default == "bulk"
-    # perf-sentry knobs: the gate follows the not-zero rule; the
-    # tolerance is a multiplier and must stay >= 1.0 (a sub-1 band
-    # would flag the median itself — malformed/too-small keeps 1.5);
-    # the window is a positive entry count
-    assert load_config(environ={}).perf_ledger is True  # default: sentry on
-    assert load_config(environ={"ZKP2P_PERF_LEDGER": "0"}).perf_ledger is False
-    assert load_config(environ={"ZKP2P_PERF_LEDGER": "true"}).perf_ledger is True
-    assert load_config(environ={"ZKP2P_PERF_TOLERANCE": "2.0"}).perf_tolerance == 2.0
-    assert load_config(environ={"ZKP2P_PERF_TOLERANCE": "0.5"}).perf_tolerance == 1.5
-    assert load_config(environ={"ZKP2P_PERF_TOLERANCE": "junk"}).perf_tolerance == 1.5
-    assert load_config(environ={"ZKP2P_PERF_WINDOW": "3"}).perf_window == 3
-    assert load_config(environ={"ZKP2P_PERF_WINDOW": "0"}).perf_window == 1
-    assert load_config(environ={"ZKP2P_PERF_WINDOW": "junk"}).perf_window == 8
     # PR-20 floor knobs: interleave and witness-u64 follow the C
     # runtime's not-zero rule (committed ON, off only on a leading
     # '0'); radix-8 follows the C gate's leading-'1' rule — committed
@@ -265,24 +241,6 @@ def test_reader_matched_parsers():
     assert load_config(environ={"ZKP2P_NTT_RADIX8": "0"}).ntt_radix8 is False
     assert load_config(environ={"ZKP2P_NTT_RADIX8": "true"}).ntt_radix8 is False
     assert load_config(environ={"ZKP2P_NTT_RADIX8": ""}).ntt_radix8 is False
-    # flame-sampler knobs: gate default OFF (not-zero rule), the rate
-    # must stay strictly positive (a 0 Hz sampler parks forever —
-    # malformed/non-positive keeps the prime 47), capture_n is a
-    # positive sweep count, cooldown 0 = unlimited captures
-    assert load_config(environ={}).flame is False  # default: sampler off
-    assert load_config(environ={"ZKP2P_FLAME": "1"}).flame is True
-    assert load_config(environ={"ZKP2P_FLAME": "0"}).flame is False
-    assert load_config(environ={"ZKP2P_FLAME": "yes"}).flame is True
-    assert load_config(environ={"ZKP2P_FLAME_HZ": "101"}).flame_hz == 101.0
-    assert load_config(environ={"ZKP2P_FLAME_HZ": "0"}).flame_hz == 47.0
-    assert load_config(environ={"ZKP2P_FLAME_HZ": "-5"}).flame_hz == 47.0
-    assert load_config(environ={"ZKP2P_FLAME_HZ": "junk"}).flame_hz == 47.0
-    assert load_config(environ={"ZKP2P_FLAME_CAPTURE_N": "5"}).flame_capture_n == 5
-    assert load_config(environ={"ZKP2P_FLAME_CAPTURE_N": "0"}).flame_capture_n == 1
-    assert load_config(environ={"ZKP2P_FLAME_CAPTURE_N": "junk"}).flame_capture_n == 2
-    assert load_config(environ={"ZKP2P_FLAME_COOLDOWN_S": "0"}).flame_cooldown_s == 0.0
-    assert load_config(environ={"ZKP2P_FLAME_COOLDOWN_S": "-3"}).flame_cooldown_s == 0.0
-    assert load_config(environ={"ZKP2P_FLAME_COOLDOWN_S": "junk"}).flame_cooldown_s == 60.0
 
 
 def test_env_is_the_only_layer_above_defaults():

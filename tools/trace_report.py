@@ -451,81 +451,6 @@ def chrome_trace(requests: List[dict], run: Optional[str] = None,
     return {"traceEvents": meta + slices, "displayTimeUnit": "ms"}
 
 
-def load_flame_capture(path: str) -> Optional[dict]:
-    """Fail-closed reader for a utils.flameprof capture file (kept
-    dependency-free: this tool must run standalone).  One JSON object
-    with kind/schema and a str->int stacks map, or None — a truncated
-    or foreign file must never render as a flamegraph."""
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if not isinstance(doc, dict) or doc.get("kind") != "zkp2p_flame_capture":
-        return None
-    if doc.get("schema") != 1:
-        return None
-    stacks = doc.get("stacks")
-    if not isinstance(stacks, dict) or not all(
-        isinstance(k, str) and isinstance(v, int) and v >= 0
-        for k, v in stacks.items()
-    ):
-        return None
-    return doc
-
-
-def render_flame(cap: dict) -> str:
-    """Collapsed-stack text (the flamegraph.pl wire format), heaviest
-    stack first — pipe straight into flamegraph.pl."""
-    rows = sorted((cap.get("stacks") or {}).items(), key=lambda kv: (-kv[1], kv[0]))
-    return "\n".join(f"{k} {v}" for k, v in rows)
-
-
-def flame_events(cap: dict, pid: int = 990001) -> List[dict]:
-    """Chrome trace events for one flame capture: the collapsed stacks
-    folded into a trie and rendered as nested X slices under a
-    dedicated flame pid — one synthetic millisecond of track time per
-    sample, so slice WIDTH is sample share (a flamegraph on its side
-    in Perfetto).  Merges beside the request waterfalls: the flame pid
-    is its own process row, its timeline synthetic by construction."""
-    stacks = cap.get("stacks") or {}
-    root: Dict[str, dict] = {}
-    for stack, count in stacks.items():
-        frames = [fr for fr in stack.split(";") if fr]
-        level = root
-        for fr in frames:
-            node = level.setdefault(fr, {"count": 0, "children": {}})
-            node["count"] += count
-            level = node["children"]
-    label = (
-        f"flame {cap.get('circuit', '?')}/{cap.get('stage', '?')} "
-        f"@{cap.get('hz', '?')}Hz ({cap.get('trigger', '?')})"
-    )
-    events: List[dict] = [
-        {"ph": "M", "name": "process_name", "pid": pid, "args": {"name": label}},
-        {"ph": "M", "name": "thread_name", "pid": pid, "tid": 1,
-         "args": {"name": f"{cap.get('samples', 0)} samples"}},
-    ]
-    ms = 1000.0  # µs per sample of synthetic track time
-
-    def walk(level: Dict[str, dict], t0: float) -> None:
-        offset = t0
-        for name in sorted(level):
-            node = level[name]
-            # parent appended before children: importers nest equal-ts
-            # complete events by emission order
-            events.append({
-                "ph": "X", "name": name, "cat": "flame", "pid": pid, "tid": 1,
-                "ts": round(offset, 3), "dur": round(node["count"] * ms, 3),
-                "args": {"samples": node["count"]},
-            })
-            walk(node["children"], offset)
-            offset += node["count"] * ms
-
-    walk(root, 0.0)
-    return events
-
-
 def fleet_sinks(fleet_dir: str) -> List[str]:
     """Discover every JSONL sink a fleet run left behind, from its
     fleet dir (default `<spool>/.fleet`): the shared per-spool sink
@@ -782,11 +707,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         help="single-request timeline: arrival -> claims -> takeovers -> terminal, "
              "with owning worker and queue-wait per hop",
     )
-    ap.add_argument(
-        "--flame", metavar="CAPTURE",
-        help="flame capture JSON (utils.flameprof): print its collapsed stacks; "
-             "with --chrome-trace, render/merge a flame track pid into the trace",
-    )
     args = ap.parse_args(argv)
     if args.fleet_dir:
         found = fleet_sinks(args.fleet_dir)
@@ -794,34 +714,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"[trace_report] no sinks found for fleet dir {args.fleet_dir}", file=sys.stderr)
             return 1
         args.files = list(args.files) + [p for p in found if p not in args.files]
-    flame_cap = None
-    if args.flame:
-        flame_cap = load_flame_capture(args.flame)
-        if flame_cap is None:
-            print(
-                f"[trace_report] refusing {args.flame}: not a valid "
-                "zkp2p_flame_capture (truncated, foreign, or schema drift)",
-                file=sys.stderr,
-            )
-            return 1
     if not args.files:
-        if flame_cap is not None:
-            # flame-only mode: no sink needed — collapsed text, or a
-            # standalone flame-track trace with --chrome-trace
-            if args.chrome_trace:
-                ev = flame_events(flame_cap)
-                with open(args.chrome_trace, "w") as f:
-                    json.dump({"traceEvents": ev, "displayTimeUnit": "ms"}, f)
-                n = sum(1 for e in ev if e.get("ph") == "X")
-                print(
-                    f"[trace_report] wrote {n} flame slice(s) to "
-                    f"{args.chrome_trace} (load in https://ui.perfetto.dev)",
-                    file=sys.stderr,
-                )
-            else:
-                print(render_flame(flame_cap))
-            return 0
-        ap.error("need sink file(s), --fleet-dir, or --flame")
+        ap.error("need sink file(s) or --fleet-dir")
 
     if args.diff and len(args.files) == 2:
         # file-vs-file diff: --diff labels the columns
@@ -840,11 +734,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 0
     if args.chrome_trace:
         trace = chrome_trace(requests, run=args.run, stages=stages)
-        if flame_cap is not None:
-            # the flame track rides its own pid beside the request
-            # waterfalls (appended AFTER the sort: parent-before-child
-            # emission order is what nests the equal-ts slices)
-            trace["traceEvents"].extend(flame_events(flame_cap))
         n_slices = sum(1 for e in trace["traceEvents"] if e.get("ph") == "X")
         n_flows = sum(1 for e in trace["traceEvents"] if e.get("ph") == "s")
         with open(args.chrome_trace, "w") as f:

@@ -719,17 +719,6 @@ def cmd_tune(args):
     if prof is None:
         _log("tune: nothing tuned (native library unavailable)")
         sys.exit(1)
-    # perf-ledger stamp: the sweep's measured bests become one
-    # structured entry (source=tune) so host slowdowns show up as a
-    # trend across tunes, not just a changed profile on disk
-    try:
-        from ..utils.perfledger import record as perf_record, tune_stages
-
-        where = perf_record("tune", "microbench", tune_stages(prof))
-        if where:
-            _log(f"tune: sweep bests stamped into the perf ledger ({where})")
-    except Exception:  # noqa: BLE001 — observation must never fail the tune
-        pass
 
 
 def cmd_warm_cache(args):
@@ -802,212 +791,6 @@ def cmd_warm_cache(args):
     # round-trip proof
     if f1 - f0 == 0:
         _log("warm-cache: zero new cache entries — every executable loaded warm")
-    # perf-ledger stamp: the round trip's wall + backend_compile rail
-    # (source=warm_cache) — a cold-start regression (cache miss storm,
-    # slower deserialize) becomes a ledger trend, not a vibe
-    try:
-        from ..utils.perfledger import record as perf_record
-
-        wall_ms = round(dt * 1e3, 3)
-        compile_ms = round((s1 - s0) * 1e3, 3)
-        where = perf_record(
-            "warm_cache", args.circuit,
-            {
-                "warm_cache/wall": {"p50_ms": wall_ms, "p95_ms": wall_ms, "n": 1},
-                "warm_cache/backend_compile": {
-                    "p50_ms": compile_ms, "p95_ms": compile_ms,
-                    "n": max(1, int(ev1 - ev0)),
-                },
-            },
-        )
-        if where:
-            _log(f"warm-cache: round trip stamped into the perf ledger ({where})")
-    except Exception:  # noqa: BLE001 — observation must never fail the warm
-        pass
-
-
-def cmd_flame(args):
-    """On-demand flame profile (utils.flameprof; docs/OBSERVABILITY.md
-    §flame profiler): run a real prove loop under the sampling profiler
-    for --duration seconds, print the collapsed-stack profile
-    (flamegraph.pl wire format — pipe into flamegraph.pl directly) and
-    write a trigger="manual" capture file beside .bench_cache, which
-    `tools/trace_report.py --flame <capture> --chrome-trace out.json`
-    merges into a Perfetto track."""
-    from ..utils import flameprof
-    from ..utils.config import load_config
-
-    # flags are TRANSPORT: arm the gate for this invocation so the
-    # sampler may run and the recorded arm (and digest) reflect it
-    os.environ["ZKP2P_FLAME"] = "1"
-    if args.hz is not None:
-        os.environ["ZKP2P_FLAME_HZ"] = str(args.hz)
-    _log(f"flame: arm {flameprof.flame_arm()}")
-    cfg = load_config()
-
-    from ..prover.groth16_tpu import device_pk_from_zkey
-
-    prove_fn = _prover_fn(args)
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
-    try:
-        zk = _load_zkey(args)
-        _check_zkey_matches(zk, cs)
-        dpk = device_pk_from_zkey(zk, infer_widths=_infer_widths(args))
-    except (OSError, SystemExit):
-        # no zkey on disk: a dev setup keeps the command self-contained
-        # (the profile's shape is what matters, not the key's origin)
-        _log("flame: no zkey found — running the dev setup in-process")
-        from ..prover.groth16_tpu import device_pk
-        from ..snark.groth16 import setup
-
-        pk, _vk = setup(cs, seed="flame-profile")
-        dpk = device_pk(pk, cs)
-    w, _pub = _witness_for(args, cs, meta)
-
-    # one warmup prove OUTSIDE the sampler: first-call compiles and
-    # table builds are real costs, but not the steady state a profile
-    # is meant to attribute
-    prove_fn(dpk, w)
-
-    sampler = flameprof.FlameSampler(hz=cfg.flame_hz).start()
-    t0 = time.perf_counter()
-    proves = 0
-    while True:
-        prove_fn(dpk, w)
-        proves += 1
-        if time.perf_counter() - t0 >= args.duration:
-            break
-    path = flameprof.write_capture(
-        sampler, circuit=args.circuit, stage="on-demand", trigger="manual",
-    )
-    body = sampler.result()
-    _log(
-        f"flame: {proves} prove(s) in {body['duration_s']:.1f}s — "
-        f"{body['samples']} samples over {body['windows']} windows "
-        f"@ {cfg.flame_hz:g} Hz, sampler self-cost "
-        f"{body['sampler']['self_ms']:.1f} ms"
-    )
-    if path:
-        _log(f"flame: capture -> {path}")
-    else:
-        _log("flame: capture NOT persisted (cache dir disabled)")
-    print(flameprof.collapsed_text(body["stacks"]))
-
-
-def cmd_perf(args):
-    """Perf-regression sentry (utils.perfledger; docs/OBSERVABILITY.md
-    §perf sentry): render per-(circuit, stage) trendlines + regression
-    verdicts from the host's stage-cost ledger; `--backfill` imports
-    BENCH_r*.json-shaped records at the repo root, `--rebaseline`
-    freezes current budgets as PERF_BASELINE.json, `--gate` replays the
-    ledger head against that band and exits nonzero on drift (rc 1
-    drift, rc 2 fail-closed)."""
-    from ..utils import flameprof
-    from ..utils import perfledger as pl
-    from ..utils.config import load_config
-
-    did_action = False
-    if args.backfill:
-        did_action = True
-        n = pl.backfill_bench(log=_log)
-        _log(f"perf: backfill appended {n} entr{'y' if n == 1 else 'ies'}")
-    if args.rebaseline:
-        did_action = True
-        doc = pl.write_baseline(
-            baseline_path=args.baseline or None, ledger_path=args.ledger or None,
-            window=args.window, tolerance=args.tolerance,
-        )
-        if doc is None:
-            _log("perf: rebaseline FAILED — no valid ledger entries to freeze "
-                 "(run a bench / tune / service sweep, or --backfill, first)")
-            sys.exit(2)
-        bands = sum(len(v) for v in doc["bands"].values())
-        _log(f"perf: baseline frozen — {bands} band(s), "
-             f"window={doc['window']} tolerance={doc['tolerance']:g}")
-    if args.gate:
-        rc, verdicts = pl.gate_check(
-            baseline_path=args.baseline or None, ledger_path=args.ledger or None,
-            log=_log,
-        )
-        for v in verdicts:
-            if v["verdict"] in ("new", "gone"):
-                print(f"{v['verdict']:<8} {v['circuit']}/{v['stage']}")
-                continue
-            print(
-                f"{v['verdict']:<8} {v['circuit']}/{v['stage']}: "
-                f"head p50 {v['p50_ms']:.1f} ms vs budget {v['budget_ms']:.1f} ms "
-                f"(band median {v['median_ms']:.1f} ms)"
-            )
-            # DRIFT -> the flame capture that shows WHY (utils.flameprof)
-            if v["verdict"] == "DRIFT":
-                for cpath, cdoc in flameprof.captures_for(
-                    v["circuit"], v["stage"]
-                )[:1]:
-                    print(f"       capture: {cpath} "
-                          f"(trigger {cdoc.get('trigger')}, "
-                          f"entry {cdoc.get('entry_digest')})")
-        drifts = sum(1 for v in verdicts if v["verdict"] == "DRIFT")
-        improved = sum(1 for v in verdicts if v["verdict"] == "IMPROVED")
-        print(f"perf-gate: {'DRIFT' if rc == 1 else 'FAIL CLOSED' if rc else 'ok'} "
-              f"({drifts} drifting stage(s) of {len(verdicts)})")
-        # a head landing well UNDER its band means the band is stale-
-        # loose: say so and name the fix — the improvement becomes the
-        # guarded floor only after a rebaseline
-        if improved:
-            print(f"perf-gate: {improved} IMPROVED stage(s) — band is "
-                  "stale-loose; freeze the new floor with "
-                  "`zkp2p-tpu perf --rebaseline`")
-        sys.exit(rc)
-    if did_action:
-        return
-    # default: trendlines + verdicts against the current budgets
-    entries, refused = pl.load_entries(args.ledger or None)
-    if not entries:
-        _log(f"perf: no valid ledger entries for this host (refused: {refused})")
-        sys.exit(1)
-    cfg = load_config()
-    budgets = pl.derive_budgets(entries, window=args.window, tolerance=args.tolerance)
-    series = {}
-    for e in entries:
-        circuit = str(e.get("circuit", "?"))
-        if args.circuit and circuit != args.circuit:
-            continue
-        for stage, st in e["stages"].items():
-            if args.stage and args.stage not in stage:
-                continue
-            series.setdefault((circuit, stage), []).append(float(st["p50_ms"]))
-    if args.json:
-        print(json.dumps({
-            "budgets": budgets,
-            "series": {f"{c}/{s}": v for (c, s), v in sorted(series.items())},
-            "refused": refused,
-        }, indent=1, sort_keys=True))
-        return
-    marks = "_.-=#"  # low..high within each stage's own range
-    for (circuit, stage), vals in sorted(series.items()):
-        lo, hi = min(vals), max(vals)
-        span = (hi - lo) or 1.0
-        line = "".join(marks[int((v - lo) / span * (len(marks) - 1))] for v in vals[-48:])
-        b = (budgets.get(circuit) or {}).get(stage)
-        if b is None:
-            verdict = "no-budget"
-        else:
-            verdict = "REGRESSED" if vals[-1] > b["budget_ms"] else "ok"
-        print(
-            f"{circuit}/{stage:<28} [{line}] last {vals[-1]:.1f} ms "
-            + (f"budget {b['budget_ms']:.1f} ms " if b else "")
-            + f"(n={len(vals)}) {verdict}"
-        )
-        # a REGRESSED stage with an overrun-triggered capture on disk
-        # gets the pointer printed under its trendline — the sentry's
-        # "that" row linked to the sampler's "why" file
-        if verdict == "REGRESSED":
-            for cpath, cdoc in flameprof.captures_for(circuit, stage)[:1]:
-                print(f"    capture: {cpath} (trigger {cdoc.get('trigger')}, "
-                      f"entry {cdoc.get('entry_digest')})")
-    if any(refused.values()):
-        _log(f"perf: refused entries: {refused} "
-             f"(window={cfg.perf_window} tolerance={cfg.perf_tolerance:g})")
 
 
 def main(argv=None):
@@ -1232,47 +1015,6 @@ def main(argv=None):
     # without importing jax or touching the compilation cache (the
     # circuit tier builds real circuits but still needs only numpy)
     s.set_defaults(fn=cmd_lint, no_jax=True)
-
-    s = sub.add_parser(
-        "flame",
-        help="on-demand flame profile: a real prove loop under the sampler -> "
-             "collapsed stacks on stdout + a capture file beside .bench_cache",
-    )
-    s.add_argument("--duration", type=float, default=30.0,
-                   help="prove-loop wall clock in s (at least one prove always runs)")
-    s.add_argument("--hz", type=float, default=None,
-                   help="sampling rate override (default: ZKP2P_FLAME_HZ)")
-    s.add_argument("--zkey", help="zkey path or chunk glob (default: BUILD_DIR/"
-                                  "circuit_final.zkey; missing = in-process dev setup)")
-    s.add_argument("--no-infer-widths", action="store_true",
-                   help="disable the zkey bit-constraint width inference")
-    s.add_argument("--prover", choices=["tpu", "native"], default="native",
-                   help="prover arm under the sampler (native = the C runtime "
-                        "the synthetic frames attribute)")
-    s.add_argument("--message", help=argparse.SUPPRESS)
-    s.add_argument("--eml", help=argparse.SUPPRESS)
-    s.add_argument("--order-id", type=int, default=1)
-    s.add_argument("--claim-id", type=int, default=0)
-    s.set_defaults(fn=cmd_flame)
-
-    s = sub.add_parser(
-        "perf",
-        help="perf-regression sentry: ledger trendlines, stage budgets, baseline drift gate",
-    )
-    s.add_argument("--ledger", default="", help="ledger path override (default: host-keyed beside .bench_cache)")
-    s.add_argument("--baseline", default="", help="baseline path (default: PERF_BASELINE.json at the repo root)")
-    s.add_argument("--circuit", default="", help="filter trendlines to one circuit label")
-    s.add_argument("--stage", default="", help="substring filter over stage names")
-    s.add_argument("--window", type=int, default=None, help="trailing-window override (ZKP2P_PERF_WINDOW)")
-    s.add_argument("--tolerance", type=float, default=None, help="budget multiplier override (ZKP2P_PERF_TOLERANCE)")
-    s.add_argument("--json", action="store_true", help="machine-readable budgets + series")
-    s.add_argument("--backfill", action="store_true", help="import committed BENCH_r*.json history (idempotent)")
-    s.add_argument("--rebaseline", action="store_true", help="freeze current budgets as the committed baseline band")
-    s.add_argument("--gate", action="store_true",
-                   help="replay the ledger head against the baseline band; rc 1 = drift, rc 2 = fail closed")
-    # no_jax: the sentry reads JSON on disk — it must answer in seconds
-    # (and run in CI) without paying a backend import
-    s.set_defaults(fn=cmd_perf, no_jax=True)
 
     s = sub.add_parser("batch", help="prove a directory of inputs as one batch")
     s.add_argument("--indir", required=True)

@@ -205,13 +205,6 @@ def _write_heartbeat(svc, fleet_dir: str, state: Optional[str] = None) -> None:
     sched_hb = getattr(svc, "_sched_hb", None)
     if sched_hb:
         hb["sched"] = dict(sched_hb)
-    # the worker's perf-sentry counters (utils.perfledger budgets vs
-    # terminal-request spans): overrun/check totals + budgets loaded —
-    # the fleet plane sums these into the perf_regression alert signal
-    # and `zkp2p-tpu top` renders the per-worker overrun column
-    perf_hb = getattr(svc, "_perf_hb", None)
-    if perf_hb:
-        hb["perf"] = dict(perf_hb)
     # serialized SLO window (capped — the heartbeat is written every
     # ~5 s): the fleet plane's FALLBACK merge source when the worker's
     # /snapshot scrape fails (port not yet bound, worker mid-restart),
@@ -793,10 +786,6 @@ class FleetSupervisor:
             # depths) — rides the heartbeat, rendered by `zkp2p-tpu top`
             if hb.get("sched"):
                 workers[slot.wid]["sched"] = hb["sched"]
-            # the worker's perf-sentry counters (stage-budget overruns)
-            # — rides the heartbeat, rendered by `zkp2p-tpu top`
-            if hb.get("perf"):
-                workers[slot.wid]["perf"] = hb["perf"]
         sched_block: Dict = {"autoscale": self.autoscale}
         if self.autoscale:
             sched_block.update({

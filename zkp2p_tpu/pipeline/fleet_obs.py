@@ -131,10 +131,6 @@ class FleetPlane:
         self.engine = AlertEngine(fleet_rules(cfg), registry=REGISTRY, log=self._log, clock=clock)
         self._trend = TrendTracker(keep_s=max(10 * self.scrape_s, 4 * cfg.alert_for_s, 60.0))
         self._restart_trend = TrendTracker(keep_s=max(cfg.breaker_window_s, 60.0))
-        # stage-budget overruns (perf sentry): the perf_regression rule
-        # fires on the DELTA inside its hysteresis window, so the trend
-        # keeps at least that much history
-        self._overrun_trend = TrendTracker(keep_s=max(10 * self.scrape_s, 4 * cfg.alert_for_s, 60.0))
         self._restarts_window_s = cfg.breaker_window_s
         self._alert_for_s = cfg.alert_for_s
         self._lock = threading.Lock()
@@ -188,11 +184,6 @@ class FleetPlane:
         live = unarmed = unreachable = 0
         degraded = 0
         hb_gap: Optional[float] = None
-        # perf sentry: overruns summed over workers that REPORT budgets
-        # — a worker with an empty budget book contributes nothing, and
-        # zero reporting workers keeps the signal None (alert HOLDs; a
-        # fresh host's empty ledger must not page)
-        perf_workers = perf_overruns = 0
         # list(): the supervisor's autoscaler inserts slots mid-run,
         # and iterating the live dict from this (scrape) thread would
         # RuntimeError exactly at scale events — when the merged
@@ -205,13 +196,6 @@ class FleetPlane:
             hb = self.sup._hb(slot) or {}
             if hb.get("degraded"):
                 degraded += 1
-            perf_hb = hb.get("perf")
-            if perf_hb and perf_hb.get("budgets"):
-                perf_workers += 1
-                try:
-                    perf_overruns += int(perf_hb.get("overruns") or 0)
-                except (TypeError, ValueError):
-                    pass
             age = self.sup._hb_age_s(slot)
             if age is not None:
                 hb_gap = age if hb_gap is None else max(hb_gap, age)
@@ -272,11 +256,6 @@ class FleetPlane:
         total_restarts = sum(s.restarts for s in list(self.sup.slots.values()))
         self._restart_trend.update(t, total_restarts)
         restarts_recent = self._restart_trend.delta(self._restarts_window_s, t)
-        budget_overruns: Optional[int] = perf_overruns if perf_workers else None
-        overruns_recent: Optional[float] = None
-        if budget_overruns is not None:
-            self._overrun_trend.update(t, budget_overruns)
-            overruns_recent = self._overrun_trend.delta(self._alert_for_s, t)
         signals = {
             "burn_fast": merged_slo["burn_fast"],
             "burn_slow": merged_slo["burn_slow"],
@@ -287,8 +266,6 @@ class FleetPlane:
             "parked": sum(1 for s in list(self.sup.slots.values()) if s.state == "parked"),
             "degraded": degraded,
             "hb_gap_s": hb_gap,
-            "budget_overruns": budget_overruns,
-            "overruns_recent": overruns_recent,
         }
         for tr in self.engine.evaluate(signals, now=t):
             self._alert_log.append(tr)
@@ -553,33 +530,18 @@ def render_top(body: Dict) -> str:
         lines.append("alerts: none firing")
     workers = body.get("workers") or {}
     if workers:
-        # flame column only when some worker's perf block carries a
-        # capture pointer (utils.flameprof via the heartbeat): a fresh
-        # fleet with no captures renders the PR-18 table unchanged
-        flame_col = any(
-            (w.get("perf") or {}).get("capture") for w in workers.values()
-        )
         lines.append(f"{'worker':<8} {'state':<9} {'pid':>7} {'port':>6} "
-                     f"{'restarts':>8} {'rss_mb':>8} {'hb_age':>7} {'degr':>5} {'overrun':>8}"
-                     + ("  flame" if flame_col else ""))
+                     f"{'restarts':>8} {'rss_mb':>8} {'hb_age':>7} {'degr':>5}")
         for wid in sorted(workers):
             w = workers[wid]
             rss = w.get("rss_mb")
             age = w.get("hb_age_s")
-            # perf-sentry column: stage-budget overruns this worker has
-            # counted ("-" = no budget book loaded — fresh ledger, not
-            # a clean bill of health)
-            perf = w.get("perf") or {}
-            over = perf.get("overruns") if perf.get("budgets") else None
-            cap = perf.get("capture") or {}
             lines.append(
                 f"{wid:<8} {w.get('state', '?'):<9} {str(w.get('pid') or '-'):>7} "
                 f"{str(w.get('port') or '-'):>6} {w.get('restarts', 0):>8} "
                 f"{(f'{rss:.0f}' if isinstance(rss, (int, float)) else '-'):>8} "
                 f"{(f'{age:.1f}' if isinstance(age, (int, float)) else '-'):>7} "
-                f"{('y' if w.get('degraded') else '-'):>5} "
-                f"{(str(over) if isinstance(over, (int, float)) else '-'):>8}"
-                + (f"  {cap.get('file', '-')}" if flame_col else "")
+                f"{('y' if w.get('degraded') else '-'):>5}"
             )
     scrape = body.get("scrape") or {}
     if scrape:

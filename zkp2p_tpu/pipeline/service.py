@@ -460,7 +460,6 @@ class ProvingService:
         spool_cap: Optional[int] = None,
         retries: Optional[int] = None,
         retry_backoff_s: Optional[float] = None,
-        circuit: str = "",
     ):
         """witness_fn: request payload -> witness vector (raises on bad
         input); public_fn: witness -> public signals.
@@ -542,16 +541,6 @@ class ProvingService:
         # (the `sched` block in fleet /status and `zkp2p-tpu top`)
         self._sched_ctl = None
         self._sched_hb: Optional[Dict] = None
-        # perf-regression sentry (utils.perfledger): the budget book
-        # every terminal request's spans are checked against, loaded
-        # lazily on the first terminal record (the gate and ledger are
-        # env/disk-derived — stable under a running service), the
-        # cumulative overrun/check counters the fleet heartbeat carries
-        # (`perf` block in fleet /status and `zkp2p-tpu top`), and the
-        # per-stage span samples the exit-time ledger stamp aggregates.
-        # `circuit` labels this service's ledger entries and selects its
-        # budget rows; "" = the generic "service" bucket.
-        self.circuit = circuit or "service"
         # a member of a replica set (pipeline.replicas; `join_set`): the
         # index of the local device this service's key lives on, carried
         # by every span its threads close and every request record; how
@@ -570,10 +559,6 @@ class ProvingService:
         self.n_batches = 0
         self.n_done = 0
         self._between = _BetweenSweeps()
-        self._perf_book = None
-        self._perf_lock = threading.Lock()
-        self._perf_hb: Optional[Dict] = None
-        self._perf_agg: Dict[str, List[float]] = {}
 
     def join_set(self, replica: int, live: Callable[[], int], sinks: Dict, sinks_lock,
                  on_loop_up: Callable[[int, Dict], None], witness_turn: threading.Lock) -> None:
@@ -850,93 +835,10 @@ class ProvingService:
                     default_tracker().observe(time.time() - anchor, ok=(state == "done"))
             except Exception:  # noqa: BLE001 — observation only
                 pass
-            # perf sentry: this request's spans vs the ledger-derived
-            # stage budgets (utils.perfledger) — overruns are counted
-            # per stage and surfaced through the fleet heartbeat; spans
-            # also pool into the exit-time ledger stamp
-            try:
-                self._perf_check(req)
-            except Exception:  # noqa: BLE001 — observation only
-                pass
         else:
             # non-terminal sweep outcome (deferred): its own counter —
             # requests_total stays one-inc-per-TERMINAL-transition
             REGISTRY.counter("zkp2p_service_deferred_total").inc()
-
-    def _perf_check(self, req: Request) -> None:
-        """Check one terminal request's lifecycle spans against the
-        ledger-derived stage budgets (utils.perfledger.BudgetBook —
-        dict lookups only on this path; the book is loaded once).  An
-        over-budget span incs zkp2p_stage_budget_overruns_total{stage};
-        cumulative counts ride the fleet heartbeat as the `perf` block.
-        With the gate off the book is empty and this is a no-op beyond
-        the span pooling guard."""
-        from ..utils.perfledger import BudgetBook
-
-        book = self._perf_book
-        if book is None:
-            book = self._perf_book = BudgetBook.load(self.circuit)
-            REGISTRY.gauge("zkp2p_perf_budget_stages").set(float(len(book)))
-        if not req.spans:
-            return
-        overruns = checked = 0
-        with self._perf_lock:
-            for sp in req.spans:
-                name, ms = sp.get("name"), sp.get("ms")
-                if not name or ms is None:
-                    continue
-                # pool every span for the exit-time ledger stamp (a
-                # fresh host builds its first budgets from live sweeps)
-                self._perf_agg.setdefault(name, []).append(float(ms))
-                verdict = book.over(name, ms)
-                if verdict is None:
-                    continue  # no budget for this stage: never counts
-                checked += 1
-                if verdict:
-                    overruns += 1
-                    REGISTRY.counter(
-                        "zkp2p_stage_budget_overruns_total", {"stage": name}
-                    ).inc()
-                    # overrun-triggered flame capture (utils.flameprof):
-                    # gated by ZKP2P_FLAME, one capture at a time,
-                    # cooldown-limited — the sentry's "why" half.  The
-                    # capture cross-links the budget's ledger head
-                    # digest so `zkp2p-tpu perf` can walk DRIFT ->
-                    # capture file.
-                    try:
-                        from ..utils.flameprof import controller as _flame
-
-                        _flame().trigger(
-                            self.circuit, name,
-                            entry_digest=book.head_digest(name),
-                            budget_ms=book.budget_ms(name),
-                            over_ms=float(ms),
-                        )
-                    except Exception:  # noqa: BLE001 — observation only
-                        pass
-            if self._perf_hb is None:
-                self._perf_hb = {"overruns": 0, "checked": 0, "budgets": len(book)}
-            self._perf_hb["overruns"] += overruns
-            self._perf_hb["checked"] += checked
-
-    def _perf_stamp(self) -> None:
-        """Exit-time ledger stamp: one entry aggregating this run's
-        terminal-request span costs (source=service), gated inside
-        perfledger.record by ZKP2P_PERF_LEDGER.  Sampling at run
-        granularity — not per request — is what keeps the ledger's
-        steady-state overhead under the documented <1%."""
-        from ..utils.perfledger import record as perf_record, stage_stats
-
-        with self._perf_lock:
-            agg, self._perf_agg = self._perf_agg, {}
-        stages = {
-            name: stats
-            for name, samples in agg.items()
-            for stats in [stage_stats(samples)]
-            if stats is not None
-        }
-        if stages:
-            perf_record("service", self.circuit, stages, run_id=run_id())
 
     def _record_deferred(
         self,
@@ -1856,21 +1758,6 @@ class ProvingService:
             # and no record this sweep — the claim-file discipline means
             # a later sweep (or another worker) picks them up.
             raise producer_error[0]
-        # flame sweep boundary: an overrun-triggered capture spans the
-        # next flame_capture_n FULL sweeps after its trigger; when this
-        # tick completes one, the pointer rides the heartbeat perf
-        # block so `zkp2p-tpu top` can name the capture file
-        try:
-            from ..utils.flameprof import controller as _flame
-
-            if _flame().sweep_tick() is not None:
-                ptr = _flame().pointer()
-                with self._perf_lock:
-                    if self._perf_hb is None:
-                        self._perf_hb = {"overruns": 0, "checked": 0, "budgets": 0}
-                    self._perf_hb["capture"] = ptr
-        except Exception:  # noqa: BLE001 — observation must never fail a sweep
-            pass
         return stats
 
     def _consume(self, spool, ready_q, knobs, stats, slots) -> int:
@@ -2087,20 +1974,10 @@ class ProvingService:
         # the sampler appends zkp2p_timeseries lines to the same sink
         # the request records ride.
         from ..utils.config import load_config
-        from ..utils.flameprof import flame_arm
-        from ..utils.perfledger import perf_arm
         from ..utils.slo import slo_arm, timeseries_arm
 
         slo_arm()
         timeseries_arm()
-        # perf-ledger gate: the stage-budget sentry (utils.perfledger)
-        # — armed here so a ledger-on service run never shares a digest
-        # with the ledger-off oracle arm
-        perf_arm()
-        # flame-sampler gate: overrun-triggered captures ride the perf
-        # sentry (utils.flameprof) — armed here so a sampler-on run
-        # never shares a digest with the zero-overhead off arm
-        flame_arm()
         # fleet membership gate: "worker" when the supervisor stamped an
         # identity into our env, else "off" — a fleet member and a solo
         # service are digest-distinguishable code paths (the ONE
@@ -2201,13 +2078,6 @@ class ProvingService:
         # RECORDED), and the fleet heartbeat says "draining" so the
         # supervisor sees a deliberate exit, not a hang
         _flush()
-        # perf-ledger stamp: this run's aggregated span costs become
-        # one `source=service` ledger entry (gate-checked inside) —
-        # the live-sweep sample the next run's budgets are derived from
-        try:
-            self._perf_stamp()
-        except Exception:  # noqa: BLE001 — observation only
-            pass
         if hb_stop is not None:
             hb_stop.set()
         if fleet_dir:

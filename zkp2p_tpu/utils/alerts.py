@@ -215,10 +215,6 @@ class AlertEngine:
 #   degraded                workers whose heartbeat says degraded=True
 #   hb_gap_s                max heartbeat age over live workers (None
 #                           when no live worker has beaten yet)
-#   budget_overruns         stage-budget overruns summed over workers
-#                           reporting perf budgets (None when no worker
-#                           has budgets loaded — fresh ledger: HOLD)
-#   overruns_recent         overrun delta inside the trend window
 
 
 def _num(signals: Dict, key: str):
@@ -271,18 +267,6 @@ def fleet_rules(cfg=None) -> List[Rule]:
         gap = _num(s, "hb_gap_s")
         return None if gap is None else gap >= hb_gap_thr
 
-    def perf_regression(s: Dict) -> Optional[bool]:
-        # budget_overruns is None when NO live worker has perf budgets
-        # loaded for its (fingerprint, circuit) — a fresh host with an
-        # empty ledger must HOLD, never page (docs/OBSERVABILITY.md
-        # §perf sentry).  Fires only while overruns are still being
-        # ACCRUED (the recent delta), so a historical burst clears.
-        ov = _num(s, "budget_overruns")
-        if ov is None:
-            return None
-        rec = _num(s, "overruns_recent")
-        return (rec or 0) > 0
-
     return [
         Rule(
             "slo_burn", slo_burn, for_s=for_s, clear_s=clear_s,
@@ -311,15 +295,5 @@ def fleet_rules(cfg=None) -> List[Rule]:
         Rule(
             "heartbeat_gap", heartbeat_gap, for_s=0.0, clear_s=clear_s,
             detail=lambda s: f"max heartbeat age {s.get('hb_gap_s')}s >= {hb_gap_thr:g}s",
-        ),
-        Rule(
-            # hysteresis like slo_burn: one slow span is a blip; a
-            # stage running over its ledger budget for a full for_s
-            # window is a regression
-            "perf_regression", perf_regression, for_s=for_s, clear_s=clear_s,
-            detail=lambda s: (
-                f"stage budget overruns {s.get('budget_overruns')} total, "
-                f"+{s.get('overruns_recent')} in window"
-            ),
         ),
     ]

@@ -448,20 +448,6 @@ def preflight(workload: bool = True, log=None, cfg=None, stamp: bool = True) -> 
 
     profile_arm()
 
-    # perf-ledger gate (utils.perfledger): the stage-cost ledger and
-    # its live budgets — a ledger-on run must never share a digest with
-    # the ledger-off oracle arm of an overhead A/B
-    from .perfledger import perf_arm
-
-    perf_arm()
-
-    # flame-sampler gate (utils.flameprof): the in-process sampling
-    # profiler + overrun-triggered captures — the arm carries the
-    # sampling rate, so runs at different Hz are distinguishable too
-    from .flameprof import flame_arm
-
-    flame_arm()
-
     if workload:
         # one tiny jitted op: proves the backend executes and ticks the
         # compile listener

@@ -107,34 +107,6 @@ def _nonneg_float(default: float):
     return parse
 
 
-def _min_one_float(default: float):
-    # perf budget multiplier: must be >= 1.0 — a budget BELOW the
-    # trailing median would page on every healthy request; malformed or
-    # out-of-range keeps the committed default
-    def parse(s: str) -> float:
-        try:
-            v = float(s)
-        except ValueError:
-            return default
-        return v if v >= 1.0 else default
-
-    return parse
-
-
-def _pos_float(default: float):
-    # sampling rates (Hz): must be strictly positive — a 0 Hz sampler
-    # would park its thread forever; malformed or non-positive keeps
-    # the committed default
-    def parse(s: str) -> float:
-        try:
-            v = float(s)
-        except ValueError:
-            return default
-        return v if v > 0.0 else default
-
-    return parse
-
-
 def _fraction(default: float):
     # SLO target fraction: must land strictly inside (0, 1) — a target
     # of 0 or 1 makes the burn-rate denominator meaningless; malformed
@@ -421,26 +393,6 @@ KNOBS: Dict[str, Tuple[str, object, object]] = {
     "tpu_shard": ("ZKP2P_TPU_SHARD", str, "off"),
     "tpu_mesh": ("ZKP2P_TPU_MESH", str, ""),
     "worker_tier": ("ZKP2P_WORKER_TIER", str, ""),
-    # perf-regression sentry (utils.perfledger; docs/OBSERVABILITY.md
-    # §perf sentry): the stage-cost ledger gate ("0" = the whole
-    # subsystem off — no appends, no budgets, no overrun counting; the
-    # fail-closed oracle arm of a ledger A/B), the budget multiplier
-    # over the trailing-window median (>= 1.0), and the trailing-window
-    # length in ledger entries the median is taken over.
-    "perf_ledger": ("ZKP2P_PERF_LEDGER", _not_zero, True),
-    "perf_tolerance": ("ZKP2P_PERF_TOLERANCE", _min_one_float(1.5), 1.5),
-    "perf_window": ("ZKP2P_PERF_WINDOW", _pos_int(8), 8),
-    # flame sampler (utils.flameprof; docs/OBSERVABILITY.md §flame
-    # profiler): the sampling-profiler gate ("1" = the background
-    # sampler may run and sentry overruns trigger captures; default OFF
-    # — the zero-overhead oracle arm), the sampling rate in Hz (prime
-    # by default so the sampler never phase-locks with periodic stage
-    # work), how many service sweeps a triggered capture spans, and the
-    # per-process cooldown between triggered captures (0 = no limit).
-    "flame": ("ZKP2P_FLAME", _not_zero, False),
-    "flame_hz": ("ZKP2P_FLAME_HZ", _pos_float(47.0), 47.0),
-    "flame_capture_n": ("ZKP2P_FLAME_CAPTURE_N", _pos_int(2), 2),
-    "flame_cooldown_s": ("ZKP2P_FLAME_COOLDOWN_S", _nonneg_float(60.0), 60.0),
 }
 
 # The A/B arm switches: a function that branches on one of these must
@@ -448,7 +400,7 @@ KNOBS: Dict[str, Tuple[str, object, object]] = {
 ARMABLE = (
     "msm_glv", "msm_batch_affine", "msm_overlap",
     "msm_multi", "msm_precomp", "matvec_seg", "ntt_pool", "sched",
-    "profile", "tpu_shard", "worker_tier", "perf_ledger", "flame",
+    "profile", "tpu_shard", "worker_tier",
     "msm_interleave", "ntt_radix8", "witness_u64",
 )
 
@@ -524,13 +476,6 @@ class ProverConfig:
     tpu_shard: str = "off"
     tpu_mesh: str = ""
     worker_tier: str = ""
-    perf_ledger: bool = True
-    perf_tolerance: float = 1.5
-    perf_window: int = 8
-    flame: bool = False
-    flame_hz: float = 47.0
-    flame_capture_n: int = 2
-    flame_cooldown_s: float = 60.0
     # knob -> "default" | "env"
     provenance: Dict[str, str] = field(default_factory=dict, compare=False)
 
