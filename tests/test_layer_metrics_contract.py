@@ -6,8 +6,9 @@ of the whole command in a toy cell of the shape its committed `workloads`
 name.  A program PR that renames a span or a counter fails its own case here,
 not the driver's check (`null` under `per_layer`).
 
-Four runs serve all cases, each made once: one chip under a closed loop, one
-chip under an open loop with a deadline, the 1x4 mesh and the replica set.
+Five runs serve all cases, each made once: one chip under a closed loop, one
+chip under an open loop with a deadline, the 1x4 mesh, the replica set, and one
+chip whose batches are more than it holds and run as chunks.
 The cases are read from the committed file, so a new metric is a new case.
 The fixtures and the stand-ins for the device are `benchmarks/tests`' and
 `test_run_mesh`'s; nothing is written there."""
@@ -23,6 +24,7 @@ import shutil
 import pytest
 
 from test_run_mesh import mesh_road_with_the_oracle_s_proofs
+from test_run_sha256 import stood_in_device_in_chunks_of_two
 
 from benchmarks import run as bench_run
 from benchmarks.tests.conftest import FIXTURE_ROOT, REPO, StubChip, host_backed_device_prover
@@ -46,6 +48,8 @@ def _shape(workload: dict) -> str:
         return "replicas"
     if config["arms"]["tpu_shard"] != "off":
         return "mesh"
+    if int(config["arms"]["batch_chunk"]) < int(traffic.get("batch_size") or config["batch_size"]):
+        return "chunked"  # a batch is several chunks
     return "open" if traffic["loop"] == "open" else "closed"
 
 
@@ -59,6 +63,8 @@ RUNS = {
     "mesh": (os.path.join(TESTS, "fixture_root_mesh"), "toy-mesh4.single", mesh_road_with_the_oracle_s_proofs),
     # the replicas behind an `inputs_fn`, as venmo-256-192-replica4's are
     "replicas": (os.path.join(TESTS, "fixture_root_replicas"), "toy-inputs-replica4.bulk32", host_backed_device_prover),
+    # the preimage circuit at 64 bytes, a batch of four as two chunks of two, as sha256-4k's are
+    "chunked": (os.path.join(TESTS, "fixture_root_sha256"), "toy-sha256.bulk", stood_in_device_in_chunks_of_two),
 }
 # `service/inputs` is the batched witness tier's span; the one-chip toy builds witnesses one by one
 ONLY_IN = {"inputs_ms_per_proof": "replicas"}

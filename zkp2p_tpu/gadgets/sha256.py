@@ -257,6 +257,34 @@ def state_words_from_const(cs: ConstraintSystem, values: Sequence[int], tag: str
     return words
 
 
+def padding_byte_bits(cs: ConstraintSystem, message_bytes: int, tag: str = "pad") -> List[List[int]]:
+    """SHA-256's padding of a message of exactly `message_bytes` bytes
+    (FIPS 180-4 5.1.1: 0x80, zeros to 56 mod 64, the bit length as 64
+    bits big-endian) as per-byte bit wires, each pinned to its constant:
+    the fixed-length twin of `sha256Pad` (`shaHash.ts:17-36`), which pads
+    OUTSIDE the circuit and leaves the padding the prover's to choose."""
+    import numpy as np
+
+    pad = b"\x80" + b"\x00" * ((55 - message_bytes) % 64) + (8 * message_bytes).to_bytes(8, "big")
+    out: List[List[int]] = []
+    flat: List[int] = []
+    consts: List[int] = []
+    for bi, byte in enumerate(pad):
+        bits = []
+        for i in range(8):
+            bit = (byte >> i) & 1
+            wire = cs.new_wire(f"{tag}.{bi}.{i}")
+            cs.enforce_eq(LC.of(wire), LC.const(bit), f"{tag}/const")
+            cs.set_width(wire, 1)  # pinned to 0 or 1
+            bits.append(wire)
+            flat.append(wire)
+            consts.append(bit)
+        out.append(bits)
+    c = np.asarray(consts, dtype=np.int64)
+    cs.compute_block(flat, lambda m, c=c: np.broadcast_to(c[:, None], (c.shape[0], m.shape[1])), [])
+    return out
+
+
 def sha256_blocks(
     cs: ConstraintSystem,
     padded_byte_bits: List[List[int]],

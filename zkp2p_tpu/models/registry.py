@@ -94,6 +94,48 @@ def build_sha2b() -> Tuple[object, List[int]]:
     return cs, out
 
 
+def build_sha256_preimage(message_bytes: int) -> Tuple[object, List[int]]:
+    """SHA-256 of a private preimage of exactly `message_bytes` bytes
+    with the digest public: the circuit of public Groth16 prover
+    comparisons (celer-network/zk-benchmark, "The Pantheon of Zero
+    Knowledge Proof Development Frameworks"), and of the benchmark's
+    sha256-4k cell.  Where `build_sha2b` takes pre-padded bytes, so the
+    padding is the prover's and the digest is of nothing in particular,
+    this wires the padding as constants.  Public signals: [0] the first
+    16 digest bytes, [1] the last 16, each as a big-endian 128-bit
+    integer.  Returns (cs, message byte wires)."""
+    from ..gadgets import core, sha256
+    from ..snark.r1cs import LC, ConstraintSystem
+
+    cs = ConstraintSystem(f"sha256-preimage-{message_bytes}")
+    halves = [cs.new_public("digest_hi"), cs.new_public("digest_lo")]
+    msg = cs.new_wires(message_bytes, "msg")
+    cs.mark_input(msg)
+    bits = core.assert_bytes(cs, msg, "msg") + sha256.padding_byte_bits(cs, message_bytes)
+    out = sha256.sha256_blocks(cs, bits, None)
+    # `out` is h0..h7, bit i of a word at weight 2^i; the digest is the words big-endian, h0 first
+    for half, pub in enumerate(halves):
+        terms = {out[32 * (4 * half + w) + i]: 1 << (i + 32 * (3 - w)) for w in range(4) for i in range(32)}
+        cs.enforce_eq(LC(terms), LC.of(pub), "digest/pack")
+    return cs, msg
+
+
+def sha256_preimage_inputs(msg_wires: List[int], payload: Dict) -> Tuple[List[int], Dict[int, int]]:
+    """A request of `build_sha256_preimage`'s circuit -> (public signals,
+    private inputs).  The message comes as {"msg": [one int 0-255 a
+    byte]} or as {"msg_hex": "two hex digits a byte"} (a third of the
+    bytes on the wire, and written and parsed as one string).  A payload
+    of another length, with a value outside a byte or with digits that
+    are not hex raises."""
+    import hashlib
+
+    msg = bytes.fromhex(payload["msg_hex"]) if "msg_hex" in payload else bytes(payload["msg"])  # both refuse what is no byte
+    if len(msg) != len(msg_wires):
+        raise ValueError(f"the circuit hashes {len(msg_wires)} bytes, the request carries {len(msg)}")
+    digest = hashlib.sha256(msg).digest()
+    return [int.from_bytes(digest[:16], "big"), int.from_bytes(digest[16:], "big")], dict(zip(msg_wires, msg))
+
+
 def _build_regex_actor():
     """Minted from regexc (the reference's regex_to_circom L0 layer):
     see regexc.compiler.reveal_circuit."""
@@ -137,6 +179,15 @@ SPECS: Dict[str, CircuitSpec] = {
         CircuitSpec(
             "sha2b", lambda: build_sha2b()[0], 0,
             "two-block SHA-256, the benchmark's sha2b shape",
+        ),
+        CircuitSpec(
+            "sha256-64", lambda: build_sha256_preimage(64)[0], 2,
+            "SHA-256 of a 64-byte preimage, digest public, at the CI shape (two blocks)",
+        ),
+        CircuitSpec(
+            "sha256-4k", lambda: build_sha256_preimage(4096)[0], 2,
+            "SHA-256 of a 4,096-byte preimage, digest public: the benchmark's sha256-4k (65 blocks, 2^21 domain)",
+            flagship=True,
         ),
         CircuitSpec(
             "regex_actor", _build_regex_actor, 2,

@@ -32,7 +32,7 @@ def _log(*a):
     print("[zkp2p-tpu]", *a, file=sys.stderr, flush=True)
 
 
-def _build_circuit(name: str, header: int, body: int):
+def _build_circuit(name: str, header: int, body: int, message_bytes: int = 64):
     if name == "venmo":
         from ..models.venmo import VenmoParams, build_venmo_circuit
 
@@ -55,6 +55,13 @@ def _build_circuit(name: str, header: int, body: int):
         bits = core.assert_bytes(cs, msg)
         sha256.sha256_blocks(cs, bits, None)
         return cs, (None, msg)
+    if name == "sha256_preimage":
+        # the digest public and the padding wired in: `sha256` above takes
+        # pre-padded bytes and has no public signal
+        from ..models.registry import build_sha256_preimage
+
+        cs, msg = build_sha256_preimage(message_bytes)
+        return cs, (None, msg)
     if name == "toy":
         # smoke-test circuit: public out = (x*y)^2 over two byte inputs
         from ..field.bn254 import R
@@ -70,7 +77,7 @@ def _build_circuit(name: str, header: int, body: int):
         cs.enforce(LC.of(z), LC.of(z), LC.of(out), "sq")
         cs.compute(z, lambda a, b: a * b % R, [x, y])
         return cs, (None, [x, y, out])
-    raise SystemExit(f"unknown circuit {name!r} (have: venmo, email_verify, sha256, toy)")
+    raise SystemExit(f"unknown circuit {name!r} (have: venmo, email_verify, sha256, sha256_preimage, toy)")
 
 
 def cmd_setup(args):
@@ -82,7 +89,7 @@ def cmd_setup(args):
     os.makedirs(args.build_dir, exist_ok=True)
     t0 = time.perf_counter()
     _log(f"building circuit {args.circuit} ...")
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
     _log(f"constraints={cs.num_constraints} wires={cs.num_wires} ({time.perf_counter()-t0:.0f}s)")
     if not args.skip_audit:
         # the registry admission gate (docs/STATIC_ANALYSIS.md §circuit
@@ -92,7 +99,8 @@ def cmd_setup(args):
         from ..models.registry import SPECS
         from ..snark.analysis import audit_circuit, require_clean
 
-        spec = SPECS.get(args.circuit)
+        # the preimage circuit's layout is one at every --message-bytes
+        spec = SPECS.get("sha256-64" if args.circuit == "sha256_preimage" else args.circuit)
         rep = require_clean(audit_circuit(
             cs,
             name=f"{args.circuit}_{args.max_header}_{args.max_body}",
@@ -203,6 +211,19 @@ def _witness_for(args, cs, meta, source=None):
             email, modulus = make_twitter_email(key), key.n
         inputs = generate_email_verify_inputs(email, modulus, params, lay)
         return cs.witness(inputs.public_signals, inputs.seed), inputs.public_signals
+    elif args.circuit == "sha256_preimage":
+        from ..models.registry import sha256_preimage_inputs
+
+        msg = args.message
+        if source:
+            with open(source) as f:
+                msg = json.load(f)["message"]
+        data = (msg or "zkp2p").encode()
+        if len(data) > len(lay):
+            raise SystemExit(f"the message has {len(data)} bytes, --message-bytes is {len(lay)}")
+        # a shorter message is zero-filled: the circuit hashes exactly --message-bytes bytes
+        pub, seed = sha256_preimage_inputs(lay, {"msg": list(data.ljust(len(lay), b"\x00"))})
+        return cs.witness(pub, seed), pub
     elif args.circuit == "toy":
         from ..field.bn254 import R
 
@@ -266,7 +287,7 @@ def cmd_prove(args):
         _log(f"wrote {args.proof} {args.public}")
         return
 
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
     zk = _load_zkey(args)
     _check_zkey_matches(zk, cs)
     dpk = device_pk_from_zkey(zk, infer_widths=_infer_widths(args))
@@ -327,7 +348,7 @@ def cmd_batch(args):
         # sequential per-proof proves inside)
         from ..prover.native_prove import prove_native_batch as prove_tpu_batch  # noqa: F811
 
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
     zk = _load_zkey(args)
     _check_zkey_matches(zk, cs)
     dpk = device_pk_from_zkey(zk, infer_widths=_infer_widths(args))
@@ -362,11 +383,11 @@ def cmd_service(args):
     from ..pipeline.service import ProvingService
     from ..prover.groth16_tpu import device_pk_from_zkey
 
-    if args.circuit not in ("venmo", "email_verify"):
-        raise SystemExit("service supports the email circuits (venmo, email_verify)")
+    if args.circuit not in ("venmo", "email_verify", "sha256_preimage"):
+        raise SystemExit("service supports the email circuits (venmo, email_verify) and sha256_preimage")
     from ..formats.proof_json import load, vkey_from_json
 
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
     zk = _load_zkey(args)
     _check_zkey_matches(zk, cs)
     dpk = device_pk_from_zkey(zk, infer_widths=_infer_widths(args))
@@ -397,7 +418,11 @@ def cmd_service(args):
         stale_claim_s=args.stale_claim_s, deadline_s=args.deadline_s,
         spool_cap=args.spool_cap,
     )
-    make = ProvingService.for_venmo if args.circuit == "venmo" else ProvingService.for_email_verify
+    if args.circuit == "sha256_preimage":
+        def make(cs, msg_wires, _params, key, vk, **kw):
+            return ProvingService.for_sha256_preimage(cs, msg_wires, key, vk, **kw)
+    else:
+        make = ProvingService.for_venmo if args.circuit == "venmo" else ProvingService.for_email_verify
     replicas = getattr(args, "replicas", "1")
     if replicas != "1":
         # one replica a local device, in this process, on the one spool
@@ -458,6 +483,7 @@ def cmd_fleet(args):
             "--circuit", args.circuit,
             "--max-header", str(args.max_header),
             "--max-body", str(args.max_body),
+            "--message-bytes", str(args.message_bytes),
             "service",
             "--spool", args.spool,
             "--batch", str(args.batch),
@@ -606,7 +632,7 @@ def cmd_serve(args):
 
         if args.circuit != "venmo":
             raise SystemExit("/api/onramp proves venmo receipts; pass --circuit venmo")
-        cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+        cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
         zk = _load_zkey(args)
         _check_zkey_matches(zk, cs)
         prover = ProverBundle(cs=cs, dpk=device_pk_from_zkey(zk, infer_widths=_infer_widths(args)), params=meta[0], layout=meta[1])
@@ -763,7 +789,7 @@ def cmd_warm_cache(args):
     f0, b0 = _cache_entries()
     ev0, s0 = _compile_totals()
 
-    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body)
+    cs, meta = _build_circuit(args.circuit, args.max_header, args.max_body, args.message_bytes)
     from ..prover import device_pk
     from ..prover.groth16_tpu import prove_tpu_batch
     from ..snark.groth16 import setup
@@ -799,6 +825,8 @@ def main(argv=None):
     ap.add_argument("--circuit", default=os.environ.get("CIRCUIT_NAME", "sha256"))
     ap.add_argument("--max-header", type=int, default=256)
     ap.add_argument("--max-body", type=int, default=192)
+    ap.add_argument("--message-bytes", type=int, default=64,
+                    help="sha256_preimage: the fixed preimage length (4096: the benchmark's sha256-4k)")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     s = sub.add_parser("setup", help="build circuit + dev zkey + vkey + verifier.sol")

@@ -77,6 +77,8 @@ TERMINAL_STATES = (
 # a non-atomic uploader may still be writing it — before the sweep
 # judges it corrupt and terminals error-bad-input.
 TORN_REQ_GRACE_S = 2.0
+# a sweep that finds a request younger than this waits the difference out and lists again, once
+BURST_SETTLE_S = 0.05
 
 # Degradation ladder (last resort before error-failed-to-prove): each
 # rung re-proves the isolated request with one more fast path gated off,
@@ -1423,77 +1425,91 @@ class ProvingService:
         from .sched import sched_mode
 
         adaptive = sched_mode() == "adaptive"
-        pending: List[Request] = []
-        for fn in sorted(os.listdir(spool)):
-            if ".claim.stale." in fn:
-                # scavenge steal-aside litter: a taker SIGKILLed between
-                # its rename and its unlink leaves this behind, and no
-                # other path ever matches the name
-                p = os.path.join(spool, fn)
-                try:
-                    if time.time() - os.path.getmtime(p) > self.stale_claim_s:
-                        os.unlink(p)
-                except OSError:
-                    pass
-                continue
-            if not fn.endswith(".req.json"):
-                continue
-            base = fn[: -len(".req.json")]
-            if os.path.exists(os.path.join(spool, base + ".proof.json")) or os.path.exists(
-                os.path.join(spool, base + ".error.json")
-            ):
-                self._release_claim(os.path.join(spool, base))
-                continue
-            # a FRESH claim = a peer is on it right now: not claimable
-            # this sweep, and counting it as backlog would let the
-            # admission cap shed viable requests off an inflated number
-            # (stale claims pass through — they are takeover candidates)
-            try:
-                if time.time() - os.path.getmtime(os.path.join(spool, base + ".claim")) < self.stale_claim_s:
+        def scan() -> List[Request]:
+            found: List[Request] = []
+            for fn in sorted(os.listdir(spool)):
+                if ".claim.stale." in fn:
+                    # scavenge steal-aside litter: a taker SIGKILLed between
+                    # its rename and its unlink leaves this behind, and no
+                    # other path ever matches the name
+                    p = os.path.join(spool, fn)
+                    try:
+                        if time.time() - os.path.getmtime(p) > self.stale_claim_s:
+                            os.unlink(p)
+                    except OSError:
+                        pass
                     continue
-            except OSError:
-                pass  # no claim: free for the taking
-            fpath = os.path.join(spool, fn)
-            try:
-                with open(fpath) as f:
-                    payload = json.load(f)
-            except ValueError as e:
-                # torn/malformed .req.json (half-written upload,
-                # truncated copy): terminal it as error-bad-input and
-                # KEEP SWEEPING — one corrupt file must not sink the
-                # sweep and every batchmate behind it.  A YOUNG torn
-                # file gets the benefit of the doubt first: a
-                # non-atomic uploader (scp, cp) may still be writing
-                # it, and a permanent terminal on a request that was
-                # about to become valid is unrecoverable.
+                if not fn.endswith(".req.json"):
+                    continue
+                base = fn[: -len(".req.json")]
+                if os.path.exists(os.path.join(spool, base + ".proof.json")) or os.path.exists(
+                    os.path.join(spool, base + ".error.json")
+                ):
+                    self._release_claim(os.path.join(spool, base))
+                    continue
+                # a FRESH claim = a peer is on it right now: not claimable
+                # this sweep, and counting it as backlog would let the
+                # admission cap shed viable requests off an inflated number
+                # (stale claims pass through — they are takeover candidates)
                 try:
-                    if time.time() - os.path.getmtime(fpath) < TORN_REQ_GRACE_S:
-                        continue  # may still be mid-write: next sweep judges it
+                    if time.time() - os.path.getmtime(os.path.join(spool, base + ".claim")) < self.stale_claim_s:
+                        continue
                 except OSError:
-                    continue  # vanished: nothing to judge
-                req = Request(path=os.path.join(spool, base), payload={}, rid=base)
-                if self._try_claim(req.path):
-                    req.t_claim = time.time()
-                    self._terminal_error(spool, req, "error-bad-input", e, knobs, stats)
-                continue
-            except OSError:
-                continue  # vanished/unreadable this sweep: retry next sweep
-            try:
-                t_submit = os.path.getmtime(fpath)
-            except OSError:
-                t_submit = time.time()
-            # priority lane: explicit payload value wins, anything
-            # unrecognized falls to the configured default (bulk) — a
-            # typo'd priority must not mint a third lane
-            prio = payload.get("priority") if isinstance(payload, dict) else None
-            if prio not in ("interactive", "bulk"):
-                prio = self._priority_default
-            pending.append(
-                Request(
-                    path=os.path.join(spool, base), payload=payload, rid=base,
-                    t_submit=t_submit, priority=prio,
+                    pass  # no claim: free for the taking
+                fpath = os.path.join(spool, fn)
+                try:
+                    with open(fpath) as f:
+                        payload = json.load(f)
+                except ValueError as e:
+                    # torn/malformed .req.json (half-written upload,
+                    # truncated copy): terminal it as error-bad-input and
+                    # KEEP SWEEPING — one corrupt file must not sink the
+                    # sweep and every batchmate behind it.  A YOUNG torn
+                    # file gets the benefit of the doubt first: a
+                    # non-atomic uploader (scp, cp) may still be writing
+                    # it, and a permanent terminal on a request that was
+                    # about to become valid is unrecoverable.
+                    try:
+                        if time.time() - os.path.getmtime(fpath) < TORN_REQ_GRACE_S:
+                            continue  # may still be mid-write: next sweep judges it
+                    except OSError:
+                        continue  # vanished: nothing to judge
+                    req = Request(path=os.path.join(spool, base), payload={}, rid=base)
+                    if self._try_claim(req.path):
+                        req.t_claim = time.time()
+                        self._terminal_error(spool, req, "error-bad-input", e, knobs, stats)
+                    continue
+                except OSError:
+                    continue  # vanished/unreadable this sweep: retry next sweep
+                try:
+                    t_submit = os.path.getmtime(fpath)
+                except OSError:
+                    t_submit = time.time()
+                # priority lane: explicit payload value wins, anything
+                # unrecognized falls to the configured default (bulk) — a
+                # typo'd priority must not mint a third lane
+                prio = payload.get("priority") if isinstance(payload, dict) else None
+                if prio not in ("interactive", "bulk"):
+                    prio = self._priority_default
+                found.append(
+                    Request(
+                        path=os.path.join(spool, base), payload=payload, rid=base,
+                        t_submit=t_submit, priority=prio,
+                    )
                 )
-            )
+            return found
+
+        pending = scan()
+        if pending:
+            # a burst still arriving: callers that submit together take a few
+            # milliseconds to write their requests, and a listing between two
+            # of them would cut the burst into a full batch and a padded one
+            # (at 40 s a batch, PERF.md §6, PR 45) — let the youngest settle
+            # and list once more
+            age = time.time() - max(r.t_submit for r in pending)
+            if 0 <= age < BURST_SETTLE_S:
+                time.sleep(BURST_SETTLE_S - age)
+                pending = scan()
 
         # Admission control.  Adaptive arm: the controller plans the
         # whole sweep — expected-deadline-miss shedding (shed exactly
@@ -1924,6 +1940,19 @@ class ProvingService:
             return inputs.public_signals, inputs.seed
 
         return cls._from_inputs_fn(cs, dpk, vk, inputs_fn, **kw)
+
+    @classmethod
+    def for_sha256_preimage(cls, cs, msg_wires, dpk, vk, **kw) -> "ProvingService":
+        """Service wired for the fixed-length SHA-256 preimage circuit
+        (`models.registry.build_sha256_preimage`, whose `msg_wires` these
+        are): a request's payload is {"msg": [one int 0-255 a message
+        byte]} or {"msg_hex": "two hex digits a byte"}, and its proof's
+        two public signals are the SHA-256 digest of those bytes.  A
+        payload of another length, or with a value outside a byte, is
+        that request's error, not its batch's."""
+        from ..models.registry import sha256_preimage_inputs
+
+        return cls._from_inputs_fn(cs, dpk, vk, lambda payload: sha256_preimage_inputs(msg_wires, payload), **kw)
 
     def run(
         self,

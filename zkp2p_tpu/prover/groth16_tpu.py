@@ -34,6 +34,7 @@ one email at a time); this is the TPU data-parallel axis.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import secrets
 import threading
@@ -1052,12 +1053,19 @@ class _StageWatch:
 
             adopt_stack(stack)  # the spans nest under `device`, open on the proving thread
             adopt_context(ctx)
-            t_ready = t0
-            for name, chunk, t_enqueue, value, attrs in iter(self._q.get, None):
+            t_ready, failed = t0, False
+            for item in iter(self._q.get, None):
+                if isinstance(item, threading.Event):  # `settled`: every span enqueued before it is written
+                    item.set()
+                    continue
+                if failed:
+                    continue
+                name, chunk, t_enqueue, value, attrs = item
                 try:
                     jax.block_until_ready(value)
                 except Exception:  # noqa: BLE001 — the proving thread meets it where it reads the accumulator
-                    return
+                    failed = True  # no span more; whoever waits in `settled` is still answered
+                    continue
                 if name == "upload":  # the chunk's witnesses, not a stage: the stage clock stays
                     record(name, t_enqueue, time.time(), chunk=chunk, **attrs)
                     continue
@@ -1078,6 +1086,14 @@ class _StageWatch:
         arrived."""
         self._q.put(("upload", self.chunk, self._t, limbs, {"bytes": nbytes}))
         return limbs
+
+    def settled(self) -> Optional[float]:
+        """Returns when every stage enqueued so far has its result ready
+        and its span written: `t_ready`, the instant the last of them was."""
+        done = threading.Event()
+        self._q.put(done)
+        done.wait()
+        return self.t_ready
 
     def close(self) -> None:
         """Returns when the last stage enqueued has its result ready and
@@ -1699,7 +1715,12 @@ def prove_tpu_batch(
     one (`device_idle`, `_record_idle`), per key placement and feeding
     thread.  A batch of several chunks that waits one chunk out before it
     enqueues the next (`chunk < BATCH_CHUNK_MAX`) leaves the device empty
-    between them too: that wait is inside `device` and is not accounted."""
+    between them too: that wait is inside `device`, and `chunk_wait`
+    is its span (one a chunk after the first, `chunk` on it): from the
+    instant the last stage of the chunk before was ready to this chunk's
+    first stage being enqueued, so the wait's residue, the upload and
+    `to_mont`'s dispatch.  `zkp2p_prove_chunks_total` counts the chunks
+    proved, one padded by repeats included."""
     from ..utils.audit import sample_device_memory
     from ..utils.metrics import REGISTRY
     from ..utils.trace import thread_tally, trace
@@ -1762,19 +1783,23 @@ def prove_tpu_batch(
                     for i, span in enumerate(spans):
                         if i:
                             limbs = _chunk_limbs(span)
+                        wait = contextlib.nullcontext()
                         if i and chunk < BATCH_CHUNK_MAX:
                             # fewer than four at a time: the device's memory is the
                             # ceiling, and a chunk enqueued behind another has its
                             # buffers planned beside it — wait the last one out
                             jax.block_until_ready(parts[-1])
+                            wait = trace("chunk_wait", t0=watch.settled(), chunk=i)
                         watch.chunk = i
-                        if mesh is not None:
+                        with wait:
+                            if mesh is None:
+                                # one batched to_mont per chunk (not one device dispatch per
+                                # witness); the h_planes stage includes it; beside a pinned key
+                                w = FR.to_mont(watch.uploaded(
+                                    jnp.asarray(limbs) if key_dev is None else jax.device_put(limbs, key_dev), limbs.nbytes))
+                        if mesh is not None:  # the mesh road puts the chunk to its chips itself
                             parts.append(_prove_batch_sharded(dpk, limbs, mesh, watch))
                         else:
-                            # one batched to_mont per chunk (not one device dispatch per
-                            # witness); the h_planes stage includes it; beside a pinned key
-                            w = FR.to_mont(watch.uploaded(
-                                jnp.asarray(limbs) if key_dev is None else jax.device_put(limbs, key_dev), limbs.nbytes))
                             parts.append(_prove_device(dpk, w, watch=watch))
                         # sub-chunk HBM watermark: the batched pipeline's peak is
                         # linear in the vmapped chunk (r5: 15.75 G OOM at batch=16
@@ -1807,4 +1832,5 @@ def prove_tpu_batch(
             ]
             sample_device_memory("tpu/prove_batch")  # exit watermark: batch HBM peak
     REGISTRY.counter("zkp2p_proves_total", {"prover": "tpu"}).inc(len(witnesses))
+    REGISTRY.counter("zkp2p_prove_chunks_total").inc(len(spans))
     return proofs
